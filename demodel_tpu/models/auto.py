@@ -42,7 +42,9 @@ def model_from_pull(store, report, mesh=None, placement=None):
 
     ``placement`` (a delivered :class:`~demodel_tpu.sink.hbm.Placement`)
     supplies the weight arrays when given; otherwise weights are delivered
-    from the store now under the default plan.
+    from the store now under the default plan. Either way the loader
+    consumes them: ``placement.arrays`` is left holding only the tensors
+    the model did not take.
     """
     files = report["files"] if isinstance(report, dict) else [
         vars(f) for f in report.files]
@@ -57,11 +59,12 @@ def model_from_pull(store, report, mesh=None, placement=None):
 
         placement = deliver_report_to_hbm(store, report, mesh=mesh)
     weights = placement.arrays
+    n_tensors = len(weights)  # the loaders consume the mapping
 
     if model_type == "llama":
         _check_supported(config)
         cfg = llama_mod.LlamaConfig.from_hf(config)
-        params = load_llama_params(weights, cfg)
+        params = load_llama_params(weights, cfg, mesh=mesh)
         fn = functools.partial(llama_mod.forward, cfg=cfg, mesh=mesh)
     elif model_type == "gpt2":
         _check_supported(config)
@@ -77,5 +80,5 @@ def model_from_pull(store, report, mesh=None, placement=None):
         raise ValueError(f"unsupported model_type {model_type!r} "
                          "(supported: llama, gpt2, bert)")
     log.info("auto: built %s from pulled snapshot (%d tensors)",
-             model_type, len(weights))
+             model_type, n_tensors)
     return fn, params, cfg

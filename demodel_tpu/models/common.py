@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from demodel_tpu.utils.env import env_bool
+
 
 def rms_norm(x, weight, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
@@ -28,12 +30,8 @@ def layer_norm(x, weight, bias, eps: float = 1e-5):
 
 def use_flash_attention() -> bool:
     """Route model attention through the fused pallas kernel
-    (ops/flash_attention.py)? DEMODEL_FLASH_ATTN forces either way;
-    unset, the default is ON on a TPU backend once the committed on-chip
-    parity record exists (ops/flash_default.py — VERDICT r4 #2), OFF
-    elsewhere: the einsum path lets XLA fuse freely at short sequence,
-    flash wins once the score tensor or GQA-repeated KV cache dominates
-    HBM."""
-    from demodel_tpu.ops.flash_default import use_flash_attention as _p
-
-    return _p()
+    (ops/flash_attention.py)? Only when ``DEMODEL_FLASH_ATTN`` says so:
+    the einsum path lets XLA fuse freely at short sequence, and whether
+    flash wins once the score tensor or the GQA-repeated KV cache
+    dominates HBM has not been measured on the chip."""
+    return env_bool("DEMODEL_FLASH_ATTN")

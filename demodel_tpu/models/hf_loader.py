@@ -6,29 +6,54 @@ rebuilds each family's params pytree. torch ``nn.Linear`` stores
 ``[out, in]`` — those transpose on the way in; GPT-2's Conv1D already
 stores ``[in, out]`` and loads verbatim. Optional name prefixes
 ("model.", "transformer.", "bert.") are stripped automatically.
+
+A placed ``jax.Array`` never leaves the device: it enters the tree as it
+is, or transposed where it lives. The loaders CONSUME the mapping — each
+tensor is popped as it enters the tree — so a delivered weight is freed
+as soon as its transposed copy exists and boot holds about one copy of
+the model in HBM, not two.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
-import numpy as np
 
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
-from demodel_tpu.models.llama import LlamaConfig
+from demodel_tpu.models.llama import LlamaConfig, param_shardings
 
 _PREFIXES = ("", "model.", "transformer.", "bert.")
+
+
+@functools.lru_cache(maxsize=None)
+def _placer(transpose: bool, sharding):
+    """One jitted (transpose +) reshard per target layout — the layers of
+    a model share it, so it compiles once per distinct weight shape."""
+    return jax.jit((lambda x: x.T) if transpose else (lambda x: x),
+                   out_shardings=sharding)
+
+
+def _lay(arr, transpose: bool = False, sharding=None):
+    """``arr`` as a tree leaf: under ``sharding`` (the model's own layout
+    for this leaf) when given, else with the placement it arrived with."""
+    if sharding is not None:
+        return _placer(transpose, sharding)(arr)
+    if not isinstance(arr, jax.Array):
+        arr = jnp.asarray(arr)  # host numpy (tests, tools)
+    return arr.T if transpose else arr
 
 
 class _Weights:
     def __init__(self, weights: dict):
         self.w = weights
 
-    def get(self, name: str, transpose: bool = False):
+    def get(self, name: str, transpose: bool = False, sharding=None):
         for p in _PREFIXES:
             if p + name in self.w:
-                arr = jnp.asarray(np.asarray(self.w[p + name]))
-                return arr.T if transpose else arr
+                return _lay(self.w.pop(p + name), transpose, sharding)
         raise KeyError(f"checkpoint has no tensor {name!r} "
                        f"(tried prefixes {_PREFIXES})")
 
@@ -36,31 +61,43 @@ class _Weights:
         return any(p + name in self.w for p in _PREFIXES)
 
 
-def load_llama_params(weights: dict, cfg: LlamaConfig) -> dict:
+def load_llama_params(weights: dict, cfg: LlamaConfig, mesh=None) -> dict:
+    """``mesh`` lays every leaf out as :func:`llama.param_shardings`
+    wants it (column/row-parallel over ``tp``): the delivery plan's
+    leading-axis shards are re-laid on the mesh's devices."""
     w = _Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
     layers = []
     for i in range(cfg.num_hidden_layers):
         pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
         layers.append({
-            "attn_norm": w.get(pre + "input_layernorm.weight"),
-            "q_proj": w.get(pre + "self_attn.q_proj.weight", transpose=True),
-            "k_proj": w.get(pre + "self_attn.k_proj.weight", transpose=True),
-            "v_proj": w.get(pre + "self_attn.v_proj.weight", transpose=True),
-            "o_proj": w.get(pre + "self_attn.o_proj.weight", transpose=True),
-            "mlp_norm": w.get(pre + "post_attention_layernorm.weight"),
-            "gate_proj": w.get(pre + "mlp.gate_proj.weight", transpose=True),
-            "up_proj": w.get(pre + "mlp.up_proj.weight", transpose=True),
-            "down_proj": w.get(pre + "mlp.down_proj.weight", transpose=True),
+            "attn_norm": w.get(pre + "input_layernorm.weight",
+                               sharding=lsh.get("attn_norm")),
+            "q_proj": lin("self_attn.q_proj.weight", "q_proj"),
+            "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+            "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            "mlp_norm": w.get(pre + "post_attention_layernorm.weight",
+                              sharding=lsh.get("mlp_norm")),
+            "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+            "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+            "down_proj": lin("mlp.down_proj.weight", "down_proj"),
         })
-    embed = w.get("embed_tokens.weight")
+    embed = w.get("embed_tokens.weight", sharding=sh.get("embed"))
     if w.has("lm_head.weight"):
-        head = w.get("lm_head.weight", transpose=True)
+        head = w.get("lm_head.weight", transpose=True,
+                     sharding=sh.get("lm_head"))
     else:  # tied embeddings
-        head = embed.T
+        head = _lay(embed, True, sh.get("lm_head"))
     return {
         "embed": embed,
         "layers": layers,
-        "final_norm": w.get("norm.weight"),
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
         "lm_head": head,
     }
 

@@ -63,6 +63,11 @@ class LlamaConfig:
                 "num_key_value_heads", config.get("num_attention_heads", 32)),
             rope_theta=config.get("rope_theta", 10000.0),
             rms_norm_eps=config.get("rms_norm_eps", 1e-6),
+            # the checkpoint's dtype (transformers writes ``torch_dtype``
+            # up to 4.55 and ``dtype`` after): caches and the KV pool are
+            # built in it, so they agree with the weights
+            dtype=(config.get("torch_dtype") or config.get("dtype")
+                   or "float32"),
         )
 
 
@@ -157,13 +162,14 @@ def _rope(x, positions, theta: float):
 def _head_align(x, mesh: Mesh | None):
     """Constrain [B,T,H,hd] to a HEAD-aligned tp sharding (or replicate
     when the head count doesn't divide tp). Without this, a column-sharded
-    projection reshape leaves each shard holding *half a head*, and the
-    rotate-half slice+concat inside :func:`_rope` crosses the shard
-    boundary — a combination this jax/XLA-CPU build miscompiles under
-    multi-axis meshes (wrong VALUES, not just wrong layout; the
-    sp-mesh odd-prompt decode divergence ROADMAP carried). Head-aligned
-    shards are also the layout TP attention wants: every later op in the
-    cache path is per-head."""
+    projection reshape can leave each shard holding *part of a head*, and
+    the rotate-half slice+concat inside :func:`_rope` then crosses the
+    shard boundary. A layout choice, not a correctness fix: jax 0.4.37's
+    XLA-CPU miscompiled that combination under multi-axis meshes, the
+    installed 0.9.0 gives the same logits with and without the constraint
+    (sp, tp and dp×tp meshes, odd prompt — checked in PR 21). Head-aligned
+    shards are the layout TP attention wants: every later op in the cache
+    path is per-head."""
     if mesh is None:
         return x
     tp = int(mesh.shape.get("tp", 1))

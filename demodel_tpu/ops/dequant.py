@@ -1,28 +1,28 @@
-"""On-device GGUF dequantization (pallas kernels + jnp fallback).
+"""On-device GGUF dequantization.
 
 The HBM sink ships the *quantized* payload over the host→device link and
 widens on device (SURVEY.md §2.3 "Sharded HBM placement"): for Q8_0 that is
-a 3.8× link saving over shipping f32. Dispatch per format:
+a 3.8× link saving over shipping f32. Every format runs as fused jnp math
+— the transform is bandwidth-bound elementwise work, which is XLA's job:
 
-- **Q8_0 / Q4_0**: a pallas kernel over 256-row 2-D tiles (any block
-  count — row tails are padded and sliced off) on real TPU; pure-jnp
-  math off-TPU (the interpreter executes grids in Python — minutes per
-  tensor).
-- **K-quants (Q2_K…Q6_K)**: always the fused-jnp math path at runtime —
-  the bit-unpacking layouts (12/16-byte operands, rank-1 scale vectors)
-  are lane-hostile and their one-super-block kernels do not satisfy
-  Mosaic's tiling rules on real TPU; XLA's fused elementwise graph is
-  the right tool for this bandwidth-bound transform. The kernels remain
-  as an interpret-only parity oracle under DEMODEL_FORCE_PALLAS.
+- **Q8_0 / Q4_0**: the Pallas kernels these once had compiled under
+  Mosaic with bit-identical output and lost to this math on the v5e at
+  every size tried (PR 21: a 4096×4096 tensor under jit, 1.86 ms against
+  0.53 ms for Q8_0 and 1.89 against 1.05 for Q4_0; called eagerly per
+  tensor, as the sink does, 120–165 ms a call whatever the size).
+  A kernel that comes back has those numbers to beat.
+- **K-quants (Q2_K…Q6_K)**: the bit-unpacking layouts (12/16-byte
+  operands, rank-1 scale vectors) are lane-hostile and their
+  one-super-block kernels do not satisfy Mosaic's tiling rules on real
+  TPU. The kernels remain as an interpret-only parity oracle under
+  DEMODEL_FORCE_PALLAS.
 
 Bit layouts follow the llama.cpp/ggml block spec; the numpy decoders in
 :mod:`demodel_tpu.formats.gguf` (``REF_DEQUANT``) are the normative
-reference these kernels are tested against (tests/test_dequant.py).
+reference this math is tested against (tests/test_dequant.py).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,34 +31,14 @@ from jax.experimental import pallas as pl
 
 from demodel_tpu.formats import gguf
 
-#: quant blocks (rows) per pallas grid step for Q4_0/Q8_0. 256 rows keeps
-#: every operand Mosaic-tileable: sublane tiling is 8 (f32 scales), 16
-#: (bf16 out) and 32 (int8 payload), and 256 is a multiple of all three —
-#: the old rank-1 (8,)-row blocks failed Mosaic's rank-1 tiling check on
-#: the first real-chip compile (round 5)
-Q_TILE = 256
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
 
 def _force_pallas() -> bool:
-    """DEMODEL_FORCE_PALLAS=1 pins the pallas path regardless of backend
-    (the kernel parity tests set it; interpret mode executes the grid in
-    Python)."""
+    """DEMODEL_FORCE_PALLAS=1 runs the K-quant math through its
+    interpret-mode pallas oracle (the kernel parity tests set it; the
+    interpreter executes the grid in Python)."""
     import os
 
     return os.environ.get("DEMODEL_FORCE_PALLAS", "").strip() == "1"
-
-
-def _use_pallas() -> bool:
-    """Pallas on the real chip; vectorized jnp elsewhere. The interpreter
-    executes the grid step-by-step in Python — measured 267 s for ONE
-    8M-element Q8_0 tensor on this host, vs <1 s for the identical
-    `_math` jnp — so off-TPU delivery takes the math path and the kernels
-    stay covered by the dedicated kernel tests."""
-    return _force_pallas() or jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------- Q8_0/Q4_0
@@ -69,47 +49,9 @@ def _q8_0_math(d, qs, out_dtype):
             * qs.astype(jnp.float32)).astype(out_dtype)
 
 
-def _q8_0_kernel(d_ref, qs_ref, o_ref, *, out_dtype):
-    # d block is (R, 1) f32 — broadcasts across the 32 lane columns
-    o_ref[...] = (d_ref[...] * qs_ref[...].astype(jnp.float32)).astype(
-        out_dtype)
-
-
-def _pad_rows(x, nbp: int):
-    nb = x.shape[0]
-    if nbp == nb:
-        return jnp.asarray(x)
-    widths = [(0, nbp - nb)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(jnp.asarray(x), widths)
-
-
 def dequant_q8_0(d, qs, out_dtype=jnp.bfloat16):
     """d: (nb,) f16, qs: (nb, 32) i8 → flat (nb*32,) out_dtype."""
-    nb = d.shape[0]
-    if nb == 0 or not _use_pallas():
-        return _q8_0_math(jnp.asarray(d), jnp.asarray(qs), out_dtype).reshape(-1)
-    nbp = -(-nb // Q_TILE) * Q_TILE  # pad the row tail; sliced off below
-    dp = _pad_rows(jnp.asarray(d).astype(jnp.float32), nbp).reshape(nbp, 1)
-    qsp = _pad_rows(qs, nbp)
-    try:
-        out = pl.pallas_call(
-            functools.partial(_q8_0_kernel, out_dtype=out_dtype),
-            grid=(nbp // Q_TILE,),
-            in_specs=[pl.BlockSpec((Q_TILE, 1), lambda i: (i, 0)),
-                      pl.BlockSpec((Q_TILE, gguf.QK), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((Q_TILE, gguf.QK), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((nbp, gguf.QK), out_dtype),
-            interpret=_interpret(),
-        )(dp, qsp)
-    except Exception:  # noqa: BLE001 — Mosaic compile errors vary by version
-        # a Mosaic tiling rejection on some chip generation must degrade
-        # to the (slower, correct) jnp math, not fail the whole delivery;
-        # the parity oracle pins the kernel, so surface the error there
-        if _force_pallas():
-            raise
-        return _q8_0_math(jnp.asarray(d), jnp.asarray(qs),
-                          out_dtype).reshape(-1)
-    return out.reshape(-1)[:nb * gguf.QK]
+    return _q8_0_math(jnp.asarray(d), jnp.asarray(qs), out_dtype).reshape(-1)
 
 
 def _q4_0_math(d, qs, out_dtype):
@@ -120,39 +62,9 @@ def _q4_0_math(d, qs, out_dtype):
     return (d.astype(jnp.float32)[:, None] * q).astype(out_dtype)
 
 
-def _q4_0_kernel(d_ref, qs_ref, o_ref, *, out_dtype):
-    qs = qs_ref[...].astype(jnp.int32)
-    lo = (qs & 0xF) - 8
-    hi = (qs >> 4) - 8
-    q = jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
-    o_ref[...] = (d_ref[...] * q).astype(out_dtype)
-
-
 def dequant_q4_0(d, qs, out_dtype=jnp.bfloat16):
     """d: (nb,) f16, qs: (nb, 16) u8 → flat (nb*32,) out_dtype."""
-    nb = d.shape[0]
-    if nb == 0 or not _use_pallas():
-        return _q4_0_math(jnp.asarray(d), jnp.asarray(qs), out_dtype).reshape(-1)
-    nbp = -(-nb // Q_TILE) * Q_TILE
-    dp = _pad_rows(jnp.asarray(d).astype(jnp.float32), nbp).reshape(nbp, 1)
-    qsp = _pad_rows(qs, nbp)
-    try:
-        out = pl.pallas_call(
-            functools.partial(_q4_0_kernel, out_dtype=out_dtype),
-            grid=(nbp // Q_TILE,),
-            in_specs=[pl.BlockSpec((Q_TILE, 1), lambda i: (i, 0)),
-                      pl.BlockSpec((Q_TILE, gguf.QK // 2), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((Q_TILE, gguf.QK), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((nbp, gguf.QK), out_dtype),
-            interpret=_interpret(),
-        )(dp, qsp)
-    except Exception:  # noqa: BLE001 — Mosaic compile errors vary by version
-        # same degrade-not-crash stance as dequant_q8_0 above
-        if _force_pallas():
-            raise
-        return _q4_0_math(jnp.asarray(d), jnp.asarray(qs),
-                          out_dtype).reshape(-1)
-    return out.reshape(-1)[:nb * gguf.QK]
+    return _q4_0_math(jnp.asarray(d), jnp.asarray(qs), out_dtype).reshape(-1)
 
 
 # ----------------------------------------------------------------- K-quants

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from demodel_tpu.utils.env import env_bool
+
 NEG_INF = -1e30  # large-but-finite: -inf rows would NaN through exp/where
 
 
@@ -47,11 +49,8 @@ def _use_flash_ring() -> bool:
     """Compute each ring step with the fused pallas kernel
     (ops/flash_attention.py), combining per-step partials in log space —
     no (B,H,Tq,Tk) score tensor per step, and no GQA head repeat riding
-    the ppermute? DEMODEL_FLASH_RING forces either way; unset, defaults
-    ON on validated TPU silicon (ops/flash_default.py)."""
-    from demodel_tpu.ops.flash_default import use_flash_ring as _p
-
-    return _p()
+    the ppermute? Only when ``DEMODEL_FLASH_RING`` says so."""
+    return env_bool("DEMODEL_FLASH_RING")
 
 
 def _ring_attention_flash(q, k, v, axis_name, causal, scale, kv_len):

@@ -16,25 +16,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def shard_map_nocheck(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with the static replication checker disabled, across
-    the jax API rename (``check_rep`` until 0.5, ``check_vma`` from 0.6).
+    """``shard_map`` with the static replication checker disabled.
     Collective outputs here ARE identical across the mapped axis, but the
-    checker can't statically infer that in either spelling."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    checker can't statically infer that."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def redistribute(arr: jax.Array, sharding: NamedSharding) -> jax.Array:

@@ -70,10 +70,15 @@ def load_model(model: str, cfg, *, source: str = "hf",
     tiered store / peer plane (:func:`delivery.pull_to_hbm` — cache
     hits serve from disk/RAM tiers, misses ride single-flight), place
     the weights, and start serving them. ``cfg`` is the
-    :class:`~demodel_tpu.config.ProxyConfig` naming the store."""
+    :class:`~demodel_tpu.config.ProxyConfig` naming the store. ``mesh``
+    defaults to every local device (``tp`` = device count); delivery,
+    the loader and the engine all get the one resolved here."""
     from demodel_tpu import delivery
     from demodel_tpu.models import auto, llama
+    from demodel_tpu.parallel.mesh import make_mesh
 
+    if mesh is None:
+        mesh = make_mesh()
     with trace.span("serve.load-model", model=model, source=source):
         report, placed = delivery.pull_to_hbm(
             model, cfg, source=source, revision=revision,

@@ -17,10 +17,10 @@ running sequences into a dense ``[B, S, Hkv, hd]`` view
 business, which is what makes admission/eviction a host-side list
 operation instead of a device reshape.
 
-Pool arrays are host numpy on purpose: the pool is the *memory ledger*
-(alloc/free exactness, budget-bounded admission), while compute shapes
-stay static for jit via the scheduler's bucketing. A TPU resident-pool
-variant slots in behind the same lease API.
+Pool arrays are host numpy in the model's dtype (``ml_dtypes.bfloat16``
+for a bf16 checkpoint): the pool is the *memory ledger* (alloc/free
+exactness, budget-bounded admission), while compute shapes stay static
+for jit via the scheduler's bucketing.
 """
 
 from __future__ import annotations
@@ -28,6 +28,7 @@ from __future__ import annotations
 import threading
 from typing import Any
 
+import ml_dtypes  # noqa: F401 — registers bfloat16 with numpy's dtype names
 import numpy as np
 
 from demodel_tpu.tier import TierBudget

@@ -208,14 +208,22 @@ class GenEngine:
         import jax
 
         from demodel_tpu.models import llama
+        from demodel_tpu.utils import compile_cache
 
+        compile_cache.place()
+        if params["embed"].dtype != jax.numpy.dtype(cfg.dtype):
+            # config.json and the safetensors disagree: say so at boot,
+            # not as a dtype error inside the first request's prefill
+            raise ValueError(
+                f"weights are {params['embed'].dtype} but the model "
+                f"config says {cfg.dtype}")
         self.params = params
         self.cfg = cfg
         self.mesh = mesh
         self.model = model
         self.pool = pool if pool is not None else KVBlockPool(
             cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim,
-            block_tokens=block_tokens, budget_mb=kv_mb)
+            block_tokens=block_tokens, budget_mb=kv_mb, dtype=cfg.dtype)
         self.max_batch = int(max_batch or gen_max_batch())
         self.max_new_cap = int(max_new_tokens or gen_max_new_tokens())
         self.admission = AdmissionQueue(

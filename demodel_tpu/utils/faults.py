@@ -45,7 +45,7 @@ log = get_logger("faults")
 T = TypeVar("T")
 
 
-# ------------------------------------------------------------ error taxonomy
+# ------------------------------------------------------------ error classes
 
 
 class WireError(IOError):

@@ -7,7 +7,7 @@ included — while injecting faults per a seeded :class:`FaultPlan`:
 - ``reset-at-byte``: serve N body bytes, then kill the socket with an RST
   (``SO_LINGER 0``) — the sharpest mid-window failure shape;
 - ``stall``: sit on the request past the client's read deadline, then
-  drop the connection (the wedged-tunnel shape);
+  drop the connection (the hung-but-accepting-peer shape);
 - ``503-burst``: answer ``503 Retry-After: 0`` for the next K matching
   requests (the bounded-pool overflow shape);
 - ``truncate``: promise the full Content-Length, deliver N bytes, close

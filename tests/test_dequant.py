@@ -1,8 +1,9 @@
-"""On-device dequant kernels vs the normative numpy decoders.
+"""On-device dequant vs the normative numpy decoders: the jnp math every
+format runs as, and the K-quants' interpret-mode pallas oracle of it.
 
 Random packed bytes (every bit pattern is a valid block) exercise the full
 bit-layout space; end-to-end cases additionally run encode → GGUF container
-→ decode_raw → kernel and compare against the reference decode of the same
+→ decode_raw → dequant and compare against the reference decode of the same
 bytes."""
 
 import numpy as np
@@ -15,8 +16,8 @@ from demodel_tpu.ops import dequant as dq
 
 @pytest.fixture(autouse=True)
 def _force_pallas(monkeypatch):
-    """These are the KERNEL tests: pin the pallas path (interpret mode on
-    CPU) even though off-TPU delivery takes the vectorized math path."""
+    """Run the K-quant math through its pallas oracle (interpret mode);
+    Q8_0/Q4_0 have no kernel and run the math itself."""
     monkeypatch.setenv("DEMODEL_FORCE_PALLAS", "1")
 
 
@@ -76,12 +77,12 @@ def _compare(ggml_type: int, nblocks: int):
 
 
 @pytest.mark.parametrize("nblocks", [8, 64, 2048])
-def test_q8_0_pallas_matches_reference(nblocks):
+def test_q8_0_matches_reference(nblocks):
     _compare(gguf.GGML_Q8_0, nblocks)
 
 
 @pytest.mark.parametrize("nblocks", [8, 64, 2048])
-def test_q4_0_pallas_matches_reference(nblocks):
+def test_q4_0_matches_reference(nblocks):
     _compare(gguf.GGML_Q4_0, nblocks)
 
 
@@ -110,9 +111,8 @@ def test_q6_k_pallas_matches_reference(nblocks):
     _compare(gguf.GGML_Q6_K, nblocks)
 
 
-def test_odd_block_count_falls_back():
-    """Block counts that don't tile the pallas grid take the jnp fallback —
-    numerically identical, no crash."""
+def test_odd_block_counts():
+    """Block counts of no convenient multiple decode the same."""
     for nb in (1, 3, 9):
         _compare(gguf.GGML_Q8_0, nb)
         _compare(gguf.GGML_Q4_0, nb)

@@ -222,8 +222,8 @@ def test_503_burst_is_retried_through(tmp_path, mesh8):
 
 
 def test_stall_past_deadline_fails_over(tmp_path, mesh8, monkeypatch):
-    """A peer that accepts and then sits on the request (the wedged-tunnel
-    shape) costs one read-timeout, then the window fails over to the
+    """A peer that accepts and then sits on the request (hung, but still
+    accepting) costs one read-timeout, then the window fails over to the
     healthy twin — bounded wall-clock, bytes exact."""
     from demodel_tpu.sink.remote import pull_manifest_to_hbm
 

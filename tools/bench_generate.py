@@ -38,8 +38,6 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def _env_i(name: str, default: int) -> int:
     raw = os.environ.get(name, "").strip()
