@@ -497,14 +497,17 @@ def make_handler(registry: RestoreRegistry, proxy=None):
                 self._send(413, b'{"error":"body exceeds 8 MiB limit"}')
                 return
             try:
-                body = json.loads(self.rfile.read(length))
-                prompt = body["prompt"]
-                if not isinstance(prompt, list) or not prompt:
-                    raise ValueError(
-                        "prompt must be a non-empty list of token ids")
-                max_new = int(body.get("max_new_tokens", 16))
-                stream = bool(body.get("stream", False))
-                timeout = float(body.get("timeout", 300.0))
+                # the handler's own share of a request: body read, parse
+                # and validation, up to the hand-off to the engine
+                with trace.span("serve.http-parse", bytes=length):
+                    body = json.loads(self.rfile.read(length))
+                    prompt = body["prompt"]
+                    if not isinstance(prompt, list) or not prompt:
+                        raise ValueError("prompt must be a non-empty "
+                                         "list of token ids")
+                    max_new = int(body.get("max_new_tokens", 16))
+                    stream = bool(body.get("stream", False))
+                    timeout = float(body.get("timeout", 300.0))
             except Exception as e:  # noqa: BLE001 — bad body → client error
                 metrics.HUB.inc(labeled("gen_http_total", code="400"))
                 self._send(400, json.dumps({"error": str(e)}).encode())

@@ -32,6 +32,7 @@ import ml_dtypes  # noqa: F401 — registers bfloat16 with numpy's dtype names
 import numpy as np
 
 from demodel_tpu.tier import TierBudget
+from demodel_tpu.utils import trace
 from demodel_tpu.utils.env import gen_block_tokens, gen_kv_mb
 from demodel_tpu.utils.logging import get_logger
 from demodel_tpu.utils.metrics import HUB
@@ -43,6 +44,7 @@ log = get_logger("serve.kvcache")
 HUB.set_gauge("gen_kv_blocks_in_use", 0)
 HUB.inc("gen_kv_blocks_alloc_total", 0)
 HUB.inc("gen_kv_blocks_freed_total", 0)
+HUB.inc("gen_d2h_bytes_total", 0)
 
 
 class PoolExhausted(Exception):
@@ -147,16 +149,22 @@ class KVBlockPool:
     def write_prompt(self, lease: BlockLease, kv) -> None:
         """Page a prefill's KV out into the lease: ``kv`` is the
         per-layer ``(k, v)`` list from ``step_prefill``, each
-        [1, T, Hkv, hd]."""
-        k = np.stack([np.asarray(lk[0]) for lk, _lv in kv])
-        v = np.stack([np.asarray(lv[0]) for _lk, lv in kv])
-        T = k.shape[1]
-        bs = self.block_tokens
-        for j in range(0, T, bs):
-            blk = lease.blocks[j // bs]
-            n = min(bs, T - j)
-            self.k[:, blk, :n] = k[:, j:j + n]
-            self.v[:, blk, :n] = v[:, j:j + n]
+        [1, T, Hkv, hd]. The ``serve.kv-pageout`` span covers the 2L
+        pulls to the host and the block copies; its ``bytes`` (what
+        crosses device → host, from the shapes) feed
+        ``gen_d2h_bytes_total``."""
+        T = kv[0][0].shape[1]
+        nbytes = sum(lk.nbytes + lv.nbytes for lk, lv in kv)
+        with trace.span("serve.kv-pageout", prompt=T, bytes=nbytes):
+            k = np.stack([np.asarray(lk[0]) for lk, _lv in kv])
+            v = np.stack([np.asarray(lv[0]) for _lk, lv in kv])
+            bs = self.block_tokens
+            for j in range(0, T, bs):
+                blk = lease.blocks[j // bs]
+                n = min(bs, T - j)
+                self.k[:, blk, :n] = k[:, j:j + n]
+                self.v[:, blk, :n] = v[:, j:j + n]
+            HUB.inc("gen_d2h_bytes_total", nbytes)
 
     def write_token(self, lease: BlockLease, pos: int, k, v) -> None:
         """Write one decoded position: ``k``/``v`` are [L, Hkv, hd]."""
@@ -165,23 +173,34 @@ class KVBlockPool:
         self.k[:, blk, off] = k
         self.v[:, blk, off] = v
 
-    def gather(self, leases: list[BlockLease], width: int):
-        """Dense [L, B, width, Hkv, hd] K and V views of ``leases`` —
+    def gather(self, leases: list[BlockLease], width: int, rows: int):
+        """Dense [L, rows, width, Hkv, hd] K and V views of ``leases`` —
         the per-step ragged batch the model consumes. Rows past a
         sequence's filled length are stale pool bytes; the model masks
         them by length (see ``llama.step_decode``), so short sequences
-        simply index block 0 for table slots they don't have."""
-        bs = self.block_tokens
-        nb = -(-int(width) // bs)
-        ids = np.zeros((len(leases), nb), np.int64)
-        for i, lease in enumerate(leases):
-            got = lease.blocks[:nb]
-            ids[i, :len(got)] = got
+        simply index block 0 for table slots they don't have. ``rows``
+        (at least one per lease) pads the batch with zero rows up to the
+        scheduler's jit bucket. The ``serve.kv-gather`` span covers the
+        gather and the pad; its ``bytes`` are the two rectangles it
+        returns, from the pool's geometry."""
+        B, width, rows = len(leases), int(width), int(rows)
         L = self.k.shape[0]
-        k = self.k[:, ids].reshape(L, len(leases), nb * bs,
-                                   *self.k.shape[3:])[:, :, :width]
-        v = self.v[:, ids].reshape(L, len(leases), nb * bs,
-                                   *self.v.shape[3:])[:, :, :width]
+        bs = self.block_tokens
+        with trace.span("serve.kv-gather", batch=B, width=width,
+                        bytes=rows * width * (self.block_bytes // bs)):
+            nb = -(-width // bs)
+            ids = np.zeros((B, nb), np.int64)
+            for i, lease in enumerate(leases):
+                got = lease.blocks[:nb]
+                ids[i, :len(got)] = got
+            k = self.k[:, ids].reshape(L, B, nb * bs,
+                                       *self.k.shape[3:])[:, :, :width]
+            v = self.v[:, ids].reshape(L, B, nb * bs,
+                                       *self.v.shape[3:])[:, :, :width]
+            if rows > B:
+                pad = ((0, 0), (0, rows - B)) + ((0, 0),) * (k.ndim - 2)
+                k = np.pad(k, pad)
+                v = np.pad(v, pad)
         return k, v
 
     # ------------------------------------------------------------ intro

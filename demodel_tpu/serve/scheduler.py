@@ -46,6 +46,9 @@ HUB.inc(labeled("gen_tokens_total", stage="decode"), 0)
 HUB.inc("gen_requests_total", 0)
 HUB.inc("gen_rejected_total", 0)
 HUB.inc("gen_evicted_total", 0)
+HUB.inc("gen_h2d_bytes_total", 0)  # its twin, d2h, is the kv pool's
+HUB.inc(labeled("gen_new_shapes_total", stage="prefill"), 0)
+HUB.inc(labeled("gen_new_shapes_total", stage="decode"), 0)
 HUB.set_gauge("gen_queue_depth", 0)
 HUB.set_gauge("gen_running", 0)
 
@@ -74,6 +77,10 @@ class Request:
         self.tokens: list[int] = []
         self.error: str | None = None
         self.ticket: "AdmissionTicket | None" = None
+        #: the submitting thread's ``serve.admit`` span as a W3C header:
+        #: the engine thread parents ``serve.prefill`` on it, so a
+        #: request's handler and engine spans share one trace
+        self.traceparent: str | None = None
         self.submitted_s = time.time()
         self.started_s: float | None = None
         self.finished_s: float | None = None
@@ -211,6 +218,9 @@ class GenEngine:
         from demodel_tpu.utils import compile_cache
 
         compile_cache.place()
+        # every span of the process on the profiler's clock: with no
+        # profiler session the annotation does nothing
+        trace.set_annotator(jax.profiler.TraceAnnotation)
         if params["embed"].dtype != jax.numpy.dtype(cfg.dtype):
             # config.json and the safetensors disagree: say so at boot,
             # not as a dtype error inside the first request's prefill
@@ -240,6 +250,10 @@ class GenEngine:
         self._work = threading.Condition(threading.Lock())
         self._ids = itertools.count(1)
         self._tokens = {"prefill": 0, "decode": 0}
+        #: prompt lengths and (batch bucket, width) pairs already run
+        #: (engine thread only): the first run of each compiles or loads
+        #: a program inside its ``-device`` span
+        self._shapes_run: set[tuple] = set()
         self.started_s = time.time()
         self._thread = threading.Thread(target=self._run, name="gen-engine",
                                         daemon=True)
@@ -307,6 +321,10 @@ class GenEngine:
         req = Request(next(self._ids), toks, want)
         rejected: QueueOverflow | None = None
         with trace.span("serve.admit", request=req.id, prompt=len(toks)):
+            # a head-sampled-out request keeps none: its prefill then
+            # rolls for itself, as every prefill did before
+            if not trace.subtree_suppressed():
+                req.traceparent = trace.traceparent()
             with self._work:
                 if self._stop:
                     raise RuntimeError("engine stopped")
@@ -420,18 +438,38 @@ class GenEngine:
         self._start_seq(req, lease)
         return True
 
+    def _first_run(self, stage: str, *shape: int) -> bool:
+        """True the first time the engine runs this shape, counted in
+        ``gen_new_shapes_total``."""
+        key = (stage, *shape)
+        if key in self._shapes_run:
+            return False
+        self._shapes_run.add(key)
+        HUB.inc(labeled("gen_new_shapes_total", stage=stage))
+        return True
+
     def _start_seq(self, req: Request, lease) -> None:
+        import jax
         import jax.numpy as jnp
         import numpy as np
 
         req.started_s = time.time()
         HUB.observe("gen_queue_wait_seconds",
                     req.started_s - req.submitted_s)
+        T = len(req.prompt)
         try:
-            with trace.span("serve.prefill", request=req.id,
-                            prompt=len(req.prompt)):
-                tokens = jnp.asarray([req.prompt], jnp.int32)
-                logits, kv = self._jprefill(self.params, tokens)
+            with trace.span("serve.prefill", remote_parent=req.traceparent,
+                            request=req.id, prompt=T):
+                with trace.span("serve.prefill-device", prompt=T,
+                                new_shape=self._first_run("prefill", T)):
+                    tokens = jnp.asarray([req.prompt], jnp.int32)
+                    logits, kv = self._jprefill(self.params, tokens)
+                    if trace.enabled():
+                        # export tier only, like the compute spans: off
+                        # it the span ends at dispatch and the page-out's
+                        # first pull takes the wait, its slice dispatched
+                        # while the device still works
+                        jax.block_until_ready((logits, kv))
                 self.pool.write_prompt(lease, kv)
                 tok0 = int(np.argmax(np.asarray(logits[0])))
         except Exception as exc:  # noqa: BLE001 - engine must survive
@@ -460,7 +498,12 @@ class GenEngine:
 
     def _decode_step(self) -> None:
         """Advance every running sequence one token, ragged lengths and
-        all — the continuous-batching inner loop."""
+        all — the continuous-batching inner loop. One cycle is five
+        sibling spans on the engine thread: ``serve.kv-gather`` (inside
+        the pool), ``serve.decode-h2d``, ``serve.decode-step`` (whose
+        children are ``serve.decode-device`` and ``serve.decode-fetch``),
+        ``serve.decode-post`` and ``serve.decode-release``."""
+        import jax
         import jax.numpy as jnp
         import numpy as np
 
@@ -476,40 +519,58 @@ class GenEngine:
         for i, s in enumerate(batch):
             toks[i] = s.last_tok
             lens[i] = s.length
-        k, v = self.pool.gather([s.lease for s in batch], width)
-        if Bb > B:  # pad rows ride along with length 0 and are dropped
-            pad = ((0, 0), (0, Bb - B)) + ((0, 0),) * (k.ndim - 2)
-            k = np.pad(k, pad)
-            v = np.pad(v, pad)
-        cache = [(jnp.asarray(k[li]), jnp.asarray(v[li]))
-                 for li in range(k.shape[0])]
+        # pad rows ride along with length 0 and are dropped
+        k, v = self.pool.gather([s.lease for s in batch], width, rows=Bb)
+        h2d = k.nbytes + v.nbytes + toks.nbytes + lens.nbytes
+        with trace.span("serve.decode-h2d", bytes=h2d):
+            cache = [(jnp.asarray(k[li]), jnp.asarray(v[li]))
+                     for li in range(k.shape[0])]
+            jtoks, jlens = jnp.asarray(toks), jnp.asarray(lens)
+            HUB.inc("gen_h2d_bytes_total", h2d)
         try:
             with trace.span("serve.decode-step", batch=B, width=width):
-                logits, new_kv = self._jdecode(
-                    self.params, jnp.asarray(toks), cache,
-                    jnp.asarray(lens))
-                out = np.asarray(logits)
-                nk = np.stack([np.asarray(lk[:, 0]) for lk, _lv in new_kv])
-                nv = np.stack([np.asarray(lv[:, 0]) for _lk, lv in new_kv])
+                with trace.span("serve.decode-device", batch=B, width=width,
+                                new_shape=self._first_run("decode", Bb,
+                                                          width)):
+                    logits, new_kv = self._jdecode(self.params, jtoks,
+                                                   cache, jlens)
+                    # the fetch's first pull would wait here anyway
+                    jax.block_until_ready((logits, new_kv))
+                d2h = logits.nbytes + sum(lk.nbytes + lv.nbytes
+                                          for lk, lv in new_kv)
+                with trace.span("serve.decode-fetch", bytes=d2h):
+                    out = np.asarray(logits)
+                    nk = np.stack([np.asarray(lk[:, 0])
+                                   for lk, _lv in new_kv])
+                    nv = np.stack([np.asarray(lv[:, 0])
+                                   for _lk, lv in new_kv])
+                    HUB.inc("gen_d2h_bytes_total", d2h)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             log.error("decode step failed (batch=%d): %s", B, exc)
             for seq in batch:
                 self._retire(seq, error=f"decode failed: {exc}")
             return
-        done = 0
-        for i, seq in enumerate(batch):
-            self.pool.write_token(seq.lease, seq.length, nk[:, i], nv[:, i])
-            seq.length += 1
-            tok = int(np.argmax(out[i]))
-            seq.last_tok = tok
-            seq.generated += 1
-            seq.req._emit(tok)
-            if seq.generated >= seq.req.max_new_tokens:
-                self._retire(seq)
-                done += 1
-        with self._work:
-            self._tokens["decode"] += B
-        HUB.inc(labeled("gen_tokens_total", stage="decode"), B)
+        with trace.span("serve.decode-post", batch=B) as post:
+            retired = 0
+            for i, seq in enumerate(batch):
+                self.pool.write_token(seq.lease, seq.length, nk[:, i],
+                                      nv[:, i])
+                seq.length += 1
+                tok = int(np.argmax(out[i]))
+                seq.last_tok = tok
+                seq.generated += 1
+                seq.req._emit(tok)
+                if seq.generated >= seq.req.max_new_tokens:
+                    self._retire(seq)
+                    retired += 1
+            with self._work:
+                self._tokens["decode"] += B
+            HUB.inc(labeled("gen_tokens_total", stage="decode"), B)
+            post.set_attr("retired", retired)
+        # the step's rectangles, on the host and on the device, die here
+        # and not at the return, so that freeing them has a name
+        with trace.span("serve.decode-release", bytes=h2d):
+            del k, v, cache, logits, new_kv
 
     def _retire(self, seq: _Seq, error: str | None = None) -> None:
         """Finished/evicted/failed: blocks free IMMEDIATELY (the next

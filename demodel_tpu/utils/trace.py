@@ -28,6 +28,9 @@ Design, smallest-thing-that-works:
   child span, so a multi-host pull stitches into ONE trace.
 - span-duration summaries feed the existing metrics exposition:
   ``trace_spans_total{span=...}`` / ``trace_span_seconds_total{span=...}``.
+- :func:`set_annotator` — one optional hook entered and left with every
+  span, for putting the spans on a second clock (the serving plane hands
+  it ``jax.profiler.TraceAnnotation``; this module imports no jax).
 
 Observability has THREE tiers (the live-ops rebuild):
 
@@ -240,11 +243,12 @@ def enable(jsonl_path: str | None = None) -> None:
 def reset() -> None:
     """Drop exporter state and re-read the env (tests; cheap). Clears the
     in-flight registry too — spans left open by a failed test must not
-    haunt the next test's statusz snapshot."""
-    global _FORCED, _state
+    haunt the next test's statusz snapshot — and removes the annotator."""
+    global _FORCED, _state, _annotator
     with _state_lock:
         _FORCED = False
         _state = None
+        _annotator = None
     with _inflight_lock:
         _inflight.clear()
 
@@ -449,6 +453,25 @@ def _install_recorder_signal() -> None:
 
 # ------------------------------------------------------------------- Span
 
+#: optional hook that puts every entered span on a second clock: a callable
+#: ``name -> context manager``, entered and left with the span. The serving
+#: plane installs ``jax.profiler.TraceAnnotation`` here (this module imports
+#: no jax), so a profiler session shows the spans beside the device's work.
+_annotator: Callable[[str], Any] | None = None
+
+
+def set_annotator(annotate: Callable[[str], Any] | None) -> None:
+    """Install (or with None remove) the span annotator."""
+    global _annotator
+    _annotator = annotate
+
+
+def _drop_annotator(exc: Exception) -> None:
+    """A hook that fails is taken out, with one warning: it costs later
+    spans their annotation, never the program its span state."""
+    set_annotator(None)
+    _log().warning("span annotator failed and was removed: %s", exc)
+
 
 class Span:
     """One timed operation. Use via ``with trace.span("window-read", ...):``
@@ -459,7 +482,7 @@ class Span:
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
                  "events", "status", "error", "_t0", "_wall0", "dur",
                  "_token", "_thread_name", "_thread_ident",
-                 "_suppress_export", "_unsampled_token")
+                 "_suppress_export", "_unsampled_token", "_annotation")
 
     def __init__(self, name: str, trace_id: str, parent_id: str | None,
                  attrs: dict[str, Any] | None,
@@ -486,6 +509,7 @@ class Span:
         #: recorder/statusz/histograms stay whole — but never exports
         self._suppress_export = suppress_export
         self._unsampled_token: contextvars.Token[bool] | None = None
+        self._annotation: Any = None
         # live until finish(): the /debug/statusz in-flight view
         with _inflight_lock:
             _inflight[id(self)] = self
@@ -511,10 +535,24 @@ class Span:
             # tasks) inherit the export-drop with this root — whole
             # traces drop from the export, never mid-trace fragments
             self._unsampled_token = _unsampled.set(True)
+        annotate = _annotator
+        if annotate is not None:
+            try:
+                annotation = annotate(self.name)
+                annotation.__enter__()
+                self._annotation = annotation
+            except Exception as e:  # noqa: BLE001 — see _drop_annotator
+                _drop_annotator(e)
         return self
 
     def __exit__(self, exc_type: type[BaseException] | None,
                  exc: BaseException | None, tb: object) -> None:
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            try:
+                annotation.__exit__(exc_type, exc, tb)
+            except Exception as e:  # noqa: BLE001 — see _drop_annotator
+                _drop_annotator(e)
         if self._unsampled_token is not None:
             _unsampled.reset(self._unsampled_token)
             self._unsampled_token = None

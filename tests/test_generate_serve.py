@@ -94,7 +94,7 @@ class TestKVBlockPool:
 
     def test_write_gather_roundtrip(self, tiny_model):
         """Paged writes read back exactly through the dense gather, at
-        ragged widths and across block boundaries."""
+        ragged widths and across block boundaries, padded to ``rows``."""
         _, cfg = tiny_model
         L, Hkv, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                       cfg.head_dim)
@@ -109,7 +109,10 @@ class TestKVBlockPool:
         pool.write_prompt(lease_b, [(kb[li], kb[li] + 1) for li in range(L)])
         tok = rng.normal(size=(L, Hkv, hd)).astype(np.float32)
         pool.write_token(lease_a, t_a, tok, tok - 1)  # append one position
-        k, v = pool.gather([lease_a, lease_b], width=t_a + 1)
+        # rows: the scheduler's jit bucket; rows past the leases are zero
+        k, v = pool.gather([lease_a, lease_b], width=t_a + 1, rows=4)
+        assert k.shape == v.shape == (L, 4, t_a + 1, Hkv, hd)
+        assert not k[:, 2:].any() and not v[:, 2:].any()
         np.testing.assert_array_equal(k[:, 0, :t_a], ka[:, 0])
         np.testing.assert_array_equal(k[:, 0, t_a], tok)
         np.testing.assert_array_equal(v[:, 0, t_a], tok - 1)
@@ -445,3 +448,275 @@ class TestGenerateHTTP:
             assert gen["admission"]["outstanding"] == 0
         finally:
             serve.current().stop()
+
+
+# ------------------------------------------------- the engine cycle's spans
+
+#: the engine thread's root spans of one decode cycle, in order
+CYCLE = ["serve.kv-gather", "serve.decode-h2d", "serve.decode-step",
+         "serve.decode-post", "serve.decode-release"]
+CHILDREN = {"serve.http-parse": "serve.restore",
+            "serve.admit": "serve.restore",
+            "serve.prefill": "serve.admit",
+            "serve.prefill-device": "serve.prefill",
+            "serve.kv-pageout": "serve.prefill",
+            "serve.decode-device": "serve.decode-step",
+            "serve.decode-fetch": "serve.decode-step"}
+
+
+def _pow2(n):
+    return 1 << max(0, n - 1).bit_length()
+
+
+class TestServeSpans:
+    """Counts only (CPU, toy model): which spans a served request leaves,
+    under which parent, with which byte counts and counters."""
+
+    PROMPTS = [9, 5, 12, 9]
+    MAX_NEW = 6
+    BLOCK = 16
+
+    @pytest.fixture(scope="class")
+    def run(self, tiny_model, tmp_path_factory):
+        from demodel_tpu.restore.server import (RestoreRegistry,
+                                                RestoreServer)
+        from demodel_tpu.store import Store
+        from demodel_tpu.utils import trace
+        from demodel_tpu.utils.metrics import HUB
+
+        params, cfg = tiny_model
+        mp = pytest.MonkeyPatch()
+        for var in ("DEMODEL_TRACE", "DEMODEL_TRACE_SAMPLE", "DEMODEL_OBS"):
+            mp.delenv(var, raising=False)
+        trace.reset()
+        trace.enable()
+        store = Store(tmp_path_factory.mktemp("spans") / "store")
+        server = RestoreServer(RestoreRegistry(store),
+                               host="127.0.0.1").start()
+        before = HUB.snapshot()
+        engine = serve.boot(params, cfg, max_batch=3, queue_limit=16,
+                            max_new_tokens=self.MAX_NEW, kv_mb=4,
+                            block_tokens=self.BLOCK)
+        bodies = [{"prompt": _prompt(cfg, n, seed=i),
+                   "max_new_tokens": self.MAX_NEW}
+                  for i, n in enumerate(self.PROMPTS)]
+        docs: list = [None] * len(bodies)
+
+        def post(i):
+            docs[i] = _post(f"http://127.0.0.1:{server.port}/generate",
+                            bodies[i])[1]
+
+        try:
+            threads = [threading.Thread(target=post, args=(i,))
+                       for i in range(len(bodies))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=240)
+            assert all(d is not None for d in docs)
+        finally:
+            engine.stop()
+            server.stop()
+            serve.install(None)
+            store.close()
+        after = HUB.snapshot()
+        spans = [r for r in trace.buffer().snapshot()
+                 if r["name"].startswith("serve.")]
+        trace.reset()
+        mp.undo()
+
+        def delta(name):
+            return after.get(name, 0) - before.get(name, 0)
+
+        def named(name):
+            return sorted((r for r in spans if r["name"] == name),
+                          key=lambda r: r["ts"])
+
+        return {"cfg": cfg, "docs": docs, "bodies": bodies, "spans": spans,
+                "delta": delta, "named": named}
+
+    @pytest.mark.parametrize("name", sorted(CHILDREN))
+    def test_span_sits_under_its_parent(self, run, name):
+        by_id = {r["span"]: r for r in run["spans"]}
+        found = run["named"](name)
+        assert found
+        for r in found:
+            parent = by_id[r["parent"]]
+            assert parent["name"] == CHILDREN[name]
+            assert parent["trace"] == r["trace"]
+
+    def test_one_of_each_phase_a_cycle(self, run):
+        steps = run["named"]("serve.decode-step")
+        assert steps
+        for name in CYCLE + ["serve.decode-device", "serve.decode-fetch"]:
+            assert len(run["named"](name)) == len(steps), name
+        # the engine thread's phases are roots, one after another
+        flat = sorted((r for r in run["spans"] if r["name"] in CYCLE),
+                      key=lambda r: r["ts"])
+        assert [r["name"] for r in flat] == CYCLE * len(steps)
+        assert all(r["parent"] is None for r in flat)
+        n_req = len(self.PROMPTS)
+        assert len(run["named"]("serve.http-parse")) == n_req
+        assert len(run["named"]("serve.prefill-device")) == n_req
+        assert len(run["named"]("serve.kv-pageout")) == n_req
+        decoded = sum(r["attrs"]["batch"] for r in steps)
+        assert decoded == n_req * (self.MAX_NEW - 1)
+        assert sum(r["attrs"]["retired"]
+                   for r in run["named"]("serve.decode-post")) == n_req
+
+    def test_bytes_follow_the_pools_geometry(self, run):
+        cfg = run["cfg"]
+        item = np.dtype(cfg.dtype).itemsize
+        token = (2 * cfg.num_hidden_layers * cfg.num_key_value_heads
+                 * cfg.head_dim * item)     # K and V of one position
+        for gather, h2d, step, fetch, release in zip(
+                run["named"]("serve.kv-gather"),
+                run["named"]("serve.decode-h2d"),
+                run["named"]("serve.decode-step"),
+                run["named"]("serve.decode-fetch"),
+                run["named"]("serve.decode-release")):
+            rows = _pow2(step["attrs"]["batch"])
+            width = step["attrs"]["width"]
+            assert width % self.BLOCK == 0
+            assert gather["attrs"]["batch"] == step["attrs"]["batch"]
+            assert gather["attrs"]["width"] == width
+            assert gather["attrs"]["bytes"] == rows * width * token
+            # the rectangle, and a token and a length (int32) per row
+            assert h2d["attrs"]["bytes"] == rows * width * token + rows * 8
+            assert release["attrs"]["bytes"] == h2d["attrs"]["bytes"]
+            # the logits, and K and V of the new position, per row
+            assert fetch["attrs"]["bytes"] == rows * (
+                cfg.vocab_size * item + token)
+        for r in run["named"]("serve.kv-pageout"):
+            assert r["attrs"]["bytes"] == r["attrs"]["prompt"] * token
+        assert sorted(r["attrs"]["prompt"]
+                      for r in run["named"]("serve.kv-pageout")) == \
+            sorted(self.PROMPTS)
+        assert sorted(r["attrs"]["bytes"]
+                      for r in run["named"]("serve.http-parse")) == \
+            sorted(len(json.dumps(b).encode()) for b in run["bodies"])
+
+    def test_byte_counters_are_the_sums_of_the_spans(self, run):
+        def total(name):
+            return sum(r["attrs"]["bytes"] for r in run["named"](name))
+
+        assert run["delta"]("gen_h2d_bytes_total") == \
+            total("serve.decode-h2d") > 0
+        assert run["delta"]("gen_d2h_bytes_total") == \
+            total("serve.decode-fetch") + total("serve.kv-pageout")
+
+    @pytest.mark.parametrize("stage,span,shape", [
+        ("prefill", "serve.prefill-device",
+         lambda a: a["prompt"]),
+        ("decode", "serve.decode-device",
+         lambda a: (_pow2(a["batch"]), a["width"]))])
+    def test_new_shapes_counted_once_each(self, run, stage, span, shape):
+        from demodel_tpu.utils.metrics import labeled
+
+        seen, first = set(), []
+        for r in run["named"](span):
+            first.append(shape(r["attrs"]) not in seen)
+            seen.add(shape(r["attrs"]))
+        assert [r["attrs"]["new_shape"] for r in run["named"](span)] == first
+        assert run["delta"](labeled("gen_new_shapes_total",
+                                    stage=stage)) == len(seen)
+        if stage == "prefill":
+            assert len(seen) == len(set(self.PROMPTS))
+
+    def test_a_request_is_one_trace(self, run):
+        by_id = {r["span"]: r for r in run["spans"]}
+        admits = {r["attrs"]["request"]: r
+                  for r in run["named"]("serve.admit")}
+        prefills = run["named"]("serve.prefill")
+        assert len(prefills) == len(admits) == len(self.PROMPTS)
+        for p in prefills:
+            admit = admits[p["attrs"]["request"]]
+            assert p["trace"] == admit["trace"]
+            assert p["parent"] == admit["span"]
+            assert by_id[admit["parent"]]["name"] == "serve.restore"
+        assert len({p["trace"] for p in prefills}) == len(prefills)
+
+    def test_observability_off_same_tokens_no_spans(self, run, tiny_model,
+                                                    monkeypatch):
+        from demodel_tpu.utils import trace
+
+        params, cfg = tiny_model
+        monkeypatch.setenv("DEMODEL_OBS", "0")
+        monkeypatch.delenv("DEMODEL_TRACE", raising=False)
+        trace.reset()
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=16,
+                           max_new_tokens=self.MAX_NEW, kv_mb=4,
+                           block_tokens=self.BLOCK).start()
+        try:
+            assert trace.mode() == "off"
+            reqs = [engine.submit(b["prompt"], self.MAX_NEW)
+                    for b in run["bodies"]]
+            outs = [r.result(timeout=240) for r in reqs]
+        finally:
+            engine.stop()
+            recorded = (trace.buffer().snapshot()
+                        + trace.recorder().snapshot())
+            monkeypatch.undo()
+            trace.reset()
+        assert outs == [d["tokens"] for d in run["docs"]]
+        assert recorded == []
+        assert all(r.traceparent is None for r in reqs)
+
+    @pytest.mark.parametrize("tier,waits", [("observe", 0), ("export", 1)])
+    def test_prefill_waits_for_the_device_only_when_exporting(
+            self, tiny_model, monkeypatch, tier, waits):
+        """``serve.prefill-device`` syncs only in the export tier; by
+        default it ends at dispatch and the page-out takes the wait (one
+        token out, so no decode step runs and syncs)."""
+        from demodel_tpu.utils import trace
+
+        params, cfg = tiny_model
+        for var in ("DEMODEL_TRACE", "DEMODEL_OBS"):
+            monkeypatch.delenv(var, raising=False)
+        trace.reset()
+        if tier == "export":
+            trace.enable()
+        synced = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: synced.append(1) or real(x))
+        engine = GenEngine(params, cfg, max_batch=1, kv_mb=4,
+                           block_tokens=self.BLOCK).start()
+        try:
+            assert trace.mode() == tier
+            out = engine.submit(_prompt(cfg, 7), 1).result(timeout=240)
+        finally:
+            engine.stop()
+            monkeypatch.undo()
+            trace.reset()
+        assert len(out) == 1 and len(synced) == waits
+
+    def test_annotator_is_installed_by_building_an_engine(self):
+        """Dep-light: the restore server and the tracing module leave the
+        hook empty and the serving plane unimported; importing the plane
+        installs nothing; building an engine installs the profiler's
+        annotation."""
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        code = """
+import sys
+import demodel_tpu.restore.server
+from demodel_tpu.utils import trace
+assert trace._annotator is None
+assert "demodel_tpu.serve" not in sys.modules
+import jax
+from demodel_tpu import serve
+from demodel_tpu.models import llama
+assert trace._annotator is None
+cfg = llama.LlamaConfig.tiny()
+engine = serve.GenEngine(llama.init_params(jax.random.key(0), cfg), cfg,
+                         max_batch=1, kv_mb=1)
+assert trace._annotator is jax.profiler.TraceAnnotation
+engine.stop()
+"""
+        out = subprocess.run([sys.executable, "-c", code],
+                             cwd=Path(__file__).resolve().parent.parent,
+                             capture_output=True, text=True, timeout=240)
+        assert out.returncode == 0, out.stderr

@@ -110,6 +110,82 @@ def test_parent_child_nesting_same_thread():
     assert recs[0]["parent"] == recs[1]["span"]
 
 
+def test_annotator_entered_and_left_once_per_span_in_order():
+    """The hook that puts spans on a second clock (the serving plane
+    installs ``jax.profiler.TraceAnnotation``): entered after the span
+    starts, left before it finishes, once each, nested like the spans;
+    and this module still imports without jax."""
+    calls: list[tuple[str, str]] = []
+
+    @contextlib.contextmanager
+    def fake(name):
+        calls.append(("enter", name))
+        try:
+            yield
+        finally:
+            calls.append(("exit", name))
+
+    trace.enable()
+    with trace.span("before-install"):
+        pass
+    trace.set_annotator(fake)
+    try:
+        with trace.span("outer"):
+            with trace.span("inner", k=1):
+                assert calls[-1] == ("enter", "inner")
+        with pytest.raises(ValueError):
+            with trace.span("failing"):
+                raise ValueError("boom")
+    finally:
+        trace.set_annotator(None)
+    with trace.span("after-removal"):
+        pass
+    assert calls == [("enter", "outer"), ("enter", "inner"),
+                     ("exit", "inner"), ("exit", "outer"),
+                     ("enter", "failing"), ("exit", "failing")]
+    assert [r["name"] for r in _records()] == [
+        "before-install", "inner", "outer", "failing", "after-removal"]
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import demodel_tpu.utils.trace as t; "
+         "assert t._annotator is None; "
+         "assert not [m for m in sys.modules if m.split('.')[0] == 'jax']"],
+        cwd=REPO, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_failing_annotator_leaves_span_state_whole_and_reset_removes_it():
+    """A hook that raises, on entry or on exit, is taken out and costs no
+    span its state; ``reset`` takes a sound hook out too."""
+    class Broken:
+        def __init__(self, where):
+            self.where = where
+
+        def __enter__(self):
+            if self.where == "enter":
+                raise RuntimeError("hook")
+
+        def __exit__(self, *exc):
+            raise RuntimeError("hook")
+
+    trace.enable()
+    for where in ("enter", "exit"):
+        trace.set_annotator(lambda name, where=where: Broken(where))
+        with trace.span(f"{where}.outer") as outer:
+            assert trace._annotator is None or where == "exit"
+            with trace.span(f"{where}.inner") as inner:
+                assert trace.current() is inner
+            assert trace.current() is outer
+        assert trace._annotator is None
+        assert trace.current() is None
+        assert trace.inflight() == []
+    assert [r["name"] for r in _records()] == [
+        "enter.inner", "enter.outer", "exit.inner", "exit.outer"]
+    trace.set_annotator(contextlib.nullcontext)
+    trace.reset()
+    assert trace._annotator is None
+
+
 def test_error_status_recorded():
     trace.enable()
     with pytest.raises(ValueError):
