@@ -214,23 +214,21 @@ def check_against_reference(engine, prompt: list[int]) -> dict:
     import jax.numpy as jnp
 
     from demodel_tpu.models import llama
+    from demodel_tpu.serve.scheduler import _Seq
 
+    # the two programs exactly as GenEngine runs them, over its own pool
+    # (the engine thread is idle: every request has been answered)
     T = len(prompt)
-    tokens = jnp.asarray([prompt], jnp.int32)
-    logits_p, kv = engine._jprefill(engine.params, tokens)
-    tok0 = int(np.argmax(np.asarray(logits_p[0], np.float32)))
-    # the step exactly as GenEngine._decode_step feeds it: the prompt's
-    # KV in a block-rounded, power-of-two-wide rectangle, length T
-    bs = engine.pool.block_tokens
-    width = bs
-    while width < T:
-        width *= 2
-    pad = ((0, 0), (0, width - T), (0, 0), (0, 0))
-    cache = [(jnp.asarray(np.pad(np.asarray(k), pad)),
-              jnp.asarray(np.pad(np.asarray(v), pad))) for k, v in kv]
-    logits_d, _ = engine._jdecode(
-        engine.params, jnp.asarray([tok0], jnp.int32), cache,
-        jnp.asarray([T], jnp.int32))
+    pool = engine.pool
+    lease = pool.alloc(pool.blocks_for(T + 1))
+    try:
+        logits_p = engine._prefill(prompt, lease)
+        tok0 = int(np.argmax(np.asarray(logits_p[0], np.float32)))
+        _width, rows = engine._decode_inputs([_Seq(None, lease, T, tok0)])
+        logits_d = pool.apply(engine._jdecode, engine.params,
+                              jax.device_put(rows, pool.replicated))
+    finally:
+        lease.free()
 
     params32 = jax.tree.map(lambda a: a.astype(jnp.float32), engine.params)
     cfg32 = dataclasses.replace(engine.cfg, dtype="float32")

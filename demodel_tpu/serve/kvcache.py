@@ -1,4 +1,4 @@
-"""Paged KV cache: fixed-size blocks in one preallocated host pool.
+"""Paged KV cache: fixed-size blocks in one preallocated pool on the device.
 
 vLLM's PagedAttention memory discipline grafted onto the repo's tier
 accounting: the pool preallocates ``num_blocks`` blocks of
@@ -10,17 +10,34 @@ on statusz next to the RAM tier, and a finished sequence's blocks return
 to the free list immediately — no per-sequence ``max_len`` rectangle,
 no fragmentation beyond the last partial block.
 
-The model never sees a block table: the scheduler gathers each step's
-running sequences into a dense ``[B, S, Hkv, hd]`` view
-(:meth:`KVBlockPool.gather`) and writes the step's new K/V back through
-:meth:`KVBlockPool.write_token` — placement is entirely the pool's
-business, which is what makes admission/eviction a host-side list
-operation instead of a device reshape.
+The pool is two halves that never touch each other:
 
-Pool arrays are host numpy in the model's dtype (``ml_dtypes.bfloat16``
-for a bf16 checkpoint): the pool is the *memory ledger* (alloc/free
-exactness, budget-bounded admission), while compute shapes stay static
-for jit via the scheduler's bucketing.
+- the **ledger** on the host: free list, :class:`BlockLease`, budget,
+  counters, ``describe()``. Admission and eviction are list operations.
+- the **arrays** on the device: ``k`` and ``v``, ``[L, num_blocks + 1,
+  Hkv, block_tokens, hd]`` in the model's dtype (positions and head
+  width innermost, the order the TPU's attention reads; the extra block
+  is scratch no lease can hold), so the byte budget
+  (``DEMODEL_GEN_KV_MB``) is an HBM budget. Under a ``tp`` mesh they are
+  sharded on the KV-head axis by ``llama._head_align``'s rule (replicated
+  when the heads do not divide). Every program that writes them takes
+  them donated and returns them (:meth:`KVBlockPool.apply`), so the
+  bytes never move: a decode step sends a block table in and gets logits
+  back.
+
+The model still never sees a block table: inside the engine's jitted
+programs :func:`read_table` turns the table into the dense ``[B, S, Hkv,
+hd]`` rectangles ``llama.step_decode`` consumes, and :func:`put_blocks` /
+:func:`put_positions` place a prefill's or a step's new K/V — placement
+is entirely this module's business.
+
+**One signature for life.** ``jax.jit`` keys its executables on an
+argument's sharding and on whether it is committed. The arrays are
+therefore born as the output of a program that is told to return
+``self.sharding``, and every program that takes them must return them
+with ``out_shardings=pool.sharding``: the first call on a fresh pool and
+every later one then hit the same executable (a second one would compile,
+or load from the persistent cache, in the middle of serving).
 """
 
 from __future__ import annotations
@@ -28,11 +45,13 @@ from __future__ import annotations
 import threading
 from typing import Any
 
-import ml_dtypes  # noqa: F401 — registers bfloat16 with numpy's dtype names
-import numpy as np
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from demodel_tpu.tier import TierBudget
-from demodel_tpu.utils import trace
 from demodel_tpu.utils.env import gen_block_tokens, gen_kv_mb
 from demodel_tpu.utils.logging import get_logger
 from demodel_tpu.utils.metrics import HUB
@@ -44,7 +63,6 @@ log = get_logger("serve.kvcache")
 HUB.set_gauge("gen_kv_blocks_in_use", 0)
 HUB.inc("gen_kv_blocks_alloc_total", 0)
 HUB.inc("gen_kv_blocks_freed_total", 0)
-HUB.inc("gen_d2h_bytes_total", 0)
 
 
 class PoolExhausted(Exception):
@@ -77,33 +95,51 @@ class KVBlockPool:
 
     ``layers``/``kv_heads``/``head_dim`` fix the block geometry; the
     byte budget (``DEMODEL_GEN_KV_MB`` unless overridden) fixes the
-    block count. All block state sits behind one lock; the K/V arrays
-    themselves are written lock-free because a block belongs to exactly
-    one live lease and only the engine thread touches leased bytes.
+    block count; ``mesh`` (the engine's) fixes where the arrays live.
+    All block state sits behind one lock and never touches the arrays;
+    the arrays belong to the engine thread alone, which hands them to
+    its programs through :meth:`apply`.
     """
 
     def __init__(self, layers: int, kv_heads: int, head_dim: int, *,
                  block_tokens: int | None = None,
                  budget_mb: int | None = None,
-                 dtype: str = "float32"):
+                 dtype: str = "float32", mesh=None):
         self.block_tokens = int(block_tokens or gen_block_tokens())
         budget_bytes = int(budget_mb if budget_mb is not None
                            else gen_kv_mb()) << 20
-        dt = np.dtype(dtype)
+        dt = jnp.dtype(dtype)
         # K + V, every layer, one block of token positions
         self.block_bytes = (2 * layers * self.block_tokens * kv_heads
                             * head_dim * dt.itemsize)
         self.num_blocks = max(1, budget_bytes // self.block_bytes)
-        shape = (layers, self.num_blocks, self.block_tokens, kv_heads,
+        #: one block past the leasable ones, where a row that must write
+        #: nothing writes (see :func:`put_positions`)
+        self.scratch_block = self.num_blocks
+        shape = (layers, self.num_blocks + 1, kv_heads, self.block_tokens,
                  head_dim)
-        self.k = np.zeros(shape, dt)
-        self.v = np.zeros(shape, dt)
+        if mesh is None:
+            self.sharding = self.replicated = SingleDeviceSharding(
+                jax.devices()[0])
+        else:
+            tp = int(mesh.shape.get("tp", 1))
+            heads = "tp" if tp > 1 and kv_heads % tp == 0 else None
+            self.sharding = NamedSharding(
+                mesh, P(None, None, heads, None, None))
+            #: where the engine puts a program's small per-call inputs
+            self.replicated = NamedSharding(mesh, P())
+        # a program's output, like every later pool: see the module
+        # docstring ("one signature for life")
+        self._fresh = jax.jit(
+            lambda: (jnp.zeros(shape, dt), jnp.zeros(shape, dt)),
+            out_shardings=(self.sharding, self.sharding))
+        self.k, self.v = self._fresh()
         self.budget = TierBudget("gen-kv", budget_bytes)
         self._free_list = list(range(self.num_blocks - 1, -1, -1))
         self._lock = threading.Lock()
-        log.info("kv pool: %d blocks x %d tokens (%d KiB/block, %d MiB)",
-                 self.num_blocks, self.block_tokens,
-                 self.block_bytes >> 10, budget_bytes >> 20)
+        log.info("kv pool: %d blocks x %d tokens (%d KiB/block, %d MiB) "
+                 "on %s", self.num_blocks, self.block_tokens,
+                 self.block_bytes >> 10, budget_bytes >> 20, self.sharding)
 
     # ------------------------------------------------------------ sizing
     def blocks_for(self, tokens: int) -> int:
@@ -145,63 +181,26 @@ class KVBlockPool:
         HUB.inc("gen_kv_blocks_freed_total", len(blocks))
         HUB.set_gauge("gen_kv_blocks_in_use", in_use)
 
-    # ---------------------------------------------------------- data IO
-    def write_prompt(self, lease: BlockLease, kv) -> None:
-        """Page a prefill's KV out into the lease: ``kv`` is the
-        per-layer ``(k, v)`` list from ``step_prefill``, each
-        [1, T, Hkv, hd]. The ``serve.kv-pageout`` span covers the 2L
-        pulls to the host and the block copies; its ``bytes`` (what
-        crosses device → host, from the shapes) feed
-        ``gen_d2h_bytes_total``."""
-        T = kv[0][0].shape[1]
-        nbytes = sum(lk.nbytes + lv.nbytes for lk, lv in kv)
-        with trace.span("serve.kv-pageout", prompt=T, bytes=nbytes):
-            k = np.stack([np.asarray(lk[0]) for lk, _lv in kv])
-            v = np.stack([np.asarray(lv[0]) for _lk, lv in kv])
-            bs = self.block_tokens
-            for j in range(0, T, bs):
-                blk = lease.blocks[j // bs]
-                n = min(bs, T - j)
-                self.k[:, blk, :n] = k[:, j:j + n]
-                self.v[:, blk, :n] = v[:, j:j + n]
-            HUB.inc("gen_d2h_bytes_total", nbytes)
+    # ------------------------------------------------------- the arrays
+    def apply(self, program, *args):
+        """Run one of the engine's programs over the arrays:
+        ``program(*args, k, v) -> (out, k, v)`` with ``k`` and ``v``
+        donated and returned with :attr:`sharding`. The program's outputs
+        become the pool, so nothing keeps a reference to the arrays that
+        went in. Engine thread only."""
+        out, self.k, self.v = program(*args, self.k, self.v)
+        return out
 
-    def write_token(self, lease: BlockLease, pos: int, k, v) -> None:
-        """Write one decoded position: ``k``/``v`` are [L, Hkv, hd]."""
-        blk = lease.blocks[pos // self.block_tokens]
-        off = pos % self.block_tokens
-        self.k[:, blk, off] = k
-        self.v[:, blk, off] = v
+    @property
+    def lost(self) -> bool:
+        """A program took the arrays and gave none back."""
+        return self.k.is_deleted() or self.v.is_deleted()
 
-    def gather(self, leases: list[BlockLease], width: int, rows: int):
-        """Dense [L, rows, width, Hkv, hd] K and V views of ``leases`` —
-        the per-step ragged batch the model consumes. Rows past a
-        sequence's filled length are stale pool bytes; the model masks
-        them by length (see ``llama.step_decode``), so short sequences
-        simply index block 0 for table slots they don't have. ``rows``
-        (at least one per lease) pads the batch with zero rows up to the
-        scheduler's jit bucket. The ``serve.kv-gather`` span covers the
-        gather and the pad; its ``bytes`` are the two rectangles it
-        returns, from the pool's geometry."""
-        B, width, rows = len(leases), int(width), int(rows)
-        L = self.k.shape[0]
-        bs = self.block_tokens
-        with trace.span("serve.kv-gather", batch=B, width=width,
-                        bytes=rows * width * (self.block_bytes // bs)):
-            nb = -(-width // bs)
-            ids = np.zeros((B, nb), np.int64)
-            for i, lease in enumerate(leases):
-                got = lease.blocks[:nb]
-                ids[i, :len(got)] = got
-            k = self.k[:, ids].reshape(L, B, nb * bs,
-                                       *self.k.shape[3:])[:, :, :width]
-            v = self.v[:, ids].reshape(L, B, nb * bs,
-                                       *self.v.shape[3:])[:, :, :width]
-            if rows > B:
-                pad = ((0, 0), (0, rows - B)) + ((0, 0),) * (k.ndim - 2)
-                k = np.pad(k, pad)
-                v = np.pad(v, pad)
-        return k, v
+    def reset(self) -> None:
+        """Fresh, zeroed arrays after a program failed with the old ones
+        in hand. The ledger is the caller's to settle: every lease's
+        contents are gone."""
+        self.k, self.v = self._fresh()
 
     # ------------------------------------------------------------ intro
     def describe(self) -> dict[str, Any]:
@@ -215,3 +214,71 @@ class KVBlockPool:
             "in_use_blocks": self.num_blocks - free,
             "budget": self.budget.describe(),
         }
+
+
+# ------------------------------------------------- inside the programs
+# jit-traceable: the engine's prefill and decode programs call these on
+# the donated arrays. Indices are int32 arrays built on the host from the
+# leases. A row that must write nothing (a pad row of the batch bucket)
+# is given ``pool.scratch_block``, the one block no lease can hold.
+
+
+def read_table(k, v, table):
+    """The dense rectangles of a ragged batch: ``table`` [B, n] block ids
+    → per layer ``(k, v)``, each [B, n * block_tokens, Hkv, hd]. A row's
+    slots past its lease, and a pad row's, may name any block: positions
+    at or past a row's length are masked by ``llama.step_decode``."""
+    B, n = table.shape
+    L, _nb, Hkv, bs, hd = k.shape
+
+    def rect(a):
+        # [L, B, n, Hkv, bs, hd]
+        got = jnp.take(a, table, axis=1, mode="clip")
+        return got.transpose(0, 1, 2, 4, 3, 5).reshape(L, B, n * bs, Hkv, hd)
+
+    kr, vr = rect(k), rect(v)
+    return [(kr[li], vr[li]) for li in range(L)]
+
+
+def _stack(kv):
+    """Per-layer ``(k, v)`` of [B, T, Hkv, hd] → two [L, B, T, Hkv, hd]."""
+    return (jnp.stack([lk for lk, _lv in kv]),
+            jnp.stack([lv for _lk, lv in kv]))
+
+
+def put_blocks(k, v, kv, blocks):
+    """A prefill's KV into its lease: ``kv`` is ``step_prefill``'s
+    per-layer ``(k, v)``, each [1, T, Hkv, hd]; ``blocks`` the lease's
+    first ``ceil(T / block_tokens)`` ids. The tail of the last block is
+    written with zeros (it is the lease's own, and past its length)."""
+    L, _nb, Hkv, bs, hd = k.shape
+    n = blocks.shape[0]
+
+    def put(a, new):
+        T = new.shape[2]
+        new = jnp.pad(new[:, 0], ((0, 0), (0, n * bs - T), (0, 0), (0, 0)))
+        new = new.reshape(L, n, bs, Hkv, hd).transpose(0, 1, 3, 2, 4)
+        return a.at[:, blocks].set(new, mode="promise_in_bounds",
+                                   unique_indices=True)
+
+    nk, nv = _stack(kv)
+    return put(k, nk), put(v, nv)
+
+
+def put_positions(k, v, new_kv, blocks, offsets):
+    """A decode step's new K/V: ``new_kv`` is ``step_decode``'s per-layer
+    ``(k, v)``, each [B, 1, Hkv, hd]; row ``b`` lands in block
+    ``blocks[b]`` at slot ``offsets[b]``. One in-place slice update a
+    row: a scatter makes the TPU compiler copy the whole pool into
+    another layout and back (PERF.md, Findings, PR 26)."""
+    L, _nb, Hkv, _bs, hd = k.shape
+
+    def put(a, new):
+        for b in range(blocks.shape[0]):
+            a = lax.dynamic_update_slice(
+                a, new[:, b].reshape(L, 1, Hkv, 1, hd),
+                (0, blocks[b], 0, offsets[b], 0))
+        return a
+
+    nk, nv = _stack(new_kv)
+    return put(k, nk), put(v, nv)

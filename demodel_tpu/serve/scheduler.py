@@ -31,6 +31,7 @@ import time
 from collections import deque
 from typing import Any, Iterator
 
+from demodel_tpu.serve import kvcache
 from demodel_tpu.serve.kvcache import KVBlockPool, PoolExhausted
 from demodel_tpu.utils import trace
 from demodel_tpu.utils.env import (gen_max_batch, gen_max_new_tokens,
@@ -46,7 +47,8 @@ HUB.inc(labeled("gen_tokens_total", stage="decode"), 0)
 HUB.inc("gen_requests_total", 0)
 HUB.inc("gen_rejected_total", 0)
 HUB.inc("gen_evicted_total", 0)
-HUB.inc("gen_h2d_bytes_total", 0)  # its twin, d2h, is the kv pool's
+HUB.inc("gen_h2d_bytes_total", 0)
+HUB.inc("gen_d2h_bytes_total", 0)
 HUB.inc(labeled("gen_new_shapes_total", stage="prefill"), 0)
 HUB.inc(labeled("gen_new_shapes_total", stage="decode"), 0)
 HUB.set_gauge("gen_queue_depth", 0)
@@ -200,8 +202,10 @@ class GenEngine:
     """The serving loop: one thread, one model, one paged pool.
 
     All cross-thread state (`_pending`, `_running`, `_stop`, token
-    counters) is guarded by ``_work``'s lock; the jax arrays and the
-    pool's leased bytes are engine-thread-only.
+    counters) is guarded by ``_work``'s lock; the pool's arrays are
+    engine-thread-only: they live on the device, both programs take them
+    donated (``pool.apply``) and what crosses the link a step is a block
+    table one way and a row of logits the other.
     """
 
     def __init__(self, params, cfg, mesh=None, *,
@@ -233,17 +237,35 @@ class GenEngine:
         self.model = model
         self.pool = pool if pool is not None else KVBlockPool(
             cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim,
-            block_tokens=block_tokens, budget_mb=kv_mb, dtype=cfg.dtype)
+            block_tokens=block_tokens, budget_mb=kv_mb, dtype=cfg.dtype,
+            mesh=mesh)
         self.max_batch = int(max_batch or gen_max_batch())
         self.max_new_cap = int(max_new_tokens or gen_max_new_tokens())
         self.admission = AdmissionQueue(
             queue_limit if queue_limit is not None else gen_queue_limit(),
             gen_retry_after_s())
-        self._jprefill = jax.jit(
-            lambda p, t: llama.step_prefill(p, t, cfg, mesh=mesh))
-        self._jdecode = jax.jit(
-            lambda p, t, c, ln: llama.step_decode(p, t, cfg, c, ln,
-                                                  mesh=mesh))
+
+        def prefill(p, tokens, blocks, k, v):
+            logits, kv = llama.step_prefill(p, tokens, cfg, mesh=mesh)
+            return (logits, *kvcache.put_blocks(k, v, kv, blocks))
+
+        def decode(p, rows, k, v):
+            # one int32 row a sequence (see _decode_inputs)
+            toks, lens, wblocks, woffsets = (rows[:, i] for i in range(4))
+            cache = kvcache.read_table(k, v, rows[:, 4:])
+            logits, new_kv = llama.step_decode(p, toks, cfg, cache, lens,
+                                               mesh=mesh)
+            return (logits, *kvcache.put_positions(k, v, new_kv, wblocks,
+                                                   woffsets))
+
+        # the pool goes in donated and comes back as it was born
+        # (kvcache: "one signature for life"); a program's shapes follow
+        # the prompt length, or (batch bucket, width), and nothing else
+        back = (None, self.pool.sharding, self.pool.sharding)
+        self._jprefill = jax.jit(prefill, donate_argnums=(3, 4),
+                                 out_shardings=back)
+        self._jdecode = jax.jit(decode, donate_argnums=(2, 3),
+                                out_shardings=back)
         self._pending: deque[Request] = deque()
         self._running: list[_Seq] = []
         self._stop = False
@@ -273,10 +295,10 @@ class GenEngine:
             self._thread.join(timeout=30)
             if self._thread.is_alive():
                 # the engine thread is still inside a step (e.g. a long
-                # jit compile) and still writing into leased blocks —
-                # reclaiming them now would hand corruptible memory to a
-                # future engine. Leave all state for the thread to settle
-                # when it reaches the stop check.
+                # jit compile) and will still write into leased blocks —
+                # reclaiming them now would hand corruptible memory to
+                # the next lease. Leave all state for the thread to
+                # settle when it reaches the stop check.
                 with self._work:
                     n_run, n_pend = len(self._running), len(self._pending)
                 log.error("engine thread still running after 30s; "
@@ -448,34 +470,75 @@ class GenEngine:
         HUB.inc(labeled("gen_new_shapes_total", stage=stage))
         return True
 
+    def _prefill(self, prompt: list[int], lease):
+        """Ship a prompt and its lease's block ids, run the prefill
+        program over the pool (it writes those blocks itself), and
+        return the last position's logits ``[1, V]``, still on the
+        device and possibly still being computed."""
+        import jax
+        import numpy as np
+
+        pool = self.pool
+        tokens = np.asarray([prompt], np.int32)
+        blocks = np.asarray(lease.blocks[:pool.blocks_for(len(prompt))],
+                            np.int32)
+        sent = jax.device_put((tokens, blocks), pool.replicated)
+        HUB.inc("gen_h2d_bytes_total", tokens.nbytes + blocks.nbytes)
+        return pool.apply(self._jprefill, self.params, *sent)
+
+    def _decode_inputs(self, batch: list[_Seq]):
+        """What one decode step ships, built from the leases: ``(width,
+        rows)``. ``rows`` is one int32 array, a row a sequence of the
+        batch bucket — token id, length, the block and the offset its new
+        position is written at, then its slots of the block table — so
+        its shape follows (bucket, width) alone and it crosses the link
+        in one transfer."""
+        import numpy as np
+
+        pool = self.pool
+        bs = pool.block_tokens
+        nb = _pow2(-(-max(s.length for s in batch) // bs))
+        # a slot a sequence does not have reads block 0 (masked by its
+        # length); a pad row rides along with length 0, is dropped on the
+        # host, and writes into the block no lease can hold
+        rows = np.zeros((_pow2(len(batch)), 4 + nb), np.int32)
+        rows[:, 2] = pool.scratch_block
+        for row, s in zip(rows, batch):
+            got = s.lease.blocks[:nb]
+            row[:4] = (s.last_tok, s.length, s.lease.blocks[s.length // bs],
+                       s.length % bs)
+            row[4:4 + len(got)] = got
+        return bs * nb, rows
+
     def _start_seq(self, req: Request, lease) -> None:
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
         req.started_s = time.time()
         HUB.observe("gen_queue_wait_seconds",
                     req.started_s - req.submitted_s)
         T = len(req.prompt)
+        pool = self.pool
+        applied = False
         try:
             with trace.span("serve.prefill", remote_parent=req.traceparent,
                             request=req.id, prompt=T):
                 with trace.span("serve.prefill-device", prompt=T,
                                 new_shape=self._first_run("prefill", T)):
-                    tokens = jnp.asarray([req.prompt], jnp.int32)
-                    logits, kv = self._jprefill(self.params, tokens)
+                    logits = self._prefill(req.prompt, lease)
+                    applied = True
                     if trace.enabled():
                         # export tier only, like the compute spans: off
-                        # it the span ends at dispatch and the page-out's
-                        # first pull takes the wait, its slice dispatched
-                        # while the device still works
-                        jax.block_until_ready((logits, kv))
-                self.pool.write_prompt(lease, kv)
-                tok0 = int(np.argmax(np.asarray(logits[0])))
+                        # it the span ends at dispatch and the pull of
+                        # the logits takes the wait
+                        jax.block_until_ready((logits, pool.k, pool.v))
+                tok0 = int(np.asarray(logits)[0].astype(np.float32).argmax())
+                HUB.inc("gen_d2h_bytes_total", logits.nbytes)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             lease.free()
             log.error("prefill failed for request %d: %s", req.id, exc)
             self._finish_req(req, error=f"prefill failed: {exc}")
+            self._settle_pool(applied, f"prefill failed: {exc}")
             return
         seq = _Seq(req, lease, len(req.prompt), tok0)
         with self._work:
@@ -490,6 +553,17 @@ class GenEngine:
         if seq.generated >= req.max_new_tokens:
             self._retire(seq)
 
+    def _settle_pool(self, applied: bool, error: str) -> None:
+        """After a program failed: if it had the pool's arrays in hand
+        (donated and gone, or returned by a run that then failed), what
+        every running sequence cached is lost with them — retire them
+        all with the error and go on with a fresh, empty pool."""
+        if not (applied or self.pool.lost):
+            return
+        for seq in self._snapshot_running():
+            self._retire(seq, error=error)
+        self.pool.reset()
+
     def _evict_cancelled(self) -> None:
         for seq in self._snapshot_running():
             if seq.req.cancelled.is_set():
@@ -498,65 +572,52 @@ class GenEngine:
 
     def _decode_step(self) -> None:
         """Advance every running sequence one token, ragged lengths and
-        all — the continuous-batching inner loop. One cycle is five
-        sibling spans on the engine thread: ``serve.kv-gather`` (inside
-        the pool), ``serve.decode-h2d``, ``serve.decode-step`` (whose
-        children are ``serve.decode-device`` and ``serve.decode-fetch``),
-        ``serve.decode-post`` and ``serve.decode-release``."""
+        all — the continuous-batching inner loop. One cycle is three
+        sibling spans on the engine thread: ``serve.decode-h2d`` (token
+        ids, lengths, write coordinates and the block table, one array),
+        ``serve.decode-step`` (whose children are ``serve.decode-device``
+        and ``serve.decode-fetch``, the logits alone) and
+        ``serve.decode-post``."""
         import jax
-        import jax.numpy as jnp
         import numpy as np
 
         batch = self._snapshot_running()
         if not batch:
             return
         B = len(batch)
-        Bb = _pow2(B)
-        bs = self.pool.block_tokens
-        width = bs * _pow2(-(-max(s.length for s in batch) // bs))
-        toks = np.zeros((Bb,), np.int32)
-        lens = np.zeros((Bb,), np.int32)
-        for i, s in enumerate(batch):
-            toks[i] = s.last_tok
-            lens[i] = s.length
-        # pad rows ride along with length 0 and are dropped
-        k, v = self.pool.gather([s.lease for s in batch], width, rows=Bb)
-        h2d = k.nbytes + v.nbytes + toks.nbytes + lens.nbytes
-        with trace.span("serve.decode-h2d", bytes=h2d):
-            cache = [(jnp.asarray(k[li]), jnp.asarray(v[li]))
-                     for li in range(k.shape[0])]
-            jtoks, jlens = jnp.asarray(toks), jnp.asarray(lens)
-            HUB.inc("gen_h2d_bytes_total", h2d)
+        pool = self.pool
+        with trace.span("serve.decode-h2d") as ship:
+            width, rows = self._decode_inputs(batch)
+            ship.set_attr("bytes", rows.nbytes)
+            sent = jax.device_put(rows, pool.replicated)
+            HUB.inc("gen_h2d_bytes_total", rows.nbytes)
+        applied = False
         try:
             with trace.span("serve.decode-step", batch=B, width=width):
                 with trace.span("serve.decode-device", batch=B, width=width,
-                                new_shape=self._first_run("decode", Bb,
-                                                          width)):
-                    logits, new_kv = self._jdecode(self.params, jtoks,
-                                                   cache, jlens)
-                    # the fetch's first pull would wait here anyway
-                    jax.block_until_ready((logits, new_kv))
-                d2h = logits.nbytes + sum(lk.nbytes + lv.nbytes
-                                          for lk, lv in new_kv)
-                with trace.span("serve.decode-fetch", bytes=d2h):
+                                new_shape=self._first_run(
+                                    "decode", len(rows), width)):
+                    logits = pool.apply(self._jdecode, self.params, sent)
+                    applied = True
+                    # the fetch's pull would wait here anyway
+                    jax.block_until_ready((logits, pool.k, pool.v))
+                with trace.span("serve.decode-fetch", bytes=logits.nbytes):
                     out = np.asarray(logits)
-                    nk = np.stack([np.asarray(lk[:, 0])
-                                   for lk, _lv in new_kv])
-                    nv = np.stack([np.asarray(lv[:, 0])
-                                   for _lk, lv in new_kv])
-                    HUB.inc("gen_d2h_bytes_total", d2h)
+                    HUB.inc("gen_d2h_bytes_total", logits.nbytes)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             log.error("decode step failed (batch=%d): %s", B, exc)
             for seq in batch:
                 self._retire(seq, error=f"decode failed: {exc}")
+            self._settle_pool(applied, f"decode failed: {exc}")
             return
         with trace.span("serve.decode-post", batch=B) as post:
             retired = 0
-            for i, seq in enumerate(batch):
-                self.pool.write_token(seq.lease, seq.length, nk[:, i],
-                                      nv[:, i])
+            # float32 holds every value of the model's dtype, so the
+            # choice is the same; numpy's argmax is far quicker there
+            # (as after a prefill)
+            picks = out[:B].astype(np.float32).argmax(axis=1)
+            for seq, tok in zip(batch, picks.tolist()):
                 seq.length += 1
-                tok = int(np.argmax(out[i]))
                 seq.last_tok = tok
                 seq.generated += 1
                 seq.req._emit(tok)
@@ -567,10 +628,6 @@ class GenEngine:
                 self._tokens["decode"] += B
             HUB.inc(labeled("gen_tokens_total", stage="decode"), B)
             post.set_attr("retired", retired)
-        # the step's rectangles, on the host and on the device, die here
-        # and not at the return, so that freeing them has a name
-        with trace.span("serve.decode-release", bytes=h2d):
-            del k, v, cache, logits, new_kv
 
     def _retire(self, seq: _Seq, error: str | None = None) -> None:
         """Finished/evicted/failed: blocks free IMMEDIATELY (the next
