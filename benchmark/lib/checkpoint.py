@@ -1,12 +1,14 @@
-"""A seeded Llama-architecture checkpoint that is never held in memory.
+"""A seeded checkpoint that is never held in memory.
 
-The benchmark's weights are a pure function of ``(seed, tensor, position)``:
-every 2-D weight is N(0, 1/fan_in) (the 65536 quantiles of the normal, one
-drawn per element by a counter-seeded SFC64 stream), every norm is ones,
-all in bfloat16 and in Hugging Face's tensor names and ``[out, in]``
-layout. The files of the sharded safetensors repository exist only as
-:class:`VirtualFile` objects that produce any byte range on demand, one
-MiB-sized chunk at a time, so
+The benchmark's weights are a pure function of ``(seed, tensor, position)``.
+Which tensors there are, and what fills each, is the table of the
+configuration's family (:mod:`families`: ``tensors(config)``, chosen by
+``model_type``): ``normal`` is N(0, 1/fan_in) (the 65536 quantiles of the
+normal, one drawn per element by a counter-seeded SFC64 stream), ``ones``
+and ``zeros`` are what they say, all in bfloat16 and in the names and
+layout the family gives (Hugging Face's, ``[out, in]``). The files of the
+sharded safetensors repository exist only as :class:`VirtualFile` objects
+that produce any byte range on demand, one MiB-sized chunk at a time, so
 
 - the loopback hub (:mod:`hub`) serves 12 GB (or 46 GB) of weights without
   allocating them: on the chip machine touching fresh memory costs more
@@ -30,6 +32,8 @@ from concurrent.futures import ThreadPoolExecutor
 import ml_dtypes
 import numpy as np
 
+from . import families
+
 #: elements generated per counter-seeded stream (1 MiB of bfloat16)
 CHUNK = 1 << 19
 
@@ -43,39 +47,23 @@ def _normal_quantiles() -> np.ndarray:
 
 
 class _Tensor:
-    __slots__ = ("index", "name", "shape", "nbytes", "offset", "fan_in")
+    __slots__ = ("index", "name", "shape", "nbytes", "offset", "fill",
+                 "fan_in")
 
-    def __init__(self, index: int, name: str, shape: tuple[int, ...]):
+    def __init__(self, index: int, name: str, spec: families.Filled):
+        shape, fill, fan_in = families.Filled(*spec)
+        if fill not in ("normal", "ones", "zeros") \
+                or (fill == "normal" and int(fan_in) <= 0):
+            raise ValueError(f"tensor {name!r}: fill {fill!r} with fan-in "
+                             f"{fan_in!r}; a family says normal (with the "
+                             "fan-in), ones or zeros")
         self.index = index
         self.name = name
-        self.shape = shape
-        self.nbytes = int(np.prod(shape)) * 2
+        self.shape = tuple(int(n) for n in shape)
+        self.nbytes = int(np.prod(self.shape)) * 2
         self.offset = 0          # byte offset in its file's data section
-        self.fan_in = shape[1] if len(shape) == 2 else 0   # 0: a norm
-
-
-def tensor_shapes(config: dict) -> dict[str, tuple[int, ...]]:
-    """HF tensor name → shape for a ``LlamaForCausalLM`` config."""
-    D, I = config["hidden_size"], config["intermediate_size"]
-    V, L = config["vocab_size"], config["num_hidden_layers"]
-    H, Hkv = config["num_attention_heads"], config["num_key_value_heads"]
-    hd = config.get("head_dim") or D // H
-    shapes: dict[str, tuple[int, ...]] = {"model.embed_tokens.weight": (V, D)}
-    for i in range(L):
-        p = f"model.layers.{i}."
-        shapes.update({
-            p + "input_layernorm.weight": (D,),
-            p + "self_attn.q_proj.weight": (H * hd, D),
-            p + "self_attn.k_proj.weight": (Hkv * hd, D),
-            p + "self_attn.v_proj.weight": (Hkv * hd, D),
-            p + "self_attn.o_proj.weight": (D, H * hd),
-            p + "post_attention_layernorm.weight": (D,),
-            p + "mlp.gate_proj.weight": (I, D),
-            p + "mlp.up_proj.weight": (I, D),
-            p + "mlp.down_proj.weight": (D, I),
-        })
-    shapes.update({"model.norm.weight": (D,), "lm_head.weight": (V, D)})
-    return shapes
+        self.fill = fill
+        self.fan_in = int(fan_in)
 
 
 class VirtualFile:
@@ -133,14 +121,16 @@ class Checkpoint:
         base = _normal_quantiles()
         self._tables: dict[int, np.ndarray] = {}
         self.tensors: dict[str, _Tensor] = {}
-        for i, (name, shape) in enumerate(tensor_shapes(config).items()):
-            t = _Tensor(i, name, shape)
+        table = families.of(config).tensors(config)
+        for i, (name, spec) in enumerate(table.items()):
+            t = _Tensor(i, name, spec)
             self.tensors[name] = t
-            if t.fan_in and t.fan_in not in self._tables:
+            if t.fill == "normal" and t.fan_in not in self._tables:
                 self._tables[t.fan_in] = (
                     base / np.sqrt(np.float32(t.fan_in))
                 ).astype(BF16).view(np.uint16)
-        self._ones = np.full(CHUNK, 1.0, BF16).view(np.uint16)
+        self._const = {"ones": np.full(CHUNK, 1.0, BF16).view(np.uint16),
+                       "zeros": np.zeros(CHUNK, np.uint16)}
 
         total = sum(t.nbytes for t in self.tensors.values())
         self.files: dict[str, bytes | VirtualFile] = {
@@ -172,8 +162,8 @@ class Checkpoint:
     def _chunk(self, t: _Tensor, j: int) -> np.ndarray:
         """Chunk ``j`` of tensor ``t`` as uint16 bit patterns of bfloat16."""
         n = min(CHUNK, t.nbytes // 2 - j * CHUNK)
-        if not t.fan_in:
-            return self._ones[:n]
+        if t.fill != "normal":
+            return self._const[t.fill][:n]
         raw = np.random.SFC64([self.seed, t.index, j]).random_raw(
             (n + 3) // 4).view(np.uint16)[:n]
         return np.take(self._tables[t.fan_in], raw, mode="wrap")

@@ -7,7 +7,10 @@ A metric's data file (``benchmark/end_to_end/<name>.json`` or
 arguments and returns a number, or ``None`` when it finds nothing to read
 (the harness then leaves the metric out of the line). A new metric that an
 existing reader can compute is a data file and an entry in
-``BENCHMARK.json``, nothing else.
+``BENCHMARK.json``, nothing else. A reader that is not here is named by
+path, ``"reader": "<module>:<function>"`` for a module under ``lib/``
+(``families.<model_type>:<function>`` for one in a family's own file), and
+has the same signature: a new quantity is a new file too.
 
 Times are the host's ``time.time()``; a request's times are taken on the
 client's side of HTTP. Spans and counters are the program's own
@@ -17,10 +20,11 @@ come from the profiler's trace through :mod:`xplane`.
 
 from __future__ import annotations
 
+import importlib
 import re
 from dataclasses import dataclass, field
 
-from . import costs, xplane
+from . import families, xplane
 
 
 @dataclass
@@ -212,14 +216,15 @@ def trace_op_share(obs: Observed, pattern: str):
 
 def prefill_flops_roofline(obs: Observed, span: str, attr: str):
     """The least time the prefills of the window could take on the MXU
-    (operations from :func:`costs.prefill_flops` over the peak, divided
+    (operations from the family's ``prefill_flops`` over the peak, divided
     among the cell's chips) over the device time inside their spans."""
     spans = [s for s in obs.window_spans(span) if attr in s.get("attrs", {})]
     took = _device_mean(obs, lambda ops: xplane.seconds_within(
         ops, [(s["ts"], s["ts"] + s["dur"]) for s in spans]))
     if not took:
         return None
-    flops = sum(costs.prefill_flops(obs.model, s["attrs"][attr])
+    family = families.of(obs.model)
+    flops = sum(family.prefill_flops(obs.model, s["attrs"][attr])
                 for s in spans)
     least = flops / (obs.peaks["bf16_flops_per_s"] * obs.chips)
     return 100.0 * least / took
@@ -227,8 +232,9 @@ def prefill_flops_roofline(obs: Observed, span: str, attr: str):
 
 def decode_bytes_roofline(obs: Observed, span: str):
     """The least time the window's decode steps could take reading HBM
-    (:func:`costs.decode_bytes`: weights once a step, the filled cache
-    behind every token decoded) over the device time inside their spans."""
+    (the family's ``decode_bytes``, which is given the attributes of every
+    step's span and the cached positions behind every token decoded) over
+    the device time inside their spans."""
     spans = obs.window_spans(span)
     took = _device_mean(obs, lambda ops: xplane.seconds_within(
         ops, [(s["ts"], s["ts"] + s["dur"]) for s in spans]))
@@ -236,11 +242,12 @@ def decode_bytes_roofline(obs: Observed, span: str):
         return None
     # token k of a request (k >= 2) came from a step that read its prompt
     # and the k - 2 tokens fed before it
-    positions = sum(len(r.prompt) + k - 1
-                    for r in obs.records
-                    for k, t in enumerate(r.times) if k >= 1
-                    and obs.t0 <= t <= obs.t1)
-    need = costs.decode_bytes(obs.model, len(spans), positions)
+    lengths = [len(r.prompt) + k - 1
+               for r in obs.records
+               for k, t in enumerate(r.times) if k >= 1
+               and obs.t0 <= t <= obs.t1]
+    need = families.of(obs.model).decode_bytes(
+        obs.model, [s.get("attrs", {}) for s in spans], lengths)
     least = need / (obs.peaks["hbm_bytes_per_s"] * obs.chips)
     return 100.0 * least / took
 
@@ -257,12 +264,28 @@ READERS = {f.__name__: f for f in (
     decode_bytes_roofline, memory_peak_gb)}
 
 
+def resolve(name: str):
+    """The reader a metric's data file names: a bare name is one of this
+    module's ``READERS``; ``<module>:<function>`` is a function of a module
+    under ``lib/`` (``families.<model_type>:<function>``), so that a metric
+    no reader here computes arrives as a new file."""
+    module, _, function = name.rpartition(":")
+    if not module:
+        if name not in READERS:
+            raise ValueError(
+                f"no reader called {name!r}; there are {sorted(READERS)}, "
+                "and a reader of another module under benchmark/lib/ is "
+                "named \"<module>:<function>\"")
+        return READERS[name]
+    found = getattr(importlib.import_module(f"{__package__}.{module}"),
+                    function, None)
+    if not callable(found):
+        raise ValueError(f"benchmark/lib/{module.replace('.', '/')}.py has "
+                         f"no reader called {function!r}")
+    return found
+
+
 def read(obs: Observed, spec: dict):
     """Apply the reader a metric's data file names; None when there is
     nothing to read."""
-    try:
-        fn = READERS[spec["reader"]]
-    except KeyError:
-        raise ValueError(f"no reader called {spec['reader']!r}; there are "
-                         f"{sorted(READERS)}") from None
-    return fn(obs, **spec.get("args", {}))
+    return resolve(spec["reader"])(obs, **spec.get("args", {}))

@@ -1,9 +1,11 @@
-"""The plain reference: a Llama-architecture forward pass in float32.
+"""The plain reference: a forward pass in float32, and its comparison.
 
-Straightforward ``jax.numpy`` following the published equations (RMSNorm,
-rotate-half RoPE, grouped-query causal attention, SwiGLU), one sequence at
-a time, no cache, no batching, no kernel, matmuls at ``highest`` precision
-(on a TPU a float32 matmul otherwise runs in bfloat16 passes). It imports
+The layers are the family's (:mod:`families`: ``logits`` of the file named
+by the configuration's ``model_type``); here are the pieces every family
+builds them from and the comparison ``correct`` makes. Straightforward
+``jax.numpy`` following the published equations, one sequence at a time,
+no cache, no batching, no kernel, matmuls at ``highest`` precision (on a
+TPU a float32 matmul otherwise runs in bfloat16 passes). It imports
 nothing of the program under test and takes nothing the program made: the
 weights come again from the seed through :class:`checkpoint.Checkpoint`,
 one layer at a time, so that a model whose float32 copy would not fit runs
@@ -31,31 +33,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import families
+
 HIGHEST = jax.lax.Precision.HIGHEST
 PAD = 256          # sequences are padded to a multiple: few shapes compile
 ROWS = 64          # and so are the positions whose logits are wanted
 
-_LAYER_TENSORS = {
-    "attn_norm": "input_layernorm.weight",
-    "q": "self_attn.q_proj.weight", "k": "self_attn.k_proj.weight",
-    "v": "self_attn.v_proj.weight", "o": "self_attn.o_proj.weight",
-    "mlp_norm": "post_attention_layernorm.weight",
-    "gate": "mlp.gate_proj.weight", "up": "mlp.up_proj.weight",
-    "down": "mlp.down_proj.weight",
-}
 
-
-def _int8(a, axis):
+def int8(a, axis):
     scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0
     scale = jnp.where(scale == 0, 1.0, scale)
     return jnp.round(a / scale) * scale
 
 
-def _linear(x, w, mode: str):
+def linear(x, w, mode: str):
     """``x [T, in] @ w[out, in].T`` in the precision ``mode`` names."""
     w = w.astype(jnp.float32)
     if mode == "int8":
-        x, w = _int8(x, 1), _int8(w, 1)
+        x, w = int8(x, 1), int8(w, 1)
     elif mode == "bfloat16":
         x = x.astype(jnp.bfloat16).astype(jnp.float32)
     elif mode != "float32":
@@ -68,12 +63,12 @@ def _linear(x, w, mode: str):
     return y
 
 
-def _rms_norm(x, w, eps: float):
+def rms_norm(x, w, eps: float):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
-def _rope(x, theta: float):
+def rope(x, theta: float):
     """``x [T, H, hd]`` at positions 0..T-1, Hugging Face's rotate-half."""
     T, _H, hd = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -84,31 +79,9 @@ def _rope(x, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
-@partial(jax.jit, static_argnames=("H", "Hkv", "eps", "theta", "mode"))
-def _layer(x, w, *, H: int, Hkv: int, eps: float, theta: float, mode: str):
-    T, _D = x.shape
-    h = _rms_norm(x, w["attn_norm"], eps)
-    q = _linear(h, w["q"], mode).reshape(T, H, -1)
-    k = _linear(h, w["k"], mode).reshape(T, Hkv, -1)
-    v = _linear(h, w["v"], mode).reshape(T, Hkv, -1)
-    hd = q.shape[-1]
-    q, k = _rope(q, theta), _rope(k, theta)
-    k = jnp.repeat(k, H // Hkv, axis=1)
-    v = jnp.repeat(v, H // Hkv, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, k, precision=HIGHEST) / np.sqrt(hd)
-    causal = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-    s = jnp.where(causal[None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    a = jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST).reshape(T, -1)
-    x = x + _linear(a, w["o"], mode)
-    h = _rms_norm(x, w["mlp_norm"], eps)
-    y = jax.nn.silu(_linear(h, w["gate"], mode)) * _linear(h, w["up"], mode)
-    return x + _linear(y, w["down"], mode)
-
-
 @partial(jax.jit, static_argnames=("eps", "mode"))
 def _head(x, norm, head, *, eps: float, mode: str):
-    return _linear(_rms_norm(x, norm, eps), head, mode)
+    return linear(rms_norm(x, norm, eps), head, mode)
 
 
 def logits(ckpt, sequences: list[list[int]], wanted: list[range],
@@ -116,36 +89,43 @@ def logits(ckpt, sequences: list[list[int]], wanted: list[range],
     """Logits of each ``sequences[i]`` at the positions ``wanted[i]``
     (position p's logits predict token p + 1), as ``[n, V]`` arrays whose
     first ``len(wanted[i])`` rows are those positions; ``n`` is rounded up
-    to a multiple of ``ROWS`` and the rows past them are padding."""
-    cfg = ckpt.config
-    H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
-    kw = dict(H=H, Hkv=Hkv, eps=eps, theta=theta, mode=mode)
+    to a multiple of ``ROWS`` and the rows past them are padding. The
+    forward pass is the one of the checkpoint's family."""
+    return families.of(ckpt.config).logits(ckpt, sequences, wanted, mode)
 
-    embed = ckpt.tensor("model.embed_tokens.weight")
+
+def embed(ckpt, name: str, sequences: list[list[int]]) -> list[jax.Array]:
+    """Rows of the embedding tensor ``name`` for each sequence, float32,
+    padded with zero rows to a multiple of ``PAD`` positions."""
+    table = ckpt.tensor(name)
     xs = []
     for seq in sequences:
-        rows = np.zeros((-(-len(seq) // PAD) * PAD, embed.shape[1]),
+        rows = np.zeros((-(-len(seq) // PAD) * PAD, table.shape[1]),
                         np.float32)
-        rows[:len(seq)] = embed[np.asarray(seq)].astype(np.float32)
+        rows[:len(seq)] = table[np.asarray(seq)].astype(np.float32)
         xs.append(jnp.asarray(rows))
-    del embed
+    return xs
 
-    def load(i: int) -> dict:
-        return {k: ckpt.tensor(f"model.layers.{i}.{name}")
-                for k, name in _LAYER_TENSORS.items()}
 
-    n_layers = cfg["num_hidden_layers"]
-    with ThreadPoolExecutor(1) as ahead:      # the next layer, meanwhile
+def layers_ahead(load, n_layers: int):
+    """Yield ``load(0)``, ``load(1)``, ... on the device, each made from
+    the seed on another thread while the one before it is in use. A
+    caller that drops its layer (``del``) before asking for the next has
+    one layer on the device at a time."""
+    with ThreadPoolExecutor(1) as ahead:
         nxt = ahead.submit(load, 0)
         for i in range(n_layers):
             w = jax.device_put(nxt.result())
             if i + 1 < n_layers:
                 nxt = ahead.submit(load, i + 1)
-            xs = jax.block_until_ready([_layer(x, w, **kw) for x in xs])
+            yield w
             del w
-    norm = jax.device_put(ckpt.tensor("model.norm.weight"))
-    head = jax.device_put(ckpt.tensor("lm_head.weight"))
+
+
+def head_rows(xs: list[jax.Array], wanted: list[range], norm, head, *,
+              eps: float, mode: str) -> list[jax.Array]:
+    """The final norm and the output head at the ``wanted`` positions of
+    each sequence, the rows padded to a multiple of ``ROWS``."""
     out = []
     for x, want in zip(xs, wanted):
         rows = np.asarray(want)

@@ -15,7 +15,10 @@ Everything particular to a cell is data found by name from
 ``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<traffic>.json``,
 ``cells/<workload>.json`` (what ``correct`` samples and its limits),
 ``end_to_end/<metric>.json`` and ``layer_metrics/<metric>.json`` (the
-reader of each metric). See ``README.md`` beside this file.
+reader of each metric), ``spans/*.json`` (the host spans idle time is
+booked to). What knows the architecture is the file of
+``lib/families/`` that the configuration's ``model_type`` names. See
+``README.md`` beside this file.
 
 Without a TPU (or with fewer chips than the cell asks for) this exits 1 and
 prints no result. ``--rehearse`` is the one exception, for finding faults
@@ -48,11 +51,6 @@ sys.path.insert(0, str(ROOT))
 from lib import xplane  # noqa: E402  (no JAX until a trace is read)
 
 MODEL_ID = "bench/model"
-
-#: the toy the rehearsal swaps in for the published sizes (CPU, counts only)
-REHEARSAL_MODEL = {"hidden_size": 128, "intermediate_size": 256,
-                   "num_hidden_layers": 2, "num_attention_heads": 8,
-                   "num_key_value_heads": 4, "vocab_size": 512}
 
 
 def say(msg: str) -> None:
@@ -264,12 +262,14 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
     model = {k: v for k, v in cfg_doc.items() if k != "benchmark"}
     chips = int(cell["chips"])
 
-    if args.rehearse:
-        model.update(REHEARSAL_MODEL)
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.rehearse:   # the family's toy sizes, on the CPU: counts only
+        os.environ["JAX_PLATFORMS"] = "cpu"     # before anything imports JAX
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
                                    f"--xla_force_host_platform_device_count="
                                    f"{chips}").strip()
+        from lib import families
+
+        model.update(families.of(model).rehearsal(model))
     import jax
 
     devices = jax.devices()
@@ -308,6 +308,7 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
 
     compiles = Compiles()
     phases = Phases()
+    after = Phases()        # what a run costs once the window has closed
     phases.seconds["start"] = time.time() - T_START   # imports, the runtime
     cache_dir = compile_cache.place()
     if args.trace:
@@ -393,12 +394,14 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
                        if r.sent is not None and r.sent <= obs.t1]
         if tracing:
             drop_marks(marks)
-            jax.profiler.stop_trace()
+            with after("trace_stop"):
+                jax.profiler.stop_trace()
             tracing = False
             obs.spans = spans.buffer().snapshot()
             path = xplane.find_trace(work / "trace")
             if path is not None:
-                obs.trace = xplane.read(path, marks, chips)
+                with after("trace_read"):
+                    obs.trace = xplane.read(path, marks, chips)
         stats = [d.memory_stats() or {} for d in devices[:chips]]
         obs.memory_peak_bytes = max(
             (s.get("peak_bytes_in_use", 0) for s in stats), default=0)
@@ -499,9 +502,6 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
             f"first choice, in {time.perf_counter() - t_ref:.1f} s")
     else:
         say("reference: the window served nothing to compare")
-    for name, value, limit in checks:
-        say(f"correct: {name} = {value:.6g} (limit {limit:g}) "
-            f"{'ok' if value <= limit else 'OVER'}")
     reasons = []
     if not checks:
         reasons.append("nothing compared with the reference")
@@ -519,15 +519,22 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
         reasons.append("a rehearsal is never a result")
     correct = not reasons
     say("correct: " + ("true" if correct else "false: " + "; ".join(reasons)))
+    for name, value, limit in checks:   # the last lines of standard error
+        print(f"[bench] compared: {name} = {value:.6g} (limit {limit:g}) "
+              f"{'ok' if value <= limit else 'OVER'}", file=sys.stderr,
+              flush=True)
 
     # ------------------------------------------------------------ metrics
+    t_metrics = time.perf_counter()
     kind = "per_layer" if args.trace else "end_to_end"
     folder = HERE / ("layer_metrics" if args.trace else "end_to_end")
     metrics: dict[str, dict] = {}
-    for m in cell_metrics(bench, cell["name"], kind):
-        value = readers.read(obs, load_json(folder / f"{m['name']}.json"))
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    with after("metrics"):
+        for m in cell_metrics(bench, cell["name"], kind):
+            value = readers.read(obs,
+                                 load_json(folder / f"{m['name']}.json"))
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device["memory_peak_bytes"] = obs.memory_peak_bytes
     result: dict = {"correct": correct, "attempted": len(obs.records),
                     "failed": len(failed), "metrics": metrics,
@@ -535,12 +542,16 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
     if args.trace and obs.trace is not None and obs.trace.devices:
         device["busy_s"] = readers.device_busy_seconds(obs)
         device["window_s"] = obs.seconds
-        result["breakdown"] = breakdown(obs)
+        with after("breakdown"):
+            result["breakdown"] = breakdown(obs)
         say(f"trace: {len(obs.trace.devices)} device(s), "
             f"{sum(len(v) for v in obs.trace.devices.values())} operations, "
             f"clock marks matched {obs.trace.marks_found}, offset "
             f"{obs.trace.clock_offset_s:.6f} s; device busy inside engine "
             f"spans {inside_spans_share(obs):.1f} % of all busy")
+    say("after the window, seconds: " + ", ".join(
+        f"{k} {v:.2f}" for k, v in after.seconds.items())
+        + f", reference {t_metrics - t_ref:.2f}")
     if args.keep is not None:
         args.keep.mkdir(parents=True, exist_ok=True)
         with open(args.keep / f"{cell['name']}-{args.seed}.json", "w",
@@ -557,11 +568,20 @@ def run(args) -> tuple[int, dict | None, list[str]]:  # noqa: C901
             {k: v["value"] for k, v in metrics.items()}))
         result["metrics"] = {}
         result.pop("breakdown", None)
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in checks}    # comes last
     return 0, result, reasons
 
 
-ENGINE_SPANS = ["serve.prefill", "serve.decode-step", "serve.admit",
-                "serve.restore"]
+def engine_spans() -> tuple[list[str], list[str]]:
+    """The host spans of ``spans/*.json``, innermost first (a deeper span
+    takes the instant it shares with a shallower one), and those of them
+    that hold the device's programs."""
+    rows = [row for path in sorted((HERE / "spans").glob("*.json"))
+            for row in load_json(path)["spans"]]
+    rows.sort(key=lambda row: -row["depth"])
+    return ([row["name"] for row in rows],
+            [row["name"] for row in rows if row.get("programs")])
 
 
 def breakdown(obs) -> dict:
@@ -570,13 +590,15 @@ def breakdown(obs) -> dict:
     n = len(obs.trace.devices)
     ops: dict[str, float] = {}
     idle: dict[str, float] = {}
+    order, _programs = engine_spans()
+    known = set(order)
     host = [(s["name"], s["ts"], s["ts"] + s["dur"]) for s in obs.spans
-            if s["name"] in ENGINE_SPANS]
+            if s["name"] in known]
     for dev_ops in obs.trace.devices.values():
         for name, secs in xplane.op_sums(dev_ops, obs.t0, obs.t1).items():
             ops[name] = ops.get(name, 0.0) + secs / n
         gaps = xplane.idle_gaps(dev_ops, obs.t0, obs.t1)
-        for name, secs in xplane.attribute(gaps, host, ENGINE_SPANS).items():
+        for name, secs in xplane.attribute(gaps, host, order).items():
             idle[name] = idle.get(name, 0.0) + secs / n
 
     def top(d: dict[str, float]) -> list:
@@ -588,14 +610,15 @@ def breakdown(obs) -> dict:
 
 def inside_spans_share(obs) -> float:
     """A check on the clocks: the share of device busy time that falls
-    inside the engine's prefill and decode spans (near 100 when the
-    profiler's clock and the host's are aligned)."""
+    inside the spans that hold the device's programs (prefill and decode
+    step; near 100 when the profiler's clock and the host's are aligned)."""
+    _order, programs = engine_spans()
     iv = [(s["ts"], s["ts"] + s["dur"]) for s in obs.spans
-          if s["name"] in ENGINE_SPANS[:2]]
+          if s["name"] in programs]
     tot = ins = 0.0
     for dev_ops in obs.trace.devices.values():
         clipped = xplane.clip(dev_ops, obs.t0, obs.t1)
-        tot += sum(b - a for a, b in xplane.busy_intervals(clipped))
+        tot += xplane.busy_seconds(clipped, obs.t0, obs.t1)
         ins += xplane.seconds_within(clipped, iv)
     return 100.0 * ins / tot if tot else 0.0
 

@@ -25,7 +25,7 @@ import numpy as np
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parent))
 
-from lib import checkpoint, loadgen, reference  # noqa: E402
+from lib import checkpoint, families, loadgen, reference  # noqa: E402
 
 TAIL = 32
 
@@ -35,7 +35,8 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--rehearse", action="store_true",
-                    help="run.py's toy model, to try the script on the CPU")
+                    help="the family's toy model, to try the script on the "
+                         "CPU")
     args = ap.parse_args()
     import jax.numpy as jnp
 
@@ -45,9 +46,7 @@ def main() -> int:
     doc = json.loads((HERE.parent.parent / config["file"]).read_text())
     model = {k: v for k, v in doc.items() if k != "benchmark"}
     if args.rehearse:
-        from run import REHEARSAL_MODEL
-
-        model.update(REHEARSAL_MODEL)
+        model.update(families.of(model).rehearsal(model))
     traffic = json.loads((HERE.parent / "traffic"
                           / f"{cell['traffic']}.json").read_text())
     for seed in args.seeds:

@@ -29,7 +29,8 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parent))
 sys.path.insert(0, str(HERE.parent.parent))
 
-TOY = {"hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 4,
+TOY = {"model_type": "llama", "hidden_size": 256,
+       "intermediate_size": 512, "num_hidden_layers": 4,
        "num_attention_heads": 8, "num_key_value_heads": 2,
        "vocab_size": 8192, "rms_norm_eps": 1e-6, "rope_theta": 5e6,
        "torch_dtype": "bfloat16"}

@@ -4,16 +4,22 @@
 1. The reduction from device intervals to numbers (``lib/xplane.py``: the
    busy union, the idle gaps, which host span covers each gap, sums by
    operation) against ``tiny_trace.json``, a slice of a trace recorded on
-   the chip, rasterised here at 100 ns by other code.
-2. The operation counts of ``lib/costs.py`` against what XLA reports for
-   the program's own prefill (``compiled.cost_analysis()``) at a small
-   size: the count of what the program executes within 5 %, and the count
-   of what the algorithm needs (causal half of attention, the head for one
-   position) below it.
+   the chip, rasterised here at 100 ns by other code; and its arrays
+   against the plain loops they replaced (``loops.py``), to the last
+   digits that summing in another order leaves. ``python3
+   benchmark/tests/test_reduction.py <file> ...`` holds the two against
+   each other on traces kept from the chip (``run.py --keep``; ``.gz``
+   is read too).
+2. The operation counts of ``lib/families/llama.py`` against what XLA
+   reports for the program's own prefill (``compiled.cost_analysis()``) at
+   a small size: the count of what the program executes within 5 %, and
+   the count of what the algorithm needs (causal half of attention, the
+   head for one position) below it.
 """
 
 from __future__ import annotations
 
+import gzip
 import json
 import sys
 from pathlib import Path
@@ -21,10 +27,13 @@ from pathlib import Path
 import numpy as np
 
 HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent))
 sys.path.insert(0, str(HERE.parent.parent))
 
-from lib import costs, xplane  # noqa: E402
+import loops  # noqa: E402
+from lib import xplane  # noqa: E402
+from lib.families import llama as costs  # noqa: E402
 
 TICK = 1e-7
 
@@ -82,6 +91,57 @@ def test_reduction_against_recorded_trace():
     assert xplane.op_name("%all-reduce.4.1") == "all-reduce"
 
 
+def arrays_against_loops(doc: dict, order: list[str]) -> dict[str, float]:
+    """Every number the harness takes from a trace, by the arrays and by
+    the loops, over the kept ``doc`` (``window`` or ``t0``/``t1``, ``trace``,
+    ``spans``): the largest difference found, relative to the value."""
+    t0, t1 = doc["window"] if "window" in doc else (doc["t0"], doc["t1"])
+    spans = [(s["name"], s["ts"], s["ts"] + s["dur"]) for s in doc["spans"]]
+    worst: dict[str, float] = {}
+
+    def hold(what: str, got: float, want: float) -> None:
+        worst[what] = max(worst.get(what, 0.0),
+                          abs(got - want) / max(abs(want), 1e-12))
+
+    for ops in xplane.Trace.from_json(doc["trace"]).devices.values():
+        plain = list(ops)
+        hold("busy_seconds", xplane.busy_seconds(ops, t0, t1),
+             loops.busy_seconds(plain, t0, t1))
+        gaps, pgaps = xplane.idle_gaps(ops, t0, t1), loops.idle_gaps(
+            plain, t0, t1)
+        assert len(gaps) == len(pgaps)
+        assert np.array_equal(np.asarray(gaps), np.asarray(pgaps))
+        by, pby = (m.attribute(g, spans, order)
+                   for m, g in ((xplane, gaps), (loops, pgaps)))
+        assert by.keys() == pby.keys(), (by, pby)
+        for name in pby:
+            hold("attribute", by[name], pby[name])
+        sums, psums = xplane.op_sums(ops, t0, t1), loops.op_sums(
+            plain, t0, t1)
+        assert sums.keys() == psums.keys()
+        for name in psums:
+            hold("op_sums", sums[name], psums[name])
+        for kind in sorted({n for n, _a, _b in spans}):
+            iv = [(a, b) for n, a, b in spans if n == kind]
+            hold("seconds_within", xplane.seconds_within(ops, iv),
+                 loops.seconds_within(plain, iv))
+        clipped = xplane.clip(ops, t0, t1)
+        assert list(clipped) == loops.clip(plain, t0, t1)
+        assert np.array_equal(xplane.busy_intervals(clipped), np.asarray(
+            loops.busy_intervals(loops.clip(plain, t0, t1))))
+    return worst
+
+
+def test_arrays_read_what_the_loops_read():
+    import run as harness
+
+    doc = json.loads((HERE / "tiny_trace.json").read_text())
+    worst = arrays_against_loops(doc, harness.engine_spans()[0])
+    assert set(worst) == {"busy_seconds", "attribute", "op_sums",
+                          "seconds_within"}
+    assert max(worst.values()) < 1e-12, worst
+
+
 def test_flop_counts_against_xla():
     import jax
     import jax.numpy as jnp
@@ -110,12 +170,22 @@ def test_flop_counts_against_xla():
           "num_key_value_heads": 4, "vocab_size": 64000}
     assert costs.kv_bytes_per_position(yi) == 65536
     assert abs(costs.parameters(yi) - 6.061e9) < 1e6
-    assert abs(costs.decode_bytes(yi, 1, 8000)
+    assert abs(costs.decode_bytes(yi, [{}], [1000] * 8)
                - (2 * (costs.parameters(yi) - 64000 * 4096 - 65 * 4096)
                   + 8000 * 65536)) < 1
 
 
 if __name__ == "__main__":
+    if sys.argv[1:]:
+        import run as harness
+
+        for kept in sys.argv[1:]:
+            opener = gzip.open if kept.endswith(".gz") else open
+            with opener(kept, "rt") as f:
+                print(kept, arrays_against_loops(
+                    json.load(f), harness.engine_spans()[0]))
+        sys.exit(0)
     test_reduction_against_recorded_trace()
+    test_arrays_read_what_the_loops_read()
     test_flop_counts_against_xla()
     print("reduction and operation counts: ok")
