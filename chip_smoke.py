@@ -222,11 +222,12 @@ def check_against_reference(engine, prompt: list[int]) -> dict:
     pool = engine.pool
     lease = pool.alloc(pool.blocks_for(T + 1))
     try:
-        logits_p = engine._prefill(prompt, lease)
+        logits_p, *_stats = engine._prefill(prompt, lease)
         tok0 = int(np.argmax(np.asarray(logits_p[0], np.float32)))
         _width, rows = engine._decode_inputs([_Seq(None, lease, T, tok0)])
-        logits_d = pool.apply(engine._jdecode, engine.params,
-                              jax.device_put(rows, pool.replicated))
+        logits_d, *_stats = pool.apply(
+            engine._jdecode, engine.params,
+            jax.device_put(rows, pool.replicated))
     finally:
         lease.free()
 

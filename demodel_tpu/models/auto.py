@@ -13,10 +13,12 @@ import functools
 import json
 
 from demodel_tpu.models import bert as bert_mod
+from demodel_tpu.models import exaone_moe as exaone_moe_mod
 from demodel_tpu.models import gpt2 as gpt2_mod
 from demodel_tpu.models import llama as llama_mod
 from demodel_tpu.models.hf_loader import (
     load_bert_params,
+    load_exaone_moe_params,
     load_gpt2_params,
     load_llama_params,
 )
@@ -38,7 +40,9 @@ def _check_supported(config: dict) -> None:
 
 
 def model_from_pull(store, report, mesh=None, placement=None):
-    """(forward_fn, params, cfg) from a pulled snapshot.
+    """(forward_fn, params, cfg) from a pulled snapshot (``forward_fn`` is
+    None for a family that only the serving engine runs, through its
+    module's ``step_prefill`` / ``step_decode``).
 
     ``placement`` (a delivered :class:`~demodel_tpu.sink.hbm.Placement`)
     supplies the weight arrays when given; otherwise weights are delivered
@@ -76,9 +80,15 @@ def model_from_pull(store, report, mesh=None, placement=None):
         cfg = bert_mod.BertConfig.from_hf(config)
         params = load_bert_params(weights, cfg)
         fn = functools.partial(bert_mod.encode, cfg=cfg, mesh=mesh)
+    elif model_type == "exaone_moe":
+        # its window layers are the model's own (``sliding_windows``), so
+        # ``sliding_window`` is no unsupported feature here
+        cfg = exaone_moe_mod.ExaoneMoeConfig.from_hf(config)
+        params = load_exaone_moe_params(weights, cfg, mesh=mesh)
+        fn = None   # served through its step functions only
     else:
         raise ValueError(f"unsupported model_type {model_type!r} "
-                         "(supported: llama, gpt2, bert)")
+                         "(supported: llama, gpt2, bert, exaone_moe)")
     log.info("auto: built %s from pulled snapshot (%d tensors)",
              model_type, n_tensors)
     return fn, params, cfg

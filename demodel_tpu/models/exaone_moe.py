@@ -1,0 +1,468 @@
+"""EXAONE-MoE (``model_type`` ``exaone_moe``): window and full attention
+layers side by side, sparse experts with token-choice top-k routing.
+
+The layer, for input ``x`` [T, D] (every norm an RMSNorm with a learned
+weight; each sub-layer's *output* is normalised before the residual add,
+the EXAONE 4.0 convention):
+
+- ``x += RMSNorm(attn(x))``: ``q``/``k`` get an RMSNorm over the head width
+  (one weight a layer); a ``sliding_attention`` layer rotates them (RoPE,
+  whole head) and position ``i`` sees ``j`` with ``0 <= i - j < window``; a
+  ``full_attention`` layer rotates nothing and sees every ``j <= i``.
+- ``x += RMSNorm(mlp(x))``: a dense SwiGLU (``mlp_layer_types`` ``dense``),
+  or ``s = sigmoid(x @ Wr)`` in float32, the ``num_experts_per_tok`` experts
+  with the largest ``s + b`` chosen, ``w_e = scale * s_e / sum(chosen s)``,
+  ``sum_e w_e E_e(x) + S(x)`` with ``S`` the shared expert.
+
+**One chip's share of an expert-parallel replica.** ``num_experts`` counts
+the experts this checkpoint *holds*; ``ep_size`` shares of that size make
+the layer, and this is share ``ep_rank``: the router is ``num_experts *
+ep_size`` wide and every token chooses among all of them, the held experts
+are ``[ep_rank * num_experts, (ep_rank + 1) * num_experts)``, and the layer
+computes their part of the result for the assignments that fall on them:
+no capacity, no dropped token. What the absent experts would add is left
+out and the partial result goes on (there is no exchange on one chip, and
+nothing stands in for one). Under a mesh with an ``ep`` axis the held
+experts are split once more over that axis and the parts are summed.
+
+The multi-token-prediction layer of the published model drafts tokens for
+self-speculation and changes no next-token logit: it is not built here, and
+its tensors are left where the loader found them.
+
+**The cache.** The serving engine's pool holds every position of every
+layer in one geometry; a window layer's decode *reads* only the table
+slots that cover its last ``window - 1`` positions (:func:`step_decode`).
+A bounded ring for window layers, which would also stop *holding* what no
+step reads again, only shows at contexts of thousands and is not built.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from demodel_tpu.models.common import rms_norm
+from demodel_tpu.models.llama import _rope
+from demodel_tpu.utils.metrics import HUB, labeled
+
+#: the engine hands ``step_decode`` the pool and the block table
+#: (``kvcache.Paged``) where it hands a model whose layers all read the
+#: whole table the rectangles: window layers read their own slots
+PAGED_CACHE = True
+
+HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
+HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
+HUB.inc("gen_moe_experts_hit_total", 0)
+
+
+@dataclass(frozen=True)
+class ExaoneMoeConfig:
+    vocab_size: int = 153600
+    hidden_size: int = 6144
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    num_experts: int = 128          # held here
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    ep_size: int = 1                # shares that make a layer
+    ep_rank: int = 0                # which of them this is
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    #: a layer's window, 0 for a full-attention layer (never rotated)
+    sliding_windows: tuple[int, ...] = ()
+    #: whether a layer's MLP is the expert layer
+    sparse: tuple[bool, ...] = ()
+    dtype: str = "float32"
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.ep_size
+
+    @property
+    def sparse_layers(self) -> int:
+        return sum(self.sparse)
+
+    @classmethod
+    def tiny(cls, **over) -> "ExaoneMoeConfig":
+        """Test-sized: two periods ``LLLG``, layer 0 dense, window 8, a
+        quarter of 16 experts held, 4 a token."""
+        kw = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                  moe_intermediate_size=32, num_hidden_layers=8,
+                  num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+                  num_experts=4, num_experts_per_tok=4, ep_size=4,
+                  sliding_windows=(8, 8, 8, 0) * 2,
+                  sparse=(False,) + (True,) * 7)
+        kw.update(over)
+        return cls(**kw)
+
+    @classmethod
+    def from_hf(cls, config: dict) -> "ExaoneMoeConfig":
+        """From a ``config.json``. ``layer_types``, ``sliding_windows`` and
+        ``mlp_layer_types`` may be longer than ``num_hidden_layers`` (a
+        checkpoint cut in depth keeps the published lists): the first
+        ``num_hidden_layers`` entries count."""
+        for key, only in (("n_group", 1), ("topk_group", 1),
+                          ("scoring_func", "sigmoid"),
+                          ("hidden_act", "silu")):
+            if config.get(key, only) != only:
+                raise ValueError(f"config field {key}={config[key]!r} is "
+                                 "not supported by this stack")
+        rope = config.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_type {rope['rope_type']!r} is not "
+                             "supported by this stack")
+        L = int(config["num_hidden_layers"])
+        kinds = list(config["layer_types"])[:L]
+        windows = list(config.get("sliding_windows")
+                       or [config["sliding_window"]] * L)[:L]
+        mlps = list(config.get("mlp_layer_types") or [
+            "dense" if i < config.get("first_k_dense_replace", 0)
+            else "sparse" for i in range(L)])[:L]
+        if len(kinds) != L or len(windows) != L or len(mlps) != L:
+            raise ValueError(f"layer_types, sliding_windows and "
+                             f"mlp_layer_types must cover {L} layers")
+        H = int(config["num_attention_heads"])
+        return cls(
+            vocab_size=int(config["vocab_size"]),
+            hidden_size=int(config["hidden_size"]),
+            intermediate_size=int(config["intermediate_size"]),
+            moe_intermediate_size=int(config["moe_intermediate_size"]),
+            num_hidden_layers=L,
+            num_attention_heads=H,
+            num_key_value_heads=int(config.get("num_key_value_heads", H)),
+            head_dim=int(config.get("head_dim")
+                         or config["hidden_size"] // H),
+            num_experts=int(config["num_experts"]),
+            num_experts_per_tok=int(config["num_experts_per_tok"]),
+            num_shared_experts=int(config.get("num_shared_experts", 0)),
+            ep_size=int(config.get("ep_size", 1)),
+            ep_rank=int(config.get("ep_rank", 0)),
+            routed_scaling_factor=float(
+                config.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(config.get("norm_topk_prob", True)),
+            rope_theta=float(rope.get("rope_theta",
+                                      config.get("rope_theta", 1e6))),
+            rms_norm_eps=float(config.get("rms_norm_eps", 1e-5)),
+            sliding_windows=tuple(
+                int(w) if kind == "sliding_attention" else 0
+                for kind, w in zip(kinds, windows)),
+            sparse=tuple(m == "sparse" for m in mlps),
+            dtype=(config.get("torch_dtype") or config.get("dtype")
+                   or "float32"),
+        )
+
+
+# ------------------------------------------------------------------ params
+
+
+def init_params(key, cfg: ExaoneMoeConfig) -> dict:
+    """Seeded N(0, 1/fan_in) matrices, norms of ones, a zero selection
+    bias: the tree :func:`hf_loader.load_exaone_moe_params` builds."""
+    dt = jnp.dtype(cfg.dtype)
+    D, hd = cfg.hidden_size, cfg.head_dim
+    H, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    F, E = cfg.moe_intermediate_size, cfg.num_experts
+    keys = iter(jax.random.split(key, 16 * cfg.num_hidden_layers + 2))
+
+    def dense(*shape, fan_in=None):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                / np.sqrt(fan_in or shape[-2])).astype(dt)
+
+    layers = []
+    for sparse in cfg.sparse:
+        layer = {
+            "q_proj": dense(D, H * hd), "k_proj": dense(D, Hkv * hd),
+            "v_proj": dense(D, Hkv * hd), "o_proj": dense(H * hd, D),
+            "q_norm": jnp.ones((hd,), dt), "k_norm": jnp.ones((hd,), dt),
+            "attn_norm": jnp.ones((D,), dt), "mlp_norm": jnp.ones((D,), dt),
+        }
+        if sparse:
+            Fs = F * cfg.num_shared_experts
+            layer.update({
+                "router": dense(D, cfg.router_width),
+                "router_bias": jnp.zeros((cfg.router_width,), jnp.float32),
+                "experts_gate_up": dense(E, D, 2 * F),
+                "experts_down": dense(E, F, D),
+                "shared_gate_proj": dense(D, Fs),
+                "shared_up_proj": dense(D, Fs),
+                "shared_down_proj": dense(Fs, D),
+            })
+        else:
+            I = cfg.intermediate_size
+            layer.update({"gate_proj": dense(D, I), "up_proj": dense(D, I),
+                          "down_proj": dense(I, D)})
+        layers.append(layer)
+    return {
+        "embed": dense(cfg.vocab_size, D, fan_in=1),
+        "layers": layers,
+        "final_norm": jnp.ones((D,), dt),
+        "lm_head": dense(D, cfg.vocab_size),
+    }
+
+
+def _ep(mesh: Mesh | None) -> int:
+    return int(mesh.shape.get("ep", 1)) if mesh is not None else 1
+
+
+def param_shardings(cfg: ExaoneMoeConfig, mesh: Mesh) -> dict:
+    """NamedSharding tree matching :func:`init_params`: the held experts
+    split over ``ep`` (when they divide), everything else replicated, as
+    in the deployment the configuration stands for (attention, the shared
+    expert and the router on every chip)."""
+    rep = NamedSharding(mesh, P())
+    held = NamedSharding(mesh, P("ep")) \
+        if _ep(mesh) > 1 and cfg.num_experts % _ep(mesh) == 0 else rep
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    tree = jax.tree.map(lambda _leaf: rep, shapes)
+    for layer in tree["layers"]:
+        for name in ("experts_gate_up", "experts_down"):
+            if name in layer:
+                layer[name] = held
+    return tree
+
+
+# -------------------------------------------------------------- attention
+
+
+def _attn(layer, x, cfg: ExaoneMoeConfig, positions, *, window: int,
+          past=None):
+    """``x`` [B, T, D] at ``positions`` [B, T] → ``(out, (k, v))`` with the
+    new keys and values [B, T, Hkv, hd]. ``window`` 0 is a full layer
+    (nothing rotated, every earlier key seen); otherwise q and k are
+    rotated and a key ``window`` or more behind is not seen. Alone
+    (prefill) the new keys are all there is; ``past`` (decode) is ``(k, v,
+    kpos, live)``: cached keys and values as the pool holds them, [B, m,
+    Hkv, block_tokens, hd], the positions [B, m * block_tokens] of their
+    slots and which of those hold this sequence's own."""
+    B, T, _D = x.shape
+    hd, H, Hkv = cfg.head_dim, cfg.num_attention_heads, \
+        cfg.num_key_value_heads
+    q = rms_norm((x @ layer["q_proj"]).reshape(B, T, H, hd),
+                 layer["q_norm"], cfg.rms_norm_eps)
+    k = rms_norm((x @ layer["k_proj"]).reshape(B, T, Hkv, hd),
+                 layer["k_norm"], cfg.rms_norm_eps)
+    v = (x @ layer["v_proj"]).reshape(B, T, Hkv, hd)
+    if window:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    q = q.reshape(B, T, Hkv, H // Hkv, hd)     # no repeat of k and v
+
+    def masked(s, kpos, live=True):
+        """Scores [B, Hkv, g, T, S] in float32, a key at ``kpos`` [B, S]
+        kept where the query sees it."""
+        behind = positions[:, :, None] - kpos[:, None, :]
+        keep = (behind >= 0) & (behind < window if window else True) & live
+        return jnp.where(keep[:, None, None],
+                         (s * hd ** -0.5).astype(jnp.float32), -1e30)
+
+    s_new = masked(jnp.einsum("bqkgd,bskd->bkgqs", q, k), positions)
+    if past is None:
+        p = jax.nn.softmax(s_new, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+    else:
+        pk, pv, kpos, live = past
+        m, c = pk.shape[1], pk.shape[3]
+        s_past = jnp.einsum("bqkgd,bmkcd->bkgqmc", q, pk).reshape(
+            B, Hkv, H // Hkv, T, m * c)
+        # one softmax over cached and new keys, without copying the cached
+        # blocks next to the new row
+        p = jax.nn.softmax(jnp.concatenate(
+            [masked(s_past, kpos, live[:, None, :]), s_new], axis=-1),
+            axis=-1).astype(x.dtype)
+        out = jnp.einsum("bkgqmc,bmkcd->bqkgd",
+                         p[..., :m * c].reshape(*p.shape[:4], m, c), pv) \
+            + jnp.einsum("bkgqs,bskd->bqkgd", p[..., m * c:], v)
+    return out.reshape(B, T, H * hd) @ layer["o_proj"], (k, v)
+
+
+# ---------------------------------------------------------- expert layer
+
+
+def _swiglu(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def _held_part(x, live, chosen, weights, gate_up, down, first):
+    """The held experts' part of the routed sum. ``x`` [N, D]; ``chosen``
+    [N, K] expert ids over the whole router and ``weights`` [N, K] theirs;
+    ``gate_up`` [E, D, 2F] and ``down`` [E, F, D] the held experts, which
+    are ``first .. first + E - 1``; rows not ``live`` (the pad rows of a
+    batch bucket) choose nothing. Returns ``(y [N, D] float32, tokens
+    [E])``. Every assignment that falls on a held expert is computed: the
+    rows are sorted by expert and each projection is one grouped product
+    over the groups' actual sizes."""
+    N, K = chosen.shape
+    E, F = down.shape[0], down.shape[1]
+    local = chosen - first
+    held = (local >= 0) & (local < E) & live[:, None]
+    group = jnp.where(held, local, E).reshape(N * K)    # E: not computed
+    order = jnp.argsort(group, stable=True)
+    tokens = (group[:, None] == jnp.arange(E)[None, :]).sum(
+        axis=0, dtype=jnp.int32)
+    rows = x[order // K]                                # [N * K, D]
+    with jax.named_scope("moe.experts"):
+        h = lax.ragged_dot(rows, gate_up, tokens)
+        h = jax.nn.silu(h[:, :F]) * h[:, F:]
+        y = lax.ragged_dot(h, down, tokens,
+                           preferred_element_type=jnp.float32)
+    # rows past the groups' end belong to no held expert: whatever the
+    # grouped product left there is dropped, not scaled
+    w = jnp.where(held, weights, 0.0).reshape(N * K)[order]
+    y = jnp.where(w[:, None] != 0, y * w[:, None], 0.0)
+    back = jnp.argsort(order)                           # the unsort
+    return y[back].reshape(N, K, -1).sum(axis=1), tokens
+
+
+def _moe(layer, x, live, cfg: ExaoneMoeConfig, mesh: Mesh | None):
+    """``x`` [N, D] → ``(mlp(x) [N, D], tokens per held expert [E])``."""
+    K = cfg.num_experts_per_tok
+    with jax.named_scope("moe.route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), layer["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _best, chosen = lax.top_k(s + layer["router_bias"], K)
+        weights = jnp.take_along_axis(s, chosen, axis=1)
+        if cfg.norm_topk_prob:
+            weights = weights / weights.sum(axis=1, keepdims=True)
+        weights = weights * cfg.routed_scaling_factor
+        n = _ep(mesh)
+        if n > 1 and cfg.num_experts % n == 0:
+            each = cfg.num_experts // n
+
+            def part(x, live, chosen, weights, gate_up, down):
+                first = cfg.ep_rank * cfg.num_experts \
+                    + lax.axis_index("ep") * each
+                y, tokens = _held_part(x, live, chosen, weights, gate_up,
+                                       down, first)
+                return (lax.psum(y, "ep"),
+                        lax.all_gather(tokens, "ep", tiled=True))
+
+            y, tokens = jax.shard_map(
+                part, mesh=mesh, in_specs=(P(),) * 4 + (P("ep"),) * 2,
+                out_specs=(P(), P()), axis_names={"ep"}, check_vma=False)(
+                x, live, chosen, weights, layer["experts_gate_up"],
+                layer["experts_down"])
+        else:
+            y, tokens = _held_part(
+                x, live, chosen, weights, layer["experts_gate_up"],
+                layer["experts_down"], cfg.ep_rank * cfg.num_experts)
+    shared = _swiglu(x, layer["shared_gate_proj"], layer["shared_up_proj"],
+                     layer["shared_down_proj"]) \
+        if cfg.num_shared_experts else 0.0
+    return y.astype(x.dtype) + shared, tokens
+
+
+def _mlp(layer, x, live, cfg, mesh):
+    """``x`` [B, T, D] → ``(mlp(x), tokens per held expert or None)``."""
+    if "router" not in layer:
+        return _swiglu(x, layer["gate_proj"], layer["up_proj"],
+                       layer["down_proj"]), None
+    B, T, D = x.shape
+    y, tokens = _moe(layer, x.reshape(B * T, D), live.reshape(B * T), cfg,
+                     mesh)
+    return y.reshape(B, T, D), tokens
+
+
+def _forward(params, tokens, cfg, positions, live, pasts, mesh):
+    """Every layer over ``tokens`` [B, T] → ``(x, new kv, expert tokens
+    [sparse layers, E])``; ``pasts`` is a layer's ``past`` or None."""
+    x = params["embed"][tokens]
+    new_kv, counts = [], []
+    for layer, window, past in zip(params["layers"], cfg.sliding_windows,
+                                   pasts):
+        with jax.named_scope("attn.window" if window else "attn.full"):
+            a, kv = _attn(layer, x, cfg, positions, window=window, past=past)
+        new_kv.append(kv)
+        x = x + rms_norm(a, layer["attn_norm"], cfg.rms_norm_eps)
+        m, n = _mlp(layer, x, live, cfg, mesh)
+        if n is not None:
+            counts.append(n)
+        x = x + rms_norm(m, layer["mlp_norm"], cfg.rms_norm_eps)
+    return x, new_kv, jnp.stack(counts) if counts else jnp.zeros(
+        (0, cfg.num_experts), jnp.int32)
+
+
+def _head(params, x, cfg):
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps) \
+        @ params["lm_head"]
+
+
+# ------------------------------------------------------ the engine's steps
+
+
+def step_prefill(params, tokens, cfg: ExaoneMoeConfig,
+                 mesh: Mesh | None = None):
+    """``tokens`` [B, T] (equal lengths) → ``(last_logits [B, V], kv,
+    expert_tokens)``: ``kv`` the per-layer ``(k, v)``, each [B, T, Hkv,
+    hd], for the caller to page into the pool; ``expert_tokens`` [sparse
+    layers, held experts] int32, the assignments each held expert got."""
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    x, kv, counts = _forward(params, tokens, cfg, positions,
+                             jnp.ones((B, T), bool),
+                             [None] * cfg.num_hidden_layers, mesh)
+    return _head(params, x[:, -1], cfg), kv, counts
+
+
+def window_slots(window: int, block_tokens: int) -> int:
+    """Table slots that cover the ``window - 1`` cached positions a window
+    layer's new token sees, wherever they start in a block."""
+    return -(-(window - 1) // block_tokens) + 1
+
+
+def step_decode(params, tokens, cfg: ExaoneMoeConfig, cache, lengths,
+                mesh: Mesh | None = None):
+    """One decode step over a ragged batch: ``tokens`` [B], ``lengths``
+    [B] the filled prefix of each row (0 for a pad row of the bucket,
+    which then chooses no expert), ``cache`` the engine's pool with the
+    batch's block table (``kvcache.Paged``: ``table`` [B, n],
+    ``block_tokens``, ``read(layer, ids)``). A full layer reads all ``n``
+    slots of a row; a window layer the :func:`window_slots` that cover its
+    last ``window - 1`` positions. Returns ``(logits [B, V], new_kv,
+    expert_tokens)`` like :func:`step_prefill`, ``new_kv`` each [B, 1, Hkv,
+    hd] for the caller to write at ``lengths``."""
+    B, n = cache.table.shape
+    bs = cache.block_tokens
+
+    def slots(window: int):
+        """``(block ids [B, m], positions [B, m * bs])`` a layer reads."""
+        m = window_slots(window, bs) if window else n
+        if m >= n:
+            return cache.table, jnp.broadcast_to(jnp.arange(n * bs),
+                                                 (B, n * bs))
+        first = jnp.clip((lengths - (window - 1)) // bs, 0, n - m)
+        at = first[:, None] + jnp.arange(m)[None, :]
+        return (jnp.take_along_axis(cache.table, at, axis=1),
+                first[:, None] * bs + jnp.arange(m * bs)[None, :])
+
+    views = {w: slots(w) for w in set(cfg.sliding_windows)}
+    pasts = [(*cache.read(li, views[w][0]), views[w][1],
+              views[w][1] < lengths[:, None])
+             for li, w in enumerate(cfg.sliding_windows)]
+    x, new_kv, counts = _forward(params, tokens[:, None], cfg,
+                                 lengths[:, None], (lengths > 0)[:, None],
+                                 pasts, mesh)
+    return _head(params, x[:, 0], cfg), new_kv, counts
+
+
+def observe(expert_tokens, tokens: int, cfg: ExaoneMoeConfig) -> dict:
+    """A step's ``expert_tokens`` (on the host) and the tokens it ran →
+    the span's attributes; the counters are counted here."""
+    landed = int(expert_tokens.sum())
+    hit = int((expert_tokens > 0).sum())
+    HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
+    HUB.inc(labeled("gen_moe_assignments_total", held="false"),
+            tokens * cfg.num_experts_per_tok * cfg.sparse_layers - landed)
+    HUB.inc("gen_moe_experts_hit_total", hit)
+    return {"expert_tokens": landed, "experts_hit": hit}

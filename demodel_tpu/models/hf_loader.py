@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from demodel_tpu.models import exaone_moe
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
 from demodel_tpu.models.llama import LlamaConfig, param_shardings
@@ -99,6 +100,87 @@ def load_llama_params(weights: dict, cfg: LlamaConfig, mesh=None) -> dict:
         "layers": layers,
         "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
         "lm_head": head,
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _stacker(projections: int, sharding):
+    """Jitted: ``projections`` runs of per-expert ``[out, in]`` matrices →
+    one ``[E, in, projections * out]``, the runs side by side."""
+    def stack(*ws):
+        n = len(ws) // projections
+        return jnp.concatenate(
+            [jnp.stack([w.T for w in ws[i * n:(i + 1) * n]])
+             for i in range(projections)], axis=2)
+
+    return jax.jit(stack, out_shardings=sharding)
+
+
+def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
+                           mesh=None) -> dict:
+    """The tree of :func:`exaone_moe.init_params` from a checkpoint that
+    holds one share of the experts under their global indices
+    (``mlp.experts.<ep_rank * num_experts + j>``). Per-expert matrices are
+    stacked, gate beside up, so that a projection is one grouped product;
+    the router keeps its whole width. Tensors of the multi-token-prediction
+    layer stay in ``weights``."""
+    w = _Weights(weights)
+    sh = exaone_moe.param_shardings(cfg, mesh) if mesh is not None else {}
+    first = cfg.ep_rank * cfg.num_experts
+    layers = []
+    for i, sparse in enumerate(cfg.sparse):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def experts(projs, leaf):
+            return _stacker(len(projs), lsh.get(leaf))(*(
+                w.get(f"{pre}mlp.experts.{first + j}.{p}_proj.weight")
+                for p in projs for j in range(cfg.num_experts)))
+
+        layer = {
+            "q_proj": lin("self_attn.q_proj.weight", "q_proj"),
+            "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+            "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            "q_norm": vec("self_attn.q_norm.weight", "q_norm"),
+            "k_norm": vec("self_attn.k_norm.weight", "k_norm"),
+            "attn_norm": vec("post_attn_layernorm.weight", "attn_norm"),
+            "mlp_norm": vec("post_feedforward_layernorm.weight", "mlp_norm"),
+        }
+        if sparse:
+            layer.update({
+                "router": lin("mlp.gate.weight", "router"),
+                "router_bias": vec("mlp.gate.e_score_correction_bias",
+                                   "router_bias").astype(jnp.float32),
+                "experts_gate_up": experts(("gate", "up"),
+                                           "experts_gate_up"),
+                "experts_down": experts(("down",), "experts_down"),
+                "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
+                                        "shared_gate_proj"),
+                "shared_up_proj": lin("mlp.shared_experts.up_proj.weight",
+                                      "shared_up_proj"),
+                "shared_down_proj": lin("mlp.shared_experts.down_proj.weight",
+                                        "shared_down_proj"),
+            })
+        else:
+            layer.update({
+                "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+                "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+                "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
     }
 
 

@@ -74,7 +74,7 @@ def load_model(model: str, cfg, *, source: str = "hf",
     defaults to every local device (``tp`` = device count); delivery,
     the loader and the engine all get the one resolved here."""
     from demodel_tpu import delivery
-    from demodel_tpu.models import auto, llama
+    from demodel_tpu.models import auto
     from demodel_tpu.parallel.mesh import make_mesh
 
     if mesh is None:
@@ -89,10 +89,6 @@ def load_model(model: str, cfg, *, source: str = "hf",
                 store, report, mesh=mesh, placement=placed)
         finally:
             store.close()
-    if not isinstance(mcfg, llama.LlamaConfig):
-        raise ValueError(
-            f"serving supports llama-family models; {model!r} resolved "
-            f"to {type(mcfg).__name__}")
     engine = GenEngine(params, mcfg, mesh=mesh, model=model,
                        **engine_kw).start()
     install(engine)

@@ -25,11 +25,13 @@ The pool is two halves that never touch each other:
   bytes never move: a decode step sends a block table in and gets logits
   back.
 
-The model still never sees a block table: inside the engine's jitted
-programs :func:`read_table` turns the table into the dense ``[B, S, Hkv,
-hd]`` rectangles ``llama.step_decode`` consumes, and :func:`put_blocks` /
-:func:`put_positions` place a prefill's or a step's new K/V — placement
-is entirely this module's business.
+Inside the engine's jitted programs :func:`read_table` turns the table
+into the dense ``[B, S, Hkv, hd]`` rectangles ``llama.step_decode``
+consumes (that model never sees a block table); a model whose layers read
+different slots of the table is handed :class:`Paged` and reads layer by
+layer. Either way :func:`put_blocks` / :func:`put_positions` place a
+prefill's or a step's new K/V — placement is entirely this module's
+business.
 
 **One signature for life.** ``jax.jit`` keys its executables on an
 argument's sharding and on whether it is committed. The arrays are
@@ -43,7 +45,7 @@ or load from the persistent cache, in the middle of serving).
 from __future__ import annotations
 
 import threading
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -238,6 +240,37 @@ def read_table(k, v, table):
 
     kr, vr = rect(k), rect(v)
     return [(kr[li], vr[li]) for li in range(L)]
+
+
+class Paged(NamedTuple):
+    """The pool and a batch's block table, for a model whose layers do not
+    all read the same slots (a window layer reads the few that cover its
+    window) and which therefore reads the pool itself, layer by layer,
+    where :func:`read_table` would build every layer's whole rectangle."""
+
+    k: jax.Array
+    v: jax.Array
+    table: jax.Array        # [B, n] block ids, as :func:`read_table` takes
+
+    @property
+    def block_tokens(self) -> int:
+        return self.k.shape[3]
+
+    def read(self, layer: int, ids):
+        """Blocks ``ids`` [B, m] of one layer as the pool holds them:
+        ``(k, v)``, each [B, m, Hkv, block_tokens, hd], not transposed. The
+        caller masks what a row does not own, as with :func:`read_table`.
+        One gather from the pool itself (layers and blocks as one axis,
+        which costs nothing): a slice of one layer first is a copy of it,
+        the whole pool a step over all layers."""
+        L, nb = self.k.shape[:2]
+        at = ids + layer * nb
+
+        def blocks(a):
+            return jnp.take(a.reshape(L * nb, *a.shape[2:]), at, axis=0,
+                            mode="clip")
+
+        return blocks(self.k), blocks(self.v)
 
 
 def _stack(kv):

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import itertools
 import queue as queue_mod
+import sys
 import threading
 import time
 from collections import deque
@@ -218,8 +219,21 @@ class GenEngine:
                  model: str = "inline"):
         import jax
 
-        from demodel_tpu.models import llama
         from demodel_tpu.utils import compile_cache
+
+        # the loaded config names its model module, whose two step
+        # functions the programs below run: ``step_prefill(params, tokens,
+        # cfg, mesh=) -> (last_logits, kv, *stats)`` and ``step_decode(
+        # params, tokens, cfg, cache, lengths, mesh=) -> (logits, new_kv,
+        # *stats)``. ``stats`` (small arrays, or none) come back with the
+        # logits and go to the module's ``observe``, which counts them and
+        # names the step span's attributes.
+        module = sys.modules[type(cfg).__module__]
+        if not hasattr(module, "step_decode"):
+            raise ValueError(
+                f"serving needs step_prefill and step_decode, which "
+                f"{module.__name__} ({type(cfg).__name__}) does not have")
+        self._module = module
 
         compile_cache.place()
         # every span of the process on the profiler's clock: with no
@@ -246,17 +260,24 @@ class GenEngine:
             gen_retry_after_s())
 
         def prefill(p, tokens, blocks, k, v):
-            logits, kv = llama.step_prefill(p, tokens, cfg, mesh=mesh)
-            return (logits, *kvcache.put_blocks(k, v, kv, blocks))
+            logits, kv, *stats = module.step_prefill(p, tokens, cfg,
+                                                    mesh=mesh)
+            return ((logits, *stats),
+                    *kvcache.put_blocks(k, v, kv, blocks))
+
+        # a model whose layers all read the whole table is handed the
+        # rectangles; one that says PAGED_CACHE reads the pool itself
+        read = kvcache.Paged if getattr(module, "PAGED_CACHE", False) \
+            else kvcache.read_table
 
         def decode(p, rows, k, v):
             # one int32 row a sequence (see _decode_inputs)
             toks, lens, wblocks, woffsets = (rows[:, i] for i in range(4))
-            cache = kvcache.read_table(k, v, rows[:, 4:])
-            logits, new_kv = llama.step_decode(p, toks, cfg, cache, lens,
-                                               mesh=mesh)
-            return (logits, *kvcache.put_positions(k, v, new_kv, wblocks,
-                                                   woffsets))
+            cache = read(k, v, rows[:, 4:])
+            logits, new_kv, *stats = module.step_decode(p, toks, cfg, cache,
+                                                       lens, mesh=mesh)
+            return ((logits, *stats),
+                    *kvcache.put_positions(k, v, new_kv, wblocks, woffsets))
 
         # the pool goes in donated and comes back as it was born
         # (kvcache: "one signature for life"); a program's shapes follow
@@ -473,8 +494,9 @@ class GenEngine:
     def _prefill(self, prompt: list[int], lease):
         """Ship a prompt and its lease's block ids, run the prefill
         program over the pool (it writes those blocks itself), and
-        return the last position's logits ``[1, V]``, still on the
-        device and possibly still being computed."""
+        return the last position's logits ``[1, V]`` and the model's
+        stats, if it has any, still on the device and possibly still
+        being computed."""
         import jax
         import numpy as np
 
@@ -524,16 +546,21 @@ class GenEngine:
             with trace.span("serve.prefill", remote_parent=req.traceparent,
                             request=req.id, prompt=T):
                 with trace.span("serve.prefill-device", prompt=T,
-                                new_shape=self._first_run("prefill", T)):
-                    logits = self._prefill(req.prompt, lease)
+                                new_shape=self._first_run("prefill", T)
+                                ) as dev:
+                    logits, *stats = self._prefill(req.prompt, lease)
                     applied = True
                     if trace.enabled():
                         # export tier only, like the compute spans: off
                         # it the span ends at dispatch and the pull of
                         # the logits takes the wait
                         jax.block_until_ready((logits, pool.k, pool.v))
-                tok0 = int(np.asarray(logits)[0].astype(np.float32).argmax())
+                        self._observe(dev, jax.device_get(stats), T)
+                        stats = []
+                logits, *stats = jax.device_get([logits, *stats])
+                tok0 = int(logits[0].astype(np.float32).argmax())
                 HUB.inc("gen_d2h_bytes_total", logits.nbytes)
+                self._observe(None, stats, T)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             lease.free()
             log.error("prefill failed for request %d: %s", req.id, exc)
@@ -552,6 +579,16 @@ class GenEngine:
         HUB.inc(labeled("gen_tokens_total", stage="decode"))
         if seq.generated >= req.max_new_tokens:
             self._retire(seq)
+
+    def _observe(self, span, stats: list, tokens: int) -> None:
+        """A step's stats, pulled to the host, to the model module that
+        made them: it counts them and names ``span``'s attributes."""
+        if not stats:
+            return
+        attrs = self._module.observe(*stats, tokens=tokens, cfg=self.cfg)
+        if span is not None:
+            for key, value in attrs.items():
+                span.set_attr(key, value)
 
     def _settle_pool(self, applied: bool, error: str) -> None:
         """After a program failed: if it had the pool's arrays in hand
@@ -593,17 +630,20 @@ class GenEngine:
             HUB.inc("gen_h2d_bytes_total", rows.nbytes)
         applied = False
         try:
-            with trace.span("serve.decode-step", batch=B, width=width):
+            with trace.span("serve.decode-step", batch=B,
+                            width=width) as step:
                 with trace.span("serve.decode-device", batch=B, width=width,
                                 new_shape=self._first_run(
                                     "decode", len(rows), width)):
-                    logits = pool.apply(self._jdecode, self.params, sent)
+                    logits, *stats = pool.apply(self._jdecode, self.params,
+                                                sent)
                     applied = True
                     # the fetch's pull would wait here anyway
                     jax.block_until_ready((logits, pool.k, pool.v))
                 with trace.span("serve.decode-fetch", bytes=logits.nbytes):
-                    out = np.asarray(logits)
+                    out, *stats = jax.device_get([logits, *stats])
                     HUB.inc("gen_d2h_bytes_total", logits.nbytes)
+                self._observe(step, stats, B)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             log.error("decode step failed (batch=%d): %s", B, exc)
             for seq in batch:

@@ -611,15 +611,22 @@ class TestOneSignatureForLife:
     engine under a 1-device ``tp`` mesh with mesh-placed weights (as
     ``load_model`` gives them) runs every shape once, then again; the
     second round must find every executable it needs. A pool with two
-    signatures (fresh against returned-by-a-program) compiles there."""
+    signatures (fresh against returned-by-a-program) compiles there.
+    Run for both model modules the engine serves: the pool, its donation
+    and the programs' signatures are the engine's, whatever the steps."""
 
-    @pytest.fixture()
-    def placed(self, tiny_model):
+    @pytest.fixture(params=["llama", "exaone_moe"])
+    def placed(self, request):
+        from demodel_tpu.models import exaone_moe
         from demodel_tpu.parallel.mesh import make_mesh
 
-        params, cfg = tiny_model
+        module, cfg = {
+            "llama": (llama, llama.LlamaConfig.tiny()),
+            "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig.tiny()),
+        }[request.param]
+        params = module.init_params(jax.random.key(2), cfg)
         mesh = make_mesh(1)
-        return (jax.device_put(params, llama.param_shardings(cfg, mesh)),
+        return (jax.device_put(params, module.param_shardings(cfg, mesh)),
                 cfg, mesh)
 
     @pytest.fixture(params=["observe", "export"])
