@@ -222,12 +222,12 @@ def check_against_reference(engine, prompt: list[int]) -> dict:
     pool = engine.pool
     lease = pool.alloc(pool.blocks_for(T + 1))
     try:
-        logits_p, *_stats = engine._prefill(prompt, lease)
-        tok0 = int(np.argmax(np.asarray(logits_p[0], np.float32)))
+        ids_p, (logits_p, *_stats) = engine._prefill(prompt, lease)
+        tok0 = int(ids_p[0])
         _width, rows = engine._decode_inputs([_Seq(None, lease, T, tok0)])
-        logits_d, *_stats = pool.apply(
+        ids_d, (logits_d, *_stats) = pool.apply(
             engine._jdecode, engine.params,
-            jax.device_put(rows, pool.replicated))
+            jax.device_put(rows, pool.replicated), engine._prev_ids)
     finally:
         lease.free()
 
@@ -239,9 +239,13 @@ def check_against_reference(engine, prompt: list[int]) -> dict:
             params32, jnp.asarray([prompt + [tok0]], jnp.int32))
     ref = np.asarray(ref[0], np.float32)
     out = {}
-    for name, got, want in (("prefill", logits_p[0], ref[T - 1]),
-                            ("decode", logits_d[0], ref[T])):
+    for name, got, want, chose in (
+            ("prefill", logits_p[0], ref[T - 1], tok0),
+            ("decode", logits_d[0], ref[T], int(ids_d[0]))):
         got = np.asarray(got, np.float32)
+        if chose != int(got.argmax()):
+            raise AssertionError(f"{name} chose token {chose}, its logits' "
+                                 f"first maximum is {int(got.argmax())}")
         if got.shape != want.shape or not np.isfinite(got).all():
             raise AssertionError(f"{name} logits: shape {got.shape}, "
                                  f"finite={np.isfinite(got).all()}")

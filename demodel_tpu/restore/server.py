@@ -767,6 +767,13 @@ def make_handler(registry: RestoreRegistry, proxy=None):
     return RestoreHandler
 
 
+class _Listener(ThreadingHTTPServer):
+    #: socketserver's default backlog is 5; a gateway's sessions connect
+    #: together (32 in one wave of the benchmark's warm-up) and one of
+    #: them was reset by the peer (chip run, PR 29)
+    request_queue_size = 128
+
+
 class RestoreServer:
     """Threaded HTTP server over a RestoreRegistry. ``proxy`` (optional)
     adds the native data-plane counters to ``/metrics``."""
@@ -775,7 +782,7 @@ class RestoreServer:
                  port: int = 0, proxy=None):
         self.registry = registry
         self._proxy = proxy
-        self.httpd = ThreadingHTTPServer((host, port), make_handler(registry, proxy))
+        self.httpd = _Listener((host, port), make_handler(registry, proxy))
         self.port = self.httpd.server_address[1]
         self._thread = threading.Thread(target=self.httpd.serve_forever, daemon=True)
 

@@ -20,6 +20,15 @@ Compute stays jit-friendly: decode batches are padded to power-of-two
 batch/width buckets (padded rows decode with ``length 0`` and are
 dropped on the host side), so the number of distinct compiled shapes is
 logarithmic in batch size and sequence length.
+
+The engine keeps one decode step in flight. Both programs choose the
+token themselves (argmax in float32, first maximum) and a step takes the
+ids it feeds from the previous step's ids on the device, so what the next
+step needs from the host (lengths, write coordinates, block table, who is
+in the batch: the engine retires by count alone) is known before this one
+has run: step j+1 is shipped and queued, and only then are step j's ids
+pulled, emitted and retired. Somebody to admit drains the pipe first, so a
+prefill runs alone on the device.
 """
 
 from __future__ import annotations
@@ -52,6 +61,8 @@ HUB.inc("gen_h2d_bytes_total", 0)
 HUB.inc("gen_d2h_bytes_total", 0)
 HUB.inc(labeled("gen_new_shapes_total", stage="prefill"), 0)
 HUB.inc(labeled("gen_new_shapes_total", stage="decode"), 0)
+HUB.inc(labeled("gen_decode_steps_total", ahead="0"), 0)
+HUB.inc(labeled("gen_decode_steps_total", ahead="1"), 0)
 HUB.set_gauge("gen_queue_depth", 0)
 HUB.set_gauge("gen_running", 0)
 
@@ -180,16 +191,42 @@ class AdmissionQueue:
 
 
 class _Seq:
-    """Engine-internal running-sequence state."""
+    """Engine-internal running-sequence state. ``length``, ``planned`` and
+    ``row`` run ahead of ``generated``: they count the steps dispatched,
+    ``generated`` the tokens pulled and emitted."""
 
-    __slots__ = ("req", "lease", "length", "last_tok", "generated")
+    __slots__ = ("req", "lease", "length", "last_tok", "generated",
+                 "planned", "row", "retired")
 
     def __init__(self, req: Request, lease, length: int, last_tok: int):
         self.req = req
         self.lease = lease
-        self.length = length      # KV positions written so far
-        self.last_tok = last_tok  # next token to feed
-        self.generated = 1        # last_tok itself came from the prefill
+        self.length = length      # KV positions the dispatched steps write
+        self.last_tok = last_tok  # the newest token the host has seen
+        self.generated = 1        # emitted; last_tok came from the prefill
+        self.planned = 1          # tokens the dispatched programs yield
+        #: its row of the newest decode step's ids, where the next step
+        #: finds its token; -1 fresh from the prefill (``last_tok`` is fed)
+        self.row = -1
+        self.retired = False
+
+
+class _Step:
+    """One dispatched decode step: who rode it, and its outputs, still on
+    the device and possibly still being computed."""
+
+    __slots__ = ("batch", "width", "rows", "ahead", "new_shape", "ids",
+                 "stats")
+
+    def __init__(self, batch: list[_Seq], width: int, rows, ahead: bool,
+                 new_shape: bool):
+        self.batch = batch
+        self.width = width
+        self.rows = rows            # shipped; dropped once launched
+        self.ahead = ahead          # dispatched behind a step in flight
+        self.new_shape = new_shape  # first run of its (bucket, width)
+        self.ids = None             # [_pow2(max_batch)] int32, by _launch
+        self.stats: list = []
 
 
 def _pow2(n: int) -> int:
@@ -206,7 +243,10 @@ class GenEngine:
     counters) is guarded by ``_work``'s lock; the pool's arrays are
     engine-thread-only: they live on the device, both programs take them
     donated (``pool.apply``) and what crosses the link a step is a block
-    table one way and a row of logits the other.
+    table one way and the chosen ids (4 B a row) the other. ``_flight``
+    (the decode step dispatched and not yet pulled) and ``_prev_ids`` (the
+    newest step's ids, which the next step reads on the device) are the
+    engine thread's too.
     """
 
     def __init__(self, params, cfg, mesh=None, *,
@@ -218,6 +258,7 @@ class GenEngine:
                  kv_mb: int | None = None,
                  model: str = "inline"):
         import jax
+        import jax.numpy as jnp
 
         from demodel_tpu.utils import compile_cache
 
@@ -226,8 +267,8 @@ class GenEngine:
         # cfg, mesh=) -> (last_logits, kv, *stats)`` and ``step_decode(
         # params, tokens, cfg, cache, lengths, mesh=) -> (logits, new_kv,
         # *stats)``. ``stats`` (small arrays, or none) come back with the
-        # logits and go to the module's ``observe``, which counts them and
-        # names the step span's attributes.
+        # chosen ids and go to the module's ``observe``, which counts them
+        # and names the step span's attributes.
         module = sys.modules[type(cfg).__module__]
         if not hasattr(module, "step_decode"):
             raise ValueError(
@@ -259,10 +300,20 @@ class GenEngine:
             queue_limit if queue_limit is not None else gen_queue_limit(),
             gen_retry_after_s())
 
+        #: every decode step returns this many ids, whatever its bucket,
+        #: so the ids one step hands the next have one shape for life
+        n_ids = _pow2(self.max_batch)
+
+        def choose(logits, n):
+            # greedy, the first maximum, in float32 (which holds every
+            # value of the model's dtype); rows past the bucket read 0
+            ids = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+            return jnp.pad(ids.astype(jnp.int32), (0, n - ids.shape[0]))
+
         def prefill(p, tokens, blocks, k, v):
             logits, kv, *stats = module.step_prefill(p, tokens, cfg,
                                                     mesh=mesh)
-            return ((logits, *stats),
+            return ((choose(logits, 1), (logits, *stats)),
                     *kvcache.put_blocks(k, v, kv, blocks))
 
         # a model whose layers all read the whole table is handed the
@@ -270,23 +321,36 @@ class GenEngine:
         read = kvcache.Paged if getattr(module, "PAGED_CACHE", False) \
             else kvcache.read_table
 
-        def decode(p, rows, k, v):
+        def decode(p, rows, prev_ids, k, v):
             # one int32 row a sequence (see _decode_inputs)
-            toks, lens, wblocks, woffsets = (rows[:, i] for i in range(4))
-            cache = read(k, v, rows[:, 4:])
+            lit, lens, wblocks, woffsets, src = (rows[:, i]
+                                                 for i in range(5))
+            toks = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], lit)
+            cache = read(k, v, rows[:, 5:])
             logits, new_kv, *stats = module.step_decode(p, toks, cfg, cache,
                                                        lens, mesh=mesh)
-            return ((logits, *stats),
+            return ((choose(logits, n_ids), (logits, *stats)),
                     *kvcache.put_positions(k, v, new_kv, wblocks, woffsets))
 
         # the pool goes in donated and comes back as it was born
-        # (kvcache: "one signature for life"); a program's shapes follow
-        # the prompt length, or (batch bucket, width), and nothing else
-        back = (None, self.pool.sharding, self.pool.sharding)
+        # (kvcache: "one signature for life"), the ids come back
+        # replicated, as the next step takes them; a program's shapes
+        # follow the prompt length, or (batch bucket, width), and nothing
+        # else. The logits stay on the device: nothing the engine does
+        # pulls them (chip_smoke and the tests of a model module do)
+        pool = self.pool
+        back = ((pool.replicated, None), pool.sharding, pool.sharding)
         self._jprefill = jax.jit(prefill, donate_argnums=(3, 4),
                                  out_shardings=back)
-        self._jdecode = jax.jit(decode, donate_argnums=(2, 3),
+        self._jdecode = jax.jit(decode, donate_argnums=(3, 4),
                                 out_shardings=back)
+        # what the first step takes for the previous step's ids: born as a
+        # program's output with the sharding every later one has, so the
+        # first step runs the executable the others run (PR 25's trap)
+        self._ids0 = jax.jit(lambda: jnp.zeros((n_ids,), jnp.int32),
+                             out_shardings=pool.replicated)()
+        self._prev_ids = self._ids0
+        self._flight: _Step | None = None
         self._pending: deque[Request] = deque()
         self._running: list[_Seq] = []
         self._stop = False
@@ -417,15 +481,21 @@ class GenEngine:
         while True:
             with self._work:
                 while not self._stop and not self._pending \
-                        and not self._running:
+                        and not self._running and self._flight is None:
                     self._work.wait()
                 if self._stop:
+                    # a step in flight is left to the device; stop()
+                    # settles its sequences
+                    self._flight = None
                     return
             progressed = False
-            while self._admit_one():
+            # a prefill runs alone on the device: nobody is admitted over
+            # a step in flight (_decode_step dispatches none ahead once
+            # somebody can be, so the pipe is empty one cycle later)
+            while self._flight is None and self._admit_one():
                 progressed = True
             self._evict_cancelled()
-            if self._snapshot_running():
+            if self._flight is not None or self._snapshot_running():
                 self._decode_step()
             elif not progressed:
                 # pending work exists but nothing could be admitted and
@@ -494,9 +564,10 @@ class GenEngine:
     def _prefill(self, prompt: list[int], lease):
         """Ship a prompt and its lease's block ids, run the prefill
         program over the pool (it writes those blocks itself), and
-        return the last position's logits ``[1, V]`` and the model's
-        stats, if it has any, still on the device and possibly still
-        being computed."""
+        return ``(ids, (logits, *stats))``: the token it chose ``[1]``,
+        the last position's logits ``[1, V]`` and the model's stats, if
+        it has any, still on the device and possibly still being
+        computed."""
         import jax
         import numpy as np
 
@@ -512,9 +583,10 @@ class GenEngine:
         """What one decode step ships, built from the leases: ``(width,
         rows)``. ``rows`` is one int32 array, a row a sequence of the
         batch bucket — token id, length, the block and the offset its new
-        position is written at, then its slots of the block table — so
-        its shape follows (bucket, width) alone and it crosses the link
-        in one transfer."""
+        position is written at, the row of the previous step's ids its
+        token is taken from on the device (-1: the id in column 0 is fed),
+        then its slots of the block table — so its shape follows (bucket,
+        width) alone and it crosses the link in one transfer."""
         import numpy as np
 
         pool = self.pool
@@ -523,18 +595,18 @@ class GenEngine:
         # a slot a sequence does not have reads block 0 (masked by its
         # length); a pad row rides along with length 0, is dropped on the
         # host, and writes into the block no lease can hold
-        rows = np.zeros((_pow2(len(batch)), 4 + nb), np.int32)
+        rows = np.zeros((_pow2(len(batch)), 5 + nb), np.int32)
         rows[:, 2] = pool.scratch_block
+        rows[:, 4] = -1
         for row, s in zip(rows, batch):
             got = s.lease.blocks[:nb]
-            row[:4] = (s.last_tok, s.length, s.lease.blocks[s.length // bs],
-                       s.length % bs)
-            row[4:4 + len(got)] = got
+            row[:5] = (s.last_tok, s.length, s.lease.blocks[s.length // bs],
+                       s.length % bs, s.row)
+            row[5:5 + len(got)] = got
         return bs * nb, rows
 
     def _start_seq(self, req: Request, lease) -> None:
         import jax
-        import numpy as np
 
         req.started_s = time.time()
         HUB.observe("gen_queue_wait_seconds",
@@ -548,18 +620,19 @@ class GenEngine:
                 with trace.span("serve.prefill-device", prompt=T,
                                 new_shape=self._first_run("prefill", T)
                                 ) as dev:
-                    logits, *stats = self._prefill(req.prompt, lease)
+                    ids, (_logits, *stats) = self._prefill(req.prompt, lease)
                     applied = True
+                    pulled = ids.nbytes + sum(a.nbytes for a in stats)
                     if trace.enabled():
                         # export tier only, like the compute spans: off
                         # it the span ends at dispatch and the pull of
-                        # the logits takes the wait
-                        jax.block_until_ready((logits, pool.k, pool.v))
+                        # the id takes the wait
+                        jax.block_until_ready((ids, pool.k, pool.v))
                         self._observe(dev, jax.device_get(stats), T)
                         stats = []
-                logits, *stats = jax.device_get([logits, *stats])
-                tok0 = int(logits[0].astype(np.float32).argmax())
-                HUB.inc("gen_d2h_bytes_total", logits.nbytes)
+                ids, *stats = jax.device_get([ids, *stats])
+                tok0 = int(ids[0])
+                HUB.inc("gen_d2h_bytes_total", pulled)
                 self._observe(None, stats, T)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             lease.free()
@@ -607,72 +680,149 @@ class GenEngine:
                 HUB.inc("gen_evicted_total")
                 self._retire(seq, error="evicted")
 
+    def _hold_back(self, flight: _Step) -> bool:
+        """No step may be dispatched behind ``flight``: the engine is
+        stopping, or somebody waits whom it can admit once ``flight`` is
+        posted (a row is free or frees then, and so are the blocks the
+        head of the queue reserves)."""
+        with self._work:
+            if self._stop:
+                return True
+            if not self._pending:
+                return False
+            head = self._pending[0]
+            rows = self.max_batch - len(self._running)
+        ending = [s for s in flight.batch
+                  if not s.retired and s.planned >= s.req.max_new_tokens]
+        if rows + len(ending) <= 0:
+            return False
+        need = self.pool.blocks_for(len(head.prompt)
+                                    + head.max_new_tokens - 1)
+        return head.cancelled.is_set() or need <= self.pool.free_blocks \
+            + sum(len(s.lease.blocks) for s in ending)
+
+    def _ship(self, batch: list[_Seq], ahead: bool) -> _Step:
+        """Build and send one step's rows from what the host knows of
+        ``batch`` and advance each sequence past the step, so that the
+        next one can be built before this one has run."""
+        import jax
+
+        width, rows = self._decode_inputs(batch)
+        for i, seq in enumerate(batch):
+            seq.length += 1
+            seq.planned += 1
+            seq.row = i
+        step = _Step(batch, width, jax.device_put(rows, self.pool.replicated),
+                     ahead, self._first_run("decode", len(rows), width))
+        HUB.inc("gen_h2d_bytes_total", rows.nbytes)
+        return step
+
+    def _launch(self, step: _Step) -> None:
+        """Queue the program of a shipped step on the pool the newest
+        program returns, fed by the newest step's ids; its own ids start
+        for the host the moment it ends."""
+        ids, (_logits, *stats) = self.pool.apply(
+            self._jdecode, self.params, step.rows, self._prev_ids)
+        for out in (ids, *stats):
+            out.copy_to_host_async()
+        step.rows, step.ids, step.stats = None, ids, stats
+        self._prev_ids = ids
+
     def _decode_step(self) -> None:
         """Advance every running sequence one token, ragged lengths and
-        all — the continuous-batching inner loop. One cycle is three
-        sibling spans on the engine thread: ``serve.decode-h2d`` (token
-        ids, lengths, write coordinates and the block table, one array),
-        ``serve.decode-step`` (whose children are ``serve.decode-device``
-        and ``serve.decode-fetch``, the logits alone) and
-        ``serve.decode-post``."""
+        all — the continuous-batching inner loop, one step ahead of the
+        host. One cycle is one ``serve.decode-step`` span around four
+        children: ``serve.decode-h2d`` (the rows of the steps it
+        dispatches: the step to pull if the pipe is empty, and the next
+        one unless it holds back), ``serve.decode-device`` (those
+        dispatches and the wait for the ids of the step in flight, which
+        the device runs meanwhile), ``serve.decode-fetch`` (ids and stats,
+        4 B a row) and ``serve.decode-post`` (emit, retire). The span
+        carries the attributes of the step it pulls. A program that
+        failed is found here, at the pull, with its successor queued on a
+        pool that is lost: both steps' sequences are retired."""
         import jax
-        import numpy as np
 
-        batch = self._snapshot_running()
-        if not batch:
+        flight, self._flight = self._flight, None
+        applied = flight is not None
+        running = [] if applied else self._snapshot_running()
+        if not (applied or running):
             return
-        B = len(batch)
-        pool = self.pool
-        with trace.span("serve.decode-h2d") as ship:
-            width, rows = self._decode_inputs(batch)
-            ship.set_attr("bytes", rows.nbytes)
-            sent = jax.device_put(rows, pool.replicated)
-            HUB.inc("gen_h2d_bytes_total", rows.nbytes)
-        applied = False
+        todo: list[_Step] = []
+        nxt = None
         try:
-            with trace.span("serve.decode-step", batch=B,
-                            width=width) as step:
-                with trace.span("serve.decode-device", batch=B, width=width,
-                                new_shape=self._first_run(
-                                    "decode", len(rows), width)):
-                    logits, *stats = pool.apply(self._jdecode, self.params,
-                                                sent)
-                    applied = True
+            with trace.span("serve.decode-step") as cycle:
+                with trace.span("serve.decode-h2d") as ship:
+                    if flight is None:
+                        flight = self._ship(running, ahead=False)
+                        todo.append(flight)
+                    if not self._hold_back(flight):
+                        batch = [s for s in flight.batch if not s.retired
+                                 and s.planned < s.req.max_new_tokens]
+                        if batch:
+                            nxt = self._ship(batch, ahead=True)
+                            todo.append(nxt)
+                    ship.set_attr("bytes", sum(t.rows.nbytes for t in todo))
+                B = len(flight.batch)
+                for key, value in (("batch", B), ("width", flight.width),
+                                   ("ahead", flight.ahead)):
+                    cycle.set_attr(key, value)
+                with trace.span("serve.decode-device", batch=B,
+                                width=flight.width,
+                                new_shape=any(t.new_shape for t in todo)):
+                    for step in todo:
+                        applied = True
+                        self._launch(step)
+                    self._flight = nxt
                     # the fetch's pull would wait here anyway
-                    jax.block_until_ready((logits, pool.k, pool.v))
-                with trace.span("serve.decode-fetch", bytes=logits.nbytes):
-                    out, *stats = jax.device_get([logits, *stats])
-                    HUB.inc("gen_d2h_bytes_total", logits.nbytes)
-                self._observe(step, stats, B)
+                    jax.block_until_ready((flight.ids, *flight.stats))
+                pulled = flight.ids.nbytes + sum(a.nbytes
+                                                 for a in flight.stats)
+                with trace.span("serve.decode-fetch", bytes=pulled):
+                    ids, *stats = jax.device_get([flight.ids, *flight.stats])
+                    # the device's copies go now, not as the frame ends:
+                    # a buffer's release lets go of the interpreter lock,
+                    # and once the emit has woken the handlers that costs
+                    # a turn behind each of them, after the span closed
+                    # (1.7 ms a cycle at 32 rows)
+                    flight.ids = flight.stats = None
+                    HUB.inc("gen_d2h_bytes_total", pulled)
+                self._observe(cycle, stats, B)
+                with trace.span("serve.decode-post", batch=B) as post:
+                    retired = emitted = 0
+                    for seq, tok in zip(flight.batch, ids.tolist()):
+                        if seq.retired:     # evicted with its row in flight
+                            continue
+                        seq.last_tok = tok
+                        seq.generated += 1
+                        seq.req._emit(tok)
+                        emitted += 1
+                        if seq.generated >= seq.req.max_new_tokens:
+                            self._retire(seq)
+                            retired += 1
+                    with self._work:
+                        self._tokens["decode"] += emitted
+                    HUB.inc(labeled("gen_tokens_total", stage="decode"),
+                            emitted)
+                    HUB.inc(labeled("gen_decode_steps_total",
+                                    ahead=str(int(flight.ahead))))
+                    post.set_attr("retired", retired)
         except Exception as exc:  # noqa: BLE001 - engine must survive
-            log.error("decode step failed (batch=%d): %s", B, exc)
-            for seq in batch:
+            # a step dispatched ahead carries none but riders of ``flight``
+            riders = [s for s in (flight.batch if flight else running)
+                      if not s.retired]
+            log.error("decode step failed (batch=%d): %s", len(riders), exc)
+            for seq in riders:
                 self._retire(seq, error=f"decode failed: {exc}")
+            self._flight = None
+            self._prev_ids = self._ids0
             self._settle_pool(applied, f"decode failed: {exc}")
-            return
-        with trace.span("serve.decode-post", batch=B) as post:
-            retired = 0
-            # float32 holds every value of the model's dtype, so the
-            # choice is the same; numpy's argmax is far quicker there
-            # (as after a prefill)
-            picks = out[:B].astype(np.float32).argmax(axis=1)
-            for seq, tok in zip(batch, picks.tolist()):
-                seq.length += 1
-                seq.last_tok = tok
-                seq.generated += 1
-                seq.req._emit(tok)
-                if seq.generated >= seq.req.max_new_tokens:
-                    self._retire(seq)
-                    retired += 1
-            with self._work:
-                self._tokens["decode"] += B
-            HUB.inc(labeled("gen_tokens_total", stage="decode"), B)
-            post.set_attr("retired", retired)
 
     def _retire(self, seq: _Seq, error: str | None = None) -> None:
         """Finished/evicted/failed: blocks free IMMEDIATELY (the next
         _admit_one can use them this very iteration)."""
         seq.lease.free()
+        seq.retired = True
         with self._work:
             if seq in self._running:
                 self._running.remove(seq)

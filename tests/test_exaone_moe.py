@@ -76,7 +76,7 @@ def _engine_logits(engine, prompts, steps: int):
     seqs, rows = [], []
     for prompt in prompts:
         lease = pool.alloc(pool.blocks_for(len(prompt) + steps))
-        logits, *_stats = engine._prefill(prompt, lease)
+        _ids, (logits, *_stats) = engine._prefill(prompt, lease)
         first = np.asarray(logits, np.float32)
         seqs.append(_Seq(None, lease, len(prompt), int(first[0].argmax())))
         rows.append([first[0]])
@@ -85,8 +85,9 @@ def _engine_logits(engine, prompts, steps: int):
         for f, s in zip(fed, seqs):
             f.append(s.last_tok)
         _width, sent = engine._decode_inputs(seqs)
-        logits, *_stats = pool.apply(engine._jdecode, engine.params,
-                                     jax.device_put(sent))
+        _ids, (logits, *_stats) = pool.apply(
+            engine._jdecode, engine.params, jax.device_put(sent),
+            engine._prev_ids)
         out = np.asarray(logits, np.float32)
         for s, row, lg in zip(seqs, rows, out):
             s.length += 1

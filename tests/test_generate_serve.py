@@ -178,32 +178,48 @@ class TestKVBlockPool:
 
 
 class TestGenEngine:
-    @pytest.mark.parametrize("lengths,max_new,block", [
-        ([9, 5, 12, 9], 6, 16),
+    @pytest.mark.parametrize("lengths,news,block,join", [
+        ([9, 5, 12, 9], [6] * 4, 16, "third-after-first-is-done"),
         # lengths pass 8 and 16 = the width exactly: the fed position is
         # the one concatenated past the rectangle, in a block the table
         # does not reach yet
-        ([7, 3, 14, 7], 12, 4)], ids=["staggered", "bucket-edge"])
+        ([7, 3, 14, 7], [12] * 4, 4, "third-after-first-is-done"),
+        # who is in the batch changes every step or two, so a row's token
+        # comes from another row of the previous step's ids (``src``); the
+        # one-token request never decodes; two wait for a row
+        ([9, 5, 12, 7, 4, 6], [2, 3, 7, 1, 5, 9], 4, "at-once"),
+        # each joins once the one before it streams: an admission between
+        # two steps of a full pipe, its first token fed from the host
+        ([6, 11, 4, 9], [8, 3, 6, 5], 4, "each-after-a-token"),
+        ([5, 8], [9, 4], 16, "each-after-a-token")],
+        ids=["staggered", "bucket-edge", "unequal", "join-mid-pipe",
+             "pair-parts"])
     def test_matches_one_at_a_time_reference(self, tiny_model, lengths,
-                                             max_new, block):
-        """Continuous batching with staggered admission must produce the
-        same greedy tokens as the sequential reference decoder."""
+                                             news, block, join):
+        """Continuous batching, one step ahead of the host, with staggered
+        admission must produce the same greedy tokens as the sequential
+        reference decoder."""
         params, cfg = tiny_model
         prompts = [_prompt(cfg, n, seed=i) for i, n in enumerate(lengths)]
-        refs = [np.asarray(llama.generate(params, cfg, p, max_new))[0]
-                for p in prompts]
+        refs = [np.asarray(llama.generate(params, cfg, p, n))[0]
+                for p, n in zip(prompts, news)]
+        before = HUB.snapshot()
         engine = GenEngine(params, cfg, max_batch=3, queue_limit=16,
-                           max_new_tokens=max_new, kv_mb=4,
+                           max_new_tokens=max(news), kv_mb=4,
                            block_tokens=block).start()
         try:
             reqs = []
-            for i, p in enumerate(prompts):  # staggered: join mid-decode
-                if i == 2:
-                    reqs[0].result(timeout=120)
-                reqs.append(engine.submit(p, max_new))
+            for i, (p, n) in enumerate(zip(prompts, news)):
+                if join == "third-after-first-is-done" and i == 2:
+                    reqs[0].result(timeout=120)     # join mid-decode
+                if join == "each-after-a-token" and reqs:
+                    next(reqs[-1].iter_tokens(timeout=120))
+                reqs.append(engine.submit(p, n))
             outs = [r.result(timeout=120) for r in reqs]
         finally:
             engine.stop()
+        ahead = labeled("gen_decode_steps_total", ahead="1")
+        assert HUB.snapshot()[ahead] > before.get(ahead, 0)
         for out, ref in zip(outs, refs):
             assert out == [int(t) for t in ref]
         assert engine.pool.describe()["in_use_blocks"] == 0
@@ -263,6 +279,64 @@ class TestGenEngine:
             assert engine.admission.describe()["outstanding"] == 0
         finally:
             engine.stop()
+
+    def test_cancel_with_a_step_in_flight(self, tiny_model):
+        """The evicted sequence's blocks come home at the step boundary,
+        before the step it rides has been pulled; its row is computed and
+        dropped, and the one beside it decodes the reference's tokens."""
+        params, cfg = tiny_model
+        engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+                           max_new_tokens=7, kv_mb=1, block_tokens=4)
+        prompts = [_prompt(cfg, 6, seed=1), _prompt(cfg, 9, seed=2)]
+        ref = [int(t) for t in
+               np.asarray(llama.generate(params, cfg, prompts[1], 7))[0]]
+        try:
+            gone, kept = _drive(engine, prompts, 7)
+            engine._decode_step()
+            assert engine._flight is not None
+            held = engine.pool.in_use_blocks
+            gone.cancel()
+            engine._evict_cancelled()
+            assert engine.pool.in_use_blocks == held - engine.pool.blocks_for(
+                len(prompts[0]) + 7 - 1)
+            with pytest.raises(RuntimeError, match="evicted"):
+                gone.result(timeout=10)
+            assert len(gone.tokens) == 2
+            while engine._flight is not None or engine._snapshot_running():
+                engine._decode_step()
+            assert kept.result(timeout=10) == ref
+            assert len(gone.tokens) == 2
+            assert engine.pool.in_use_blocks == 0
+            assert engine.admission.describe()["outstanding"] == 0
+        finally:
+            engine.stop()
+
+    @pytest.mark.parametrize("driven", ["by-hand", "by-its-thread"])
+    def test_stop_with_a_step_in_flight(self, tiny_model, driven):
+        """``stop()`` leaves no block leased and no admission outstanding
+        though a step was dispatched and never pulled."""
+        params, cfg = tiny_model
+        engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+                           max_new_tokens=64, kv_mb=4)
+        prompts = [_prompt(cfg, 8, seed=i) for i in range(3)]
+        try:
+            if driven == "by-hand":
+                reqs = _drive(engine, prompts, 64)
+                engine._decode_step()
+                assert engine._flight is not None
+            else:
+                engine.start()
+                reqs = [engine.submit(p, 64) for p in prompts]
+                for r in reqs[:2]:      # both decode; the third waits
+                    for _ in zip(range(3), r.iter_tokens(timeout=120)):
+                        pass
+        finally:
+            engine.stop()
+        for r in reqs:
+            with pytest.raises(RuntimeError, match="shutdown"):
+                r.result(timeout=10)
+        assert engine.pool.describe()["in_use_blocks"] == 0
+        assert engine.admission.describe()["outstanding"] == 0
 
     def test_queue_overflow_raises_with_retry_after(self, tiny_model):
         params, cfg = tiny_model
@@ -374,9 +448,10 @@ class TestDevicePool:
     def test_pad_row_and_missing_slot_change_no_leased_block(self,
                                                              tiny_model):
         """B = 3 in a bucket of 4, ragged lengths at a width the short
-        rows' leases do not fill: the step writes each row's one new
-        position and, for the pad row, the scratch block. Block 0 (what
-        a missing table slot reads) belongs to a bystander."""
+        rows' leases do not fill: the step, and the step dispatched ahead
+        of its pull, write each row's one new position each and, for the
+        pad row, the scratch block. Block 0 (what a missing table slot
+        reads) belongs to a bystander."""
         params, cfg = tiny_model
         pool = _pool(cfg, block_tokens=4)
         engine = GenEngine(params, cfg, pool=pool, max_batch=4,
@@ -396,8 +471,9 @@ class TestDevicePool:
             k0, v0 = _pool_bytes(pool)
             engine._decode_step()
             k1, v1 = _pool_bytes(pool)
-            wrote = {(s.lease.blocks[(s.length - 1) // 4],
-                      (s.length - 1) % 4) for s in seqs}
+            assert engine._flight is not None and engine._flight.ahead
+            wrote = {(s.lease.blocks[at // 4], at % 4) for s in seqs
+                     for at in (s.length - 2, s.length - 1)}
             for before, after in ((k0, k1), (v0, v1)):
                 diff = (before != after).any(axis=(0, 2, 4))  # [blk, slot]
                 got = {(int(b), int(o)) for b, o in np.argwhere(diff)}
@@ -459,23 +535,32 @@ class TestDevicePool:
         # lengths 7..16 at batch 1: widths 8 (to length 8) and 16
         assert engine._jdecode._cache_size() == shapes("decode") == 2
 
-    def test_a_step_ships_a_table_and_pulls_back_logits(self, tiny_model):
+    def test_a_step_ships_a_table_and_pulls_back_ids(self, tiny_model):
+        """Three tokens a request, so two decode steps: the first cycle
+        ships both tables (the step, and the one it dispatches ahead) and
+        pulls one step's ids, the second ships nothing and pulls the
+        other's. The logits never cross."""
         params, cfg = tiny_model
-        engine = GenEngine(params, cfg, max_batch=4, queue_limit=8,
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
                            max_new_tokens=4, kv_mb=1, block_tokens=4)
+        names = ("gen_h2d_bytes_total", "gen_d2h_bytes_total",
+                 labeled("gen_decode_steps_total", ahead="0"),
+                 labeled("gen_decode_steps_total", ahead="1"))
         try:
             _drive(engine, [_prompt(cfg, n, seed=n) for n in (3, 9, 18)], 3)
-            before = HUB.snapshot()
-            engine._decode_step()
-            after = HUB.snapshot()
+            seen = [HUB.snapshot()]
+            for _ in range(2):
+                engine._decode_step()
+                seen.append(HUB.snapshot())
+            assert engine._flight is None and not engine._snapshot_running()
         finally:
             engine.stop()
-        item = np.dtype(cfg.dtype).itemsize
-        rows, slots = 4, 8      # a bucket of 4; length 18: 5 → 8 blocks
-        assert after["gen_d2h_bytes_total"] \
-            - before["gen_d2h_bytes_total"] == rows * cfg.vocab_size * item
-        shipped = after["gen_h2d_bytes_total"] - before["gen_h2d_bytes_total"]
-        assert shipped == rows * (4 + slots) * 4 < 1024
+        cycles = [[b[n] - a[n] for n in names] for a, b in zip(seen, seen[1:])]
+        # a bucket of 4 rows (= _pow2(max_batch)); length 18: 5 → 8 blocks;
+        # a row: token, length, write block and offset, src, its 8 slots
+        table, ids = 4 * (5 + 8) * 4, 4 * 4
+        assert cycles == [[2 * table, ids, 1, 0], [0, ids, 0, 1]]
+        assert engine.pool.in_use_blocks == 0
 
     def test_pool_is_sharded_on_the_kv_heads_under_tp(self, tiny_model):
         """Two CPU devices, ``tp`` = 2 = the toy's KV heads: the arrays
@@ -511,16 +596,21 @@ class TestDevicePool:
         odd = KVBlockPool(2, 3, 8, block_tokens=4, budget_mb=1, mesh=mesh)
         assert odd.k.sharding.is_fully_replicated
 
-    @pytest.mark.parametrize("stage", ["prefill", "decode"])
+    @pytest.mark.parametrize("stage", ["prefill", "decode", "decode-ahead",
+                                       "decode-pull"])
     def test_a_program_that_fails_with_the_pool_in_hand(self, tiny_model,
-                                                        stage):
+                                                        monkeypatch, stage):
         """The program deletes what it was given (as donation does) and
         raises: every running sequence is retired with the error, every
         lease comes home, and the next request is served from fresh
-        arrays."""
+        arrays. With a step in flight the failure is the dispatch behind
+        it, or (as a device reports one) the wait for its ids, its
+        successor already queued: both steps' sequences are retired and
+        the pipe is dropped."""
         params, cfg = tiny_model
         engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
                            max_new_tokens=6, kv_mb=1, block_tokens=4)
+        stage, _, flight = stage.partition("-")
         real = getattr(engine, f"_j{stage}")
 
         def boom(*args):
@@ -534,12 +624,25 @@ class TestDevicePool:
         try:
             reqs = _drive(engine, [_prompt(cfg, 6), _prompt(cfg, 11)], 6)
             assert len(engine._snapshot_running()) == 2
-            setattr(engine, f"_j{stage}", boom)
+            if flight:
+                engine._decode_step()
+                assert engine._flight.ahead
+                assert [len(r.tokens) for r in reqs] == [2, 2]
+            if flight == "pull":
+                def fell_over(_outs):
+                    monkeypatch.undo()
+                    raise RuntimeError("device fell over")
+
+                monkeypatch.setattr(jax, "block_until_ready", fell_over)
+            else:
+                setattr(engine, f"_j{stage}", boom)
             if stage == "prefill":
                 reqs += _drive(engine, [_prompt(cfg, 4)], 6)
             else:
                 engine._decode_step()
             setattr(engine, f"_j{stage}", real)
+            assert engine._flight is None
+            assert engine._prev_ids is engine._ids0
             for r in reqs:
                 with pytest.raises(RuntimeError, match="device fell over"):
                     r.result(timeout=10)
@@ -670,13 +773,26 @@ class TestOneSignatureForLife:
 
     def test_decode_shapes_compile_once(self, placed, tier):
         """Each (batch bucket, width) from a fresh pool and again later:
-        one executable each, none made in the second round."""
+        one executable each, none made in the second round. A step
+        dispatched on an empty pipe and one dispatched ahead run the same
+        one, and so does the step that takes the ids the engine was born
+        with for the previous step's."""
         params, cfg, mesh = placed
         events = _Compiles.listen()
         before = HUB.snapshot()
         engine = GenEngine(params, cfg, mesh=mesh, max_batch=2,
                            queue_limit=8, max_new_tokens=12, kv_mb=1,
-                           block_tokens=4).start()
+                           block_tokens=4)
+        launched = []
+        real = engine._launch
+
+        def watched(step):
+            launched.append(((len(step.rows), step.width), step.ahead,
+                             engine._prev_ids is engine._ids0))
+            real(step)
+
+        engine._launch = watched
+        engine.start()
 
         def wave():
             alone = engine.generate(_prompt(cfg, 6, seed=1), 12, timeout=240)
@@ -705,6 +821,13 @@ class TestOneSignatureForLife:
             == after[name] - before.get(name, 0) \
             == sum(1 for s in engine._shapes_run if s[0] == "decode")
         assert sizes[0] == engine._jprefill._cache_size() == 3
+        born, *later = launched
+        assert born[2] and not any(first for _s, _a, first in later)
+        both = {shape for shape, ahead, _f in launched if ahead} \
+            & {shape for shape, ahead, _f in launched if not ahead}
+        assert born[0] in both
+        assert {shape for shape, _a, _f in launched} \
+            == {s[1:] for s in engine._shapes_run if s[0] == "decode"}
 
     def test_donation_is_real(self, placed):
         """After every call the arrays that went in are gone: nothing was
@@ -785,6 +908,28 @@ class TestGenerateHTTP:
             _post(f"{gen_server}/generate", {"prompt": [1, 2, 3]})
         assert exc.value.code == 503
         assert b"serving disabled" in exc.value.read()
+
+    def test_backlog_holds_a_batch_of_sessions(self, gen_server):
+        """Sessions of a gateway connect together; socketserver's default
+        backlog of 5 had one of 32 reset by the peer on the chip. All of
+        them are answered here (503: no engine), none reset."""
+        from demodel_tpu.restore.server import _Listener
+
+        assert _Listener.request_queue_size >= 4 * 32
+        codes: list = []
+
+        def post():
+            try:
+                _post(gen_server + "/generate", {"prompt": [1]})
+            except urllib.error.HTTPError as exc:
+                codes.append(exc.code)
+
+        threads = [threading.Thread(target=post) for _ in range(32)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert codes == [503] * 32
 
     def test_roundtrip_matches_engine(self, gen_server, tiny_model):
         params, cfg = tiny_model
@@ -913,14 +1058,15 @@ class TestGenerateHTTP:
 
 # ------------------------------------------------- the engine cycle's spans
 
-#: the engine thread's root spans of one decode cycle, in order
-CYCLE = ["serve.decode-h2d", "serve.decode-step", "serve.decode-post"]
+#: one decode cycle is one serve.decode-step root on the engine thread;
+#: these are its children, in order
+CYCLE = ["serve.decode-h2d", "serve.decode-device", "serve.decode-fetch",
+         "serve.decode-post"]
 CHILDREN = {"serve.http-parse": "serve.restore",
             "serve.admit": "serve.restore",
             "serve.prefill": "serve.admit",
             "serve.prefill-device": "serve.prefill",
-            "serve.decode-device": "serve.decode-step",
-            "serve.decode-fetch": "serve.decode-step"}
+            **{name: "serve.decode-step" for name in CYCLE}}
 
 
 def _pow2(n):
@@ -933,6 +1079,7 @@ class TestServeSpans:
 
     PROMPTS = [9, 5, 12, 9]
     MAX_NEW = 6
+    MAX_BATCH = 3
     BLOCK = 16
 
     @pytest.fixture(scope="class")
@@ -953,7 +1100,8 @@ class TestServeSpans:
         server = RestoreServer(RestoreRegistry(store),
                                host="127.0.0.1").start()
         before = HUB.snapshot()
-        engine = serve.boot(params, cfg, max_batch=3, queue_limit=16,
+        engine = serve.boot(params, cfg, max_batch=self.MAX_BATCH,
+                            queue_limit=16,
                             max_new_tokens=self.MAX_NEW, kv_mb=4,
                             block_tokens=self.BLOCK)
         bodies = [{"prompt": _prompt(cfg, n, seed=i),
@@ -1006,14 +1154,31 @@ class TestServeSpans:
 
     def test_one_of_each_phase_a_cycle(self, run):
         steps = run["named"]("serve.decode-step")
-        assert steps
-        for name in CYCLE + ["serve.decode-device", "serve.decode-fetch"]:
+        assert steps and all(r["parent"] is None for r in steps)
+        # the four phases, in order, inside each cycle's one root
+        for step in steps:
+            inside = sorted((r for r in run["spans"]
+                             if r["parent"] == step["span"]),
+                            key=lambda r: r["ts"])
+            assert [r["name"] for r in inside] == CYCLE
+            assert step["ts"] <= inside[0]["ts"]
+            assert inside[-1]["ts"] + inside[-1]["dur"] \
+                <= step["ts"] + step["dur"] + 1e-6
+        for name in CYCLE:
             assert len(run["named"](name)) == len(steps), name
-        # the engine thread's phases are roots, one after another
-        flat = sorted((r for r in run["spans"] if r["name"] in CYCLE),
-                      key=lambda r: r["ts"])
-        assert [r["name"] for r in flat] == CYCLE * len(steps)
-        assert all(r["parent"] is None for r in flat)
+        # a cycle carries the step it pulls: dispatched behind another
+        # (ahead) or on an empty pipe, which every admission leaves
+        ahead = [r["attrs"]["ahead"] for r in steps]
+        assert ahead[0] is False and True in ahead
+        for flag in (True, False):
+            assert run["delta"](labeled("gen_decode_steps_total",
+                                        ahead=str(int(flag)))) \
+                == ahead.count(flag)
+        # a prefill runs alone: the pipe is drained before it
+        for pre in run["named"]("serve.prefill"):
+            for step in steps:
+                assert pre["ts"] + pre["dur"] <= step["ts"] + 1e-6 \
+                    or step["ts"] + step["dur"] <= pre["ts"] + 1e-6
         n_req = len(self.PROMPTS)
         assert len(run["named"]("serve.http-parse")) == n_req
         assert len(run["named"]("serve.prefill-device")) == n_req
@@ -1027,20 +1192,21 @@ class TestServeSpans:
                    for r in run["named"]("serve.decode-post")) == n_req
 
     def test_bytes_follow_the_pools_geometry(self, run):
-        cfg = run["cfg"]
-        item = np.dtype(cfg.dtype).itemsize
-        for h2d, step, fetch in zip(run["named"]("serve.decode-h2d"),
-                                    run["named"]("serve.decode-step"),
-                                    run["named"]("serve.decode-fetch")):
-            rows = _pow2(step["attrs"]["batch"])
+        tables = 0
+        for step, fetch in zip(run["named"]("serve.decode-step"),
+                               run["named"]("serve.decode-fetch")):
             width = step["attrs"]["width"]
             assert width % self.BLOCK == 0
-            # a row: token, length, write block, write offset, and its
-            # slots of the block table, all int32
-            assert h2d["attrs"]["bytes"] == rows * (
-                4 + width // self.BLOCK) * 4
-            # the logits, and nothing else
-            assert fetch["attrs"]["bytes"] == rows * cfg.vocab_size * item
+            # a row: token, length, write block, write offset, src, and
+            # its slots of the block table, all int32
+            tables += _pow2(step["attrs"]["batch"]) * (
+                5 + width // self.BLOCK) * 4
+            # the ids of a full bucket whatever the step's, nothing else
+            assert fetch["attrs"]["bytes"] == _pow2(self.MAX_BATCH) * 4
+        # every step pulled was shipped once, in its own cycle or the one
+        # before it
+        assert sum(r["attrs"]["bytes"]
+                   for r in run["named"]("serve.decode-h2d")) == tables
         assert sorted(r["attrs"]["prompt"]
                       for r in run["named"]("serve.prefill-device")) == \
             sorted(self.PROMPTS)
@@ -1050,8 +1216,7 @@ class TestServeSpans:
 
     def test_byte_counters_are_what_crosses_the_link(self, run):
         """Decode: the spans' ``bytes``. A prefill ships its prompt and
-        its lease's block ids and pulls one row of logits back."""
-        cfg = run["cfg"]
+        its lease's block ids and pulls the id it chose back."""
 
         def total(name):
             return sum(r["attrs"]["bytes"] for r in run["named"](name))
@@ -1060,8 +1225,7 @@ class TestServeSpans:
             total("serve.decode-h2d") + sum(
                 4 * (n + -(-n // self.BLOCK)) for n in self.PROMPTS)
         assert run["delta"]("gen_d2h_bytes_total") == \
-            total("serve.decode-fetch") + len(self.PROMPTS) \
-            * cfg.vocab_size * np.dtype(cfg.dtype).itemsize
+            total("serve.decode-fetch") + len(self.PROMPTS) * 4
         # README's operator reading: under a kilobyte a decoded token
         decoded = sum(r["attrs"]["batch"]
                       for r in run["named"]("serve.decode-step"))
@@ -1073,17 +1237,23 @@ class TestServeSpans:
         ("decode", "serve.decode-device",
          lambda a: (_pow2(a["batch"]), a["width"]))])
     def test_new_shapes_counted_once_each(self, run, stage, span, shape):
-        from demodel_tpu.utils.metrics import labeled
-
         seen, first = set(), []
         for r in run["named"](span):
             first.append(shape(r["attrs"]) not in seen)
             seen.add(shape(r["attrs"]))
-        assert [r["attrs"]["new_shape"] for r in run["named"](span)] == first
+        flagged = [r["attrs"]["new_shape"] for r in run["named"](span)]
         assert run["delta"](labeled("gen_new_shapes_total",
                                     stage=stage)) == len(seen)
         if stage == "prefill":
+            assert flagged == first
             assert len(seen) == len(set(self.PROMPTS))
+        else:
+            # the span names the step it pulls and flags a first run among
+            # the steps it dispatches (the one ahead, as a rule): a shape
+            # is flagged in the cycle that pulls it or the one before
+            assert flagged[0] and 1 <= sum(flagged) <= len(seen)
+            for i, new in enumerate(first):
+                assert not new or flagged[i] or flagged[i - 1]
 
     def test_a_request_is_one_trace(self, run):
         by_id = {r["span"]: r for r in run["spans"]}
