@@ -8,6 +8,7 @@ activation dtype).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -26,6 +27,50 @@ def layer_norm(x, weight, bias, eps: float = 1e-5):
     var = ((xf - mu) ** 2).mean(axis=-1, keepdims=True)
     y = (xf - mu) * lax.rsqrt(var + eps)
     return y.astype(x.dtype) * weight + bias
+
+
+def attend(q, k, v, positions, *, window: int = 0, past=None):
+    """Causal attention of new queries over their own keys and, when
+    ``past`` is given, over a paged cache read where it lies: q [B, T, H,
+    hd], k and v [B, T, Hkv, hd] at ``positions`` [B, T] → [B, T, H * hd].
+    The query heads are grouped by KV head in the products, so k and v are
+    never repeated. ``window`` 0 sees every earlier key; otherwise a key
+    ``window`` or more behind is not seen. ``past`` is ``(k, v, kpos,
+    live)``: cached keys and values as the pool holds them, [B, m, Hkv,
+    block_tokens, hd] (``kvcache.Paged.read``), the positions [B, m *
+    block_tokens] of their slots and which of those hold the row's own
+    (a row with none live, a pad row, sees only its new key). One softmax
+    in float32 over cached and new keys, probabilities in q's dtype."""
+    B, T, H, hd = q.shape
+    Hkv = k.shape[2]
+    q = q.reshape(B, T, Hkv, H // Hkv, hd)
+
+    def masked(s, kpos, live=True):
+        """Scores [B, Hkv, g, T, S] in float32, a key at ``kpos`` [B, S]
+        kept where the query sees it."""
+        behind = positions[:, :, None] - kpos[:, None, :]
+        keep = (behind >= 0) & (behind < window if window else True) & live
+        return jnp.where(keep[:, None, None],
+                         (s * hd ** -0.5).astype(jnp.float32), -1e30)
+
+    s_new = masked(jnp.einsum("bqkgd,bskd->bkgqs", q, k), positions)
+    if past is None:
+        p = jax.nn.softmax(s_new, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+    else:
+        pk, pv, kpos, live = past
+        m, c = pk.shape[1], pk.shape[3]
+        s_past = jnp.einsum("bqkgd,bmkcd->bkgqmc", q, pk).reshape(
+            B, Hkv, H // Hkv, T, m * c)
+        # one softmax over cached and new keys, without copying the cached
+        # blocks next to the new row
+        p = jax.nn.softmax(jnp.concatenate(
+            [masked(s_past, kpos, live[:, None, :]), s_new], axis=-1),
+            axis=-1).astype(q.dtype)
+        out = jnp.einsum("bkgqmc,bmkcd->bqkgd",
+                         p[..., :m * c].reshape(*p.shape[:4], m, c), pv) \
+            + jnp.einsum("bkgqs,bskd->bqkgd", p[..., m * c:], v)
+    return out.reshape(B, T, H * hd)
 
 
 def use_flash_attention() -> bool:

@@ -46,14 +46,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models.common import rms_norm
+from demodel_tpu.models.common import attend, rms_norm
 from demodel_tpu.models.llama import _rope
 from demodel_tpu.utils.metrics import HUB, labeled
-
-#: the engine hands ``step_decode`` the pool and the block table
-#: (``kvcache.Paged``) where it hands a model whose layers all read the
-#: whole table the rectangles: window layers read their own slots
-PAGED_CACHE = True
 
 HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
 HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
@@ -238,13 +233,11 @@ def param_shardings(cfg: ExaoneMoeConfig, mesh: Mesh) -> dict:
 def _attn(layer, x, cfg: ExaoneMoeConfig, positions, *, window: int,
           past=None):
     """``x`` [B, T, D] at ``positions`` [B, T] → ``(out, (k, v))`` with the
-    new keys and values [B, T, Hkv, hd]. ``window`` 0 is a full layer
-    (nothing rotated, every earlier key seen); otherwise q and k are
-    rotated and a key ``window`` or more behind is not seen. Alone
-    (prefill) the new keys are all there is; ``past`` (decode) is ``(k, v,
-    kpos, live)``: cached keys and values as the pool holds them, [B, m,
-    Hkv, block_tokens, hd], the positions [B, m * block_tokens] of their
-    slots and which of those hold this sequence's own."""
+    new keys and values [B, T, Hkv, hd]. What is this family's own: the
+    projections, the q/k norms, rotation on window layers only (``window``
+    0 is a full layer: nothing rotated). The attention itself, ``window``
+    and ``past`` (a decode step's cached blocks) are
+    :func:`common.attend`'s."""
     B, T, _D = x.shape
     hd, H, Hkv = cfg.head_dim, cfg.num_attention_heads, \
         cfg.num_key_value_heads
@@ -256,34 +249,8 @@ def _attn(layer, x, cfg: ExaoneMoeConfig, positions, *, window: int,
     if window:
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-    q = q.reshape(B, T, Hkv, H // Hkv, hd)     # no repeat of k and v
-
-    def masked(s, kpos, live=True):
-        """Scores [B, Hkv, g, T, S] in float32, a key at ``kpos`` [B, S]
-        kept where the query sees it."""
-        behind = positions[:, :, None] - kpos[:, None, :]
-        keep = (behind >= 0) & (behind < window if window else True) & live
-        return jnp.where(keep[:, None, None],
-                         (s * hd ** -0.5).astype(jnp.float32), -1e30)
-
-    s_new = masked(jnp.einsum("bqkgd,bskd->bkgqs", q, k), positions)
-    if past is None:
-        p = jax.nn.softmax(s_new, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
-    else:
-        pk, pv, kpos, live = past
-        m, c = pk.shape[1], pk.shape[3]
-        s_past = jnp.einsum("bqkgd,bmkcd->bkgqmc", q, pk).reshape(
-            B, Hkv, H // Hkv, T, m * c)
-        # one softmax over cached and new keys, without copying the cached
-        # blocks next to the new row
-        p = jax.nn.softmax(jnp.concatenate(
-            [masked(s_past, kpos, live[:, None, :]), s_new], axis=-1),
-            axis=-1).astype(x.dtype)
-        out = jnp.einsum("bkgqmc,bmkcd->bqkgd",
-                         p[..., :m * c].reshape(*p.shape[:4], m, c), pv) \
-            + jnp.einsum("bkgqs,bskd->bqkgd", p[..., m * c:], v)
-    return out.reshape(B, T, H * hd) @ layer["o_proj"], (k, v)
+    out = attend(q, k, v, positions, window=window, past=past)
+    return out @ layer["o_proj"], (k, v)
 
 
 # ---------------------------------------------------------- expert layer

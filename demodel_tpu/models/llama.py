@@ -21,7 +21,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models.common import rms_norm, use_flash_attention as _use_flash
+from demodel_tpu.models.common import (attend, rms_norm,
+                                       use_flash_attention as _use_flash)
 from demodel_tpu.ops.ring_attention import (
     dense_attention,
     ring_attention_sharded,
@@ -306,9 +307,10 @@ def step_prefill(params, tokens, cfg: LlamaConfig, mesh: Mesh | None = None):
     """Prefill leg of the serving plane: ``tokens`` [B, T] (one sequence,
     or a few of EQUAL length) → ``(last_logits [B, V], kv)`` where ``kv``
     is the per-layer ``(k, v)`` pair, each [B, T, Hkv, hd] — exactly the
-    prompt's keys/values, which the caller pages out into pool blocks
-    (:mod:`demodel_tpu.serve.kvcache`). The cache is sized to the prompt,
-    so this is :func:`forward_with_cache` with nothing left over."""
+    prompt's keys/values, which the engine's prefill program writes into
+    the lease's blocks of the pool on the device, in the same program
+    (``kvcache.put_blocks``). The cache is sized to the prompt, so this
+    is :func:`forward_with_cache` with nothing left over."""
     B, T = tokens.shape
     cache = init_cache(cfg, B, T)
     logits, kv = forward_with_cache(params, tokens, cfg, cache, 0, mesh=mesh)
@@ -320,24 +322,28 @@ def step_decode(params, tokens, cfg: LlamaConfig, cache, lengths,
     """One continuous-batching decode step over a RAGGED batch.
 
     ``tokens`` [B] int32 — the last sampled token of each running
-    sequence; ``cache`` per-layer ``(k, v)``, each [B, S, Hkv, hd] — a
-    dense gather of each sequence's paged blocks (rows at or past
-    ``lengths[b]`` are stale pool bytes and are masked out here);
+    sequence; ``cache`` the engine's pool with the batch's block table
+    (``kvcache.Paged``: ``table`` [B, n], ``block_tokens``, ``read(layer,
+    ids)``), whose blocks every layer reads where they lie (slots at or
+    past ``lengths[b]`` are stale pool bytes and are masked out here);
     ``lengths`` [B] int32 — filled prefix per sequence, so the fed token
     sits at position ``lengths[b]`` (positions need not agree across the
     batch — that is the whole point). Returns ``(logits [B, V], new_kv)``
-    with ``new_kv`` per-layer ``(k, v)`` each [B, 1, Hkv, hd], written
-    back into the pool by the caller: the pool owns placement, the model
-    never sees a block table. Rows padded up to a jit bucket ride along
+    with ``new_kv`` per-layer ``(k, v)`` each [B, 1, Hkv, hd], which the
+    engine's program writes into the pool at ``lengths``
+    (``kvcache.put_positions``). Rows padded up to a jit bucket ride along
     with ``lengths[b] == 0`` (they attend only to themselves) and are
     dropped by the caller."""
-    B = tokens.shape[0]
+    B, n = cache.table.shape
     hd = cfg.head_dim
     H, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
     positions = lengths[:, None]                      # [B, 1]
+    S = n * cache.block_tokens                        # slots a row
+    kpos = jnp.broadcast_to(jnp.arange(S), (B, S))
+    live = kpos < lengths[:, None]
     x = params["embed"][tokens[:, None]]              # [B, 1, D]
     new_kv = []
-    for layer, (ck, cv) in zip(params["layers"], cache):
+    for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
         q = (h @ layer["q_proj"]).reshape(B, 1, H, hd)
         k = (h @ layer["k_proj"]).reshape(B, 1, Hkv, hd)
@@ -348,20 +354,9 @@ def step_decode(params, tokens, cfg: LlamaConfig, cache, lengths,
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         new_kv.append((k, v))
-        S = ck.shape[1]
-        kk = jnp.concatenate([ck, k], axis=1)         # [B, S+1, Hkv, hd]
-        vv = jnp.concatenate([cv, v], axis=1)
-        rep = H // Hkv
-        kk = jnp.repeat(kk, rep, axis=2)
-        vv = jnp.repeat(vv, rep, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * hd ** -0.5
-        kpos = jnp.arange(S + 1)
-        valid = (kpos[None, :] < lengths[:, None]) | (kpos[None, :] == S)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        x = x + out.reshape(B, 1, H * hd) @ layer["o_proj"]
+        pk, pv = cache.read(li, cache.table)
+        out = attend(q, k, v, positions, past=(pk, pv, kpos, live))
+        x = x + out @ layer["o_proj"]
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         y = (jax.nn.silu(y @ layer["gate_proj"]) * (y @ layer["up_proj"])) \
             @ layer["down_proj"]

@@ -25,13 +25,12 @@ The pool is two halves that never touch each other:
   bytes never move: a decode step sends a block table in and gets logits
   back.
 
-Inside the engine's jitted programs :func:`read_table` turns the table
-into the dense ``[B, S, Hkv, hd]`` rectangles ``llama.step_decode``
-consumes (that model never sees a block table); a model whose layers read
-different slots of the table is handed :class:`Paged` and reads layer by
-layer. Either way :func:`put_blocks` / :func:`put_positions` place a
-prefill's or a step's new K/V — placement is entirely this module's
-business.
+Inside the engine's jitted programs every model reads the pool the same
+way: its ``step_decode`` is handed :class:`Paged` (the arrays and the
+batch's block table) and each layer gathers the blocks it reads where they
+lie (:meth:`Paged.read`; ``models/common.attend`` takes them in that
+layout). :func:`put_blocks` / :func:`put_positions` place a prefill's or a
+step's new K/V — placement is entirely this module's business.
 
 **One signature for life.** ``jax.jit`` keys its executables on an
 argument's sharding and on whether it is committed. The arrays are
@@ -225,32 +224,16 @@ class KVBlockPool:
 # is given ``pool.scratch_block``, the one block no lease can hold.
 
 
-def read_table(k, v, table):
-    """The dense rectangles of a ragged batch: ``table`` [B, n] block ids
-    → per layer ``(k, v)``, each [B, n * block_tokens, Hkv, hd]. A row's
-    slots past its lease, and a pad row's, may name any block: positions
-    at or past a row's length are masked by ``llama.step_decode``."""
-    B, n = table.shape
-    L, _nb, Hkv, bs, hd = k.shape
-
-    def rect(a):
-        # [L, B, n, Hkv, bs, hd]
-        got = jnp.take(a, table, axis=1, mode="clip")
-        return got.transpose(0, 1, 2, 4, 3, 5).reshape(L, B, n * bs, Hkv, hd)
-
-    kr, vr = rect(k), rect(v)
-    return [(kr[li], vr[li]) for li in range(L)]
-
-
 class Paged(NamedTuple):
-    """The pool and a batch's block table, for a model whose layers do not
-    all read the same slots (a window layer reads the few that cover its
-    window) and which therefore reads the pool itself, layer by layer,
-    where :func:`read_table` would build every layer's whole rectangle."""
+    """What a model's ``step_decode`` gets for its cache: the pool's arrays
+    and the batch's block table. A layer reads the slots it needs (all of
+    a row's, or the few that cover a window) with :meth:`read`. A row's
+    slots past its lease, and a pad row's, may name any block: the model
+    masks positions at or past a row's length."""
 
     k: jax.Array
     v: jax.Array
-    table: jax.Array        # [B, n] block ids, as :func:`read_table` takes
+    table: jax.Array        # [B, n] block ids
 
     @property
     def block_tokens(self) -> int:
@@ -259,10 +242,10 @@ class Paged(NamedTuple):
     def read(self, layer: int, ids):
         """Blocks ``ids`` [B, m] of one layer as the pool holds them:
         ``(k, v)``, each [B, m, Hkv, block_tokens, hd], not transposed. The
-        caller masks what a row does not own, as with :func:`read_table`.
-        One gather from the pool itself (layers and blocks as one axis,
-        which costs nothing): a slice of one layer first is a copy of it,
-        the whole pool a step over all layers."""
+        caller masks what a row does not own. One gather from the pool
+        itself (layers and blocks as one axis, which costs nothing): a
+        slice of one layer first is a copy of it, the whole pool a step
+        over all layers."""
         L, nb = self.k.shape[:2]
         at = ids + layer * nb
 

@@ -266,9 +266,11 @@ class GenEngine:
         # functions the programs below run: ``step_prefill(params, tokens,
         # cfg, mesh=) -> (last_logits, kv, *stats)`` and ``step_decode(
         # params, tokens, cfg, cache, lengths, mesh=) -> (logits, new_kv,
-        # *stats)``. ``stats`` (small arrays, or none) come back with the
-        # chosen ids and go to the module's ``observe``, which counts them
-        # and names the step span's attributes.
+        # *stats)``, ``cache`` a ``kvcache.Paged`` (the pool's arrays and
+        # the batch's block table) for every module. ``stats`` (small
+        # arrays, or none) come back with the chosen ids and go to the
+        # module's ``observe``, which counts them and names the step
+        # span's attributes.
         module = sys.modules[type(cfg).__module__]
         if not hasattr(module, "step_decode"):
             raise ValueError(
@@ -316,17 +318,12 @@ class GenEngine:
             return ((choose(logits, 1), (logits, *stats)),
                     *kvcache.put_blocks(k, v, kv, blocks))
 
-        # a model whose layers all read the whole table is handed the
-        # rectangles; one that says PAGED_CACHE reads the pool itself
-        read = kvcache.Paged if getattr(module, "PAGED_CACHE", False) \
-            else kvcache.read_table
-
         def decode(p, rows, prev_ids, k, v):
             # one int32 row a sequence (see _decode_inputs)
             lit, lens, wblocks, woffsets, src = (rows[:, i]
                                                  for i in range(5))
             toks = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], lit)
-            cache = read(k, v, rows[:, 5:])
+            cache = kvcache.Paged(k, v, rows[:, 5:])
             logits, new_kv, *stats = module.step_decode(p, toks, cfg, cache,
                                                        lens, mesh=mesh)
             return ((choose(logits, n_ids), (logits, *stats)),

@@ -134,10 +134,23 @@ class TestKVBlockPool:
         table = np.zeros((3, 2), np.int32)
         table[0] = lease_a.blocks[:2]
         table[1, :1] = lease_b.blocks[:1]   # b's missing slot reads block 0
-        cache = jax.jit(kvcache.read_table)(pool.k, pool.v, table)
-        k = np.stack([np.asarray(lk) for lk, _lv in cache])
-        v = np.stack([np.asarray(lv) for _lk, lv in cache])
-        assert k.shape == v.shape == (L, 3, 8, Hkv, hd)
+
+        @jax.jit
+        def read(k, v, table):
+            cache = kvcache.Paged(k, v, table)
+            assert cache.block_tokens == 4
+            return [cache.read(li, cache.table) for li in range(L)]
+
+        got = read(pool.k, pool.v, table)
+        assert got[0][0].shape == (3, 2, Hkv, 4, hd)  # as the pool holds it
+
+        def positions(a):
+            # [B, n, Hkv, bs, hd] -> [B, n * bs, Hkv, hd]
+            return np.asarray(a).transpose(0, 1, 3, 2, 4).reshape(
+                3, 8, Hkv, hd)
+
+        k = np.stack([positions(lk) for lk, _lv in got])
+        v = np.stack([positions(lv) for _lk, lv in got])
         np.testing.assert_array_equal(k[:, 0, :t_a], ka[:, 0])
         np.testing.assert_array_equal(k[:, 0, t_a], tok[:, 0, 0])
         np.testing.assert_array_equal(v[:, 0, t_a], tok[:, 0, 0] - 1)
@@ -561,6 +574,77 @@ class TestDevicePool:
         table, ids = 4 * (5 + 8) * 4, 4 * 4
         assert cycles == [[2 * table, ids, 1, 0], [0, ids, 0, 1]]
         assert engine.pool.in_use_blocks == 0
+
+    def test_every_family_is_handed_the_pool_and_its_table(self,
+                                                           monkeypatch):
+        """A family made here of two step functions: its ``step_decode``
+        gets a ``kvcache.Paged`` (the pool's arrays, the batch's block
+        table) and nothing else, with no word from the module about how it
+        wants its cache. The "model" keeps a token's id as its key and
+        chooses the sum of the row's live cached keys and the fed token:
+        right only if the table names the row's own blocks, in order, and
+        the lengths mask what lies past them."""
+        import sys
+        import types
+        from dataclasses import dataclass
+
+        V = 64
+
+        @dataclass(frozen=True)
+        class StubConfig:
+            vocab_size: int = V
+            num_hidden_layers: int = 1
+            num_key_value_heads: int = 1
+            head_dim: int = 2
+            dtype: str = "float32"
+
+        def kv_of(tokens):      # [B, T] -> one layer's (k, v) [B, T, 1, 2]
+            k = jnp.broadcast_to(
+                tokens[..., None, None].astype(jnp.float32),
+                (*tokens.shape, 1, 2))
+            return [(k, -k)]
+
+        def step_prefill(params, tokens, cfg, mesh=None):
+            return jax.nn.one_hot(tokens.sum(axis=1) % V, V), kv_of(tokens)
+
+        handed = []
+
+        def step_decode(params, tokens, cfg, cache, lengths, mesh=None):
+            handed.append((type(cache), cache.k.shape, cache.table.shape))
+            B, n = cache.table.shape
+            pk, _pv = cache.read(0, cache.table)    # [B, n, 1, bs, 2]
+            live = jnp.arange(n * cache.block_tokens)[None] \
+                < lengths[:, None]
+            past = jnp.where(live, pk[:, :, 0, :, 0].reshape(B, -1), 0)
+            chosen = (past.sum(axis=1).astype(jnp.int32) + tokens) % V
+            return jax.nn.one_hot(chosen, V), kv_of(tokens[:, None])
+
+        family = types.ModuleType("stub_family")
+        family.step_prefill, family.step_decode = step_prefill, step_decode
+        monkeypatch.setitem(sys.modules, "stub_family", family)
+        StubConfig.__module__ = "stub_family"
+        engine = GenEngine({"embed": jnp.zeros((V, 2))}, StubConfig(),
+                           max_batch=3, queue_limit=8, max_new_tokens=8,
+                           kv_mb=1, block_tokens=4).start()
+        try:
+            prompts = [_prompt(StubConfig(), n, seed=n) for n in (3, 9, 6)]
+            reqs = [engine.submit(p, 7) for p in prompts]
+            outs = [r.result(timeout=240) for r in reqs]
+        finally:
+            engine.stop()
+        for prompt, out in zip(prompts, outs):
+            total, want = sum(prompt), []
+            for _ in range(7):
+                want.append(total % V)
+                total += want[-1]
+            assert out == want
+        pool = engine.pool
+        assert pool.in_use_blocks == 0
+        assert kvcache.Paged._fields == ("k", "v", "table")
+        assert handed and all(
+            kind is kvcache.Paged and shape == pool.k.shape
+            and table[0] in (1, 2, 4)
+            for kind, shape, table in handed)
 
     def test_pool_is_sharded_on_the_kv_heads_under_tp(self, tiny_model):
         """Two CPU devices, ``tp`` = 2 = the toy's KV heads: the arrays
