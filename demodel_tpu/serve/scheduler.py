@@ -27,12 +27,16 @@ ids it feeds from the previous step's ids on the device, so what the next
 step needs from the host (lengths, write coordinates, block table, who is
 in the batch: the engine retires by count alone) is known before this one
 has run: step j+1 is shipped and queued, and only then are step j's ids
-pulled, emitted and retired. Somebody to admit drains the pipe first, so a
-prefill runs alone on the device.
+pulled, emitted and retired. An admission rides the same pipe: with a step
+in flight the prefill is queued behind it, the id it chooses is set on the
+device into the ids the next step reads, that step is shipped with the new
+row, and only then does the host wait for the first token. On an empty pipe
+the prefill runs alone and its id is pulled at once.
 """
 
 from __future__ import annotations
 
+import functools
 import itertools
 import queue as queue_mod
 import sys
@@ -63,6 +67,8 @@ HUB.inc(labeled("gen_new_shapes_total", stage="prefill"), 0)
 HUB.inc(labeled("gen_new_shapes_total", stage="decode"), 0)
 HUB.inc(labeled("gen_decode_steps_total", ahead="0"), 0)
 HUB.inc(labeled("gen_decode_steps_total", ahead="1"), 0)
+HUB.inc(labeled("gen_prefills_total", ahead="0"), 0)
+HUB.inc(labeled("gen_prefills_total", ahead="1"), 0)
 HUB.set_gauge("gen_queue_depth", 0)
 HUB.set_gauge("gen_running", 0)
 
@@ -192,23 +198,27 @@ class AdmissionQueue:
 
 class _Seq:
     """Engine-internal running-sequence state. ``length``, ``planned`` and
-    ``row`` run ahead of ``generated``: they count the steps dispatched,
+    ``row`` run ahead of ``generated``: they count the programs dispatched,
     ``generated`` the tokens pulled and emitted."""
 
     __slots__ = ("req", "lease", "length", "last_tok", "generated",
-                 "planned", "row", "retired")
+                 "planned", "row", "retired", "first")
 
     def __init__(self, req: Request, lease, length: int, last_tok: int):
         self.req = req
         self.lease = lease
         self.length = length      # KV positions the dispatched steps write
         self.last_tok = last_tok  # the newest token the host has seen
-        self.generated = 1        # emitted; last_tok came from the prefill
+        self.generated = 0        # emitted; the first comes from the prefill
         self.planned = 1          # tokens the dispatched programs yield
-        #: its row of the newest decode step's ids, where the next step
-        #: finds its token; -1 fresh from the prefill (``last_tok`` is fed)
+        #: the slot of the newest ids on the device where the next step
+        #: finds its token: its row of the newest decode step, or where its
+        #: prefill's id was set; -1: ``last_tok`` is fed from the host
         self.row = -1
         self.retired = False
+        #: its prefill's ``(new_shape, ids, stats)``, on the device, until
+        #: the first token is pulled
+        self.first = None
 
 
 class _Step:
@@ -259,6 +269,7 @@ class GenEngine:
                  model: str = "inline"):
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
         from demodel_tpu.utils import compile_cache
 
@@ -347,6 +358,21 @@ class GenEngine:
         self._ids0 = jax.jit(lambda: jnp.zeros((n_ids,), jnp.int32),
                              out_shardings=pool.replicated)()
         self._prev_ids = self._ids0
+        # a prefill queued behind a step in flight leaves its id on the
+        # device: this sets it at a free slot of the ids the next step
+        # reads. One shape for life, held as the compiled object: a jitted
+        # function would be compiled again after jax.clear_caches(), the
+        # first time an admission found a step in flight. The slots'
+        # numbers are on the device from now on
+        int32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                                  sharding=pool.replicated)
+        self._set_id = jax.jit(
+            lambda ids, slot, tok: jax.lax.dynamic_update_slice(
+                ids, tok, (slot,)),
+            out_shardings=pool.replicated).lower(
+                int32((n_ids,)), int32(()), int32((1,))).compile()
+        self._slots = jax.device_put(
+            list(np.arange(n_ids, dtype=np.int32)), pool.replicated)
         self._flight: _Step | None = None
         self._pending: deque[Request] = deque()
         self._running: list[_Seq] = []
@@ -356,7 +382,7 @@ class GenEngine:
         self._tokens = {"prefill": 0, "decode": 0}
         #: prompt lengths and (batch bucket, width) pairs already run
         #: (engine thread only): the first run of each compiles or loads
-        #: a program inside its ``-device`` span
+        #: a program where it is dispatched
         self._shapes_run: set[tuple] = set()
         self.started_s = time.time()
         self._thread = threading.Thread(target=self._run, name="gen-engine",
@@ -485,16 +511,7 @@ class GenEngine:
                     # settles its sequences
                     self._flight = None
                     return
-            progressed = False
-            # a prefill runs alone on the device: nobody is admitted over
-            # a step in flight (_decode_step dispatches none ahead once
-            # somebody can be, so the pipe is empty one cycle later)
-            while self._flight is None and self._admit_one():
-                progressed = True
-            self._evict_cancelled()
-            if self._flight is not None or self._snapshot_running():
-                self._decode_step()
-            elif not progressed:
+            if not self._turn():
                 # pending work exists but nothing could be admitted and
                 # nothing is running (shouldn't happen now that submit()
                 # rejects over-pool requests, but e.g. a leaked lease
@@ -506,19 +523,36 @@ class GenEngine:
                             and not self._running:
                         self._work.wait(timeout=0.05)
 
+    def _turn(self) -> bool:
+        """One turn of the loop: admissions on an empty pipe, evictions,
+        one decode cycle. False when there was nothing it could do."""
+        progressed = False
+        # with no step in flight there is nothing to ride: the prefill
+        # runs alone and its id is pulled at once. Over a step in flight
+        # the cycle admits (_decode_step, _ride)
+        while self._flight is None and self._admit_one():
+            progressed = True
+        self._evict_cancelled()
+        if self._flight is not None or self._snapshot_running():
+            self._decode_step()
+            return True
+        return progressed
+
     def _snapshot_running(self) -> list[_Seq]:
         with self._work:
             return list(self._running)
 
-    def _admit_one(self) -> bool:
-        """Move one waiting request into the running batch: reserve its
-        worst-case blocks, prefill, emit its first token. False when the
-        batch is full, the queue is empty, or blocks are short (head-of-
-        line waits for frees — admission order is FIFO, no starvation)."""
+    def _take_head(self):
+        """Take the head of the queue if it can be admitted now, with its
+        worst-case blocks reserved: ``(request, lease)``; the lease is
+        None for one cancelled while it waited, which is settled here.
+        None when the batch is full, the queue is empty, or blocks are
+        short (head-of-line waits for frees: admission order is FIFO, no
+        starvation)."""
         with self._work:
             if self._stop or not self._pending \
                     or len(self._running) >= self.max_batch:
-                return False
+                return None
             req = self._pending[0]
             lease = None
             if not req.cancelled.is_set():
@@ -527,7 +561,7 @@ class GenEngine:
                 try:
                     lease = self.pool.alloc(need)
                 except PoolExhausted:
-                    return False
+                    return None
                 cancelled = True
                 try:
                     cancelled = req.cancelled.is_set()
@@ -544,8 +578,18 @@ class GenEngine:
         if lease is None:
             HUB.inc("gen_evicted_total")
             self._finish_req(req, error="cancelled before start")
-            return True
-        self._start_seq(req, lease)
+        return req, lease
+
+    def _admit_one(self) -> bool:
+        """Move one waiting request into the running batch on an empty
+        pipe: reserve its blocks, prefill, emit its first token. False
+        when nobody can be admitted now."""
+        got = self._take_head()
+        if got is None:
+            return False
+        req, lease = got
+        if lease is not None:
+            self._start_seq(req, lease)
         return True
 
     def _first_run(self, stage: str, *shape: int) -> bool:
@@ -602,50 +646,110 @@ class GenEngine:
             row[5:5 + len(got)] = got
         return bs * nb, rows
 
-    def _start_seq(self, req: Request, lease) -> None:
+    def _begin(self, req: Request, lease) -> _Seq | None:
+        """Ship a request's prompt and queue its prefill: the sequence,
+        running from now on, its first token still on the device. None
+        when that failed with the arrays still the pool's (tracing,
+        compilation), which costs this request alone; a failure that took
+        them is raised on, for the caller to settle the pool."""
+        T = len(req.prompt)
+        new_shape = self._first_run("prefill", T)
+        try:
+            ids, (_logits, *stats) = self._prefill(req.prompt, lease)
+        except Exception as exc:  # noqa: BLE001 - engine must survive
+            lease.free()
+            log.error("prefill failed for request %d: %s", req.id, exc)
+            self._finish_req(req, error=f"prefill failed: {exc}")
+            if self.pool.lost:
+                raise
+            return None
+        seq = _Seq(req, lease, T, 0)
+        seq.first = (new_shape, ids, stats)
+        with self._work:
+            self._running.append(seq)
+            running = len(self._running)
+        HUB.set_gauge("gen_running", running)
+        return seq
+
+    def _ride(self, batch: list[_Seq]) -> list[_Seq]:
+        """Admit everybody who can be admitted now behind the step in
+        flight: each prefill is queued on the pool that step returns, and
+        the id it will choose is set, on the device, into the ids the next
+        step reads, at a slot no row of ``batch`` (who rides that step
+        already) is read from. ``_pow2(max_batch)`` slots and a row free
+        for each admission, so there is one. A one-token request needs
+        none."""
+        taken = {s.row for s in batch}
+        free = (i for i in range(len(self._slots)) if i not in taken)
+        joined = []
+        while (got := self._take_head()) is not None:
+            req, lease = got
+            seq = self._begin(req, lease) if lease is not None else None
+            if seq is None:
+                continue
+            if req.max_new_tokens > 1:
+                seq.row = next(free)
+                self._prev_ids = self._set_id(
+                    self._prev_ids, self._slots[seq.row], seq.first[1])
+            joined.append(seq)
+        return joined
+
+    def _start_seq(self, req: Request, lease, seq: _Seq | None = None
+                   ) -> None:
+        """A request's first token, under its ``serve.prefill`` span. On
+        an empty pipe (``seq`` None) the span holds the whole admission:
+        the prompt's ship and the dispatch inside ``serve.prefill-device``,
+        then the pull of the id. Behind a step in flight ``_ride`` has
+        shipped and dispatched in the cycle that was open, and the span is
+        the wait for the id, between that cycle and the next, while the
+        device runs the prefill with the next step queued behind it. A
+        program that failed is found here, at the pull: whoever rode the
+        pool after it is retired with it."""
         import jax
 
+        ahead = seq is not None
+        if ahead and seq.retired:   # went with a prefill that failed
+            return
         req.started_s = time.time()
         HUB.observe("gen_queue_wait_seconds",
                     req.started_s - req.submitted_s)
+        HUB.inc(labeled("gen_prefills_total", ahead=str(int(ahead))))
         T = len(req.prompt)
-        pool = self.pool
-        applied = False
         try:
             with trace.span("serve.prefill", remote_parent=req.traceparent,
-                            request=req.id, prompt=T):
-                with trace.span("serve.prefill-device", prompt=T,
-                                new_shape=self._first_run("prefill", T)
-                                ) as dev:
-                    ids, (_logits, *stats) = self._prefill(req.prompt, lease)
-                    applied = True
+                            request=req.id, prompt=T, ahead=ahead):
+                with trace.span("serve.prefill-device", prompt=T) as dev:
+                    seq = seq or self._begin(req, lease)
+                    if seq is None:
+                        return
+                    new_shape, ids, stats = seq.first
+                    dev.set_attr("new_shape", new_shape)
                     pulled = ids.nbytes + sum(a.nbytes for a in stats)
                     if trace.enabled():
                         # export tier only, like the compute spans: off
                         # it the span ends at dispatch and the pull of
                         # the id takes the wait
-                        jax.block_until_ready((ids, pool.k, pool.v))
+                        jax.block_until_ready((ids, *stats))
                         self._observe(dev, jax.device_get(stats), T)
                         stats = []
                 ids, *stats = jax.device_get([ids, *stats])
-                tok0 = int(ids[0])
+                seq.first = None
                 HUB.inc("gen_d2h_bytes_total", pulled)
                 self._observe(None, stats, T)
         except Exception as exc:  # noqa: BLE001 - engine must survive
-            lease.free()
-            log.error("prefill failed for request %d: %s", req.id, exc)
-            self._finish_req(req, error=f"prefill failed: {exc}")
-            self._settle_pool(applied, f"prefill failed: {exc}")
+            if seq is not None:
+                log.error("prefill failed for request %d: %s", req.id, exc)
+                self._retire(seq, error=f"prefill failed: {exc}")
+            self._flight = None
+            self._prev_ids = self._ids0
+            self._settle_pool(True, f"prefill failed: {exc}")
             return
-        seq = _Seq(req, lease, len(req.prompt), tok0)
         with self._work:
-            self._running.append(seq)
-            running = len(self._running)
-            self._tokens["prefill"] += len(req.prompt)
-        HUB.set_gauge("gen_running", running)
-        HUB.inc(labeled("gen_tokens_total", stage="prefill"),
-                len(req.prompt))
-        req._emit(tok0)
+            self._tokens["prefill"] += T
+        HUB.inc(labeled("gen_tokens_total", stage="prefill"), T)
+        seq.last_tok = int(ids[0])
+        seq.generated = 1
+        req._emit(seq.last_tok)
         HUB.inc(labeled("gen_tokens_total", stage="decode"))
         if seq.generated >= req.max_new_tokens:
             self._retire(seq)
@@ -676,27 +780,6 @@ class GenEngine:
             if seq.req.cancelled.is_set():
                 HUB.inc("gen_evicted_total")
                 self._retire(seq, error="evicted")
-
-    def _hold_back(self, flight: _Step) -> bool:
-        """No step may be dispatched behind ``flight``: the engine is
-        stopping, or somebody waits whom it can admit once ``flight`` is
-        posted (a row is free or frees then, and so are the blocks the
-        head of the queue reserves)."""
-        with self._work:
-            if self._stop:
-                return True
-            if not self._pending:
-                return False
-            head = self._pending[0]
-            rows = self.max_batch - len(self._running)
-        ending = [s for s in flight.batch
-                  if not s.retired and s.planned >= s.req.max_new_tokens]
-        if rows + len(ending) <= 0:
-            return False
-        need = self.pool.blocks_for(len(head.prompt)
-                                    + head.max_new_tokens - 1)
-        return head.cancelled.is_set() or need <= self.pool.free_blocks \
-            + sum(len(s.lease.blocks) for s in ending)
 
     def _ship(self, batch: list[_Seq], ahead: bool) -> _Step:
         """Build and send one step's rows from what the host knows of
@@ -731,13 +814,17 @@ class GenEngine:
         host. One cycle is one ``serve.decode-step`` span around four
         children: ``serve.decode-h2d`` (the rows of the steps it
         dispatches: the step to pull if the pipe is empty, and the next
-        one unless it holds back), ``serve.decode-device`` (those
-        dispatches and the wait for the ids of the step in flight, which
-        the device runs meanwhile), ``serve.decode-fetch`` (ids and stats,
-        4 B a row) and ``serve.decode-post`` (emit, retire). The span
-        carries the attributes of the step it pulls. A program that
-        failed is found here, at the pull, with its successor queued on a
-        pool that is lost: both steps' sequences are retired."""
+        one; behind a step in flight also the prompt and the dispatch of
+        whoever is admitted, who rides the next step: ``_ride``),
+        ``serve.decode-device`` (those steps' dispatches and the wait for
+        the ids of the step in flight, which the device runs meanwhile),
+        ``serve.decode-fetch`` (ids and stats, 4 B a row) and
+        ``serve.decode-post`` (emit, retire). The span carries the
+        attributes of the step it pulls. Once it has closed, those
+        admitted get their first tokens, each under its ``serve.prefill``
+        span. A program that failed is found here, at the pull, with its
+        successor queued on a pool that is lost: both steps' sequences
+        are retired."""
         import jax
 
         flight, self._flight = self._flight, None
@@ -746,6 +833,7 @@ class GenEngine:
         if not (applied or running):
             return
         todo: list[_Step] = []
+        joined: list[_Seq] = []
         nxt = None
         try:
             with trace.span("serve.decode-step") as cycle:
@@ -753,12 +841,14 @@ class GenEngine:
                     if flight is None:
                         flight = self._ship(running, ahead=False)
                         todo.append(flight)
-                    if not self._hold_back(flight):
-                        batch = [s for s in flight.batch if not s.retired
-                                 and s.planned < s.req.max_new_tokens]
-                        if batch:
-                            nxt = self._ship(batch, ahead=True)
-                            todo.append(nxt)
+                    batch = [s for s in flight.batch if not s.retired
+                             and s.planned < s.req.max_new_tokens]
+                    if applied:
+                        joined = self._ride(batch)
+                        batch += [s for s in joined if s.row >= 0]
+                    if batch:
+                        nxt = self._ship(batch, ahead=True)
+                        todo.append(nxt)
                     ship.set_attr("bytes", sum(t.rows.nbytes for t in todo))
                 B = len(flight.batch)
                 for key, value in (("batch", B), ("width", flight.width),
@@ -814,6 +904,9 @@ class GenEngine:
             self._flight = None
             self._prev_ids = self._ids0
             self._settle_pool(applied, f"decode failed: {exc}")
+            return
+        for seq in joined:
+            self._start_seq(seq.req, seq.lease, seq)
 
     def _retire(self, seq: _Seq, error: str | None = None) -> None:
         """Finished/evicted/failed: blocks free IMMEDIATELY (the next

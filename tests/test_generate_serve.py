@@ -30,6 +30,18 @@ def tiny_model():
     return params, cfg
 
 
+def _tiny(family):
+    """``(module, params, cfg)`` of a model module the engine serves, at
+    its test size."""
+    from demodel_tpu.models import exaone_moe
+
+    module, cfg = {
+        "llama": (llama, llama.LlamaConfig.tiny()),
+        "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig.tiny()),
+    }[family]
+    return module, module.init_params(jax.random.key(2), cfg), cfg
+
+
 def _pool(cfg, **kw):
     kw.setdefault("block_tokens", 16)
     kw.setdefault("budget_mb", 1)
@@ -681,7 +693,8 @@ class TestDevicePool:
         assert odd.k.sharding.is_fully_replicated
 
     @pytest.mark.parametrize("stage", ["prefill", "decode", "decode-ahead",
-                                       "decode-pull"])
+                                       "decode-pull", "prefill-ahead",
+                                       "prefill-pull"])
     def test_a_program_that_fails_with_the_pool_in_hand(self, tiny_model,
                                                         monkeypatch, stage):
         """The program deletes what it was given (as donation does) and
@@ -690,7 +703,10 @@ class TestDevicePool:
         arrays. With a step in flight the failure is the dispatch behind
         it, or (as a device reports one) the wait for its ids, its
         successor already queued: both steps' sequences are retired and
-        the pipe is dropped."""
+        the pipe is dropped. So with a prefill that rides the pipe: it
+        fails where it is dispatched, in the cycle that is open, or at the
+        pull of its id, with the step that carries its row queued behind
+        it."""
         params, cfg = tiny_model
         engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
                            max_new_tokens=6, kv_mb=1, block_tokens=4)
@@ -712,7 +728,16 @@ class TestDevicePool:
                 engine._decode_step()
                 assert engine._flight.ahead
                 assert [len(r.tokens) for r in reqs] == [2, 2]
-            if flight == "pull":
+            if stage == "prefill" and flight == "pull":
+                real_get = jax.device_get
+
+                def fell_over(outs):    # the step's ids come, the id not
+                    if outs[0].shape == (1,):
+                        raise RuntimeError("device fell over")
+                    return real_get(outs)
+
+                monkeypatch.setattr(jax, "device_get", fell_over)
+            elif flight == "pull":
                 def fell_over(_outs):
                     monkeypatch.undo()
                     raise RuntimeError("device fell over")
@@ -720,7 +745,12 @@ class TestDevicePool:
                 monkeypatch.setattr(jax, "block_until_ready", fell_over)
             else:
                 setattr(engine, f"_j{stage}", boom)
-            if stage == "prefill":
+            if stage == "prefill" and flight:
+                reqs.append(engine.submit(_prompt(cfg, 4), 6))
+                assert engine._turn()
+                assert len(reqs[-1].tokens) == 0
+                monkeypatch.undo()
+            elif stage == "prefill":
                 reqs += _drive(engine, [_prompt(cfg, 4)], 6)
             else:
                 engine._decode_step()
@@ -804,14 +834,9 @@ class TestOneSignatureForLife:
 
     @pytest.fixture(params=["llama", "exaone_moe"])
     def placed(self, request):
-        from demodel_tpu.models import exaone_moe
         from demodel_tpu.parallel.mesh import make_mesh
 
-        module, cfg = {
-            "llama": (llama, llama.LlamaConfig.tiny()),
-            "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig.tiny()),
-        }[request.param]
-        params = module.init_params(jax.random.key(2), cfg)
+        module, params, cfg = _tiny(request.param)
         mesh = make_mesh(1)
         return (jax.device_put(params, module.param_shardings(cfg, mesh)),
                 cfg, mesh)
@@ -913,6 +938,51 @@ class TestOneSignatureForLife:
         assert {shape for shape, _a, _f in launched} \
             == {s[1:] for s in engine._shapes_run if s[0] == "decode"}
 
+    def test_an_admission_behind_a_step_makes_no_program(self, placed):
+        """The harness's protocol when a run had to compile: warm,
+        ``jax.clear_caches()``, warm again, window. No wave admits behind
+        a step in flight, the window does at once: the program that sets
+        the prefill's id on the device must still be there, so nothing is
+        compiled or loaded beyond the shapes run again since the clear."""
+        params, cfg, mesh = placed
+        events = _Compiles.listen()
+        engine = GenEngine(params, cfg, mesh=mesh, max_batch=2,
+                           queue_limit=8, max_new_tokens=12, kv_mb=1,
+                           block_tokens=16)
+        assert isinstance(engine._set_id, jax.stages.Compiled)
+        long, short = _prompt(cfg, 20, seed=1), _prompt(cfg, 18, seed=2)
+        ahead = labeled("gen_prefills_total", ahead="1")
+
+        def side_by_side():     # a wave: both admitted on an empty pipe
+            reqs = [engine.submit(long, 12), engine.submit(short, 4)]
+            while engine._turn():
+                pass
+            return [r.result(timeout=10) for r in reqs]
+
+        try:
+            first = side_by_side()
+            jax.clear_caches()
+            mark = len(events)
+            assert side_by_side() == first
+            shapes = set(engine._shapes_run)
+            # each made again, compiled or loaded, since the clear
+            assert len(events[mark:]) >= len(shapes) == 4
+            mark, before = len(events), HUB.snapshot().get(ahead, 0)
+            reqs = [engine.submit(long, 12)]
+            assert engine._turn() and engine._flight is not None
+            reqs.append(engine.submit(short, 4))
+            while engine._turn():
+                pass
+            assert [r.result(timeout=10) for r in reqs] == first
+        finally:
+            engine.stop()
+        assert HUB.snapshot()[ahead] == before + 1
+        assert set(engine._shapes_run) == shapes
+        assert events[mark:] == []
+        assert engine._jprefill._cache_size() == 2
+        assert engine._jdecode._cache_size() == 2
+        assert engine.pool.describe()["in_use_blocks"] == 0
+
     def test_donation_is_real(self, placed):
         """After every call the arrays that went in are gone: nothing was
         copied, and nothing could have read them afterwards."""
@@ -960,6 +1030,173 @@ class TestOneSignatureForLife:
                 r.result(timeout=10)
         assert engine.pool.describe()["in_use_blocks"] == 0
         assert engine.admission.describe()["outstanding"] == 0
+
+
+# ------------------------------------------ an admission rides the pipe
+
+
+@pytest.fixture(scope="module", params=["llama", "exaone_moe"])
+def family(request):
+    return _tiny(request.param)[1:]
+
+
+class TestAdmissionRidesThePipe:
+    """An engine driven by hand, a turn of its loop at a time, so that
+    who arrives over which step in flight is the test's to say."""
+
+    KW = dict(max_batch=3, queue_limit=16, kv_mb=4, block_tokens=4)
+
+    @staticmethod
+    def _alone(params, cfg, work, **kw):
+        """The oracle of ``test_matches_one_at_a_time_reference`` for any
+        family: each request alone on an idle engine, so admitted on an
+        empty pipe and decoded in a batch of one."""
+        engine = GenEngine(params, cfg, **kw).start()
+        try:
+            return [engine.generate(p, n, timeout=240) for p, n in work]
+        finally:
+            engine.stop()
+
+    @staticmethod
+    def _play(engine, work, arrivals):
+        """Submit ``work[i]`` before turn ``arrivals[i]`` and turn the
+        loop until nothing is left."""
+        reqs = []
+        for turn in range(200):
+            while len(reqs) < len(work) and arrivals[len(reqs)] <= turn:
+                reqs.append(engine.submit(*work[len(reqs)]))
+            if not engine._turn() and len(reqs) == len(work):
+                return reqs
+        raise AssertionError("the engine never came to rest")
+
+    @pytest.mark.parametrize("lengths,news,arrivals", [
+        # one behind each of the first steps, the one-token request among
+        # them; the last two wait for a row
+        ([9, 5, 12, 7, 4, 6], [14, 3, 1, 6, 2, 5], [0, 1, 2, 3, 3, 4]),
+        # two and three ride one cycle; the batch fills, empties to one
+        # row and fills again
+        ([6, 11, 4, 9, 3, 8], [16, 2, 3, 1, 4, 2], [0, 2, 2, 5, 5, 5]),
+        # the pipe runs empty (the first is done before the second comes)
+        # and an admission finds it empty again
+        ([5, 8, 7], [2, 6, 3], [0, 4, 6])],
+        ids=["one-a-step", "several-a-cycle", "pipe-runs-empty"])
+    def test_arrivals_over_steps_in_flight(self, family, lengths, news,
+                                           arrivals):
+        """Requests that arrive while steps are in flight get the tokens
+        they get alone, the first token first."""
+        params, cfg = family
+        work = [(_prompt(cfg, n, seed=i), new)
+                for i, (n, new) in enumerate(zip(lengths, news))]
+        kw = dict(self.KW, max_new_tokens=max(news))
+        refs = self._alone(params, cfg, work, **kw)
+        before = HUB.snapshot()
+        engine = GenEngine(params, cfg, **kw)
+        try:
+            reqs = self._play(engine, work, arrivals)
+            outs = [r.result(timeout=10) for r in reqs]
+        finally:
+            engine.stop()
+        assert outs == refs
+        after = HUB.snapshot()
+
+        def delta(name, **labels):
+            name = labeled(name, **labels)
+            return after[name] - before.get(name, 0)
+
+        on_empty = delta("gen_prefills_total", ahead="0")
+        assert on_empty + delta("gen_prefills_total", ahead="1") == len(work)
+        # only an idle engine admits on an empty pipe, and only the cycle
+        # after it starts on one
+        assert on_empty == delta("gen_decode_steps_total", ahead="0") \
+            == (2 if arrivals[1] > news[0] else 1)
+        assert engine.pool.describe()["in_use_blocks"] == 0
+        assert engine.admission.describe()["outstanding"] == 0
+
+    def test_cancel_between_dispatch_and_pull(self, family):
+        """Cancelled with its prefill queued and its row shipped: the
+        first token still comes, the eviction at the next boundary frees
+        the lease once, and whoever leases those blocks next is behind the
+        programs that write them."""
+        params, cfg = family
+        work = [(_prompt(cfg, 9, seed=1), 10), (_prompt(cfg, 6, seed=2), 8),
+                (_prompt(cfg, 7, seed=3), 5)]
+        kw = dict(self.KW, max_new_tokens=10)
+        refs = self._alone(params, cfg, work, **kw)
+        engine = GenEngine(params, cfg, **kw)
+        real = engine._launch
+        try:
+            kept = engine.submit(*work[0])
+            assert engine._turn() and engine._flight is not None
+            gone = engine.submit(*work[1])
+
+            def launch_then_cancel(step):
+                real(step)
+                gone.cancel()       # dispatched, shipped, not yet pulled
+
+            engine._launch = launch_then_cancel
+            held = engine.pool.in_use_blocks
+            assert engine._turn()
+            engine._launch = real
+            assert gone.tokens == refs[1][:1] and not gone.done.is_set()
+            assert engine.pool.in_use_blocks > held
+            late = engine.submit(*work[2])
+            while engine._turn():
+                pass
+            with pytest.raises(RuntimeError, match="evicted"):
+                gone.result(timeout=10)
+            assert gone.tokens == refs[1][:1]
+            assert kept.result(timeout=10) == refs[0]
+            assert late.result(timeout=10) == refs[2]
+        finally:
+            engine.stop()
+        assert engine.pool.describe()["in_use_blocks"] == 0
+        assert engine.admission.describe()["outstanding"] == 0
+
+    def test_counter_and_span_say_which_way(self, family, monkeypatch):
+        """``gen_prefills_total{ahead}`` and ``ahead`` on ``serve.prefill``:
+        0 on an idle engine, 1 behind a step; either way one
+        ``serve.prefill-device`` inside it, and the span of one that rode
+        lies between the cycle that dispatched it and the next."""
+        from demodel_tpu.utils import trace
+
+        params, cfg = family
+        for var in ("DEMODEL_TRACE", "DEMODEL_TRACE_SAMPLE", "DEMODEL_OBS"):
+            monkeypatch.delenv(var, raising=False)
+        trace.reset()
+        trace.enable()
+        before = HUB.snapshot()
+        engine = GenEngine(params, cfg, max_new_tokens=6, **self.KW)
+        try:
+            reqs = self._play(engine, [(_prompt(cfg, 9, seed=1), 6),
+                                       (_prompt(cfg, 5, seed=2), 3)], [0, 2])
+            for r in reqs:
+                r.result(timeout=10)
+        finally:
+            engine.stop()
+            spans = sorted((r for r in trace.buffer().snapshot()
+                            if r["name"].startswith("serve.")),
+                           key=lambda r: r["ts"])
+            trace.reset()
+        after = HUB.snapshot()
+        for flag in ("0", "1"):
+            name = labeled("gen_prefills_total", ahead=flag)
+            assert after[name] - before.get(name, 0) == 1
+        roots = [r for r in spans
+                 if r["name"] in ("serve.prefill", "serve.decode-step")]
+        assert [(r["name"], r["attrs"]["ahead"]) for r in roots[:6]] == [
+            ("serve.prefill", False), ("serve.decode-step", False),
+            ("serve.decode-step", True), ("serve.decode-step", True),
+            ("serve.prefill", True), ("serve.decode-step", True)]
+        assert all(r["name"] == "serve.decode-step" and r["attrs"]["ahead"]
+                   for r in roots[6:])
+        for a, b in zip(roots, roots[1:]):      # one after the other
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-6
+        rode = roots[4]
+        assert rode["attrs"]["request"] == reqs[1].id
+        inside = [r for r in spans if r["name"] == "serve.prefill-device"]
+        assert [r["parent"] for r in inside] == [roots[0]["span"],
+                                                 rode["span"]]
+        assert all(r["attrs"]["new_shape"] for r in inside)
 
 
 # --------------------------------------------------------- HTTP surface
@@ -1251,15 +1488,26 @@ class TestServeSpans:
         for name in CYCLE:
             assert len(run["named"](name)) == len(steps), name
         # a cycle carries the step it pulls: dispatched behind another
-        # (ahead) or on an empty pipe, which every admission leaves
+        # (ahead) or on an empty pipe, which only an idle engine has: the
+        # cycle after an admission that found the pipe empty
+        prefills = run["named"]("serve.prefill")
+        roots = sorted(steps + prefills, key=lambda r: r["ts"])
         ahead = [r["attrs"]["ahead"] for r in steps]
         assert ahead[0] is False and True in ahead
-        for flag in (True, False):
-            assert run["delta"](labeled("gen_decode_steps_total",
-                                        ahead=str(int(flag)))) \
-                == ahead.count(flag)
-        # a prefill runs alone: the pipe is drained before it
-        for pre in run["named"]("serve.prefill"):
+        for last, step in zip(roots, roots[1:]):
+            if step["name"] == "serve.decode-step":
+                assert step["attrs"]["ahead"] is not (
+                    last["name"] == "serve.prefill"
+                    and not last["attrs"]["ahead"])
+        for name, found in (("gen_decode_steps_total", ahead),
+                            ("gen_prefills_total",
+                             [r["attrs"]["ahead"] for r in prefills])):
+            for flag in (True, False):
+                assert run["delta"](labeled(name, ahead=str(int(flag)))) \
+                    == found.count(flag)
+        # a prefill's span overlaps no cycle: on an empty pipe it runs
+        # alone, behind a step it is the wait between two cycles
+        for pre in prefills:
             for step in steps:
                 assert pre["ts"] + pre["dur"] <= step["ts"] + 1e-6 \
                     or step["ts"] + step["dur"] <= pre["ts"] + 1e-6
