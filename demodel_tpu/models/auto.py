@@ -16,11 +16,13 @@ from demodel_tpu.models import bert as bert_mod
 from demodel_tpu.models import exaone_moe as exaone_moe_mod
 from demodel_tpu.models import gpt2 as gpt2_mod
 from demodel_tpu.models import llama as llama_mod
+from demodel_tpu.models import qwen3_next as qwen3_next_mod
 from demodel_tpu.models.hf_loader import (
     load_bert_params,
     load_exaone_moe_params,
     load_gpt2_params,
     load_llama_params,
+    load_qwen3_next_params,
 )
 from demodel_tpu.utils.logging import get_logger
 
@@ -86,9 +88,16 @@ def model_from_pull(store, report, mesh=None, placement=None):
         cfg = exaone_moe_mod.ExaoneMoeConfig.from_hf(config)
         params = load_exaone_moe_params(weights, cfg, mesh=mesh)
         fn = None   # served through its step functions only
+    elif model_type == "qwen3_next":
+        # the config's own checks refuse what is not implemented
+        # (rope_scaling, use_sliding_window, ...)
+        cfg = qwen3_next_mod.Qwen3NextConfig.from_hf(config)
+        params = load_qwen3_next_params(weights, cfg, mesh=mesh)
+        fn = None   # served through its step functions only
     else:
         raise ValueError(f"unsupported model_type {model_type!r} "
-                         "(supported: llama, gpt2, bert, exaone_moe)")
+                         "(supported: llama, gpt2, bert, exaone_moe, "
+                         "qwen3_next)")
     log.info("auto: built %s from pulled snapshot (%d tensors)",
              model_type, n_tensors)
     return fn, params, cfg

@@ -19,11 +19,8 @@ the experts this checkpoint *holds*; ``ep_size`` shares of that size make
 the layer, and this is share ``ep_rank``: the router is ``num_experts *
 ep_size`` wide and every token chooses among all of them, the held experts
 are ``[ep_rank * num_experts, (ep_rank + 1) * num_experts)``, and the layer
-computes their part of the result for the assignments that fall on them:
-no capacity, no dropped token. What the absent experts would add is left
-out and the partial result goes on (there is no exchange on one chip, and
-nothing stands in for one). Under a mesh with an ``ep`` axis the held
-experts are split once more over that axis and the parts are summed.
+computes their part of the result (:mod:`demodel_tpu.models.experts`, which
+the other expert-parallel family shares).
 
 The multi-token-prediction layer of the published model drafts tokens for
 self-speculation and changes no next-token logit: it is not built here, and
@@ -46,13 +43,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from demodel_tpu.models import experts
 from demodel_tpu.models.common import attend, rms_norm
 from demodel_tpu.models.llama import _rope
-from demodel_tpu.utils.metrics import HUB, labeled
-
-HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
-HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
-HUB.inc("gen_moe_experts_hit_total", 0)
 
 
 @dataclass(frozen=True)
@@ -206,25 +199,15 @@ def init_params(key, cfg: ExaoneMoeConfig) -> dict:
     }
 
 
-def _ep(mesh: Mesh | None) -> int:
-    return int(mesh.shape.get("ep", 1)) if mesh is not None else 1
-
-
 def param_shardings(cfg: ExaoneMoeConfig, mesh: Mesh) -> dict:
     """NamedSharding tree matching :func:`init_params`: the held experts
     split over ``ep`` (when they divide), everything else replicated, as
     in the deployment the configuration stands for (attention, the shared
     expert and the router on every chip)."""
     rep = NamedSharding(mesh, P())
-    held = NamedSharding(mesh, P("ep")) \
-        if _ep(mesh) > 1 and cfg.num_experts % _ep(mesh) == 0 else rep
     shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    tree = jax.tree.map(lambda _leaf: rep, shapes)
-    for layer in tree["layers"]:
-        for name in ("experts_gate_up", "experts_down"):
-            if name in layer:
-                layer[name] = held
-    return tree
+    return experts.held_shardings(jax.tree.map(lambda _leaf: rep, shapes),
+                                  cfg.num_experts, mesh)
 
 
 # -------------------------------------------------------------- attention
@@ -256,43 +239,10 @@ def _attn(layer, x, cfg: ExaoneMoeConfig, positions, *, window: int,
 # ---------------------------------------------------------- expert layer
 
 
-def _swiglu(x, gate, up, down):
-    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
-
-
-def _held_part(x, live, chosen, weights, gate_up, down, first):
-    """The held experts' part of the routed sum. ``x`` [N, D]; ``chosen``
-    [N, K] expert ids over the whole router and ``weights`` [N, K] theirs;
-    ``gate_up`` [E, D, 2F] and ``down`` [E, F, D] the held experts, which
-    are ``first .. first + E - 1``; rows not ``live`` (the pad rows of a
-    batch bucket) choose nothing. Returns ``(y [N, D] float32, tokens
-    [E])``. Every assignment that falls on a held expert is computed: the
-    rows are sorted by expert and each projection is one grouped product
-    over the groups' actual sizes."""
-    N, K = chosen.shape
-    E, F = down.shape[0], down.shape[1]
-    local = chosen - first
-    held = (local >= 0) & (local < E) & live[:, None]
-    group = jnp.where(held, local, E).reshape(N * K)    # E: not computed
-    order = jnp.argsort(group, stable=True)
-    tokens = (group[:, None] == jnp.arange(E)[None, :]).sum(
-        axis=0, dtype=jnp.int32)
-    rows = x[order // K]                                # [N * K, D]
-    with jax.named_scope("moe.experts"):
-        h = lax.ragged_dot(rows, gate_up, tokens)
-        h = jax.nn.silu(h[:, :F]) * h[:, F:]
-        y = lax.ragged_dot(h, down, tokens,
-                           preferred_element_type=jnp.float32)
-    # rows past the groups' end belong to no held expert: whatever the
-    # grouped product left there is dropped, not scaled
-    w = jnp.where(held, weights, 0.0).reshape(N * K)[order]
-    y = jnp.where(w[:, None] != 0, y * w[:, None], 0.0)
-    back = jnp.argsort(order)                           # the unsort
-    return y[back].reshape(N, K, -1).sum(axis=1), tokens
-
-
 def _moe(layer, x, live, cfg: ExaoneMoeConfig, mesh: Mesh | None):
-    """``x`` [N, D] → ``(mlp(x) [N, D], tokens per held expert [E])``."""
+    """``x`` [N, D] → ``(mlp(x) [N, D], tokens per held expert [E])``:
+    this family's scoring (sigmoid, the selection bias, the scale) and its
+    shared expert around :func:`experts.routed`."""
     K = cfg.num_experts_per_tok
     with jax.named_scope("moe.route"):
         s = jax.nn.sigmoid(jnp.dot(
@@ -303,38 +253,20 @@ def _moe(layer, x, live, cfg: ExaoneMoeConfig, mesh: Mesh | None):
         if cfg.norm_topk_prob:
             weights = weights / weights.sum(axis=1, keepdims=True)
         weights = weights * cfg.routed_scaling_factor
-        n = _ep(mesh)
-        if n > 1 and cfg.num_experts % n == 0:
-            each = cfg.num_experts // n
-
-            def part(x, live, chosen, weights, gate_up, down):
-                first = cfg.ep_rank * cfg.num_experts \
-                    + lax.axis_index("ep") * each
-                y, tokens = _held_part(x, live, chosen, weights, gate_up,
-                                       down, first)
-                return (lax.psum(y, "ep"),
-                        lax.all_gather(tokens, "ep", tiled=True))
-
-            y, tokens = jax.shard_map(
-                part, mesh=mesh, in_specs=(P(),) * 4 + (P("ep"),) * 2,
-                out_specs=(P(), P()), axis_names={"ep"}, check_vma=False)(
-                x, live, chosen, weights, layer["experts_gate_up"],
-                layer["experts_down"])
-        else:
-            y, tokens = _held_part(
-                x, live, chosen, weights, layer["experts_gate_up"],
-                layer["experts_down"], cfg.ep_rank * cfg.num_experts)
-    shared = _swiglu(x, layer["shared_gate_proj"], layer["shared_up_proj"],
-                     layer["shared_down_proj"]) \
-        if cfg.num_shared_experts else 0.0
+        y, tokens = experts.routed(
+            x, live, chosen, weights, layer["experts_gate_up"],
+            layer["experts_down"], cfg.ep_rank * cfg.num_experts, mesh)
+    shared = experts.swiglu(
+        x, layer["shared_gate_proj"], layer["shared_up_proj"],
+        layer["shared_down_proj"]) if cfg.num_shared_experts else 0.0
     return y.astype(x.dtype) + shared, tokens
 
 
 def _mlp(layer, x, live, cfg, mesh):
     """``x`` [B, T, D] → ``(mlp(x), tokens per held expert or None)``."""
     if "router" not in layer:
-        return _swiglu(x, layer["gate_proj"], layer["up_proj"],
-                       layer["down_proj"]), None
+        return experts.swiglu(x, layer["gate_proj"], layer["up_proj"],
+                              layer["down_proj"]), None
     B, T, D = x.shape
     y, tokens = _moe(layer, x.reshape(B * T, D), live.reshape(B * T), cfg,
                      mesh)
@@ -366,6 +298,15 @@ def _head(params, x, cfg):
 
 
 # ------------------------------------------------------ the engine's steps
+
+
+def cache_spec(cfg: ExaoneMoeConfig):
+    """What the serving engine keeps for a sequence: every layer pages K
+    and V, window and full alike, nothing of fixed size."""
+    from demodel_tpu.serve.kvcache import CacheSpec
+
+    return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
+                     cfg.head_dim)
 
 
 def step_prefill(params, tokens, cfg: ExaoneMoeConfig,
@@ -426,10 +367,6 @@ def step_decode(params, tokens, cfg: ExaoneMoeConfig, cache, lengths,
 def observe(expert_tokens, tokens: int, cfg: ExaoneMoeConfig) -> dict:
     """A step's ``expert_tokens`` (on the host) and the tokens it ran →
     the span's attributes; the counters are counted here."""
-    landed = int(expert_tokens.sum())
-    hit = int((expert_tokens > 0).sum())
-    HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
-    HUB.inc(labeled("gen_moe_assignments_total", held="false"),
-            tokens * cfg.num_experts_per_tok * cfg.sparse_layers - landed)
-    HUB.inc("gen_moe_experts_hit_total", hit)
-    return {"expert_tokens": landed, "experts_hit": hit}
+    return experts.observe(
+        expert_tokens,
+        tokens * cfg.num_experts_per_tok * cfg.sparse_layers)

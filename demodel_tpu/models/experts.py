@@ -1,0 +1,112 @@
+"""The held experts' part of a routed layer: what every expert-parallel
+family of this stack shares.
+
+A family scores and chooses in its own way (sigmoid and a selection bias,
+softmax) and adds what every chip computes alike (a shared expert) itself;
+what is the same is told here. The layer is one chip's share of an
+expert-parallel replica: the router is as wide as the whole layer, the
+checkpoint holds ``E`` of its experts, ``first .. first + E - 1``, and the
+layer computes their part of the result for the assignments that fall on
+them: no capacity, no dropped token. What the absent experts would add is
+left out and the partial result goes on (there is no exchange on one chip,
+and nothing stands in for one). Under a mesh with an ``ep`` axis the held
+experts are split once more over that axis and the parts are summed.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from demodel_tpu.utils.metrics import HUB, labeled
+
+HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
+HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
+HUB.inc("gen_moe_experts_hit_total", 0)
+
+
+def ep_size(mesh: Mesh | None) -> int:
+    return int(mesh.shape.get("ep", 1)) if mesh is not None else 1
+
+
+def held_part(x, live, chosen, weights, gate_up, down, first):
+    """The held experts' part of the routed sum. ``x`` [N, D]; ``chosen``
+    [N, K] expert ids over the whole router and ``weights`` [N, K] theirs;
+    ``gate_up`` [E, D, 2F] and ``down`` [E, F, D] the held experts, which
+    are ``first .. first + E - 1``; rows not ``live`` (the pad rows of a
+    batch bucket) choose nothing. Returns ``(y [N, D] float32, tokens
+    [E])``. Every assignment that falls on a held expert is computed: the
+    rows are sorted by expert and each projection is one grouped product
+    over the groups' actual sizes."""
+    N, K = chosen.shape
+    E, F = down.shape[0], down.shape[1]
+    local = chosen - first
+    held = (local >= 0) & (local < E) & live[:, None]
+    group = jnp.where(held, local, E).reshape(N * K)    # E: not computed
+    order = jnp.argsort(group, stable=True)
+    tokens = (group[:, None] == jnp.arange(E)[None, :]).sum(
+        axis=0, dtype=jnp.int32)
+    rows = x[order // K]                                # [N * K, D]
+    with jax.named_scope("moe.experts"):
+        h = lax.ragged_dot(rows, gate_up, tokens)
+        h = jax.nn.silu(h[:, :F]) * h[:, F:]
+        y = lax.ragged_dot(h, down, tokens,
+                           preferred_element_type=jnp.float32)
+    # rows past the groups' end belong to no held expert: whatever the
+    # grouped product left there is dropped, not scaled
+    w = jnp.where(held, weights, 0.0).reshape(N * K)[order]
+    y = jnp.where(w[:, None] != 0, y * w[:, None], 0.0)
+    back = jnp.argsort(order)                           # the unsort
+    return y[back].reshape(N, K, -1).sum(axis=1), tokens
+
+
+def routed(x, live, chosen, weights, gate_up, down, first: int,
+           mesh: Mesh | None):
+    """:func:`held_part` on one chip, or split over the mesh's ``ep`` axis
+    (when the held experts divide) with the parts summed: ``(y [N, D]
+    float32, tokens per held expert [E])``."""
+    n, E = ep_size(mesh), down.shape[0]
+    if n == 1 or E % n:
+        return held_part(x, live, chosen, weights, gate_up, down, first)
+    each = E // n
+
+    def part(x, live, chosen, weights, gate_up, down):
+        y, tokens = held_part(x, live, chosen, weights, gate_up, down,
+                              first + lax.axis_index("ep") * each)
+        return lax.psum(y, "ep"), lax.all_gather(tokens, "ep", tiled=True)
+
+    return jax.shard_map(
+        part, mesh=mesh, in_specs=(P(),) * 4 + (P("ep"),) * 2,
+        out_specs=(P(), P()), axis_names={"ep"}, check_vma=False)(
+        x, live, chosen, weights, gate_up, down)
+
+
+def held_shardings(tree: dict, held: int, mesh: Mesh) -> dict:
+    """``tree`` (a params tree of replicated shardings) with the stacked
+    expert tensors of every layer split over ``ep`` when they divide."""
+    n = ep_size(mesh)
+    if n > 1 and held % n == 0:
+        for layer in tree["layers"]:
+            for name in ("experts_gate_up", "experts_down"):
+                if name in layer:
+                    layer[name] = NamedSharding(mesh, P("ep"))
+    return tree
+
+
+def swiglu(x, gate, up, down):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def observe(expert_tokens, assignments: int) -> dict:
+    """A step's ``expert_tokens`` ([expert layers, held experts], on the
+    host) and the assignments its tokens made in all → the step span's
+    attributes; the counters are counted here."""
+    landed = int(expert_tokens.sum())
+    hit = int((expert_tokens > 0).sum())
+    HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
+    HUB.inc(labeled("gen_moe_assignments_total", held="false"),
+            assignments - landed)
+    HUB.inc("gen_moe_experts_hit_total", hit)
+    return {"expert_tokens": landed, "experts_hit": hit}

@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from demodel_tpu.models import exaone_moe
+from demodel_tpu.models import exaone_moe, qwen3_next
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
 from demodel_tpu.models.llama import LlamaConfig, param_shardings
@@ -104,16 +104,38 @@ def load_llama_params(weights: dict, cfg: LlamaConfig, mesh=None) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _stacker(projections: int, sharding):
-    """Jitted: ``projections`` runs of per-expert ``[out, in]`` matrices →
-    one ``[E, in, projections * out]``, the runs side by side."""
-    def stack(*ws):
-        n = len(ws) // projections
-        return jnp.concatenate(
-            [jnp.stack([w.T for w in ws[i * n:(i + 1) * n]])
-             for i in range(projections)], axis=2)
+def _setter(sharding):
+    """Jitted, the stack donated: one expert's ``[out, in]`` matrix of one
+    projection, transposed, into its place ``[e, :, at : at + out]`` of
+    the stacked tensor."""
+    def put(stack, w, e, at):
+        return jax.lax.dynamic_update_slice(stack, w.T[None], (e, 0, at))
 
-    return jax.jit(stack, out_shardings=sharding)
+    return jax.jit(put, donate_argnums=0, out_shardings=sharding)
+
+
+@functools.lru_cache(maxsize=None)
+def _zeros(shape, dtype, sharding):
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
+
+
+def _stack_experts(w: "_Weights", pre: str, projs, cfg, sharding):
+    """The held experts' ``<pre>mlp.experts.<e>.<p>_proj.weight`` (``[out,
+    in]`` each, under their index in the whole layer) for the projections
+    ``projs`` → one ``[E, in, len(projs) * out]``, a projection's runs side
+    by side. Each matrix is popped, set into the stack in place and freed,
+    so boot holds the stack and one matrix, not the experts twice."""
+    D, F = cfg.hidden_size, cfg.moe_intermediate_size
+    shape = (cfg.num_experts, *((F, D) if projs == ("down",)
+                                else (D, len(projs) * F)))
+    stack = _zeros(shape, cfg.dtype, sharding)()
+    put = _setter(sharding)
+    first = cfg.ep_rank * cfg.num_experts
+    for j in range(cfg.num_experts):
+        for i, p in enumerate(projs):
+            stack = put(stack, w.get(
+                f"{pre}mlp.experts.{first + j}.{p}_proj.weight"), j, i * F)
+    return stack
 
 
 def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
@@ -126,7 +148,6 @@ def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
     layer stay in ``weights``."""
     w = _Weights(weights)
     sh = exaone_moe.param_shardings(cfg, mesh) if mesh is not None else {}
-    first = cfg.ep_rank * cfg.num_experts
     layers = []
     for i, sparse in enumerate(cfg.sparse):
         pre = f"layers.{i}."
@@ -138,10 +159,8 @@ def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
         def vec(name, leaf):
             return w.get(pre + name, sharding=lsh.get(leaf))
 
-        def experts(projs, leaf):
-            return _stacker(len(projs), lsh.get(leaf))(*(
-                w.get(f"{pre}mlp.experts.{first + j}.{p}_proj.weight")
-                for p in projs for j in range(cfg.num_experts)))
+        def held(projs, leaf):
+            return _stack_experts(w, pre, projs, cfg, lsh.get(leaf))
 
         layer = {
             "q_proj": lin("self_attn.q_proj.weight", "q_proj"),
@@ -158,9 +177,9 @@ def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
                 "router": lin("mlp.gate.weight", "router"),
                 "router_bias": vec("mlp.gate.e_score_correction_bias",
                                    "router_bias").astype(jnp.float32),
-                "experts_gate_up": experts(("gate", "up"),
+                "experts_gate_up": held(("gate", "up"),
                                            "experts_gate_up"),
-                "experts_down": experts(("down",), "experts_down"),
+                "experts_down": held(("down",), "experts_down"),
                 "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
                                         "shared_gate_proj"),
                 "shared_up_proj": lin("mlp.shared_experts.up_proj.weight",
@@ -173,6 +192,106 @@ def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
                 "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
                 "up_proj": lin("mlp.up_proj.weight", "up_proj"),
                 "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _regrouper(groups: int, widths: tuple[int, ...], sharding):
+    """Jitted: a ``[out, in]`` matrix whose rows lie in ``groups`` runs,
+    each holding ``widths`` rows of the parts side by side, → ``[in, out]``
+    with each part's rows of all runs together, the parts in order."""
+    def regroup(x):
+        x = x.reshape(groups, sum(widths), x.shape[1])
+        at, parts = 0, []
+        for n in widths:
+            parts.append(x[:, at:at + n].reshape(groups * n, -1))
+            at += n
+        return jnp.concatenate(parts).T
+
+    return jax.jit(regroup, out_shardings=sharding)
+
+
+def load_qwen3_next_params(weights: dict,
+                           cfg: "qwen3_next.Qwen3NextConfig",
+                           mesh=None) -> dict:
+    """The tree of :func:`qwen3_next.init_params` from a checkpoint that
+    holds one share of the experts under their global indices. Hugging
+    Face lays ``in_proj_qkvz`` and ``in_proj_ba`` out a key head at a time
+    (``q | k | v | z`` of one head, then the next) and ``q_proj`` an
+    attention head at a time (its query, then its gate): they are regrouped
+    so that each part is one run of columns. Tensors of the
+    multi-token-prediction layer stay in ``weights``."""
+    w = _Weights(weights)
+    sh = qwen3_next.param_shardings(cfg, mesh) if mesh is not None else {}
+    Hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    r = cfg.linear_num_value_heads // Hk
+    rdv = r * cfg.linear_value_head_dim
+    layers = []
+    for i, full in enumerate(cfg.full):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def regrouped(name, leaf, groups, widths):
+            return _regrouper(groups, widths, lsh.get(leaf))(
+                w.get(pre + name))
+
+        def held(projs, leaf):
+            return _stack_experts(w, pre, projs, cfg, lsh.get(leaf))
+
+        layer = {
+            "in_norm": vec("input_layernorm.weight", "in_norm"),
+            "post_norm": vec("post_attention_layernorm.weight", "post_norm"),
+            "router": lin("mlp.gate.weight", "router"),
+            "experts_gate_up": held(("gate", "up"), "experts_gate_up"),
+            "experts_down": held(("down",), "experts_down"),
+            "shared_gate_proj": lin("mlp.shared_expert.gate_proj.weight",
+                                    "shared_gate_proj"),
+            "shared_up_proj": lin("mlp.shared_expert.up_proj.weight",
+                                  "shared_up_proj"),
+            "shared_down_proj": lin("mlp.shared_expert.down_proj.weight",
+                                    "shared_down_proj"),
+            "shared_gate": lin("mlp.shared_expert_gate.weight",
+                               "shared_gate"),
+        }
+        if full:
+            layer.update({
+                "q_proj": regrouped("self_attn.q_proj.weight", "q_proj",
+                                    cfg.num_attention_heads,
+                                    (cfg.head_dim, cfg.head_dim)),
+                "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+                "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+                "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+                "q_norm": vec("self_attn.q_norm.weight", "q_norm"),
+                "k_norm": vec("self_attn.k_norm.weight", "k_norm"),
+            })
+        else:
+            conv = w.get(pre + "linear_attn.conv1d.weight")     # [C, 1, K]
+            layer.update({
+                "in_proj_qkvz": regrouped(
+                    "linear_attn.in_proj_qkvz.weight", "in_proj_qkvz", Hk,
+                    (dk, dk, rdv, rdv)),
+                "in_proj_ba": regrouped("linear_attn.in_proj_ba.weight",
+                                        "in_proj_ba", Hk, (r, r)),
+                "conv": _lay(conv.reshape(conv.shape[0], conv.shape[-1]),
+                             True, lsh.get("conv")),
+                "A_log": vec("linear_attn.A_log", "A_log"),
+                "dt_bias": vec("linear_attn.dt_bias", "dt_bias"),
+                "gdn_norm": vec("linear_attn.norm.weight", "gdn_norm"),
+                "out_proj": lin("linear_attn.out_proj.weight", "out_proj"),
             })
         layers.append(layer)
     return {

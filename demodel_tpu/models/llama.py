@@ -303,6 +303,15 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
     return x @ params["lm_head"], new_cache
 
 
+def cache_spec(cfg: LlamaConfig):
+    """What the serving engine keeps for a sequence: every layer pages K
+    and V, nothing of fixed size."""
+    from demodel_tpu.serve.kvcache import CacheSpec
+
+    return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
+                     cfg.head_dim)
+
+
 def step_prefill(params, tokens, cfg: LlamaConfig, mesh: Mesh | None = None):
     """Prefill leg of the serving plane: ``tokens`` [B, T] (one sequence,
     or a few of EQUAL length) → ``(last_logits [B, V], kv)`` where ``kv``

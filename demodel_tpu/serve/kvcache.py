@@ -1,4 +1,5 @@
-"""Paged KV cache: fixed-size blocks in one preallocated pool on the device.
+"""The generation cache: paged K and V, and fixed slots of recurrent state,
+in one preallocated pool on the device.
 
 vLLM's PagedAttention memory discipline grafted onto the repo's tier
 accounting: the pool preallocates ``num_blocks`` blocks of
@@ -10,27 +11,44 @@ on statusz next to the RAM tier, and a finished sequence's blocks return
 to the free list immediately — no per-sequence ``max_len`` rectangle,
 no fragmentation beyond the last partial block.
 
+**Two kinds of state, one manager.** A model's module states what it keeps
+for a sequence (:class:`CacheSpec`, from its ``cache_spec(cfg)``): which of
+its layers page K and V, and which arrays of fixed size it carries from
+token to token whatever the length (a linear-attention layer's matrix
+state, the last columns of a convolution's input). A pool built from a spec
+with such arrays also holds **slots**: a sequence's lease is its blocks and
+one slot, taken and returned together, and the device arrays of the slots
+live beside ``k`` and ``v``, ``[layers, slots + 1, ...]`` each (the extra
+slot is scratch, as the extra block is). Llama and EXAONE-MoE say "every
+layer pages, no slot", and their pool is ``k`` and ``v`` alone.
+
 The pool is two halves that never touch each other:
 
 - the **ledger** on the host: free list, :class:`BlockLease`, budget,
   counters, ``describe()``. Admission and eviction are list operations.
 - the **arrays** on the device: ``k`` and ``v``, ``[L, num_blocks + 1,
-  Hkv, block_tokens, hd]`` in the model's dtype (positions and head
+  Hkv, block_tokens, hd]`` in the model's dtype, ``L`` the layers that page
+  (positions and head
   width innermost, the order the TPU's attention reads; the extra block
   is scratch no lease can hold), so the byte budget
   (``DEMODEL_GEN_KV_MB``) is an HBM budget. Under a ``tp`` mesh they are
   sharded on the KV-head axis by ``llama._head_align``'s rule (replicated
-  when the heads do not divide). Every program that writes them takes
-  them donated and returns them (:meth:`KVBlockPool.apply`), so the
-  bytes never move: a decode step sends a block table in and gets logits
-  back.
+  when the heads do not divide); then the spec's state arrays, replicated.
+  The budget pays for the slots first and the blocks with the rest. Every
+  program that writes them takes them all donated and returns them all
+  (:meth:`KVBlockPool.apply`), so the bytes never move: a decode step
+  sends a block table in and gets ids back.
 
 Inside the engine's jitted programs every model reads the pool the same
 way: its ``step_decode`` is handed :class:`Paged` (the arrays and the
 batch's block table) and each layer gathers the blocks it reads where they
 lie (:meth:`Paged.read`; ``models/common.attend`` takes them in that
-layout). :func:`put_blocks` / :func:`put_positions` place a prefill's or a
-step's new K/V — placement is entirely this module's business.
+layout), and a layer with state reads its rows' slots
+(:meth:`Paged.read_state`). :func:`put_blocks` / :func:`put_positions`
+place a prefill's or a step's new K/V, :func:`put_slots` what it leaves in
+the slots — placement is entirely this module's business.
+A prefill writes the whole of its slot, so a slot taken again carries
+nothing over.
 
 **One signature for life.** ``jax.jit`` keys its executables on an
 argument's sharding and on whether it is committed. The arrays are
@@ -43,6 +61,7 @@ or load from the persistent cache, in the middle of serving).
 
 from __future__ import annotations
 
+import math
 import threading
 from typing import Any, NamedTuple
 
@@ -64,6 +83,23 @@ log = get_logger("serve.kvcache")
 HUB.set_gauge("gen_kv_blocks_in_use", 0)
 HUB.inc("gen_kv_blocks_alloc_total", 0)
 HUB.inc("gen_kv_blocks_freed_total", 0)
+HUB.set_gauge("gen_state_slots_in_use", 0)
+HUB.inc("gen_state_slots_alloc_total", 0)
+HUB.inc("gen_state_slots_freed_total", 0)
+
+
+class CacheSpec(NamedTuple):
+    """What a model keeps for a sequence, as its module states it
+    (``cache_spec(cfg)``): ``layers`` of its layers page K and V at
+    ``kv_heads`` heads of ``head_dim``; ``state`` names the arrays of fixed
+    size a sequence carries beside them, each ``(name, shape, dtype)`` with
+    the layers that keep it as the shape's first axis (the pool puts the
+    slot axis second, as K and V have the block axis)."""
+
+    layers: int
+    kv_heads: int
+    head_dim: int
+    state: tuple[tuple[str, tuple[int, ...], str], ...] = ()
 
 
 class PoolExhausted(Exception):
@@ -73,50 +109,67 @@ class PoolExhausted(Exception):
 
 
 class BlockLease:
-    """One sequence's blocks. Must reach :meth:`free` exactly once —
-    at completion, eviction, or error; idempotent so cleanup paths can
-    race shutdown without double-crediting the budget."""
+    """One sequence's blocks and, in a pool that has slots, its slot
+    (``None`` otherwise). Must reach :meth:`free` exactly once — at
+    completion, eviction, or error; idempotent so cleanup paths can race
+    shutdown without double-crediting the budget."""
 
-    __slots__ = ("_pool", "blocks", "_freed")
+    __slots__ = ("_pool", "blocks", "slot", "_freed")
 
-    def __init__(self, pool: "KVBlockPool", blocks: list[int]):
+    def __init__(self, pool: "KVBlockPool", blocks: list[int],
+                 slot: int | None = None):
         self._pool = pool
         self.blocks = blocks
+        self.slot = slot
         self._freed = False
 
     def free(self) -> None:
         if self._freed:
             return
         self._freed = True
-        self._pool._reclaim(self.blocks)
+        self._pool._reclaim(self.blocks, self.slot)
 
 
 class KVBlockPool:
-    """Preallocated block pool for one model's generation KV.
+    """Preallocated pool for one model's generation cache.
 
-    ``layers``/``kv_heads``/``head_dim`` fix the block geometry; the
-    byte budget (``DEMODEL_GEN_KV_MB`` unless overridden) fixes the
-    block count; ``mesh`` (the engine's) fixes where the arrays live.
-    All block state sits behind one lock and never touches the arrays;
-    the arrays belong to the engine thread alone, which hands them to
-    its programs through :meth:`apply`.
+    ``spec`` (the model module's :class:`CacheSpec`) fixes the block
+    geometry and the slot's arrays; ``slots`` how many sequences can hold
+    a slot at once (the engine's ``max_batch``; ignored when the spec has
+    no state); the byte budget (``DEMODEL_GEN_KV_MB`` unless overridden)
+    pays for the slots and fixes the block count with what is left;
+    ``mesh`` (the engine's) fixes where the arrays live. All ledger state
+    sits behind one lock and never touches the arrays; the arrays belong
+    to the engine thread alone, which hands them to its programs through
+    :meth:`apply`.
     """
 
-    def __init__(self, layers: int, kv_heads: int, head_dim: int, *,
+    def __init__(self, spec: CacheSpec, *,
+                 slots: int = 0,
                  block_tokens: int | None = None,
                  budget_mb: int | None = None,
                  dtype: str = "float32", mesh=None):
+        self.spec = spec
+        layers, kv_heads, head_dim = spec.layers, spec.kv_heads, spec.head_dim
         self.block_tokens = int(block_tokens or gen_block_tokens())
         budget_bytes = int(budget_mb if budget_mb is not None
                            else gen_kv_mb()) << 20
         dt = jnp.dtype(dtype)
-        # K + V, every layer, one block of token positions
+        # K + V, every layer that pages, one block of token positions
         self.block_bytes = (2 * layers * self.block_tokens * kv_heads
                             * head_dim * dt.itemsize)
-        self.num_blocks = max(1, budget_bytes // self.block_bytes)
+        #: one sequence's fixed state, all its arrays
+        self.slot_bytes = sum(
+            math.prod(shape) * jnp.dtype(sdt).itemsize
+            for _name, shape, sdt in spec.state)
+        self.num_slots = max(1, int(slots)) if spec.state else 0
+        self.num_blocks = max(1, (
+            budget_bytes - self.num_slots * self.slot_bytes)
+            // self.block_bytes)
         #: one block past the leasable ones, where a row that must write
-        #: nothing writes (see :func:`put_positions`)
+        #: nothing writes (see :func:`put_positions`), and one such slot
         self.scratch_block = self.num_blocks
+        self.scratch_slot = self.num_slots
         shape = (layers, self.num_blocks + 1, kv_heads, self.block_tokens,
                  head_dim)
         if mesh is None:
@@ -129,18 +182,30 @@ class KVBlockPool:
                 mesh, P(None, None, heads, None, None))
             #: where the engine puts a program's small per-call inputs
             self.replicated = NamedSharding(mesh, P())
+        #: names of the slot's arrays, in the order :attr:`arrays` holds
+        #: them after ``k`` and ``v``
+        self.state_names = tuple(name for name, _s, _d in spec.state)
+        made = [(shape, dt), (shape, dt)] + [
+            ((s[0], self.num_slots + 1, *s[1:]), jnp.dtype(sdt))
+            for _name, s, sdt in spec.state]
+        #: what every program returns the arrays with, in their order
+        self.shardings = (self.sharding, self.sharding) \
+            + (self.replicated,) * len(spec.state)
         # a program's output, like every later pool: see the module
         # docstring ("one signature for life")
         self._fresh = jax.jit(
-            lambda: (jnp.zeros(shape, dt), jnp.zeros(shape, dt)),
-            out_shardings=(self.sharding, self.sharding))
-        self.k, self.v = self._fresh()
+            lambda: tuple(jnp.zeros(s, d) for s, d in made),
+            out_shardings=self.shardings)
+        #: ``(k, v, *state)``: engine thread only
+        self.arrays = self._fresh()
         self.budget = TierBudget("gen-kv", budget_bytes)
         self._free_list = list(range(self.num_blocks - 1, -1, -1))
+        self._free_slots = list(range(self.num_slots - 1, -1, -1))
         self._lock = threading.Lock()
-        log.info("kv pool: %d blocks x %d tokens (%d KiB/block, %d MiB) "
-                 "on %s", self.num_blocks, self.block_tokens,
-                 self.block_bytes >> 10, budget_bytes >> 20, self.sharding)
+        log.info("kv pool: %d blocks x %d tokens (%d KiB/block), %d slots "
+                 "(%d KiB/slot), %d MiB on %s", self.num_blocks,
+                 self.block_tokens, self.block_bytes >> 10, self.num_slots,
+                 self.slot_bytes >> 10, budget_bytes >> 20, self.sharding)
 
     # ------------------------------------------------------------ sizing
     def blocks_for(self, tokens: int) -> int:
@@ -157,62 +222,104 @@ class KVBlockPool:
         with self._lock:
             return self.num_blocks - len(self._free_list)
 
+    @property
+    def in_use_slots(self) -> int:
+        with self._lock:
+            return self.num_slots - len(self._free_slots)
+
     # ------------------------------------------------------- alloc/free
     def alloc(self, n: int) -> BlockLease:
-        """Lease ``n`` blocks or raise :class:`PoolExhausted` — never a
-        partial grant, so admission is all-or-nothing (no overcommit:
-        the caller reserves its worst case up front)."""
+        """Lease ``n`` blocks, and a slot where the pool has them, or raise
+        :class:`PoolExhausted` — never a partial grant, so admission is
+        all-or-nothing (no overcommit: the caller reserves its worst case
+        up front)."""
         with self._lock:
             if n > len(self._free_list):
                 raise PoolExhausted(
                     f"need {n} blocks, {len(self._free_list)} free "
                     f"of {self.num_blocks}")
+            if self.num_slots and not self._free_slots:
+                raise PoolExhausted(
+                    f"all {self.num_slots} state slots are held")
             blocks = [self._free_list.pop() for _ in range(n)]
+            slot = self._free_slots.pop() if self.num_slots else None
             in_use = self.num_blocks - len(self._free_list)
-        self.budget.charge(n * self.block_bytes)
+            slots_in_use = self.num_slots - len(self._free_slots)
         HUB.inc("gen_kv_blocks_alloc_total", n)
         HUB.set_gauge("gen_kv_blocks_in_use", in_use)
-        return BlockLease(self, blocks)
+        charge = n * self.block_bytes
+        if slot is not None:
+            charge += self.slot_bytes
+            HUB.inc("gen_state_slots_alloc_total")
+            HUB.set_gauge("gen_state_slots_in_use", slots_in_use)
+        self.budget.charge(charge)
+        return BlockLease(self, blocks, slot)
 
-    def _reclaim(self, blocks: list[int]) -> None:
+    def _reclaim(self, blocks: list[int], slot: int | None) -> None:
         with self._lock:
             self._free_list.extend(blocks)
+            if slot is not None:
+                self._free_slots.append(slot)
             in_use = self.num_blocks - len(self._free_list)
-        self.budget.release(len(blocks) * self.block_bytes)
+            slots_in_use = self.num_slots - len(self._free_slots)
         HUB.inc("gen_kv_blocks_freed_total", len(blocks))
         HUB.set_gauge("gen_kv_blocks_in_use", in_use)
+        release = len(blocks) * self.block_bytes
+        if slot is not None:
+            release += self.slot_bytes
+            HUB.inc("gen_state_slots_freed_total")
+            HUB.set_gauge("gen_state_slots_in_use", slots_in_use)
+        self.budget.release(release)
 
     # ------------------------------------------------------- the arrays
+    @property
+    def k(self):
+        return self.arrays[0]
+
+    @property
+    def v(self):
+        return self.arrays[1]
+
+    @property
+    def state(self) -> dict:
+        """The slot's arrays by name, ``[layers, slots + 1, ...]`` each."""
+        return dict(zip(self.state_names, self.arrays[2:]))
+
     def apply(self, program, *args):
         """Run one of the engine's programs over the arrays:
-        ``program(*args, k, v) -> (out, k, v)`` with ``k`` and ``v``
-        donated and returned with :attr:`sharding`. The program's outputs
-        become the pool, so nothing keeps a reference to the arrays that
-        went in. Engine thread only."""
-        out, self.k, self.v = program(*args, self.k, self.v)
+        ``program(*args, k, v, *state) -> (out, k, v, *state)`` with every
+        array donated and returned with :attr:`shardings`. The program's
+        outputs become the pool, so nothing keeps a reference to the arrays
+        that went in. Engine thread only."""
+        out, *arrays = program(*args, *self.arrays)
+        self.arrays = tuple(arrays)
         return out
 
     @property
     def lost(self) -> bool:
         """A program took the arrays and gave none back."""
-        return self.k.is_deleted() or self.v.is_deleted()
+        return any(a.is_deleted() for a in self.arrays)
 
     def reset(self) -> None:
         """Fresh, zeroed arrays after a program failed with the old ones
         in hand. The ledger is the caller's to settle: every lease's
         contents are gone."""
-        self.k, self.v = self._fresh()
+        self.arrays = self._fresh()
 
     # ------------------------------------------------------------ intro
     def describe(self) -> dict[str, Any]:
         with self._lock:
             free = len(self._free_list)
+            free_slots = len(self._free_slots)
         return {
             "block_tokens": self.block_tokens,
             "block_bytes": self.block_bytes,
             "num_blocks": self.num_blocks,
             "free_blocks": free,
             "in_use_blocks": self.num_blocks - free,
+            "slot_bytes": self.slot_bytes,
+            "num_slots": self.num_slots,
+            "in_use_slots": self.num_slots - free_slots,
             "budget": self.budget.describe(),
         }
 
@@ -221,19 +328,24 @@ class KVBlockPool:
 # jit-traceable: the engine's prefill and decode programs call these on
 # the donated arrays. Indices are int32 arrays built on the host from the
 # leases. A row that must write nothing (a pad row of the batch bucket)
-# is given ``pool.scratch_block``, the one block no lease can hold.
+# is given ``pool.scratch_block`` and ``pool.scratch_slot``, the one block
+# and the one slot no lease can hold.
 
 
 class Paged(NamedTuple):
-    """What a model's ``step_decode`` gets for its cache: the pool's arrays
-    and the batch's block table. A layer reads the slots it needs (all of
-    a row's, or the few that cover a window) with :meth:`read`. A row's
-    slots past its lease, and a pad row's, may name any block: the model
-    masks positions at or past a row's length."""
+    """What a model's ``step_decode`` gets for its cache: the pool's arrays,
+    the batch's block table and, where the pool has slots, each row's. A
+    layer reads the table slots it needs (all of a row's, or the few that
+    cover a window) with :meth:`read`, a layer with state its rows' slots
+    with :meth:`read_state`. A row's table slots past its lease, and a pad
+    row's, may name any block: the model masks positions at or past a
+    row's length."""
 
     k: jax.Array
     v: jax.Array
     table: jax.Array        # [B, n] block ids
+    state: dict = {}        # name -> [layers, slots + 1, ...]
+    slots: Any = None       # [B] slot ids
 
     @property
     def block_tokens(self) -> int:
@@ -254,6 +366,29 @@ class Paged(NamedTuple):
                             mode="clip")
 
         return blocks(self.k), blocks(self.v)
+
+    def read_state(self, name: str, layer: int):
+        """The rows' slots of one layer of the state array ``name``,
+        [B, ...]: one gather, layers and slots as one axis."""
+        a = self.state[name]
+        L, ns = a.shape[:2]
+        return jnp.take(a.reshape(L * ns, *a.shape[2:]),
+                        self.slots + layer * ns, axis=0, mode="clip")
+
+
+class Written(NamedTuple):
+    """What a step of a model with fixed state hands back for the cache: the
+    new keys and values of its paging layers, as every model's step does,
+    and ``state``, name → what each row's slot holds from now on, one
+    array a layer that keeps it ([B, ...] each, in the layers' order)."""
+
+    kv: list
+    state: dict
+
+
+def parts(new):
+    """``(kv, state)`` of what a step hands back, whichever it is."""
+    return new if isinstance(new, Written) else (new, {})
 
 
 def _stack(kv):
@@ -298,3 +433,21 @@ def put_positions(k, v, new_kv, blocks, offsets):
 
     nk, nv = _stack(new_kv)
     return put(k, nk), put(v, nv)
+
+
+def put_slots(arrays, names, state, slots):
+    """What a step leaves in its rows' slots: ``arrays`` the pool's state
+    arrays in the order of ``names``; ``state[name]`` one [B, ...] array a
+    layer; row ``b`` of each lands in slot ``slots[b]`` of its layer (a
+    prefill is the step of one row, and writes the whole of its slot). One
+    in-place slice update a layer a row, from the layer's own result: no
+    copy of the rows, stacked over the layers, stands between."""
+    out = []
+    for a, name in zip(arrays, names):
+        for li, new in enumerate(state[name]):
+            for b in range(slots.shape[0]):
+                a = lax.dynamic_update_slice(
+                    a, new[b][None, None].astype(a.dtype),
+                    (li, slots[b]) + (0,) * (a.ndim - 2))
+        out.append(a)
+    return tuple(out)

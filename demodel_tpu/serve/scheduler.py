@@ -273,15 +273,18 @@ class GenEngine:
 
         from demodel_tpu.utils import compile_cache
 
-        # the loaded config names its model module, whose two step
-        # functions the programs below run: ``step_prefill(params, tokens,
-        # cfg, mesh=) -> (last_logits, kv, *stats)`` and ``step_decode(
-        # params, tokens, cfg, cache, lengths, mesh=) -> (logits, new_kv,
-        # *stats)``, ``cache`` a ``kvcache.Paged`` (the pool's arrays and
-        # the batch's block table) for every module. ``stats`` (small
-        # arrays, or none) come back with the chosen ids and go to the
-        # module's ``observe``, which counts them and names the step
-        # span's attributes.
+        # the loaded config names its model module, which states its cache
+        # (``cache_spec(cfg)``, a ``kvcache.CacheSpec``: the pool is built
+        # from it) and whose two step functions the programs below run:
+        # ``step_prefill(params, tokens, cfg, mesh=) -> (last_logits, new,
+        # *stats)`` and ``step_decode(params, tokens, cfg, cache, lengths,
+        # mesh=) -> (logits, new, *stats)``, ``cache`` a ``kvcache.Paged``
+        # (the pool's arrays, the batch's block table and slots) for every
+        # module, ``new`` the layers' new keys and values, inside a
+        # ``kvcache.Written`` with what goes into the slots where the
+        # module keeps such state. ``stats`` (small arrays, or none) come
+        # back with the chosen ids and go to the module's ``observe``, which
+        # counts them and names the step span's attributes.
         module = sys.modules[type(cfg).__module__]
         if not hasattr(module, "step_decode"):
             raise ValueError(
@@ -303,11 +306,14 @@ class GenEngine:
         self.cfg = cfg
         self.mesh = mesh
         self.model = model
+        self.max_batch = int(max_batch or gen_max_batch())
+        # a slot a running sequence: one freed by a row still in flight is
+        # written by that row before the prefill that takes it over (the
+        # device runs them in the order they were queued), as its blocks are
         self.pool = pool if pool is not None else KVBlockPool(
-            cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim,
+            module.cache_spec(cfg), slots=self.max_batch,
             block_tokens=block_tokens, budget_mb=kv_mb, dtype=cfg.dtype,
             mesh=mesh)
-        self.max_batch = int(max_batch or gen_max_batch())
         self.max_new_cap = int(max_new_tokens or gen_max_new_tokens())
         self.admission = AdmissionQueue(
             queue_limit if queue_limit is not None else gen_queue_limit(),
@@ -323,22 +329,39 @@ class GenEngine:
             ids = jnp.argmax(logits.astype(jnp.float32), axis=-1)
             return jnp.pad(ids.astype(jnp.int32), (0, n - ids.shape[0]))
 
-        def prefill(p, tokens, blocks, k, v):
-            logits, kv, *stats = module.step_prefill(p, tokens, cfg,
-                                                    mesh=mesh)
-            return ((choose(logits, 1), (logits, *stats)),
-                    *kvcache.put_blocks(k, v, kv, blocks))
+        pool = self.pool
+        names = pool.state_names
+        #: a row's (a prompt's) slot rides with its block ids, one more
+        #: int32, where the pool has slots; nothing is shipped where not
+        self._slotted = int(bool(names))
 
-        def decode(p, rows, prev_ids, k, v):
+        def prefill(p, tokens, blocks, k, v, *state):
+            logits, new, *stats = module.step_prefill(p, tokens, cfg,
+                                                     mesh=mesh)
+            kv, fresh = kvcache.parts(new)
+            if names:       # the lease's slot comes behind its block ids
+                blocks, slot = blocks[:-1], blocks[-1:]
+                state = kvcache.put_slots(state, names, fresh, slot)
+            return ((choose(logits, 1), (logits, *stats)),
+                    *kvcache.put_blocks(k, v, kv, blocks), *state)
+
+        def decode(p, rows, prev_ids, k, v, *state):
             # one int32 row a sequence (see _decode_inputs)
             lit, lens, wblocks, woffsets, src = (rows[:, i]
                                                  for i in range(5))
             toks = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], lit)
-            cache = kvcache.Paged(k, v, rows[:, 5:])
-            logits, new_kv, *stats = module.step_decode(p, toks, cfg, cache,
-                                                       lens, mesh=mesh)
+            if names:
+                slots, table = rows[:, 5], rows[:, 6:]
+                cache = kvcache.Paged(k, v, table, dict(zip(names, state)),
+                                      slots)
+            else:
+                cache = kvcache.Paged(k, v, rows[:, 5:])
+            logits, new, *stats = module.step_decode(p, toks, cfg, cache,
+                                                    lens, mesh=mesh)
+            new_kv, fresh = kvcache.parts(new)
             return ((choose(logits, n_ids), (logits, *stats)),
-                    *kvcache.put_positions(k, v, new_kv, wblocks, woffsets))
+                    *kvcache.put_positions(k, v, new_kv, wblocks, woffsets),
+                    *kvcache.put_slots(state, names, fresh, cache.slots))
 
         # the pool goes in donated and comes back as it was born
         # (kvcache: "one signature for life"), the ids come back
@@ -346,11 +369,11 @@ class GenEngine:
         # follow the prompt length, or (batch bucket, width), and nothing
         # else. The logits stay on the device: nothing the engine does
         # pulls them (chip_smoke and the tests of a model module do)
-        pool = self.pool
-        back = ((pool.replicated, None), pool.sharding, pool.sharding)
-        self._jprefill = jax.jit(prefill, donate_argnums=(3, 4),
+        back = ((pool.replicated, None), *pool.shardings)
+        donated = tuple(range(3, 3 + len(pool.arrays)))
+        self._jprefill = jax.jit(prefill, donate_argnums=donated,
                                  out_shardings=back)
-        self._jdecode = jax.jit(decode, donate_argnums=(3, 4),
+        self._jdecode = jax.jit(decode, donate_argnums=donated,
                                 out_shardings=back)
         # what the first step takes for the previous step's ids: born as a
         # program's output with the sharding every later one has, so the
@@ -614,8 +637,8 @@ class GenEngine:
 
         pool = self.pool
         tokens = np.asarray([prompt], np.int32)
-        blocks = np.asarray(lease.blocks[:pool.blocks_for(len(prompt))],
-                            np.int32)
+        blocks = np.asarray(lease.blocks[:pool.blocks_for(len(prompt))]
+                            + [lease.slot] * self._slotted, np.int32)
         sent = jax.device_put((tokens, blocks), pool.replicated)
         HUB.inc("gen_h2d_bytes_total", tokens.nbytes + blocks.nbytes)
         return pool.apply(self._jprefill, self.params, *sent)
@@ -626,24 +649,29 @@ class GenEngine:
         batch bucket — token id, length, the block and the offset its new
         position is written at, the row of the previous step's ids its
         token is taken from on the device (-1: the id in column 0 is fed),
-        then its slots of the block table — so its shape follows (bucket,
-        width) alone and it crosses the link in one transfer."""
+        its state slot where the pool has slots, then its slots of the
+        block table — so its shape follows (bucket, width) alone and it
+        crosses the link in one transfer."""
         import numpy as np
 
         pool = self.pool
         bs = pool.block_tokens
         nb = _pow2(-(-max(s.length for s in batch) // bs))
+        at = 5 + self._slotted      # where the block table starts
         # a slot a sequence does not have reads block 0 (masked by its
         # length); a pad row rides along with length 0, is dropped on the
-        # host, and writes into the block no lease can hold
-        rows = np.zeros((_pow2(len(batch)), 5 + nb), np.int32)
+        # host, and writes into the block and the slot no lease can hold
+        rows = np.zeros((_pow2(len(batch)), at + nb), np.int32)
         rows[:, 2] = pool.scratch_block
         rows[:, 4] = -1
+        rows[:, 5:at] = pool.scratch_slot
         for row, s in zip(rows, batch):
             got = s.lease.blocks[:nb]
             row[:5] = (s.last_tok, s.length, s.lease.blocks[s.length // bs],
                        s.length % bs, s.row)
-            row[5:5 + len(got)] = got
+            if self._slotted:
+                row[5] = s.lease.slot
+            row[at:at + len(got)] = got
         return bs * nb, rows
 
     def _begin(self, req: Request, lease) -> _Seq | None:
@@ -724,6 +752,8 @@ class GenEngine:
                         return
                     new_shape, ids, stats = seq.first
                     dev.set_attr("new_shape", new_shape)
+                    if self._slotted:   # the whole slot, written
+                        dev.set_attr("state_bytes", self.pool.slot_bytes)
                     pulled = ids.nbytes + sum(a.nbytes for a in stats)
                     if trace.enabled():
                         # export tier only, like the compute spans: off
@@ -854,6 +884,9 @@ class GenEngine:
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead)):
                     cycle.set_attr(key, value)
+                if self._slotted:   # each row's slot, read and written
+                    cycle.set_attr("state_bytes",
+                                   2 * B * self.pool.slot_bytes)
                 with trace.span("serve.decode-device", batch=B,
                                 width=flight.width,
                                 new_shape=any(t.new_shape for t in todo)):

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import exaone_moe, hf_loader
+from demodel_tpu.models import exaone_moe, experts, hf_loader
 from demodel_tpu.serve import GenEngine
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB, labeled
@@ -196,7 +196,7 @@ def test_shares_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.key(4), (40, whole.hidden_size))
     live = jnp.ones((40,), bool)
     full, tokens = exaone_moe._moe(layer, x, live, whole, None)
-    shared = exaone_moe._swiglu(x, layer["shared_gate_proj"],
+    shared = experts.swiglu(x, layer["shared_gate_proj"],
                                 layer["shared_up_proj"],
                                 layer["shared_down_proj"])
     total, landed = shared, 0

@@ -20,6 +20,7 @@ from demodel_tpu import serve
 from demodel_tpu.models import llama
 from demodel_tpu.serve import (BlockLease, GenEngine, KVBlockPool,
                                PoolExhausted, QueueOverflow, kvcache)
+from demodel_tpu.serve.kvcache import CacheSpec
 from demodel_tpu.utils.metrics import HUB, labeled
 
 
@@ -33,11 +34,12 @@ def tiny_model():
 def _tiny(family):
     """``(module, params, cfg)`` of a model module the engine serves, at
     its test size."""
-    from demodel_tpu.models import exaone_moe
+    from demodel_tpu.models import exaone_moe, qwen3_next
 
     module, cfg = {
         "llama": (llama, llama.LlamaConfig.tiny()),
         "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig.tiny()),
+        "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny()),
     }[family]
     return module, module.init_params(jax.random.key(2), cfg), cfg
 
@@ -45,8 +47,8 @@ def _tiny(family):
 def _pool(cfg, **kw):
     kw.setdefault("block_tokens", 16)
     kw.setdefault("budget_mb", 1)
-    return KVBlockPool(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                       cfg.head_dim, **kw)
+    return KVBlockPool(CacheSpec(cfg.num_hidden_layers,
+                                 cfg.num_key_value_heads, cfg.head_dim), **kw)
 
 
 def _prompt(cfg, n, seed=0):
@@ -125,14 +127,14 @@ class TestKVBlockPool:
         put = jax.jit(kvcache.put_blocks, donate_argnums=(0, 1))
         for lease, new, t in ((lease_a, ka, t_a), (lease_b, kb, t_b)):
             ids = np.asarray(lease.blocks[:pool.blocks_for(t)], np.int32)
-            pool.k, pool.v = put(pool.k, pool.v,
-                                 [(new[li], new[li] + 1) for li in range(L)],
-                                 ids)
+            pool.arrays = put(pool.k, pool.v,
+                              [(new[li], new[li] + 1) for li in range(L)],
+                              ids)
         # one position appended to a; row 1 is a pad row: scratch block
         tok = rng.normal(size=(L, 2, 1, Hkv, hd)).astype(np.float32)
         before = np.asarray(pool.k)
-        pool.k, pool.v = jax.jit(kvcache.put_positions,
-                                 donate_argnums=(0, 1))(
+        pool.arrays = jax.jit(kvcache.put_positions,
+                              donate_argnums=(0, 1))(
             pool.k, pool.v, [(tok[li], tok[li] - 1) for li in range(L)],
             np.asarray([lease_a.blocks[t_a // 4], pool.scratch_block],
                        np.int32),
@@ -485,8 +487,8 @@ class TestDevicePool:
         assert bystander.blocks == [0, 1]
         fill = jnp.full((cfg.num_hidden_layers, 1, 8,
                          cfg.num_key_value_heads, cfg.head_dim), 7.0)
-        pool.k, pool.v = jax.jit(kvcache.put_blocks,
-                                 donate_argnums=(0, 1))(
+        pool.arrays = jax.jit(kvcache.put_blocks,
+                              donate_argnums=(0, 1))(
             pool.k, pool.v, [(a, a) for a in fill],
             np.asarray(bystander.blocks, np.int32))
         try:
@@ -633,6 +635,8 @@ class TestDevicePool:
 
         family = types.ModuleType("stub_family")
         family.step_prefill, family.step_decode = step_prefill, step_decode
+        family.cache_spec = lambda cfg: CacheSpec(
+            cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim)
         monkeypatch.setitem(sys.modules, "stub_family", family)
         StubConfig.__module__ = "stub_family"
         engine = GenEngine({"embed": jnp.zeros((V, 2))}, StubConfig(),
@@ -652,7 +656,7 @@ class TestDevicePool:
             assert out == want
         pool = engine.pool
         assert pool.in_use_blocks == 0
-        assert kvcache.Paged._fields == ("k", "v", "table")
+        assert kvcache.Paged._fields == ("k", "v", "table", "state", "slots")
         assert handed and all(
             kind is kvcache.Paged and shape == pool.k.shape
             and table[0] in (1, 2, 4)
@@ -689,7 +693,8 @@ class TestDevicePool:
             assert pool.in_use_blocks == 0
         assert outs[0] == outs[1]
         # KV heads that do not divide tp: replicated, as _head_align says
-        odd = KVBlockPool(2, 3, 8, block_tokens=4, budget_mb=1, mesh=mesh)
+        odd = KVBlockPool(CacheSpec(2, 3, 8), block_tokens=4, budget_mb=1,
+                          mesh=mesh)
         assert odd.k.sharding.is_fully_replicated
 
     @pytest.mark.parametrize("stage", ["prefill", "decode", "decode-ahead",
@@ -832,7 +837,7 @@ class TestOneSignatureForLife:
     Run for both model modules the engine serves: the pool, its donation
     and the programs' signatures are the engine's, whatever the steps."""
 
-    @pytest.fixture(params=["llama", "exaone_moe"])
+    @pytest.fixture(params=["llama", "exaone_moe", "qwen3_next"])
     def placed(self, request):
         from demodel_tpu.parallel.mesh import make_mesh
 
@@ -1035,7 +1040,7 @@ class TestOneSignatureForLife:
 # ------------------------------------------ an admission rides the pipe
 
 
-@pytest.fixture(scope="module", params=["llama", "exaone_moe"])
+@pytest.fixture(scope="module", params=["llama", "exaone_moe", "qwen3_next"])
 def family(request):
     return _tiny(request.param)[1:]
 
