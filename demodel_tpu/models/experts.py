@@ -25,10 +25,36 @@ from demodel_tpu.utils.metrics import HUB, labeled
 HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
 HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
 HUB.inc("gen_moe_experts_hit_total", 0)
+HUB.inc("gen_moe_rows_computed_total", 0)
+
+#: assignment rows one pass of the grouped products holds: a layer with no
+#: more than this computes them all at once, a longer prompt the landed
+#: ones in slabs of this many
+SLAB = 4096
 
 
 def ep_size(mesh: Mesh | None) -> int:
     return int(mesh.shape.get("ep", 1)) if mesh is not None else 1
+
+
+def _slab(x, held, weights, idx, sizes, gate_up, down, valid=None):
+    """Assignments ``idx`` (positions in the flattened ``[N * K]``, sorted
+    by expert, ``sizes`` [E] of them to each held expert) through their
+    experts and weighted: ``[len(idx), D]`` float32. Rows past the groups'
+    end and rows not ``valid`` come out zero."""
+    K, F = held.shape[1], down.shape[1]
+    rows = x[idx // K]
+    with jax.named_scope("moe.experts"):
+        h = lax.ragged_dot(rows, gate_up, sizes)
+        h = jax.nn.silu(h[:, :F]) * h[:, F:]
+        y = lax.ragged_dot(h, down, sizes,
+                           preferred_element_type=jnp.float32)
+    # rows past the groups' end belong to no held expert: whatever the
+    # grouped product left there is dropped, not scaled
+    w = jnp.where(held, weights, 0.0).reshape(-1)[idx]
+    if valid is not None:
+        w = jnp.where(valid, w, 0.0)
+    return jnp.where(w[:, None] != 0, y * w[:, None], 0.0)
 
 
 def held_part(x, live, chosen, weights, gate_up, down, first):
@@ -38,28 +64,46 @@ def held_part(x, live, chosen, weights, gate_up, down, first):
     are ``first .. first + E - 1``; rows not ``live`` (the pad rows of a
     batch bucket) choose nothing. Returns ``(y [N, D] float32, tokens
     [E])``. Every assignment that falls on a held expert is computed: the
-    rows are sorted by expert and each projection is one grouped product
-    over the groups' actual sizes."""
+    assignments are sorted by expert, which puts the ``tokens.sum()`` that
+    landed first, and each projection is one grouped product over the
+    groups' actual sizes.
+
+    Up to :data:`SLAB` assignments (a decode step, a short prompt) that is
+    one pass over all ``N * K`` rows and an unsort. A longer prompt works
+    on the landed prefix alone, a slab of ``SLAB`` rows at a time under a
+    loop whose trip count is the data's (``ceil(landed / SLAB)``: all
+    ``N * K`` rows when every assignment lands, none when none does), and
+    each slab's rows are added to their tokens' in float32."""
     N, K = chosen.shape
-    E, F = down.shape[0], down.shape[1]
+    E = down.shape[0]
     local = chosen - first
     held = (local >= 0) & (local < E) & live[:, None]
     group = jnp.where(held, local, E).reshape(N * K)    # E: not computed
     order = jnp.argsort(group, stable=True)
     tokens = (group[:, None] == jnp.arange(E)[None, :]).sum(
         axis=0, dtype=jnp.int32)
-    rows = x[order // K]                                # [N * K, D]
-    with jax.named_scope("moe.experts"):
-        h = lax.ragged_dot(rows, gate_up, tokens)
-        h = jax.nn.silu(h[:, :F]) * h[:, F:]
-        y = lax.ragged_dot(h, down, tokens,
-                           preferred_element_type=jnp.float32)
-    # rows past the groups' end belong to no held expert: whatever the
-    # grouped product left there is dropped, not scaled
-    w = jnp.where(held, weights, 0.0).reshape(N * K)[order]
-    y = jnp.where(w[:, None] != 0, y * w[:, None], 0.0)
-    back = jnp.argsort(order)                           # the unsort
-    return y[back].reshape(N, K, -1).sum(axis=1), tokens
+    if N * K <= SLAB:
+        y = _slab(x, held, weights, order, tokens, gate_up, down)
+        back = jnp.argsort(order)                       # the unsort
+        return y[back].reshape(N, K, -1).sum(axis=1), tokens
+    ends = jnp.cumsum(tokens)
+    landed = ends[-1]
+    order = jnp.pad(order, (0, -(N * K) % SLAB))
+
+    def slab(carry):
+        i, y = carry
+        lo = i * SLAB
+        idx = lax.dynamic_slice(order, (lo,), (SLAB,))
+        sizes = (jnp.clip(ends, lo, lo + SLAB)
+                 - jnp.clip(ends - tokens, lo, lo + SLAB))
+        part = _slab(x, held, weights, idx, sizes, gate_up, down,
+                     lo + jnp.arange(SLAB) < landed)
+        return i + 1, y.at[idx // K].add(part)
+
+    _, y = lax.while_loop(
+        lambda carry: carry[0] * SLAB < landed, slab,
+        (jnp.int32(0), jnp.zeros((N, x.shape[1]), jnp.float32)))
+    return y, tokens
 
 
 def routed(x, live, chosen, weights, gate_up, down, first: int,
@@ -102,11 +146,19 @@ def swiglu(x, gate, up, down):
 def observe(expert_tokens, assignments: int) -> dict:
     """A step's ``expert_tokens`` ([expert layers, held experts], on the
     host) and the assignments its tokens made in all → the step span's
-    attributes; the counters are counted here."""
+    attributes; the counters are counted here. ``expert_rows`` is what
+    :func:`held_part`'s grouped products ran over, layer by layer, as one
+    chip runs it (the pad rows of a batch bucket not counted): over
+    ``expert_tokens`` it says how much of the work landed."""
     landed = int(expert_tokens.sum())
     hit = int((expert_tokens > 0).sum())
+    rows = assignments                  # up to a slab a layer: all of them
+    if assignments > SLAB * len(expert_tokens):
+        rows = int((-(-expert_tokens.sum(axis=1) // SLAB)).sum()) * SLAB
     HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
     HUB.inc(labeled("gen_moe_assignments_total", held="false"),
             assignments - landed)
     HUB.inc("gen_moe_experts_hit_total", hit)
-    return {"expert_tokens": landed, "experts_hit": hit}
+    HUB.inc("gen_moe_rows_computed_total", rows)
+    return {"expert_tokens": landed, "experts_hit": hit,
+            "expert_rows": rows}

@@ -292,6 +292,10 @@ def test_assignments_are_counted(small):
     rose = [after[n] - before.get(n, 0) for n in names]
     assert sum(rose) == cfg.num_experts_per_tok * tokens * cfg.sparse_layers
     assert 0 < rose[0] < rose[1]        # a quarter of the experts is here
+    # up to a slab of assignments a layer the grouped products run over
+    # every one of them, landed or not
+    assert after["gen_moe_rows_computed_total"] \
+        - before.get("gen_moe_rows_computed_total", 0) == sum(rose) >= rose[0]
     assert after["gen_moe_experts_hit_total"] \
         > before.get("gen_moe_experts_hit_total", 0)
 

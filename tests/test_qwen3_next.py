@@ -22,7 +22,7 @@ import pytest
 from demodel_tpu.models import experts, hf_loader, qwen3_next
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
-from demodel_tpu.utils.metrics import HUB
+from demodel_tpu.utils.metrics import HUB, labeled
 from tests.test_exaone_moe import _engine_logits
 
 BENCH = Path(__file__).resolve().parent.parent / "benchmark"
@@ -159,12 +159,16 @@ def test_chunked_scan_is_the_recurrence(T, chunk, carried):
 # ------------------------------------------------- against the reference
 
 
-def test_float32_program_is_the_reference(small):
+@pytest.mark.parametrize("slab", [experts.SLAB, 128])
+def test_float32_program_is_the_reference(small, slab, monkeypatch):
     """The same weights computed in float32 by the program: the chunked
     scan over 150, 70 and 9 positions, then decode through pages and
     slots with a pad row riding along, share 1 of 4 of the experts. No
     rounding to hide behind: 1e-4 on logits of order 1 (float32 sums in
-    another order)."""
+    another order). With a slab of 128 assignments the prompts' 600 and
+    280 a layer go through the experts' loop over the landed slabs, the
+    9-token prompt and the steps not."""
+    monkeypatch.setattr(experts, "SLAB", slab)
     ckpt, params, cfg = small
     got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg))
     for (_fed, lg), r in zip(got, ref):
@@ -414,11 +418,13 @@ def test_slots_and_blocks_all_come_back(small, ending, monkeypatch):
 
 def test_spans_name_the_state_and_the_experts(small):
     """``state_bytes`` on the step's and the prefill's device span (a row's
-    slot read and written; a prompt's written), ``expert_tokens`` and
-    ``experts_hit`` through the module's ``observe``."""
+    slot read and written; a prompt's written), ``expert_tokens``,
+    ``experts_hit`` and ``expert_rows`` through the module's ``observe``."""
     from demodel_tpu.utils import trace
 
     _ckpt, params, cfg = small
+    held = labeled("gen_moe_assignments_total", held="true")
+    before = HUB.snapshot()
     trace.reset()
     trace.enable()
     try:
@@ -436,6 +442,17 @@ def test_spans_name_the_state_and_the_experts(small):
                          and a["experts_hit"] > 0 for a in steps)
     dev = [s["attrs"] for s in spans if s["name"] == "serve.prefill-device"]
     assert dev and dev[0]["state_bytes"] == slot and dev[0]["experts_hit"] > 0
+    # under a slab of assignments a layer, the grouped products run over
+    # all of them: K a token a layer
+    each = cfg.num_experts_per_tok * cfg.num_hidden_layers
+    assert dev[0]["expert_rows"] == 20 * each > dev[0]["expert_tokens"] > 0
+    assert all(a["expert_rows"] == a["batch"] * each >= a["expert_tokens"]
+               for a in steps)
+    after = HUB.snapshot()
+    rows = after["gen_moe_rows_computed_total"] \
+        - before.get("gen_moe_rows_computed_total", 0)
+    assert rows == dev[0]["expert_rows"] + sum(
+        a["expert_rows"] for a in steps) >= after[held] - before.get(held, 0)
 
 
 def test_served_over_http_like_the_others(small, tmp_path):
