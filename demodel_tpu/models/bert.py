@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models.common import layer_norm, use_flash_attention as _use_flash
+from demodel_tpu.models.common import (
+    layer_norm, refuse_unsupported, use_flash_attention as _use_flash)
 
 
 @dataclass(frozen=True)
@@ -38,6 +39,7 @@ class BertConfig:
 
     @classmethod
     def from_hf(cls, config: dict) -> "BertConfig":
+        refuse_unsupported(config)
         return cls(
             vocab_size=config.get("vocab_size", 30522),
             hidden_size=config.get("hidden_size", 768),

@@ -15,6 +15,18 @@ from jax import lax
 from demodel_tpu.utils.env import env_bool
 
 
+def refuse_unsupported(config: dict, fields=(
+        "rope_scaling", "sliding_window", "attention_bias")) -> None:
+    """For a family's ``from_hf``: refuse a ``config.json`` whose ``fields``
+    are set (non-null, non-false), which change numerics in ways that
+    family does not implement — rather than drift."""
+    for fld in fields:
+        v = config.get(fld)
+        if v not in (None, False):
+            raise ValueError(
+                f"config field {fld}={v!r} is not supported by this stack")
+
+
 def rms_norm(x, weight, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     scale = lax.rsqrt((xf * xf).mean(axis=-1, keepdims=True) + eps)
@@ -29,7 +41,8 @@ def layer_norm(x, weight, bias, eps: float = 1e-5):
     return y.astype(x.dtype) * weight + bias
 
 
-def attend(q, k, v, positions, *, window: int = 0, past=None):
+def attend(q, k, v, positions, *, window: int = 0, past=None,
+           scale: float | None = None):
     """Causal attention of new queries over their own keys and, when
     ``past`` is given, over a paged cache read where it lies: q [B, T, H,
     hd], k and v [B, T, Hkv, hd] at ``positions`` [B, T] → [B, T, H * hd].
@@ -40,9 +53,11 @@ def attend(q, k, v, positions, *, window: int = 0, past=None):
     block_tokens, hd] (``kvcache.Paged.read``), the positions [B, m *
     block_tokens] of their slots and which of those hold the row's own
     (a row with none live, a pad row, sees only its new key). One softmax
-    in float32 over cached and new keys, probabilities in q's dtype."""
+    in float32 over cached and new keys, probabilities in q's dtype. The
+    scores are scaled by ``scale``, ``hd ** -0.5`` where none is given."""
     B, T, H, hd = q.shape
     Hkv = k.shape[2]
+    scale = hd ** -0.5 if scale is None else scale
     q = q.reshape(B, T, Hkv, H // Hkv, hd)
 
     def masked(s, kpos, live=True):
@@ -51,7 +66,7 @@ def attend(q, k, v, positions, *, window: int = 0, past=None):
         behind = positions[:, :, None] - kpos[:, None, :]
         keep = (behind >= 0) & (behind < window if window else True) & live
         return jnp.where(keep[:, None, None],
-                         (s * hd ** -0.5).astype(jnp.float32), -1e30)
+                         (s * scale).astype(jnp.float32), -1e30)
 
     s_new = masked(jnp.einsum("bqkgd,bskd->bkgqs", q, k), positions)
     if past is None:

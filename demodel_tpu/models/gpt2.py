@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models.common import layer_norm, use_flash_attention as _use_flash
+from demodel_tpu.models.common import (
+    layer_norm, refuse_unsupported, use_flash_attention as _use_flash)
 
 
 @dataclass(frozen=True)
@@ -34,6 +35,7 @@ class GPT2Config:
 
     @classmethod
     def from_hf(cls, config: dict) -> "GPT2Config":
+        refuse_unsupported(config)
         return cls(
             vocab_size=config.get("vocab_size", 50257),
             n_positions=config.get("n_positions", 1024),

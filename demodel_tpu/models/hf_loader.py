@@ -20,8 +20,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
-from demodel_tpu.models import exaone_moe, qwen3_next
+from demodel_tpu.models import exaone_moe, phi4flash, qwen3_next
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
 from demodel_tpu.models.llama import LlamaConfig, param_shardings
@@ -300,6 +301,80 @@ def load_qwen3_next_params(weights: dict,
         "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
         "lm_head": w.get("lm_head.weight", transpose=True,
                          sharding=sh.get("lm_head")),
+    }
+
+
+def load_phi4flash_params(weights: dict,
+                          cfg: "phi4flash.Phi4FlashConfig",
+                          mesh=None) -> dict:
+    """The tree of :func:`phi4flash.init_params` (the layers grouped and
+    stacked by :func:`phi4flash.stack_layers`). Every layer's mixer is
+    ``attn`` in the checkpoint, whatever its kind. An attention layer's
+    ``Wqkv`` (queries, then keys, then values) enters as the query columns
+    and the key and value columns apart, so that a prefill can make keys
+    for a whole prompt and a query for its last position; the head is the
+    embedding, which the tree holds once."""
+    w = _Weights(weights)
+    # tp shards nothing of this family: every leaf is laid replicated
+    rep = NamedSharding(mesh, PartitionSpec()) if mesh is not None else None
+    nq = cfg.num_attention_heads * cfg.head_dim
+    layers = []
+    for i, kind in enumerate(cfg.kinds):
+        pre = f"layers.{i}."
+
+        def lin(name):
+            return w.get(pre + name, transpose=True, sharding=rep)
+
+        def vec(name):
+            return w.get(pre + name, sharding=rep)
+
+        layer = {
+            "ln1_w": vec("input_layernorm.weight"),
+            "ln1_b": vec("input_layernorm.bias"),
+            "ln2_w": vec("post_attention_layernorm.weight"),
+            "ln2_b": vec("post_attention_layernorm.bias"),
+            "fc1": lin("mlp.fc1.weight"),
+            "fc2": lin("mlp.fc2.weight"),
+        }
+        if kind == "mamba":
+            conv = w.get(pre + "attn.conv1d.weight")        # [Dn, 1, K]
+            layer.update({
+                "in_proj": lin("attn.in_proj.weight"),
+                "conv_w": _lay(conv.reshape(conv.shape[0], conv.shape[-1]),
+                               True, rep),
+                "conv_b": vec("attn.conv1d.bias"),
+                "x_proj": lin("attn.x_proj.weight"),
+                "dt_proj": lin("attn.dt_proj.weight"),
+                "dt_bias": vec("attn.dt_proj.bias"),
+                "A_log": vec("attn.A_log"),
+                "D": vec("attn.D"),
+                "out_proj": lin("attn.out_proj.weight"),
+            })
+        elif kind == "gmu":
+            layer.update({
+                "in_proj": lin("attn.in_proj.weight"),
+                "out_proj": lin("attn.out_proj.weight"),
+            })
+        else:
+            wqkv = w.get(pre + "attn.Wqkv.weight")
+            bqkv = w.get(pre + "attn.Wqkv.bias")
+            layer.update({"wq": _lay(wqkv[:nq], True, rep),
+                          "bq": _lay(bqkv[:nq], sharding=rep)})
+            if kind != "cross":
+                layer.update({"wkv": _lay(wqkv[nq:], True, rep),
+                              "bkv": _lay(bqkv[nq:], sharding=rep)})
+            layer.update({
+                "out_proj": lin("attn.out_proj.weight"),
+                "out_bias": vec("attn.out_proj.bias"),
+                "subln": vec("attn.inner_cross_attn.subln.weight"),
+                **{f"lambda_{x}": vec(f"attn.inner_cross_attn.lambda_{x}")
+                   for x in ("q1", "k1", "q2", "k2")}})
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=rep),
+        "final_ln_w": w.get("final_layernorm.weight", sharding=rep),
+        "final_ln_b": w.get("final_layernorm.bias", sharding=rep),
+        **phi4flash.stack_layers(layers, cfg),
     }
 
 

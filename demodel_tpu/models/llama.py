@@ -21,7 +21,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models.common import (attend, rms_norm,
+from demodel_tpu.models.common import (attend, refuse_unsupported,
+                                       rms_norm,
                                        use_flash_attention as _use_flash)
 from demodel_tpu.ops.ring_attention import (
     dense_attention,
@@ -54,6 +55,7 @@ class LlamaConfig:
 
     @classmethod
     def from_hf(cls, config: dict) -> "LlamaConfig":
+        refuse_unsupported(config)
         return cls(
             vocab_size=config.get("vocab_size", 32000),
             hidden_size=config.get("hidden_size", 4096),

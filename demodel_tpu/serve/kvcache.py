@@ -1,5 +1,6 @@
-"""The generation cache: paged K and V, and fixed slots of recurrent state,
-in one preallocated pool on the device.
+"""The generation cache: paged K and V, and fixed slots of what a sequence
+keeps whatever its length (recurrent state, a window layer's ring of its
+last positions), in one preallocated pool on the device.
 
 vLLM's PagedAttention memory discipline grafted onto the repo's tier
 accounting: the pool preallocates ``num_blocks`` blocks of
@@ -15,12 +16,18 @@ no fragmentation beyond the last partial block.
 for a sequence (:class:`CacheSpec`, from its ``cache_spec(cfg)``): which of
 its layers page K and V, and which arrays of fixed size it carries from
 token to token whatever the length (a linear-attention layer's matrix
-state, the last columns of a convolution's input). A pool built from a spec
+state, a state-space layer's, the last columns of a convolution's input,
+the **ring** of a window layer: the keys and values of its last ``window``
+positions and no others, position ``p`` at place ``p mod window``, laid out
+in blocks as the pages are). A pool built from a spec
 with such arrays also holds **slots**: a sequence's lease is its blocks and
 one slot, taken and returned together, and the device arrays of the slots
 live beside ``k`` and ``v``, ``[layers, slots + 1, ...]`` each (the extra
 slot is scratch, as the extra block is). Llama and EXAONE-MoE say "every
-layer pages, no slot", and their pool is ``k`` and ``v`` alone.
+layer pages, no slot", and their pool is ``k`` and ``v`` alone; Qwen3-Next
+pages its attention layers and keeps a slot of recurrent state; Phi-4-flash
+pages one layer (which seven more read), and its slot holds eight rings,
+nine state-space states and their convolutions' tails.
 
 The pool is two halves that never touch each other:
 
@@ -46,9 +53,13 @@ lie (:meth:`Paged.read`; ``models/common.attend`` takes them in that
 layout), and a layer with state reads its rows' slots
 (:meth:`Paged.read_state`). :func:`put_blocks` / :func:`put_positions`
 place a prefill's or a step's new K/V, :func:`put_slots` what it leaves in
-the slots — placement is entirely this module's business.
-A prefill writes the whole of its slot, so a slot taken again carries
-nothing over.
+the slots: the whole of a layer's part of the slot (a list, one array a
+layer), or one part of it for every layer at once (:class:`Placed`: a
+position of a ring, :func:`ring_put`) — placement is entirely this module's
+business, the ring's order (:func:`ring_fill`, :func:`ring_positions`)
+with it. A prefill writes the whole of its slot (a ring in ring order,
+zeros where the prompt is shorter), so a slot taken again carries nothing
+over.
 
 **One signature for life.** ``jax.jit`` keys its executables on an
 argument's sharding and on whether it is committed. The arrays are
@@ -94,7 +105,11 @@ class CacheSpec(NamedTuple):
     ``kv_heads`` heads of ``head_dim``; ``state`` names the arrays of fixed
     size a sequence carries beside them, each ``(name, shape, dtype)`` with
     the layers that keep it as the shape's first axis (the pool puts the
-    slot axis second, as K and V have the block axis)."""
+    slot axis second, as K and V have the block axis). What an array is
+    (a recurrent state, a convolution's tail, a ring ``[layers, window /
+    block_tokens, kv_heads, block_tokens, head_dim]`` of a window layer's
+    last positions) is the module's to know: the pool holds it, leases it
+    with the slot and counts its bytes."""
 
     layers: int
     kv_heads: int
@@ -376,11 +391,22 @@ class Paged(NamedTuple):
                         self.slots + layer * ns, axis=0, mode="clip")
 
 
+class Placed(NamedTuple):
+    """One part of each row's slot, the same for every layer that keeps the
+    array: ``new`` [layers, B, *part] lands in row ``b``'s slot at ``at[b]``
+    (where the part starts along the slot's axes, the ones after layers
+    and slots; None: at their start)."""
+
+    new: jax.Array
+    at: Any = None
+
+
 class Written(NamedTuple):
     """What a step of a model with fixed state hands back for the cache: the
     new keys and values of its paging layers, as every model's step does,
-    and ``state``, name → what each row's slot holds from now on, one
-    array a layer that keeps it ([B, ...] each, in the layers' order)."""
+    and ``state``, name → what each row's slot holds from now on: one
+    array a layer that keeps it ([B, ...] each, in the layers' order), or
+    a :class:`Placed` where a step writes a part of the slot only."""
 
     kv: list
     state: dict
@@ -437,17 +463,71 @@ def put_positions(k, v, new_kv, blocks, offsets):
 
 def put_slots(arrays, names, state, slots):
     """What a step leaves in its rows' slots: ``arrays`` the pool's state
-    arrays in the order of ``names``; ``state[name]`` one [B, ...] array a
-    layer; row ``b`` of each lands in slot ``slots[b]`` of its layer (a
-    prefill is the step of one row, and writes the whole of its slot). One
-    in-place slice update a layer a row, from the layer's own result: no
-    copy of the rows, stacked over the layers, stands between."""
+    arrays in the order of ``names``. ``state[name]`` is one [B, ...] array
+    a layer: row ``b`` of each lands in slot ``slots[b]`` of its layer (a
+    prefill is the step of one row, and writes the whole of its slot), one
+    in-place slice update a layer a row, from the layer's own result, so
+    that no copy of the rows, stacked over the layers, stands between (a
+    matrix state of megabytes a row). Or it is a :class:`Placed`: a part
+    of the slot (one position of a ring, a convolution's few columns) for
+    all the layers at once, one in-place slice update a row, as
+    :func:`put_positions` writes the pages."""
     out = []
     for a, name in zip(arrays, names):
-        for li, new in enumerate(state[name]):
+        new = state[name]
+        if isinstance(new, Placed):
             for b in range(slots.shape[0]):
+                where = (0,) * (a.ndim - 2) if new.at is None \
+                    else tuple(new.at[b])
                 a = lax.dynamic_update_slice(
-                    a, new[b][None, None].astype(a.dtype),
-                    (li, slots[b]) + (0,) * (a.ndim - 2))
+                    a, new.new[:, b][:, None].astype(a.dtype),
+                    (0, slots[b], *where))
+        else:
+            for li, layer in enumerate(new):
+                for b in range(slots.shape[0]):
+                    a = lax.dynamic_update_slice(
+                        a, layer[b][None, None].astype(a.dtype),
+                        (li, slots[b]) + (0,) * (a.ndim - 2))
         out.append(a)
     return tuple(out)
+
+
+# ------------------------------------------------------------- the rings
+# A window layer's part of the slot: [window / block_tokens, Hkv,
+# block_tokens, hd] a layer, position ``p`` of the sequence at place ``p mod
+# window``, in blocks as :meth:`Paged.read` hands the pages to attention.
+
+
+def ring_fill(new, window: int, block_tokens: int):
+    """A prompt's keys (or values) ``new`` [B, T, Hkv, hd] as the ring a
+    prefill leaves: its last ``window`` positions in ring order, zeros at
+    the places a shorter prompt has not reached; [B, window / block_tokens,
+    Hkv, block_tokens, hd]."""
+    B, T, Hkv, hd = new.shape
+    if T <= window:
+        ring = jnp.pad(new, ((0, 0), (0, window - T), (0, 0), (0, 0)))
+    else:       # position T - window lies at place T mod window
+        ring = jnp.roll(new[:, T - window:], T % window, axis=1)
+    return ring.reshape(B, window // block_tokens, block_tokens, Hkv,
+                        hd).transpose(0, 1, 3, 2, 4)
+
+
+def ring_positions(lengths, window: int):
+    """The position each place of a row's ring holds before the row, of
+    ``lengths`` [B] positions so far, writes its next: [B, window], negative
+    where the place is still empty (all of a pad row's, of length 0). The
+    place the next position will take holds the one ``window`` behind it,
+    which a window layer no longer sees."""
+    last = lengths[:, None] - 1
+    return last - (last - jnp.arange(window)) % window
+
+
+def ring_put(new, lengths, window: int, block_tokens: int) -> Placed:
+    """A step's new keys (or values) of the window layers, [L, B, 1, Hkv,
+    hd], as what :func:`put_slots` writes: position ``lengths[b]`` of row
+    ``b`` at its place of the ring, every layer's in one slice update."""
+    place = lengths % window
+    zero = jnp.zeros_like(place)
+    return Placed(new[:, :, :, :, None, :],
+                  jnp.stack([place // block_tokens, zero,
+                             place % block_tokens, zero], axis=1))

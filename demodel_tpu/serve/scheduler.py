@@ -752,7 +752,9 @@ class GenEngine:
                         return
                     new_shape, ids, stats = seq.first
                     dev.set_attr("new_shape", new_shape)
-                    if self._slotted:   # the whole slot, written
+                    if self._slotted:
+                        # the whole slot, written (a module whose steps
+                        # move a part of it names its own: _observe)
                         dev.set_attr("state_bytes", self.pool.slot_bytes)
                     pulled = ids.nbytes + sum(a.nbytes for a in stats)
                     if trace.enabled():
@@ -884,7 +886,9 @@ class GenEngine:
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead)):
                     cycle.set_attr(key, value)
-                if self._slotted:   # each row's slot, read and written
+                if self._slotted:
+                    # each row's slot, read and written, unless the module
+                    # names what its step moved of it (_observe, below)
                     cycle.set_attr("state_bytes",
                                    2 * B * self.pool.slot_bytes)
                 with trace.span("serve.decode-device", batch=B,

@@ -2,8 +2,8 @@
 ``benchmark/tests/test_seam.py`` (pinned digests and costs of the Llama
 family, every metric file's reader, a fixture family through generator,
 hub, reference and ``run.py`` up to the engine) and of
-``benchmark/tests/test_exaone_moe_family.py`` and
-``test_qwen3_next_family.py`` but their rehearsed runs, which take minutes. The files stay where the benchmark keeps them; this module
+``benchmark/tests/test_exaone_moe_family.py``,
+``test_qwen3_next_family.py`` and ``test_phi4flash_family.py`` but their rehearsed runs, which take minutes. The files stay where the benchmark keeps them; this module
 only gives them a name under ``tests/``.
 
 One case is replaced: the seam's test of an unknown ``model_type`` names
@@ -35,7 +35,8 @@ def _cases_of(name: str) -> dict:
 
 
 globals().update(_cases_of("test_seam"))
-for _family in ("test_exaone_moe_family", "test_qwen3_next_family"):
+for _family in ("test_exaone_moe_family", "test_qwen3_next_family",
+                "test_phi4flash_family"):
     globals().update({k: v for k, v in _cases_of(_family).items()
                       if "rehears" not in k})
 

@@ -1,0 +1,96 @@
+"""The engine's two programs of the three families that came before
+Phi-4-flash, at their tiny configurations, lower to the text they lowered to
+at the parent of PR 35 (locations stripped), in float32 and in bfloat16:
+what a model module adds to ``serve/kvcache.py`` (a part of a slot written
+for all layers at once, the rings), to ``models/common.attend`` (a scale of
+its own) and to the engine's closures changes no other family's program.
+
+The digests were taken from the parent's checkout by this file's
+:func:`programs` (``PYTHONPATH=<checkout>``, the same eight virtual CPU
+devices) and are the same here. A PR that means to change a family's
+program pins its digests again, and says so.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import hashlib
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from demodel_tpu.models import exaone_moe, llama, qwen3_next
+from demodel_tpu.serve import GenEngine
+from demodel_tpu.serve.scheduler import _Seq
+
+FAMILIES = {"llama": (llama, llama.LlamaConfig),
+            "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig),
+            "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig)}
+
+PINNED = {
+    ("llama", "float32", "decode"): "9b9785ecc3612b12",
+    ("llama", "float32", "prefill"): "2384c9807e24cde3",
+    ("llama", "bfloat16", "decode"): "38d586304dc05054",
+    ("llama", "bfloat16", "prefill"): "b9db3f7fcec8e591",
+    ("exaone_moe", "float32", "decode"): "957f831bbad8f083",
+    ("exaone_moe", "float32", "prefill"): "f60f53665d09d6e1",
+    ("exaone_moe", "bfloat16", "decode"): "a884d91f5bb7064d",
+    ("exaone_moe", "bfloat16", "prefill"): "45542946a61fe728",
+    ("qwen3_next", "float32", "decode"): "45c2cc00ec7aa138",
+    ("qwen3_next", "float32", "prefill"): "d3bd2b0489d00d06",
+    ("qwen3_next", "bfloat16", "decode"): "7f92560fea02c119",
+    ("qwen3_next", "bfloat16", "prefill"): "9be76f1f541ba68d",
+}
+
+
+def programs(module, cfg) -> dict[str, str]:
+    """The lowered text of the engine's decode step (three rows of 9, 5 and
+    3 cached positions in a bucket of four) and of a 20-token prefill."""
+    params = module.init_params(jax.random.key(1), cfg)
+    engine = GenEngine(params, cfg, max_batch=4, queue_limit=8,
+                       max_new_tokens=8, kv_mb=1, block_tokens=4)
+    pool = engine.pool
+    lease = pool.alloc(8)
+    try:
+        _w, rows = engine._decode_inputs(
+            [_Seq(None, lease, n, 1) for n in (9, 5, 3)])
+        blocks = np.asarray(
+            lease.blocks[:5] + [lease.slot] * engine._slotted, np.int32)
+        return {
+            "decode": engine._jdecode.lower(
+                engine.params, rows, engine._prev_ids,
+                *pool.arrays).as_text(),
+            "prefill": engine._jprefill.lower(
+                engine.params, np.zeros((1, 20), np.int32), blocks,
+                *pool.arrays).as_text()}
+    finally:
+        lease.free()
+        engine.stop()
+
+
+def digest(text: str) -> str:
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    text = re.sub(r"#loc\d* = .*\n", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    cache: dict = {}
+
+    def of(family: str, dtype: str) -> dict[str, str]:
+        if (family, dtype) not in cache:
+            module, config = FAMILIES[family]
+            cache[family, dtype] = programs(
+                module, dataclasses.replace(config.tiny(), dtype=dtype))
+        return cache[family, dtype]
+
+    return of
+
+
+@pytest.mark.parametrize("family,dtype,stage", sorted(PINNED))
+def test_program_is_the_parents(lowered, family, dtype, stage):
+    assert digest(lowered(family, dtype)[stage]) \
+        == PINNED[family, dtype, stage]
