@@ -54,7 +54,12 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
     block_tokens] of their slots and which of those hold the row's own
     (a row with none live, a pad row, sees only its new key). One softmax
     in float32 over cached and new keys, probabilities in q's dtype. The
-    scores are scaled by ``scale``, ``hd ** -0.5`` where none is given."""
+    scores are scaled by ``scale``, ``hd ** -0.5`` where none is given.
+
+    A paged past wider than two tiles a row comes as the tiles its rows
+    have filled (``kvcache.Tiles``, from ``Paged.filled``) and the same
+    softmax runs over those alone, a chunk of tiles a trip: the rectangle
+    is the case in which nothing can be skipped."""
     B, T, H, hd = q.shape
     Hkv = k.shape[2]
     scale = hd ** -0.5 if scale is None else scale
@@ -69,7 +74,10 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
                          (s * scale).astype(jnp.float32), -1e30)
 
     s_new = masked(jnp.einsum("bqkgd,bskd->bkgqs", q, k), positions)
-    if past is None:
+    if hasattr(past, "chunk"):
+        assert not window, "a window's blocks are as wide as they are filled"
+        out = _over_tiles(q, s_new, v, past, scale)
+    elif past is None:
         p = jax.nn.softmax(s_new, axis=-1).astype(q.dtype)
         out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
     else:
@@ -86,6 +94,68 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
                          p[..., :m * c].reshape(*p.shape[:4], m, c), pv) \
             + jnp.einsum("bkgqs,bskd->bqkgd", p[..., m * c:], v)
     return out.reshape(B, T, H * hd)
+
+
+def _over_tiles(q, s_new, v, tiles, scale):
+    """:func:`attend` over the filled tiles of a paged past (the rows'
+    cached prefixes, so every query sees every position its row holds): q
+    [B, T, Hkv, g, hd], ``s_new`` the masked float32 scores of the new
+    keys [B, Hkv, g, T, T], ``v`` their values. A trip of the loop takes a
+    chunk of tiles and leaves, for each, the running softmax's three in
+    float32, side by side in one array (the values its probabilities
+    weigh, cast to q's dtype before that product; its largest score; the
+    sum of the exponentials below it); a row's tiles and its new keys are
+    combined at the end. A row with no tile filled sees its new keys
+    only."""
+    B, T, Hkv, g, hd = q.shape
+    C, n = tiles.row.shape[0], tiles.chunk_tiles
+    f32 = jnp.float32
+
+    def trip(i, acc):
+        ids, live = tiles.chunk(i)
+        pk = tiles.blocks(tiles.k, ids)
+        m, c = pk.shape[1], pk.shape[3]
+        s = jnp.einsum("nqkgd,nmkcd->nkgqmc",
+                       lax.dynamic_slice_in_dim(q_tiles, i * n, n),
+                       pk).reshape(n, Hkv, g, T, m * c)
+        keep = live[:, None, None, None, :]
+        s = jnp.where(keep, (s * scale).astype(f32), -1e30)
+        top = s.max(axis=-1, keepdims=True)
+        p = jnp.where(keep, jnp.exp(s - top), 0.0)
+        pq = p.astype(q.dtype)
+        if tiles.apart:
+            # the values are gathered when the keys are done with
+            ids, pq = lax.optimization_barrier((ids, pq))
+        o = jnp.einsum("nkgqmc,nmkcd->nkgqd",
+                       pq.reshape(n, Hkv, g, T, m, c),
+                       tiles.blocks(tiles.v, ids),
+                       preferred_element_type=f32)
+        new = jnp.concatenate([o, top, p.sum(axis=-1, keepdims=True)],
+                              axis=-1)
+        return lax.dynamic_update_slice_in_dim(acc, new, i * n, axis=0)
+
+    with jax.named_scope("attn.tiles"):
+        q_tiles = q.at[tiles.row].get(mode="promise_in_bounds")
+        # a tile none has filled: no value, the least score, no weight (a
+        # broadcast of one tile's: set in the whole array, the compiler
+        # folds it into a constant of its size)
+        acc = lax.fori_loop(
+            jnp.uint32(0), tiles.trips, trip, jnp.broadcast_to(
+                jnp.zeros((hd + 2,), f32).at[hd].set(-1e30),
+                (C, Hkv, g, T, hd + 2)))
+        # a row's tiles, side by side: [B, tiles a row]
+        own = (tiles.own >= 0)[..., None, None, None]
+        acc = acc.at[jnp.maximum(tiles.own, 0)].get(mode="promise_in_bounds")
+        tops = jnp.where(own, acc[..., hd], -1e30)      # [B, n, Hkv, g, T]
+        top = jnp.maximum(tops.max(axis=1), s_new.max(axis=-1))
+        w = jnp.where(own, jnp.exp(tops - top[:, None]), 0.0)
+        p_new = jnp.exp(s_new - top[..., None])
+        total = (w * acc[..., hd + 1]).sum(axis=1) + p_new.sum(axis=-1)
+        out = (w[..., None] * acc[..., :hd]).sum(axis=1) + jnp.einsum(
+            "bkgqs,bskd->bkgqd", p_new.astype(q.dtype), v,
+            preferred_element_type=f32)
+        out = (out / total[..., None]).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4)                  # [B, T, Hkv, g, hd]
 
 
 def use_flash_attention() -> bool:

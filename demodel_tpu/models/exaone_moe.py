@@ -336,8 +336,9 @@ def step_decode(params, tokens, cfg: ExaoneMoeConfig, cache, lengths,
     which then chooses no expert), ``cache`` the engine's pool with the
     batch's block table (``kvcache.Paged``: ``table`` [B, n],
     ``block_tokens``, ``read(layer, ids)``). A full layer reads all ``n``
-    slots of a row; a window layer the :func:`window_slots` that cover its
-    last ``window - 1`` positions. Returns ``(logits [B, V], new_kv,
+    slots of a row (of a wide table the tiles its rows have filled); a
+    window layer the :func:`window_slots` that cover its last ``window -
+    1`` positions. Returns ``(logits [B, V], new_kv,
     expert_tokens)`` like :func:`step_prefill`, ``new_kv`` each [B, 1, Hkv,
     hd] for the caller to write at ``lengths``."""
     B, n = cache.table.shape
@@ -355,8 +356,11 @@ def step_decode(params, tokens, cfg: ExaoneMoeConfig, cache, lengths,
                 first[:, None] * bs + jnp.arange(m * bs)[None, :])
 
     views = {w: slots(w) for w in set(cfg.sliding_windows)}
-    pasts = [(*cache.read(li, views[w][0]), views[w][1],
-              views[w][1] < lengths[:, None])
+    # a full layer over a wide table follows the tiles its rows have filled
+    tiles = cache.filled(lengths) if cache.wide else None
+    pasts = [cache.past(li, tiles) if tiles is not None and not w
+             else (*cache.read(li, views[w][0]), views[w][1],
+                   views[w][1] < lengths[:, None])
              for li, w in enumerate(cfg.sliding_windows)]
     x, new_kv, counts = _forward(params, tokens[:, None], cfg,
                                  lengths[:, None], (lengths > 0)[:, None],

@@ -345,13 +345,11 @@ def step_decode(params, tokens, cfg: LlamaConfig, cache, lengths,
     (``kvcache.put_positions``). Rows padded up to a jit bucket ride along
     with ``lengths[b] == 0`` (they attend only to themselves) and are
     dropped by the caller."""
-    B, n = cache.table.shape
+    B = cache.table.shape[0]
     hd = cfg.head_dim
     H, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
     positions = lengths[:, None]                      # [B, 1]
-    S = n * cache.block_tokens                        # slots a row
-    kpos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    live = kpos < lengths[:, None]
+    filled = cache.filled(lengths)
     x = params["embed"][tokens[:, None]]              # [B, 1, D]
     new_kv = []
     for li, layer in enumerate(params["layers"]):
@@ -365,8 +363,7 @@ def step_decode(params, tokens, cfg: LlamaConfig, cache, lengths,
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         new_kv.append((k, v))
-        pk, pv = cache.read(li, cache.table)
-        out = attend(q, k, v, positions, past=(pk, pv, kpos, live))
+        out = attend(q, k, v, positions, past=cache.past(li, filled))
         x = x + out @ layer["o_proj"]
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
         y = (jax.nn.silu(y @ layer["gate_proj"]) * (y @ layer["up_proj"])) \

@@ -541,9 +541,7 @@ def _forward(params, x, cfg: Phi4FlashConfig, cache=None, lengths=None):
     positions = pages = None
     if step:
         positions = lengths[:, None]
-        S = cache.table.shape[1] * cache.block_tokens
-        kpos = jnp.broadcast_to(jnp.arange(S), (B, S))
-        pages = (*cache.read(0, cache.table), kpos, kpos < lengths[:, None])
+        pages = cache.past(0, cache.filled(lengths))
         rpos = kvcache.ring_positions(lengths, W)
 
     def ssm_past(i):
@@ -654,9 +652,10 @@ def step_decode(params, tokens, cfg: Phi4FlashConfig, cache, lengths,
     the filled prefix of each row (0 for a pad row of the bucket), ``cache``
     the engine's pool (``kvcache.Paged``) with the batch's block table and
     slots. A window layer reads its rows' rings, a Mamba layer their states
-    and tails; the full-attention layer's pages, all ``n`` table slots of a
-    row, are read once, and that layer and the cross-attention layers
-    attend over the same blocks and the step's new pair. Returns ``(logits
+    and tails; the full-attention layer's pages (all ``n`` table slots of
+    a row, gathered once, or the tiles the rows have filled of a wide
+    table, a chunk at a time by each reader) are what that layer and the
+    cross-attention layers attend over, with its new pair. Returns ``(logits
     [B, V], written, counts)`` like :func:`step_prefill`: the full layer's
     new ``(k, v)`` [B, 1, Hkv / 2, 2 hd] for the caller to write at
     ``lengths``; each ring's one new position at its place (``lengths mod

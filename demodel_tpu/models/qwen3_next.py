@@ -507,21 +507,19 @@ def step_decode(params, tokens, cfg: Qwen3NextConfig, cache, lengths,
     the filled prefix of each row (0 for a pad row of the bucket, which
     then chooses no expert), ``cache`` the engine's pool (``kvcache.Paged``)
     with the batch's block table and slots. An attention layer reads all
-    ``n`` table slots of a row; a GDN layer its rows' slots of the state
+    ``n`` table slots of a row (of a wide table the tiles its rows have
+    filled, ``Paged.past``); a GDN layer its rows' slots of the state
     arrays. Returns ``(logits [B, V], written, expert_tokens)`` like
     :func:`step_prefill`, the new keys and values each [B, 1, Hkv, hd] for
     the caller to write at ``lengths``, the states and tails for it to
     write back into the rows' slots."""
     from demodel_tpu.serve.kvcache import Written
 
-    B, n = cache.table.shape
-    S = n * cache.block_tokens
-    kpos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    live = kpos < lengths[:, None]
+    filled = cache.filled(lengths)
     pasts, paged, kept = [], 0, 0
     for full in cfg.full:
         if full:
-            pasts.append((*cache.read(paged, cache.table), kpos, live))
+            pasts.append(cache.past(paged, filled))
             paged += 1
         else:
             pasts.append((cache.read_state("gdn_state", kept),

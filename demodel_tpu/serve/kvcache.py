@@ -51,7 +51,12 @@ way: its ``step_decode`` is handed :class:`Paged` (the arrays and the
 batch's block table) and each layer gathers the blocks it reads where they
 lie (:meth:`Paged.read`; ``models/common.attend`` takes them in that
 layout), and a layer with state reads its rows' slots
-(:meth:`Paged.read_state`). :func:`put_blocks` / :func:`put_positions`
+(:meth:`Paged.read_state`). A layer that reads the whole of its rows'
+pages asks for them as a past (:meth:`Paged.filled` once a step,
+:meth:`Paged.past` a layer): the rectangle of the table's width where that
+is at most two tiles of :data:`TILE_BLOCKS` slots, beyond it the tiles the
+rows have filled (:class:`Tiles`), so that a wide step's work follows what
+its rows hold and not rows x width. :func:`put_blocks` / :func:`put_positions`
 place a prefill's or a step's new K/V, :func:`put_slots` what it leaves in
 the slots: the whole of a layer's part of the slot (a list, one array a
 layer), or one part of it for every layer at once (:class:`Placed`: a
@@ -97,6 +102,8 @@ HUB.inc("gen_kv_blocks_freed_total", 0)
 HUB.set_gauge("gen_state_slots_in_use", 0)
 HUB.inc("gen_state_slots_alloc_total", 0)
 HUB.inc("gen_state_slots_freed_total", 0)
+HUB.inc("gen_kv_positions_width_total", 0)
+HUB.inc("gen_kv_positions_read_total", 0)
 
 
 class CacheSpec(NamedTuple):
@@ -354,7 +361,14 @@ class Paged(NamedTuple):
     cover a window) with :meth:`read`, a layer with state its rows' slots
     with :meth:`read_state`. A row's table slots past its lease, and a pad
     row's, may name any block: the model masks positions at or past a
-    row's length."""
+    row's length.
+
+    The table's width is :func:`table_slots` of the batch's longest row:
+    past two tiles a row it steps so coarsely that it is capacity and not
+    work, because a layer that reads the whole of its rows' pages asks for
+    them through :meth:`filled` and :meth:`past` and then follows the
+    tiles the rows have filled. A ``step_decode`` that read a wide table
+    whole (``read(layer, table)``) would pay for the width."""
 
     k: jax.Array
     v: jax.Array
@@ -382,6 +396,42 @@ class Paged(NamedTuple):
 
         return blocks(self.k), blocks(self.v)
 
+    @property
+    def wide(self) -> bool:
+        """More than two tiles a row: a step's attention over the whole
+        of its rows' pages then follows the tiles they have filled."""
+        return _wide(self.table.shape[1])
+
+    def filled(self, lengths):
+        """What a step computes once for every layer that reads the whole
+        of its rows' pages, ``lengths`` [B] positions each: where the table
+        is at most two tiles wide (there is at most a tile a row to skip)
+        the rectangle's positions and which of them a row owns,
+        ``(kpos, live)``; beyond that the flat list of the **tiles** the
+        rows have filled, a :class:`Tiles` of the pool itself. A tile is
+        :data:`TILE_BLOCKS` table slots of one row."""
+        if not self.wide:
+            B, n = self.table.shape
+            S = n * self.block_tokens
+            kpos = jnp.broadcast_to(jnp.arange(S), (B, S))
+            return kpos, kpos < lengths[:, None]
+        with jax.named_scope("kv.tiles"):
+            return _tiles(jnp.clip(self.table, 0, self.k.shape[1] - 1),
+                          lengths, self.block_tokens)
+
+    def past(self, layer: int, filled):
+        """One layer's pages as :func:`models.common.attend` takes a past:
+        ``filled`` is what :meth:`filled` gave for the step; the rectangle
+        ``(k, v, kpos, live)`` gathered to the table's width, or the filled
+        tiles where they lie in the pool."""
+        if not isinstance(filled, Tiles):
+            return (*self.read(layer, self.table), *filled)
+        L, nb = self.k.shape[:2]
+        return filled._replace(
+            k=self.k.reshape(L * nb, *self.k.shape[2:]),
+            v=self.v.reshape(L * nb, *self.v.shape[2:]),
+            ids=filled.ids + layer * nb)
+
     def read_state(self, name: str, layer: int):
         """The rows' slots of one layer of the state array ``name``,
         [B, ...]: one gather, layers and slots as one axis."""
@@ -389,6 +439,135 @@ class Paged(NamedTuple):
         L, ns = a.shape[:2]
         return jnp.take(a.reshape(L * ns, *a.shape[2:]),
                         self.slots + layer * ns, axis=0, mode="clip")
+
+
+#: table slots a tile holds: what a wide past is skipped in
+TILE_BLOCKS = 16
+#: the ratio of a wide table's widths: 2, 16, 128 tiles a row (512, 4 096,
+#: 32 768 positions at 16 a block)
+WIDE_STEP = 8
+#: tiles a trip of the loops over the filled tiles takes
+TILE_CHUNK = 128
+#: what a chunk of keys and one of values may take together of a core's
+#: fast memory (128 MiB on a v5e) and still both be held there
+FAST_BYTES = 96 << 20
+
+
+class Tiles(NamedTuple):
+    """The tiles a batch's rows have filled, in row order, of a capacity of
+    ``C`` = rows x tiles a row of which the first so many are filled: a
+    past :func:`models.common.attend` runs over a chunk at a time, never
+    over the rectangle. ``ids`` [C, TILE_BLOCKS] names each tile's blocks
+    in ``k`` and ``v`` ([N, Hkv, block_tokens, hd]: the pool, layers and
+    blocks as one axis), gathered a chunk a trip from where they lie. A
+    tile past the filled ones repeats the last of them, so no block wholly
+    past a row's length is ever read. A trip of a loop over the chunks
+    costs a handful of device operations whatever it moves, so the chunks
+    are few and large and what a trip needs of the index is ready to be
+    sliced."""
+
+    ids: jax.Array          # [C, TILE_BLOCKS] uint32
+    row: jax.Array          # [C] the row a tile belongs to
+    live: jax.Array         # [C, positions a tile] which of its slots hold
+    #                         a position of its row: none of a tile past
+    #                         the filled ones
+    own: jax.Array          # [B, tiles a row] where a row's tiles lie in
+    #                         the list, -1 where it has filled none
+    trips: jax.Array        # [] uint32: the chunks that hold a filled tile
+    #                         (unsigned: a loop's index then slices without
+    #                         a test for a negative start)
+    k: Any = None
+    v: Any = None
+
+    @property
+    def chunk_tiles(self) -> int:
+        return _chunk_tiles(self.row.shape[0])
+
+    @property
+    def apart(self) -> bool:
+        """A chunk of keys and one of values do not fit fast memory
+        together: the compiler would leave one of them in HBM, so a trip
+        gathers the values when it is done with the keys."""
+        return 2 * self.chunk_tiles * TILE_BLOCKS * math.prod(
+            self.k.shape[1:]) * self.k.dtype.itemsize > FAST_BYTES
+
+    def chunk(self, i):
+        """Chunk ``i``: its tiles' block ids [n * TILE_BLOCKS] and their
+        ``live`` [n, positions a tile]."""
+        n = self.chunk_tiles
+        return (lax.dynamic_slice_in_dim(self.ids, i * n, n).reshape(-1),
+                lax.dynamic_slice_in_dim(self.live, i * n, n))
+
+    def blocks(self, a, ids):
+        """A chunk's blocks of ``a`` (``k`` or ``v``), gathered from where
+        they lie: [n, TILE_BLOCKS, Hkv, block_tokens, hd]."""
+        return a.at[ids].get(mode="promise_in_bounds").reshape(
+            self.chunk_tiles, TILE_BLOCKS, *a.shape[1:])
+
+
+def _chunk_tiles(capacity: int) -> int:
+    return math.gcd(TILE_CHUNK, capacity)
+
+
+def _wide(slots: int) -> bool:
+    """A table of ``slots`` a row is read by its filled tiles."""
+    return slots > 2 * TILE_BLOCKS and slots % TILE_BLOCKS == 0
+
+
+def table_slots(blocks: int) -> int:
+    """The slots a row of a decode step's table whose longest row holds
+    ``blocks`` blocks: up to two tiles the power of two over it (the
+    rectangle's work is its width), past that two tiles times a power of
+    :data:`WIDE_STEP` (the work follows the filled tiles, so a width is
+    capacity, and a deployment makes ready one program a batch bucket and
+    not one for every doubling of its contexts)."""
+    slots = 1
+    while slots < min(blocks, 2 * TILE_BLOCKS):
+        slots *= 2
+    while slots < blocks:
+        slots *= WIDE_STEP
+    return slots
+
+
+def positions_read(lengths, rows: int, slots: int,
+                   block_tokens: int) -> tuple[int, int]:
+    """On the host, from the lengths it shipped: ``(width, read)``, the
+    positions of a step's table (``rows`` of the batch bucket, ``slots``
+    each) and those its attention over whole rows reads of them: the
+    filled tiles of a wide table, all of a narrow one."""
+    width = rows * slots * block_tokens
+    if not _wide(slots):
+        return width, width
+    span = TILE_BLOCKS * block_tokens
+    return width, sum(min(-(-n // span), slots // TILE_BLOCKS)
+                      for n in lengths) * span
+
+
+def _tiles(table, lengths, block_tokens: int) -> Tiles:
+    """The index of the filled tiles of ``table`` [B, n] at ``lengths``:
+    row ``b`` has ``ceil(lengths[b] / tile)`` of them, and their flat list
+    in row order comes from a cumulative sum."""
+    B, n = table.shape
+    per_row = n // TILE_BLOCKS
+    span = TILE_BLOCKS * block_tokens
+    tiles = jnp.minimum(-(-lengths // span), per_row)
+    ends = jnp.cumsum(tiles)
+    first, count = ends - tiles, ends[-1]
+    # a tile past the filled ones repeats the last filled one
+    j = jnp.minimum(jnp.arange(B * per_row), jnp.maximum(count - 1, 0))
+    row = jnp.minimum((j[:, None] >= ends[None, :]).sum(axis=1), B - 1)
+    t = j - first[row]
+    ids = table[row[:, None], t[:, None] * TILE_BLOCKS
+                + jnp.arange(TILE_BLOCKS)[None, :]]
+    live = (t[:, None] * span + jnp.arange(span)[None, :]
+            < lengths[row][:, None]) & (jnp.arange(B * per_row)
+                                        < count)[:, None]
+    u = jnp.arange(per_row)[None, :]
+    own = jnp.where(u < tiles[:, None], first[:, None] + u, -1)
+    n = _chunk_tiles(B * per_row)
+    # unsigned: a gather then has no negative index to wrap
+    return Tiles(ids.astype(jnp.uint32), row, live, own,
+                 ((count + n - 1) // n).astype(jnp.uint32))
 
 
 class Placed(NamedTuple):

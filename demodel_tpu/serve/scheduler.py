@@ -225,13 +225,16 @@ class _Step:
     """One dispatched decode step: who rode it, and its outputs, still on
     the device and possibly still being computed."""
 
-    __slots__ = ("batch", "width", "rows", "ahead", "new_shape", "ids",
-                 "stats")
+    __slots__ = ("batch", "width", "kv_positions", "rows", "ahead",
+                 "new_shape", "ids", "stats")
 
-    def __init__(self, batch: list[_Seq], width: int, rows, ahead: bool,
-                 new_shape: bool):
+    def __init__(self, batch: list[_Seq], width: int, kv_positions, rows,
+                 ahead: bool, new_shape: bool):
         self.batch = batch
         self.width = width
+        #: ``(width, read)``: the table's positions, and those the step's
+        #: attention over whole rows reads of them (``kvcache``)
+        self.kv_positions = kv_positions
         self.rows = rows            # shipped; dropped once launched
         self.ahead = ahead          # dispatched behind a step in flight
         self.new_shape = new_shape  # first run of its (bucket, width)
@@ -651,12 +654,14 @@ class GenEngine:
         token is taken from on the device (-1: the id in column 0 is fed),
         its state slot where the pool has slots, then its slots of the
         block table — so its shape follows (bucket, width) alone and it
-        crosses the link in one transfer."""
+        crosses the link in one transfer. The width follows the longest
+        row (``kvcache.table_slots``): a power of two up to two tiles,
+        coarse steps past that, where it is capacity and not work."""
         import numpy as np
 
         pool = self.pool
         bs = pool.block_tokens
-        nb = _pow2(-(-max(s.length for s in batch) // bs))
+        nb = kvcache.table_slots(-(-max(s.length for s in batch) // bs))
         at = 5 + self._slotted      # where the block table starts
         # a slot a sequence does not have reads block 0 (masked by its
         # length); a pad row rides along with length 0, is dropped on the
@@ -820,11 +825,15 @@ class GenEngine:
         import jax
 
         width, rows = self._decode_inputs(batch)
+        bs = self.pool.block_tokens
+        positions = kvcache.positions_read(
+            [s.length for s in batch], len(rows), width // bs, bs)
         for i, seq in enumerate(batch):
             seq.length += 1
             seq.planned += 1
             seq.row = i
-        step = _Step(batch, width, jax.device_put(rows, self.pool.replicated),
+        step = _Step(batch, width, positions,
+                     jax.device_put(rows, self.pool.replicated),
                      ahead, self._first_run("decode", len(rows), width))
         HUB.inc("gen_h2d_bytes_total", rows.nbytes)
         return step
@@ -883,9 +892,14 @@ class GenEngine:
                         todo.append(nxt)
                     ship.set_attr("bytes", sum(t.rows.nbytes for t in todo))
                 B = len(flight.batch)
+                table, read = flight.kv_positions
                 for key, value in (("batch", B), ("width", flight.width),
-                                   ("ahead", flight.ahead)):
+                                   ("ahead", flight.ahead),
+                                   ("kv_positions_width", table),
+                                   ("kv_positions_read", read)):
                     cycle.set_attr(key, value)
+                HUB.inc("gen_kv_positions_width_total", table)
+                HUB.inc("gen_kv_positions_read_total", read)
                 if self._slotted:
                     # each row's slot, read and written, unless the module
                     # names what its step moved of it (_observe, below)

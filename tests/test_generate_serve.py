@@ -21,6 +21,7 @@ from demodel_tpu.models import llama
 from demodel_tpu.serve import (BlockLease, GenEngine, KVBlockPool,
                                PoolExhausted, QueueOverflow, kvcache)
 from demodel_tpu.serve.kvcache import CacheSpec
+from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB, labeled
 
 
@@ -587,6 +588,107 @@ class TestDevicePool:
         # a row: token, length, write block and offset, src, its 8 slots
         table, ids = 4 * (5 + 8) * 4, 4 * 4
         assert cycles == [[2 * table, ids, 1, 0], [0, ids, 0, 1]]
+        assert engine.pool.in_use_blocks == 0
+
+    def test_a_wide_step_books_the_tiles_its_rows_have_filled(self,
+                                                              tiny_model):
+        """Blocks of 2 positions, so a tile holds 32: rows of 70, 40 and 5
+        positions at a width of 256 slots (sixteen tiles a row) read 3 + 2 +
+        1 tiles where the bucket of four rows is 2 048 positions wide, and
+        the step dispatched ahead of it the same. Every cycle's span names both
+        numbers, the counters are their sums, and ``/statusz`` has them
+        beside the other ``gen_`` counters. The tokens are the reference's
+        (the tiles, read where they lie, through a llama's 2 layers)."""
+        from demodel_tpu.utils import statusz, trace
+
+        params, cfg = tiny_model
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
+                           max_new_tokens=4, kv_mb=1, block_tokens=2)
+        names = ("gen_kv_positions_width_total",
+                 "gen_kv_positions_read_total")
+        prompts = [_prompt(cfg, n, seed=n) for n in (70, 40, 5)]
+        trace.reset()
+        trace.enable()
+        try:
+            before = HUB.snapshot()
+            reqs = _drive(engine, prompts, 3)
+            for _ in range(2):
+                engine._decode_step()
+            after = HUB.snapshot()
+            steps = [r["attrs"] for r in trace.buffer().snapshot()
+                     if r["name"] == "serve.decode-step"]
+            counters = statusz.snapshot()["counters"]
+        finally:
+            trace.reset()
+            engine.stop()
+        assert [(a["width"], a["kv_positions_width"], a["kv_positions_read"])
+                for a in steps] == [(512, 2048, 6 * 32)] * 2
+        assert [after[n] - before[n] for n in names] == [4096, 2 * 6 * 32]
+        assert all(counters[n] >= after[n] - before[n] for n in names)
+        for req, prompt in zip(reqs, prompts):
+            assert req.result(5) == np.asarray(llama.generate(
+                params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
+
+    @pytest.mark.parametrize("longest,slots", [
+        (1, 1), (2, 1), (3, 2), (9, 8), (31, 16), (32, 16), (33, 32),
+        (64, 32), (65, 256), (300, 256), (512, 256), (513, 2048),
+        (3000, 2048), (4096, 2048), (4097, 16384)])
+    def test_the_width_follows_the_longest_row(self, tiny_model, longest,
+                                               slots):
+        """Blocks of 2 positions, a tile of 32: up to two tiles a row the
+        power of two over the longest row's blocks, past that two tiles
+        times a power of eight, on both sides of each boundary. The table
+        starts at column 5 of the rows; a slot past the lease names block
+        0."""
+        params, cfg = tiny_model
+        engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+                           max_new_tokens=4, kv_mb=3, block_tokens=2)
+        lease = engine.pool.alloc(longest // 2 + 1)
+        short = engine.pool.alloc(1)
+        try:
+            width, rows = engine._decode_inputs(
+                [_Seq(None, short, 1, 1), _Seq(None, lease, longest, 1)])
+        finally:
+            lease.free()
+            short.free()
+            engine.stop()
+        assert kvcache.table_slots(-(-longest // 2)) == slots
+        assert (width, rows.shape) == (2 * slots, (2, 5 + slots))
+        held = min(len(lease.blocks), slots)
+        assert rows[1, 5:5 + held].tolist() == lease.blocks[:held]
+        assert not rows[1, 5 + held:].any() and not rows[0, 6:].any()
+
+    def test_past_two_tiles_a_bucket_runs_one_program(self, tiny_model):
+        """Blocks of 2 positions (a tile holds 32, the widths past two
+        tiles are 512 and 4 096 positions): pairs whose longer row holds
+        75, 190 and 375 positions, then one row of 120 alone, all run at a
+        width of 512, so the engine makes ready one decode program a batch
+        bucket where a width by powers of two made ready 256 and 512 for
+        the pairs alone. The tokens are the reference's: rows of unequal
+        length through the tiles, a pad row beside the last."""
+        params, cfg = tiny_model
+        engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+                           max_new_tokens=4, kv_mb=1, block_tokens=2)
+        name = labeled("gen_new_shapes_total", stage="decode")
+        waves = [[_prompt(cfg, n, seed=n) for n in wave]
+                 for wave in ((75, 40), (190, 9), (375, 130), (120,))]
+        before = HUB.snapshot()
+        try:
+            for prompts in waves:
+                reqs = _drive(engine, prompts, 3)
+                while engine._flight is not None \
+                        or engine._snapshot_running():
+                    engine._decode_step()
+                for req, prompt in zip(reqs, prompts):
+                    assert req.result(5) == np.asarray(llama.generate(
+                        params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
+            shapes = sorted(s for s in engine._shapes_run
+                            if s[0] == "decode")
+            made = HUB.snapshot()[name] - before.get(name, 0)
+        finally:
+            engine.stop()
+        assert shapes == [("decode", 1, 512), ("decode", 2, 512)]
+        assert made == engine._jdecode._cache_size() == 2
         assert engine.pool.in_use_blocks == 0
 
     def test_every_family_is_handed_the_pool_and_its_table(self,
@@ -1550,6 +1652,19 @@ class TestServeSpans:
         assert sorted(r["attrs"]["bytes"]
                       for r in run["named"]("serve.http-parse")) == \
             sorted(len(json.dumps(b).encode()) for b in run["bodies"])
+
+    def test_a_narrow_step_reads_every_position_of_its_table(self, run):
+        """At most two tiles a row (here 32 positions of 16 a block): the
+        rectangle, so a step books as many positions read as its bucket's
+        table is wide, on its span and in the counters."""
+        steps = [r["attrs"] for r in run["named"]("serve.decode-step")]
+        assert steps
+        for a in steps:
+            assert a["kv_positions_read"] == a["kv_positions_width"] \
+                == _pow2(a["batch"]) * a["width"]
+        total = sum(a["kv_positions_width"] for a in steps)
+        assert run["delta"]("gen_kv_positions_width_total") == total
+        assert run["delta"]("gen_kv_positions_read_total") == total
 
     def test_byte_counters_are_what_crosses_the_link(self, run):
         """Decode: the spans' ``bytes``. A prefill ships its prompt and

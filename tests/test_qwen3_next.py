@@ -92,13 +92,13 @@ ENGINE = dict(max_batch=4, queue_limit=8, max_new_tokens=24, kv_mb=1,
               block_tokens=4)
 
 
-def _served(ckpt, params, cfg):
+def _served(ckpt, params, cfg, lengths=LENGTHS, **over):
     """What the engine's two programs give for three prompts and 12 steps
     of their ragged batch (three rows in a bucket of four: the fourth is a
     pad row), beside the float32 reference's logits for the same
     sequences."""
-    engine = GenEngine(params, cfg, **ENGINE)
-    prompts = _prompts()
+    engine = GenEngine(params, cfg, **{**ENGINE, **over})
+    prompts = _prompts(lengths)
     try:
         got = _engine_logits(engine, prompts, steps=12)
     finally:
@@ -173,6 +173,33 @@ def test_float32_program_is_the_reference(small, slab, monkeypatch):
     got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg))
     for (_fed, lg), r in zip(got, ref):
         np.testing.assert_allclose(lg, r, rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("lengths", [LENGTHS, (60, 30, 9)],
+                         ids=["wide", "across"])
+def test_a_wide_step_follows_the_tiles_its_rows_have_filled(small, lengths):
+    """Blocks of 2 positions, so that a tile holds 32: rows of 150, 70 and
+    9 positions and a pad row, 12 steps at a width of 256 slots (sixteen
+    tiles a row, of which the rows have filled 5 to 6, 3 and 1, the pad
+    row none). Each attention layer gathers a chunk of its rows' filled
+    tiles from the pool where they lie: float32 logits and greedy ids are
+    the reference's. The same with a longest row of 60 positions, which
+    passes two tiles at its sixth step: five steps over the rectangle of
+    32 slots, then seven over the tiles of 256."""
+    ckpt, params, cfg = small
+    got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg),
+                                   lengths=lengths, block_tokens=2)
+    for (_fed, lg), r in zip(got, ref):
+        np.testing.assert_allclose(lg, r, rtol=0, atol=1e-4)
+        assert (lg.argmax(axis=-1) == r.argmax(axis=-1)).all()
+    engine = GenEngine(params, cfg, **{**ENGINE, "block_tokens": 2})
+    lease = engine.pool.alloc(81)
+    width, rows = engine._decode_inputs([_Seq(None, lease, 161, 1)])
+    step = engine._jdecode.lower(engine.params, rows, engine._prev_ids,
+                                 *engine.pool.arrays).as_text(debug_info=True)
+    lease.free()
+    engine.stop()
+    assert width == 512 and "kv.tiles" in step and "attn.tiles" in step
 
 
 class TestAgainstTheReference:
