@@ -452,20 +452,6 @@ def main() -> int:
     with timed("native plane (make -C native unless built)"):
         native.lib()
 
-    compiles = {"n": 0, "secs": 0.0, "cache_hits": 0}
-
-    def on_duration(event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiles["n"] += 1
-            compiles["secs"] += secs
-
-    def on_event(event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            compiles["cache_hits"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
     from demodel_tpu.parallel.mesh import make_mesh
 
     n = len(jax.local_devices())
@@ -475,9 +461,12 @@ def main() -> int:
     say(f"tokens generated: {info['engine_tokens']}")
     kernels = check_kernels(TINYLLAMA, prompt_len=17)
     say(f"flash kernels, max error vs reference: {kernels}")
-    say(f"compilations: {compiles['n']} taking "
-        f"{compiles['secs']:.1f} s in all, {compiles['cache_hits']} served "
-        f"from {cache_dir}")
+    # what place() has heard since: every program this process made ready
+    made = compile_cache.programs()
+    ready = list(made["ready"].values())
+    say(f"compilations: {sum(sum(by.values()) for by in ready)} taking "
+        f"{made['seconds']['load'] + made['seconds']['compile']:.1f} s in "
+        f"all, {sum(by['loaded'] for by in ready)} served from {cache_dir}")
     for d in jax.local_devices():
         say(f"peak bytes in use on {d}: "
             f"{d.memory_stats()['peak_bytes_in_use']}")

@@ -26,7 +26,7 @@ from demodel_tpu.serve.kvcache import (BlockLease, KVBlockPool,
                                        PoolExhausted)
 from demodel_tpu.serve.scheduler import (AdmissionQueue, AdmissionTicket,
                                          GenEngine, QueueOverflow, Request)
-from demodel_tpu.utils import trace
+from demodel_tpu.utils import compile_cache, trace
 
 __all__ = [
     "AdmissionQueue", "AdmissionTicket", "BlockLease", "GenEngine",
@@ -56,8 +56,15 @@ def current() -> GenEngine | None:
 
 def boot(params, cfg, mesh=None, **engine_kw) -> GenEngine:
     """Start an engine over in-memory params and install it — the
-    short path for tests/benches and pre-delivered weights."""
-    engine = GenEngine(params, cfg, mesh=mesh, **engine_kw).start()
+    short path for tests/benches and pre-delivered weights. The engine's
+    birth (the pool's arrays, the ids and ``_set_id``'s compilation, the
+    thread) is the span ``serve.engine-start``."""
+    with trace.span("serve.engine-start") as born:
+        engine = GenEngine(params, cfg, mesh=mesh, **engine_kw).start()
+        born.set_attr("max_batch", engine.max_batch)
+        born.set_attr("kv_mb", engine.pool.budget.max_bytes >> 20)
+        born.set_attr("pool_bytes",
+                      sum(a.nbytes for a in engine.pool.arrays))
     install(engine)
     return engine
 
@@ -73,23 +80,30 @@ def load_model(model: str, cfg, *, source: str = "hf",
     :class:`~demodel_tpu.config.ProxyConfig` naming the store. ``mesh``
     defaults to every local device (``tp`` = device count); delivery,
     the loader and the engine all get the one resolved here."""
-    from demodel_tpu import delivery
-    from demodel_tpu.models import auto
-    from demodel_tpu.parallel.mesh import make_mesh
+    import jax
 
-    if mesh is None:
-        mesh = make_mesh()
+    # before the loaders' layout programs: they are counted, and kept
+    compile_cache.place()
     with trace.span("serve.load-model", model=model, source=source):
+        from demodel_tpu import delivery
+        from demodel_tpu.models import auto
+        from demodel_tpu.parallel.mesh import make_mesh
+
+        if mesh is None:
+            mesh = make_mesh()
         report, placed = delivery.pull_to_hbm(
             model, cfg, source=source, revision=revision,
             endpoint=endpoint, mesh=mesh, peers=peers, deliver=True)
         store = delivery.open_store(cfg)
         try:
-            _fn, params, mcfg = auto.model_from_pull(
-                store, report, mesh=mesh, placement=placed)
+            with trace.span("serve.build-params") as built:
+                _fn, params, mcfg = auto.model_from_pull(
+                    store, report, mesh=mesh, placement=placed)
+                leaves = jax.tree.leaves(params)
+                built.set_attr("model_type",
+                               type(mcfg).__module__.rpartition(".")[2])
+                built.set_attr("tensors", len(leaves))
+                built.set_attr("bytes", sum(a.nbytes for a in leaves))
         finally:
             store.close()
-    engine = GenEngine(params, mcfg, mesh=mesh, model=model,
-                       **engine_kw).start()
-    install(engine)
-    return engine
+    return boot(params, mcfg, mesh=mesh, model=model, **engine_kw)

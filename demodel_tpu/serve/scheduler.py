@@ -47,7 +47,7 @@ from typing import Any, Iterator
 
 from demodel_tpu.serve import kvcache
 from demodel_tpu.serve.kvcache import KVBlockPool, PoolExhausted
-from demodel_tpu.utils import trace
+from demodel_tpu.utils import compile_cache, trace
 from demodel_tpu.utils.env import (gen_max_batch, gen_max_new_tokens,
                                    gen_queue_limit, gen_retry_after_s)
 from demodel_tpu.utils.logging import get_logger
@@ -273,8 +273,6 @@ class GenEngine:
         import jax
         import jax.numpy as jnp
         import numpy as np
-
-        from demodel_tpu.utils import compile_cache
 
         # the loaded config names its model module, which states its cache
         # (``cache_spec(cfg)``, a ``kvcache.CacheSpec``: the pool is built
@@ -523,6 +521,7 @@ class GenEngine:
             "uptime_s": round(time.time() - self.started_s, 3),
             "admission": self.admission.describe(),
             "kv": self.pool.describe(),
+            "programs": compile_cache.programs(),
         }
 
     # ------------------------------------------------------ engine loop
@@ -628,13 +627,16 @@ class GenEngine:
         HUB.inc(labeled("gen_new_shapes_total", stage=stage))
         return True
 
-    def _prefill(self, prompt: list[int], lease):
+    def _prefill(self, prompt: list[int], lease, new_shape: bool = False):
         """Ship a prompt and its lease's block ids, run the prefill
         program over the pool (it writes those blocks itself), and
         return ``(ids, (logits, *stats))``: the token it chose ``[1]``,
         the last position's logits ``[1, V]`` and the model's stats, if
         it has any, still on the device and possibly still being
-        computed."""
+        computed. The first run of a prompt length (``new_shape``) makes
+        a program ready where it is dispatched: that dispatch alone lies
+        in a ``serve.program-ready`` span, which ``compile_cache``'s
+        listener gives the seconds of each phase and ``how``."""
         import jax
         import numpy as np
 
@@ -644,7 +646,10 @@ class GenEngine:
                             + [lease.slot] * self._slotted, np.int32)
         sent = jax.device_put((tokens, blocks), pool.replicated)
         HUB.inc("gen_h2d_bytes_total", tokens.nbytes + blocks.nbytes)
-        return pool.apply(self._jprefill, self.params, *sent)
+        compile_cache.dispatching.shape = ("prefill", len(prompt))
+        with (trace.span(compile_cache.READY_SPAN, stage="prefill",
+                         prompt=len(prompt)) if new_shape else trace.NOOP):
+            return pool.apply(self._jprefill, self.params, *sent)
 
     def _decode_inputs(self, batch: list[_Seq]):
         """What one decode step ships, built from the leases: ``(width,
@@ -688,7 +693,8 @@ class GenEngine:
         T = len(req.prompt)
         new_shape = self._first_run("prefill", T)
         try:
-            ids, (_logits, *stats) = self._prefill(req.prompt, lease)
+            ids, (_logits, *stats) = self._prefill(req.prompt, lease,
+                                                   new_shape)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             lease.free()
             log.error("prefill failed for request %d: %s", req.id, exc)
@@ -810,6 +816,7 @@ class GenEngine:
             return
         for seq in self._snapshot_running():
             self._retire(seq, error=error)
+        compile_cache.dispatching.shape = ("other",)
         self.pool.reset()
 
     def _evict_cancelled(self) -> None:
@@ -841,9 +848,17 @@ class GenEngine:
     def _launch(self, step: _Step) -> None:
         """Queue the program of a shipped step on the pool the newest
         program returns, fed by the newest step's ids; its own ids start
-        for the host the moment it ends."""
-        ids, (_logits, *stats) = self.pool.apply(
-            self._jdecode, self.params, step.rows, self._prev_ids)
+        for the host the moment it ends. What it dispatches it names
+        first, for the listener of ``compile_cache``, and the first run of
+        a (bucket, width) lies in a ``serve.program-ready`` span, as a
+        prefill's does; no other run opens a span."""
+        bucket = len(step.rows)
+        compile_cache.dispatching.shape = ("decode", bucket, step.width)
+        with (trace.span(compile_cache.READY_SPAN, stage="decode",
+                         batch=bucket, width=step.width)
+              if step.new_shape else trace.NOOP):
+            ids, (_logits, *stats) = self.pool.apply(
+                self._jdecode, self.params, step.rows, self._prev_ids)
         for out in (ids, *stats):
             out.copy_to_host_async()
         step.rows, step.ids, step.stats = None, ids, stats
