@@ -54,9 +54,10 @@ layout), and a layer with state reads its rows' slots
 (:meth:`Paged.read_state`). A layer that reads the whole of its rows'
 pages asks for them as a past (:meth:`Paged.filled` once a step,
 :meth:`Paged.past` a layer): the rectangle of the table's width where that
-is at most two tiles of :data:`TILE_BLOCKS` slots, beyond it the tiles the
-rows have filled (:class:`Tiles`), so that a wide step's work follows what
-its rows hold and not rows x width. :func:`put_blocks` / :func:`put_positions`
+is two tiles of :data:`TILE_BLOCKS` slots (the narrowest table there is,
+:func:`table_slots`), beyond it the tiles the rows have filled
+(:class:`Tiles`), so that a wide step's work follows what its rows hold
+and not rows x width. :func:`put_blocks` / :func:`put_positions`
 place a prefill's or a step's new K/V, :func:`put_slots` what it leaves in
 the slots: the whole of a layer's part of the slot (a list, one array a
 layer), or one part of it for every layer at once (:class:`Placed`: a
@@ -364,7 +365,8 @@ class Paged(NamedTuple):
     row's length.
 
     The table's width is :func:`table_slots` of the batch's longest row:
-    past two tiles a row it steps so coarsely that it is capacity and not
+    two tiles a row whatever the rows hold up to there (the rectangle),
+    and past two tiles it steps so coarsely that it is capacity and not
     work, because a layer that reads the whole of its rows' pages asks for
     them through :meth:`filled` and :meth:`past` and then follows the
     tiles the rows have filled. A ``step_decode`` that read a wide table
@@ -516,14 +518,13 @@ def _wide(slots: int) -> bool:
 
 def table_slots(blocks: int) -> int:
     """The slots a row of a decode step's table whose longest row holds
-    ``blocks`` blocks: up to two tiles the power of two over it (the
-    rectangle's work is its width), past that two tiles times a power of
-    :data:`WIDE_STEP` (the work follows the filled tiles, so a width is
-    capacity, and a deployment makes ready one program a batch bucket and
-    not one for every doubling of its contexts)."""
-    slots = 1
-    while slots < min(blocks, 2 * TILE_BLOCKS):
-        slots *= 2
+    ``blocks`` blocks: two tiles (the rectangle, which masks what a row
+    does not own) for every longest row up to two tiles, past that two
+    tiles times a power of :data:`WIDE_STEP` (the work follows the filled
+    tiles, so a width is capacity). Either way a deployment makes ready
+    one program a batch bucket and not one for every doubling of its
+    contexts."""
+    slots = 2 * TILE_BLOCKS
     while slots < blocks:
         slots *= WIDE_STEP
     return slots

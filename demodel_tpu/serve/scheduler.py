@@ -660,8 +660,8 @@ class GenEngine:
         its state slot where the pool has slots, then its slots of the
         block table — so its shape follows (bucket, width) alone and it
         crosses the link in one transfer. The width follows the longest
-        row (``kvcache.table_slots``): a power of two up to two tiles,
-        coarse steps past that, where it is capacity and not work."""
+        row (``kvcache.table_slots``): two tiles up to two tiles, coarse
+        steps past that, where it is capacity and not work."""
         import numpy as np
 
         pool = self.pool

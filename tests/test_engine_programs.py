@@ -8,7 +8,12 @@ its own) and to the engine's closures changes no other family's program.
 The digests were taken from the parent's checkout by this file's
 :func:`programs` (``PYTHONPATH=<checkout>``, the same eight virtual CPU
 devices) and are the same here. A PR that means to change a family's
-program pins its digests again, and says so.
+program pins its digests again, and says so. PR 41 did for the six
+``decode`` ones: up to two tiles a row a table is two tiles wide, so rows of
+9, 5 and 3 positions step through 32 slots where they stepped through 4.
+``decode-past-16-blocks`` is from PR 41's parent, where only a longest row
+past 16 blocks had a table of 32 slots: the program every shorter row now
+runs is that one, text for text.
 """
 
 from __future__ import annotations
@@ -30,38 +35,50 @@ FAMILIES = {"llama": (llama, llama.LlamaConfig),
             "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig)}
 
 PINNED = {
-    ("llama", "float32", "decode"): "9b9785ecc3612b12",
+    ("llama", "float32", "decode"): "ff3c2a1e16526eb9",
+    ("llama", "float32", "decode-past-16-blocks"): "ff3c2a1e16526eb9",
     ("llama", "float32", "prefill"): "2384c9807e24cde3",
-    ("llama", "bfloat16", "decode"): "38d586304dc05054",
+    ("llama", "bfloat16", "decode"): "fecca8e7814f2856",
+    ("llama", "bfloat16", "decode-past-16-blocks"): "fecca8e7814f2856",
     ("llama", "bfloat16", "prefill"): "b9db3f7fcec8e591",
-    ("exaone_moe", "float32", "decode"): "957f831bbad8f083",
+    ("exaone_moe", "float32", "decode"): "67b0d27ade84e175",
+    ("exaone_moe", "float32", "decode-past-16-blocks"): "67b0d27ade84e175",
     ("exaone_moe", "float32", "prefill"): "f60f53665d09d6e1",
-    ("exaone_moe", "bfloat16", "decode"): "a884d91f5bb7064d",
+    ("exaone_moe", "bfloat16", "decode"): "27c74dc4fd4b723b",
+    ("exaone_moe", "bfloat16", "decode-past-16-blocks"): "27c74dc4fd4b723b",
     ("exaone_moe", "bfloat16", "prefill"): "45542946a61fe728",
-    ("qwen3_next", "float32", "decode"): "45c2cc00ec7aa138",
+    ("qwen3_next", "float32", "decode"): "d424ee57b47bb163",
+    ("qwen3_next", "float32", "decode-past-16-blocks"): "d424ee57b47bb163",
     ("qwen3_next", "float32", "prefill"): "d3bd2b0489d00d06",
-    ("qwen3_next", "bfloat16", "decode"): "7f92560fea02c119",
+    ("qwen3_next", "bfloat16", "decode"): "12ce9dce817a9064",
+    ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "12ce9dce817a9064",
     ("qwen3_next", "bfloat16", "prefill"): "9be76f1f541ba68d",
 }
 
 
 def programs(module, cfg) -> dict[str, str]:
     """The lowered text of the engine's decode step (three rows of 9, 5 and
-    3 cached positions in a bucket of four) and of a 20-token prefill."""
+    3 cached positions in a bucket of four; ``decode-past-16-blocks``: of
+    70, 5 and 3, so that the longest row holds 18 blocks of 4) and of a
+    20-token prefill."""
     params = module.init_params(jax.random.key(1), cfg)
     engine = GenEngine(params, cfg, max_batch=4, queue_limit=8,
                        max_new_tokens=8, kv_mb=1, block_tokens=4)
     pool = engine.pool
-    lease = pool.alloc(8)
-    try:
+    lease = pool.alloc(20)
+
+    def decode(*lengths):
         _w, rows = engine._decode_inputs(
-            [_Seq(None, lease, n, 1) for n in (9, 5, 3)])
+            [_Seq(None, lease, n, 1) for n in lengths])
+        return engine._jdecode.lower(engine.params, rows, engine._prev_ids,
+                                     *pool.arrays).as_text()
+
+    try:
         blocks = np.asarray(
             lease.blocks[:5] + [lease.slot] * engine._slotted, np.int32)
         return {
-            "decode": engine._jdecode.lower(
-                engine.params, rows, engine._prev_ids,
-                *pool.arrays).as_text(),
+            "decode": decode(9, 5, 3),
+            "decode-past-16-blocks": decode(70, 5, 3),
             "prefill": engine._jprefill.lower(
                 engine.params, np.zeros((1, 20), np.int32), blocks,
                 *pool.arrays).as_text()}

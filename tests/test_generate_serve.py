@@ -35,12 +35,13 @@ def tiny_model():
 def _tiny(family):
     """``(module, params, cfg)`` of a model module the engine serves, at
     its test size."""
-    from demodel_tpu.models import exaone_moe, qwen3_next
+    from demodel_tpu.models import exaone_moe, phi4flash, qwen3_next
 
     module, cfg = {
         "llama": (llama, llama.LlamaConfig.tiny()),
         "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig.tiny()),
         "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny()),
+        "phi4flash": (phi4flash, phi4flash.Phi4FlashConfig.tiny()),
     }[family]
     return module, module.init_params(jax.random.key(2), cfg), cfg
 
@@ -541,7 +542,8 @@ class TestDevicePool:
         """One prompt length at three ``max_new_tokens`` (so three lease
         sizes): one prefill executable, and as many decode executables as
         (batch bucket, width) pairs — what ``gen_new_shapes_total``
-        counts, so that a warm-up by shape reaches every program."""
+        counts, so that a warm-up by shape reaches every program. Up to
+        two tiles a row there is one width, so one program a bucket."""
         params, cfg = tiny_model
         before = HUB.snapshot()
         engine = GenEngine(params, cfg, max_batch=1, queue_limit=8,
@@ -560,8 +562,8 @@ class TestDevicePool:
             return after[name] - before.get(name, 0)
 
         assert engine._jprefill._cache_size() == shapes("prefill") == 1
-        # lengths 7..16 at batch 1: widths 8 (to length 8) and 16
-        assert engine._jdecode._cache_size() == shapes("decode") == 2
+        # lengths 7..17 at batch 1 cross two doublings of the row's blocks
+        assert engine._jdecode._cache_size() == shapes("decode") == 1
 
     def test_a_step_ships_a_table_and_pulls_back_ids(self, tiny_model):
         """Three tokens a request, so two decode steps: the first cycle
@@ -584,9 +586,10 @@ class TestDevicePool:
         finally:
             engine.stop()
         cycles = [[b[n] - a[n] for n in names] for a, b in zip(seen, seen[1:])]
-        # a bucket of 4 rows (= _pow2(max_batch)); length 18: 5 → 8 blocks;
-        # a row: token, length, write block and offset, src, its 8 slots
-        table, ids = 4 * (5 + 8) * 4, 4 * 4
+        # a bucket of 4 rows (= _pow2(max_batch)); length 18: 5 blocks in a
+        # table of two tiles; a row: token, length, write block and offset,
+        # src, its 32 slots
+        table, ids = 4 * (5 + 32) * 4, 4 * 4
         assert cycles == [[2 * table, ids, 1, 0], [0, ids, 0, 1]]
         assert engine.pool.in_use_blocks == 0
 
@@ -630,13 +633,13 @@ class TestDevicePool:
                 params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
 
     @pytest.mark.parametrize("longest,slots", [
-        (1, 1), (2, 1), (3, 2), (9, 8), (31, 16), (32, 16), (33, 32),
+        (1, 32), (2, 32), (3, 32), (9, 32), (31, 32), (32, 32), (33, 32),
         (64, 32), (65, 256), (300, 256), (512, 256), (513, 2048),
         (3000, 2048), (4096, 2048), (4097, 16384)])
     def test_the_width_follows_the_longest_row(self, tiny_model, longest,
                                                slots):
-        """Blocks of 2 positions, a tile of 32: up to two tiles a row the
-        power of two over the longest row's blocks, past that two tiles
+        """Blocks of 2 positions, a tile of 32: two tiles a row for every
+        longest row up to two tiles, whatever it holds, past that two tiles
         times a power of eight, on both sides of each boundary. The table
         starts at column 5 of the rows; a slot past the lease names block
         0."""
@@ -658,23 +661,19 @@ class TestDevicePool:
         assert rows[1, 5:5 + held].tolist() == lease.blocks[:held]
         assert not rows[1, 5 + held:].any() and not rows[0, 6:].any()
 
-    def test_past_two_tiles_a_bucket_runs_one_program(self, tiny_model):
-        """Blocks of 2 positions (a tile holds 32, the widths past two
-        tiles are 512 and 4 096 positions): pairs whose longer row holds
-        75, 190 and 375 positions, then one row of 120 alone, all run at a
-        width of 512, so the engine makes ready one decode program a batch
-        bucket where a width by powers of two made ready 256 and 512 for
-        the pairs alone. The tokens are the reference's: rows of unequal
-        length through the tiles, a pad row beside the last."""
-        params, cfg = tiny_model
-        engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+    @staticmethod
+    def _decode_programs(params, cfg, max_batch, waves):
+        """Serve ``waves`` of prompt lengths one after another at blocks of
+        2 positions, every request 3 tokens, held to the reference's:
+        the decode shapes the engine ran, and the decode programs it made
+        ready by its counter and by its cache."""
+        engine = GenEngine(params, cfg, max_batch=max_batch, queue_limit=8,
                            max_new_tokens=4, kv_mb=1, block_tokens=2)
         name = labeled("gen_new_shapes_total", stage="decode")
-        waves = [[_prompt(cfg, n, seed=n) for n in wave]
-                 for wave in ((75, 40), (190, 9), (375, 130), (120,))]
         before = HUB.snapshot()
         try:
-            for prompts in waves:
+            for wave in waves:
+                prompts = [_prompt(cfg, n, seed=n) for n in wave]
                 reqs = _drive(engine, prompts, 3)
                 while engine._flight is not None \
                         or engine._snapshot_running():
@@ -687,9 +686,70 @@ class TestDevicePool:
             made = HUB.snapshot()[name] - before.get(name, 0)
         finally:
             engine.stop()
-        assert shapes == [("decode", 1, 512), ("decode", 2, 512)]
-        assert made == engine._jdecode._cache_size() == 2
         assert engine.pool.in_use_blocks == 0
+        return shapes, made, engine._jdecode._cache_size()
+
+    def test_past_two_tiles_a_bucket_runs_one_program(self, tiny_model):
+        """Blocks of 2 positions (a tile holds 32, the widths past two
+        tiles are 512 and 4 096 positions): pairs whose longer row holds
+        75, 190 and 375 positions, then one row of 120 alone, all run at a
+        width of 512, so the engine makes ready one decode program a batch
+        bucket where a width by powers of two made ready 256 and 512 for
+        the pairs alone. The tokens are the reference's: rows of unequal
+        length through the tiles."""
+        shapes, made, cached = self._decode_programs(
+            *tiny_model, 2, ((75, 40), (190, 9), (375, 130), (120,)))
+        assert shapes == [("decode", 1, 512), ("decode", 2, 512)]
+        assert made == cached == 2
+
+    def test_up_to_two_tiles_a_bucket_runs_one_program(self, tiny_model):
+        """Blocks of 2 positions (two tiles hold 64): three rows whose
+        longest holds 3, 9, 20 and 60 positions, then one row of 13 alone,
+        all run at a width of 64, so the engine makes ready one decode
+        program a batch bucket where a width by powers of two made ready
+        one for every doubling of the longest row. The tokens are the
+        reference's: rows of unequal length in the rectangle, which masks
+        what a row does not own, and a pad row beside every three."""
+        shapes, made, cached = self._decode_programs(
+            *tiny_model, 3, ((3, 2, 1), (9, 4, 2), (20, 11, 5),
+                             (60, 33, 17), (13,)))
+        assert shapes == [("decode", 1, 64), ("decode", 4, 64)]
+        assert made == cached == 2
+
+    @pytest.mark.parametrize("name", ["exaone_moe", "qwen3_next",
+                                      "phi4flash"])
+    def test_every_family_reads_two_tiles_whatever_its_rows_hold(self, name):
+        """The families whose step over a narrow table is not the llama's
+        (``exaone_moe``'s window layers take their 5 slots as a slice of
+        the table's 32, ``qwen3_next`` and ``phi4flash`` keep slots beside
+        the pages): rows of 20, 9 and 3 positions and a pad row through
+        one program of 32 slots of 2 positions. Every token served is the
+        first choice of the module's own prefill over the sequence so far,
+        which reads no table."""
+        from demodel_tpu.models import exaone_moe
+
+        module, params, cfg = _tiny(name)
+        assert exaone_moe.window_slots(8, 2) == 5
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
+                           max_new_tokens=4, kv_mb=4, block_tokens=2)
+        prompts = [_prompt(cfg, n, seed=n) for n in (20, 9, 3)]
+        try:
+            reqs = _drive(engine, prompts, 4)
+            while engine._flight is not None or engine._snapshot_running():
+                engine._decode_step()
+            outs = [req.result(5) for req in reqs]
+            shapes = {s for s in engine._shapes_run if s[0] == "decode"}
+        finally:
+            engine.stop()
+        assert shapes == {("decode", 4, 64)}
+        first = jax.jit(
+            lambda tokens: module.step_prefill(params, tokens, cfg)[0])
+        for prompt, out in zip(prompts, outs):
+            for i, token in enumerate(out):
+                logits = first(jnp.asarray([prompt + out[:i]]))
+                assert int(np.asarray(logits)[0].argmax()) == token
+        kv = engine.pool.describe()
+        assert kv["in_use_blocks"] == 0 and not kv.get("in_use_slots")
 
     def test_every_family_is_handed_the_pool_and_its_table(self,
                                                            monkeypatch):

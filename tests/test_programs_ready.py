@@ -88,7 +88,7 @@ class TestProgramReady:
 
     @pytest.mark.parametrize("stage,shape,parent", [
         ("prefill", {"prompt": 5}, "serve.prefill-device"),
-        ("decode", {"batch": 1, "width": 16}, "serve.decode-device")])
+        ("decode", {"batch": 1, "width": 512}, "serve.decode-device")])
     def test_one_span_a_stage_with_shape_phases_and_how(self, served, stage,
                                                         shape, parent):
         [span] = [s for s in served["spans"] if s["attrs"]["stage"] == stage]
@@ -140,7 +140,7 @@ class TestProgramReady:
                 v for name, v in hub.items()
                 if f'phase="{phase}"' in name), abs=1e-5)
         last = programs["last"]
-        assert (last["stage"], last["shape"]) == ("decode", [1, 16])
+        assert (last["stage"], last["shape"]) == ("decode", [1, 512])
         assert last["how"] in ("loaded", "compiled") and last["backend_s"] > 0
 
     def test_counted_with_observability_off(self, monkeypatch):
