@@ -13,6 +13,7 @@ from __future__ import annotations
 import functools
 import json
 
+from demodel_tpu.models import axk1 as axk1_mod
 from demodel_tpu.models import bert as bert_mod
 from demodel_tpu.models import exaone_moe as exaone_moe_mod
 from demodel_tpu.models import gpt2 as gpt2_mod
@@ -20,6 +21,7 @@ from demodel_tpu.models import llama as llama_mod
 from demodel_tpu.models import phi4flash as phi4flash_mod
 from demodel_tpu.models import qwen3_next as qwen3_next_mod
 from demodel_tpu.models.hf_loader import (
+    load_axk1_params,
     load_bert_params,
     load_exaone_moe_params,
     load_gpt2_params,
@@ -81,10 +83,14 @@ def model_from_pull(store, report, mesh=None, placement=None):
         cfg = phi4flash_mod.Phi4FlashConfig.from_hf(config)
         params = load_phi4flash_params(weights, cfg, mesh=mesh)
         fn = None   # served through its step functions only
+    elif model_type == "axk1":
+        cfg = axk1_mod.AxK1Config.from_hf(config)
+        params = load_axk1_params(weights, cfg, mesh=mesh)
+        fn = None   # served through its step functions only
     else:
         raise ValueError(f"unsupported model_type {model_type!r} "
                          "(supported: llama, gpt2, bert, exaone_moe, "
-                         "qwen3_next, phi4flash)")
+                         "qwen3_next, phi4flash, axk1)")
     log.info("auto: built %s from pulled snapshot (%d tensors)",
              model_type, n_tensors)
     return fn, params, cfg

@@ -16,15 +16,25 @@ from demodel_tpu.utils.env import env_bool
 
 
 def refuse_unsupported(config: dict, fields=(
-        "rope_scaling", "sliding_window", "attention_bias")) -> None:
+        "rope_scaling", "sliding_window", "attention_bias"),
+        only: dict | None = None) -> None:
     """For a family's ``from_hf``: refuse a ``config.json`` whose ``fields``
-    are set (non-null, non-false), which change numerics in ways that
-    family does not implement — rather than drift."""
+    are set (non-null, non-false), or whose key of ``only`` has another
+    value than the one the family implements (``a.b`` is key ``b`` of the
+    group ``a``; a key left out has that value), which change numerics in
+    ways that family does not implement — rather than drift."""
     for fld in fields:
         v = config.get(fld)
         if v not in (None, False):
             raise ValueError(
                 f"config field {fld}={v!r} is not supported by this stack")
+    for fld, value in (only or {}).items():
+        group, _, key = fld.rpartition(".")
+        v = (config.get(group) or {} if group else config).get(key, value)
+        if v != value:
+            raise ValueError(
+                f"config field {fld}={v!r} is not supported by this stack "
+                f"(only {value!r})")
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
@@ -45,14 +55,20 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
            scale: float | None = None):
     """Causal attention of new queries over their own keys and, when
     ``past`` is given, over a paged cache read where it lies: q [B, T, H,
-    hd], k and v [B, T, Hkv, hd] at ``positions`` [B, T] → [B, T, H * hd].
+    hd], k [B, T, Hkv, hd] and v [B, T, Hkv, vd] at ``positions`` [B, T] →
+    [B, T, H * vd]. The values' width ``vd`` need not be the keys' (a
+    latent layer's expanded heads have keys of 192 and values of 128, its
+    absorbed ones keys of 576 and values of 512).
     The query heads are grouped by KV head in the products, so k and v are
     never repeated. ``window`` 0 sees every earlier key; otherwise a key
     ``window`` or more behind is not seen. ``past`` is ``(k, v, kpos,
     live)``: cached keys and values as the pool holds them, [B, m, Hkv,
     block_tokens, hd] (``kvcache.Paged.read``), the positions [B, m *
     block_tokens] of their slots and which of those hold the row's own
-    (a row with none live, a pad row, sees only its new key). One softmax
+    (a row with none live, a pad row, sees only its new key). A past whose
+    ``v`` is None comes from a page of one array: its values are the first
+    ``vd`` columns of its keys, taken from the blocks gathered for the
+    scores and not gathered again. One softmax
     in float32 over cached and new keys, probabilities in q's dtype. The
     scores are scaled by ``scale``, ``hd ** -0.5`` where none is given.
 
@@ -61,7 +77,7 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
     softmax runs over those alone, a chunk of tiles a trip: the rectangle
     is the case in which nothing can be skipped."""
     B, T, H, hd = q.shape
-    Hkv = k.shape[2]
+    Hkv, vd = k.shape[2], v.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
     q = q.reshape(B, T, Hkv, H // Hkv, hd)
 
@@ -82,6 +98,8 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
         out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
     else:
         pk, pv, kpos, live = past
+        if pv is None:
+            pv = pk[..., :vd]
         m, c = pk.shape[1], pk.shape[3]
         s_past = jnp.einsum("bqkgd,bmkcd->bkgqmc", q, pk).reshape(
             B, Hkv, H // Hkv, T, m * c)
@@ -93,7 +111,7 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
         out = jnp.einsum("bkgqmc,bmkcd->bqkgd",
                          p[..., :m * c].reshape(*p.shape[:4], m, c), pv) \
             + jnp.einsum("bkgqs,bskd->bqkgd", p[..., m * c:], v)
-    return out.reshape(B, T, H * hd)
+    return out.reshape(B, T, H * vd)
 
 
 def _over_tiles(q, s_new, v, tiles, scale):
@@ -106,8 +124,10 @@ def _over_tiles(q, s_new, v, tiles, scale):
     weigh, cast to q's dtype before that product; its largest score; the
     sum of the exponentials below it); a row's tiles and its new keys are
     combined at the end. A row with no tile filled sees its new keys
-    only."""
-    B, T, Hkv, g, hd = q.shape
+    only. The values are ``vd`` wide, as ``v`` is; tiles with no ``v`` of
+    their own give the first ``vd`` columns of their keys."""
+    B, T, Hkv, g, _hd = q.shape
+    vd = v.shape[-1]
     C, n = tiles.row.shape[0], tiles.chunk_tiles
     f32 = jnp.float32
 
@@ -128,7 +148,9 @@ def _over_tiles(q, s_new, v, tiles, scale):
             ids, pq = lax.optimization_barrier((ids, pq))
         o = jnp.einsum("nkgqmc,nmkcd->nkgqd",
                        pq.reshape(n, Hkv, g, T, m, c),
-                       tiles.blocks(tiles.v, ids),
+                       # a page of one array: columns of the keys in hand
+                       pk[..., :vd] if tiles.v is None
+                       else tiles.blocks(tiles.v, ids),
                        preferred_element_type=f32)
         new = jnp.concatenate([o, top, p.sum(axis=-1, keepdims=True)],
                               axis=-1)
@@ -141,21 +163,21 @@ def _over_tiles(q, s_new, v, tiles, scale):
         # folds it into a constant of its size)
         acc = lax.fori_loop(
             jnp.uint32(0), tiles.trips, trip, jnp.broadcast_to(
-                jnp.zeros((hd + 2,), f32).at[hd].set(-1e30),
-                (C, Hkv, g, T, hd + 2)))
+                jnp.zeros((vd + 2,), f32).at[vd].set(-1e30),
+                (C, Hkv, g, T, vd + 2)))
         # a row's tiles, side by side: [B, tiles a row]
         own = (tiles.own >= 0)[..., None, None, None]
         acc = acc.at[jnp.maximum(tiles.own, 0)].get(mode="promise_in_bounds")
-        tops = jnp.where(own, acc[..., hd], -1e30)      # [B, n, Hkv, g, T]
+        tops = jnp.where(own, acc[..., vd], -1e30)      # [B, n, Hkv, g, T]
         top = jnp.maximum(tops.max(axis=1), s_new.max(axis=-1))
         w = jnp.where(own, jnp.exp(tops - top[:, None]), 0.0)
         p_new = jnp.exp(s_new - top[..., None])
-        total = (w * acc[..., hd + 1]).sum(axis=1) + p_new.sum(axis=-1)
-        out = (w[..., None] * acc[..., :hd]).sum(axis=1) + jnp.einsum(
+        total = (w * acc[..., vd + 1]).sum(axis=1) + p_new.sum(axis=-1)
+        out = (w[..., None] * acc[..., :vd]).sum(axis=1) + jnp.einsum(
             "bkgqs,bskd->bkgqd", p_new.astype(q.dtype), v,
             preferred_element_type=f32)
         out = (out / total[..., None]).astype(q.dtype)
-    return out.transpose(0, 3, 1, 2, 4)                  # [B, T, Hkv, g, hd]
+    return out.transpose(0, 3, 1, 2, 4)                  # [B, T, Hkv, g, vd]
 
 
 def use_flash_attention() -> bool:

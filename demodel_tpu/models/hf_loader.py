@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from demodel_tpu.models import exaone_moe, phi4flash, qwen3_next
+from demodel_tpu.models import axk1, exaone_moe, phi4flash, qwen3_next
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
 from demodel_tpu.models.llama import LlamaConfig, param_shardings
@@ -180,6 +180,101 @@ def load_exaone_moe_params(weights: dict, cfg: "exaone_moe.ExaoneMoeConfig",
                                    "router_bias").astype(jnp.float32),
                 "experts_gate_up": held(("gate", "up"),
                                            "experts_gate_up"),
+                "experts_down": held(("down",), "experts_down"),
+                "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
+                                        "shared_gate_proj"),
+                "shared_up_proj": lin("mlp.shared_experts.up_proj.weight",
+                                      "shared_up_proj"),
+                "shared_down_proj": lin("mlp.shared_experts.down_proj.weight",
+                                        "shared_down_proj"),
+            })
+        else:
+            layer.update({
+                "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+                "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+                "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _head_splitter(heads: int, first: int, by_head: bool, sharding):
+    """Jitted: a ``[heads * (first + rest), in]`` matrix whose rows lie head
+    by head, each head's ``first`` rows before its ``rest`` → the two
+    parts, each ``[heads, in, width]`` (``by_head``) or ``[heads * width,
+    in]`` (its rows, as they lay)."""
+    def split(x):
+        x = x.reshape(heads, -1, x.shape[1])
+        parts = x[:, :first], x[:, first:]
+        if by_head:
+            return tuple(p.transpose(0, 2, 1) for p in parts)
+        return tuple(p.reshape(-1, x.shape[2]) for p in parts)
+
+    return jax.jit(split, out_shardings=(sharding, sharding))
+
+
+def load_axk1_params(weights: dict, cfg: "axk1.AxK1Config",
+                     mesh=None) -> dict:
+    """The tree of :func:`axk1.init_params` from a checkpoint of the
+    DeepSeek-V3 style of names, holding one share of the experts under
+    their global indices. ``kv_b_proj`` (a head's 128 key rows before its
+    128 value rows) is split by head into ``w_uk`` and ``w_uv``, which the
+    expanded prefill and the absorbed decode both read, ``q_b_proj`` (a
+    head's 128 unrotated rows before its 64 rotary ones) into the two
+    kinds of column; the experts are
+    stacked as :func:`load_exaone_moe_params` stacks them. A selection
+    bias in the checkpoint is refused: the module implements
+    ``topk_method`` ``none``, which has none."""
+    w = _Weights(weights)
+    sh = axk1.param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def held(projs, leaf):
+            return _stack_experts(w, pre, projs, cfg, lsh.get(leaf))
+
+        if w.has(pre + "mlp.gate.e_score_correction_bias"):
+            raise ValueError(
+                f"checkpoint tensor {pre}mlp.gate.e_score_correction_bias: "
+                "a selection bias is not supported by this stack "
+                "(topk_method none)")
+        H, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+        w_uk, w_uv = _head_splitter(H, nope, True, lsh.get("w_uk"))(
+            w.get(pre + "self_attn.kv_b_proj.weight"))
+        q_b_nope, q_b_rope = _head_splitter(
+            H, nope, False, lsh.get("q_b_nope"))(
+            w.get(pre + "self_attn.q_b_proj.weight"))
+        layer = {
+            "q_a_proj": lin("self_attn.q_a_proj.weight", "q_a_proj"),
+            "q_a_norm": vec("self_attn.q_a_layernorm.weight", "q_a_norm"),
+            "q_b_nope": q_b_nope, "q_b_rope": q_b_rope,
+            "kv_a_proj": lin("self_attn.kv_a_proj_with_mqa.weight",
+                             "kv_a_proj"),
+            "kv_a_norm": vec("self_attn.kv_a_layernorm.weight", "kv_a_norm"),
+            "w_uk": w_uk, "w_uv": w_uv,
+            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            "attn_norm": vec("input_layernorm.weight", "attn_norm"),
+            "mlp_norm": vec("post_attention_layernorm.weight", "mlp_norm"),
+        }
+        if i >= cfg.first_k_dense_replace:
+            layer.update({
+                "router": lin("mlp.gate.weight", "router"),
+                "experts_gate_up": held(("gate", "up"), "experts_gate_up"),
                 "experts_down": held(("down",), "experts_down"),
                 "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
                                         "shared_gate_proj"),

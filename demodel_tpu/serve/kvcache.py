@@ -1,6 +1,7 @@
-"""The generation cache: paged K and V, and fixed slots of what a sequence
-keeps whatever its length (recurrent state, a window layer's ring of its
-last positions), in one preallocated pool on the device.
+"""The generation cache: paged K and V (or a latent layer's one vector a
+position), and fixed slots of what a sequence keeps whatever its length
+(recurrent state, a window layer's ring of its last positions), in one
+preallocated pool on the device.
 
 vLLM's PagedAttention memory discipline grafted onto the repo's tier
 accounting: the pool preallocates ``num_blocks`` blocks of
@@ -28,6 +29,18 @@ layer pages, no slot", and their pool is ``k`` and ``v`` alone; Qwen3-Next
 pages its attention layers and keeps a slot of recurrent state; Phi-4-flash
 pages one layer (which seven more read), and its slot holds eight rings,
 nine state-space states and their convolutions' tails.
+
+**A page of one array.** A latent-attention layer keeps one vector a
+position, ``[c_kv | k_rope]``, whose first ``values`` columns are also its
+values: its module says so in its spec (``CacheSpec.values``), and the pool
+then holds **one** array of pages, ``k`` (``[L, num_blocks + 1, kv_heads,
+block_tokens, head_dim]`` like any other; ``v`` is ``None``), which it
+leases, budgets (``block_bytes`` counts it once), describes and donates as
+it does the pair. Everything below that speaks of ``k`` and ``v`` takes
+``v=None`` for such a page: :meth:`Paged.read` and :meth:`Paged.past` hand
+the blocks over once and ``models/common.attend`` takes the values as the
+leading columns of the keys it has gathered; :func:`put_blocks` and
+:func:`put_positions` write the one array.
 
 The pool is two halves that never touch each other:
 
@@ -117,12 +130,17 @@ class CacheSpec(NamedTuple):
     (a recurrent state, a convolution's tail, a ring ``[layers, window /
     block_tokens, kv_heads, block_tokens, head_dim]`` of a window layer's
     last positions) is the module's to know: the pool holds it, leases it
-    with the slot and counts its bytes."""
+    with the slot and counts its bytes. ``values`` 0 is a page of keys and
+    of values, two arrays of ``head_dim`` columns; otherwise the page is
+    one array whose ``head_dim`` columns are a position's keys and whose
+    first ``values`` columns are also its values (a latent layer's ``[c_kv
+    | k_rope]``, one "head" all query heads share)."""
 
     layers: int
     kv_heads: int
     head_dim: int
     state: tuple[tuple[str, tuple[int, ...], str], ...] = ()
+    values: int = 0
 
 
 class PoolExhausted(Exception):
@@ -178,9 +196,11 @@ class KVBlockPool:
         budget_bytes = int(budget_mb if budget_mb is not None
                            else gen_kv_mb()) << 20
         dt = jnp.dtype(dtype)
-        # K + V, every layer that pages, one block of token positions
-        self.block_bytes = (2 * layers * self.block_tokens * kv_heads
-                            * head_dim * dt.itemsize)
+        #: arrays a page is made of: K and V, or a latent layer's one
+        self.pages = 1 if spec.values else 2
+        # every layer that pages, one block of token positions
+        self.block_bytes = (self.pages * layers * self.block_tokens
+                            * kv_heads * head_dim * dt.itemsize)
         #: one sequence's fixed state, all its arrays
         self.slot_bytes = sum(
             math.prod(shape) * jnp.dtype(sdt).itemsize
@@ -206,20 +226,21 @@ class KVBlockPool:
             #: where the engine puts a program's small per-call inputs
             self.replicated = NamedSharding(mesh, P())
         #: names of the slot's arrays, in the order :attr:`arrays` holds
-        #: them after ``k`` and ``v``
+        #: them after the pages
         self.state_names = tuple(name for name, _s, _d in spec.state)
-        made = [(shape, dt), (shape, dt)] + [
+        made = [(shape, dt)] * self.pages + [
             ((s[0], self.num_slots + 1, *s[1:]), jnp.dtype(sdt))
             for _name, s, sdt in spec.state]
         #: what every program returns the arrays with, in their order
-        self.shardings = (self.sharding, self.sharding) \
+        self.shardings = (self.sharding,) * self.pages \
             + (self.replicated,) * len(spec.state)
         # a program's output, like every later pool: see the module
         # docstring ("one signature for life")
         self._fresh = jax.jit(
             lambda: tuple(jnp.zeros(s, d) for s, d in made),
             out_shardings=self.shardings)
-        #: ``(k, v, *state)``: engine thread only
+        #: ``(k, v, *state)``, or ``(k, *state)`` where the page is one
+        #: array: engine thread only
         self.arrays = self._fresh()
         self.budget = TierBudget("gen-kv", budget_bytes)
         self._free_list = list(range(self.num_blocks - 1, -1, -1))
@@ -301,16 +322,18 @@ class KVBlockPool:
 
     @property
     def v(self):
-        return self.arrays[1]
+        """None where the page is one array (its values are columns of
+        ``k``)."""
+        return self.arrays[1] if self.pages == 2 else None
 
     @property
     def state(self) -> dict:
         """The slot's arrays by name, ``[layers, slots + 1, ...]`` each."""
-        return dict(zip(self.state_names, self.arrays[2:]))
+        return dict(zip(self.state_names, self.arrays[self.pages:]))
 
     def apply(self, program, *args):
         """Run one of the engine's programs over the arrays:
-        ``program(*args, k, v, *state) -> (out, k, v, *state)`` with every
+        ``program(*args, *arrays) -> (out, *arrays)`` with every
         array donated and returned with :attr:`shardings`. The program's
         outputs become the pool, so nothing keeps a reference to the arrays
         that went in. Engine thread only."""
@@ -335,6 +358,11 @@ class KVBlockPool:
             free = len(self._free_list)
             free_slots = len(self._free_slots)
         return {
+            # what a position keeps: keys and values, or a latent layer's
+            # one vector (``value_dim`` of its ``head_dim`` columns its
+            # values too)
+            "page": "latent" if self.spec.values else "kv",
+            "value_dim": self.spec.values or self.spec.head_dim,
             "block_tokens": self.block_tokens,
             "block_bytes": self.block_bytes,
             "num_blocks": self.num_blocks,
@@ -373,7 +401,7 @@ class Paged(NamedTuple):
     whole (``read(layer, table)``) would pay for the width."""
 
     k: jax.Array
-    v: jax.Array
+    v: Any                  # None: a page of one array, values inside k
     table: jax.Array        # [B, n] block ids
     state: dict = {}        # name -> [layers, slots + 1, ...]
     slots: Any = None       # [B] slot ids
@@ -384,11 +412,12 @@ class Paged(NamedTuple):
 
     def read(self, layer: int, ids):
         """Blocks ``ids`` [B, m] of one layer as the pool holds them:
-        ``(k, v)``, each [B, m, Hkv, block_tokens, hd], not transposed. The
-        caller masks what a row does not own. One gather from the pool
-        itself (layers and blocks as one axis, which costs nothing): a
-        slice of one layer first is a copy of it, the whole pool a step
-        over all layers."""
+        ``(k, v)``, each [B, m, Hkv, block_tokens, hd], not transposed
+        (``v`` None where the page is one array: gathered once, the values
+        are columns of ``k``). The caller masks what a row does not own.
+        One gather from the pool itself (layers and blocks as one axis,
+        which costs nothing): a slice of one layer first is a copy of it,
+        the whole pool a step over all layers."""
         L, nb = self.k.shape[:2]
         at = ids + layer * nb
 
@@ -396,7 +425,7 @@ class Paged(NamedTuple):
             return jnp.take(a.reshape(L * nb, *a.shape[2:]), at, axis=0,
                             mode="clip")
 
-        return blocks(self.k), blocks(self.v)
+        return blocks(self.k), None if self.v is None else blocks(self.v)
 
     @property
     def wide(self) -> bool:
@@ -431,7 +460,8 @@ class Paged(NamedTuple):
         L, nb = self.k.shape[:2]
         return filled._replace(
             k=self.k.reshape(L * nb, *self.k.shape[2:]),
-            v=self.v.reshape(L * nb, *self.v.shape[2:]),
+            v=None if self.v is None
+            else self.v.reshape(L * nb, *self.v.shape[2:]),
             ids=filled.ids + layer * nb)
 
     def read_state(self, name: str, layer: int):
@@ -461,7 +491,9 @@ class Tiles(NamedTuple):
     past :func:`models.common.attend` runs over a chunk at a time, never
     over the rectangle. ``ids`` [C, TILE_BLOCKS] names each tile's blocks
     in ``k`` and ``v`` ([N, Hkv, block_tokens, hd]: the pool, layers and
-    blocks as one axis), gathered a chunk a trip from where they lie. A
+    blocks as one axis; ``v`` None where the page is one array and the
+    values are columns of the keys), gathered a chunk a trip from where
+    they lie. A
     tile past the filled ones repeats the last of them, so no block wholly
     past a row's length is ever read. A trip of a loop over the chunks
     costs a handful of device operations whatever it moves, so the chunks
@@ -490,8 +522,9 @@ class Tiles(NamedTuple):
         """A chunk of keys and one of values do not fit fast memory
         together: the compiler would leave one of them in HBM, so a trip
         gathers the values when it is done with the keys."""
-        return 2 * self.chunk_tiles * TILE_BLOCKS * math.prod(
-            self.k.shape[1:]) * self.k.dtype.itemsize > FAST_BYTES
+        return self.v is not None and 2 * self.chunk_tiles * TILE_BLOCKS \
+            * math.prod(self.k.shape[1:]) * self.k.dtype.itemsize \
+            > FAST_BYTES
 
     def chunk(self, i):
         """Chunk ``i``: its tiles' block ids [n * TILE_BLOCKS] and their
@@ -607,7 +640,9 @@ def put_blocks(k, v, kv, blocks):
     """A prefill's KV into its lease: ``kv`` is ``step_prefill``'s
     per-layer ``(k, v)``, each [1, T, Hkv, hd]; ``blocks`` the lease's
     first ``ceil(T / block_tokens)`` ids. The tail of the last block is
-    written with zeros (it is the lease's own, and past its length)."""
+    written with zeros (it is the lease's own, and past its length).
+    ``v`` None is a page of one array: ``kv`` is then a layer's one new
+    array each, and what comes back is ``(k,)``."""
     L, _nb, Hkv, bs, hd = k.shape
     n = blocks.shape[0]
 
@@ -618,6 +653,8 @@ def put_blocks(k, v, kv, blocks):
         return a.at[:, blocks].set(new, mode="promise_in_bounds",
                                    unique_indices=True)
 
+    if v is None:
+        return (put(k, jnp.stack(kv)),)
     nk, nv = _stack(kv)
     return put(k, nk), put(v, nv)
 
@@ -627,7 +664,8 @@ def put_positions(k, v, new_kv, blocks, offsets):
     ``(k, v)``, each [B, 1, Hkv, hd]; row ``b`` lands in block
     ``blocks[b]`` at slot ``offsets[b]``. One in-place slice update a
     row: a scatter makes the TPU compiler copy the whole pool into
-    another layout and back (PERF.md, Findings, PR 26)."""
+    another layout and back (PERF.md, Findings, PR 26). ``v`` None is a
+    page of one array, as in :func:`put_blocks`."""
     L, _nb, Hkv, _bs, hd = k.shape
 
     def put(a, new):
@@ -637,6 +675,8 @@ def put_positions(k, v, new_kv, blocks, offsets):
                 (0, blocks[b], 0, offsets[b], 0))
         return a
 
+    if v is None:
+        return (put(k, jnp.stack(new_kv)),)
     nk, nv = _stack(new_kv)
     return put(k, nk), put(v, nv)
 

@@ -281,11 +281,13 @@ class GenEngine:
         # *stats)`` and ``step_decode(params, tokens, cfg, cache, lengths,
         # mesh=) -> (logits, new, *stats)``, ``cache`` a ``kvcache.Paged``
         # (the pool's arrays, the batch's block table and slots) for every
-        # module, ``new`` the layers' new keys and values, inside a
-        # ``kvcache.Written`` with what goes into the slots where the
-        # module keeps such state. ``stats`` (small arrays, or none) come
-        # back with the chosen ids and go to the module's ``observe``, which
-        # counts them and names the step span's attributes.
+        # module, ``new`` the layers' new keys and values (a layer's one
+        # new array where the spec's page is one array, and ``cache.v`` is
+        # then None), inside a ``kvcache.Written`` with what goes into the
+        # slots where the module keeps such state. ``stats`` (small arrays,
+        # or none) come back with the chosen ids and go to the module's
+        # ``observe``, which counts them and names the step span's
+        # attributes.
         module = sys.modules[type(cfg).__module__]
         if not hasattr(module, "step_decode"):
             raise ValueError(
@@ -336,7 +338,15 @@ class GenEngine:
         #: int32, where the pool has slots; nothing is shipped where not
         self._slotted = int(bool(names))
 
-        def prefill(p, tokens, blocks, k, v, *state):
+        def held(arrays):
+            """``(k, v, state)`` of the pool's arrays, ``v`` None where
+            the page is one array (a latent layer's)."""
+            if pool.pages == 1:
+                return arrays[0], None, arrays[1:]
+            return arrays[0], arrays[1], arrays[2:]
+
+        def prefill(p, tokens, blocks, *arrays):
+            k, v, state = held(arrays)
             logits, new, *stats = module.step_prefill(p, tokens, cfg,
                                                      mesh=mesh)
             kv, fresh = kvcache.parts(new)
@@ -346,7 +356,8 @@ class GenEngine:
             return ((choose(logits, 1), (logits, *stats)),
                     *kvcache.put_blocks(k, v, kv, blocks), *state)
 
-        def decode(p, rows, prev_ids, k, v, *state):
+        def decode(p, rows, prev_ids, *arrays):
+            k, v, state = held(arrays)
             # one int32 row a sequence (see _decode_inputs)
             lit, lens, wblocks, woffsets, src = (rows[:, i]
                                                  for i in range(5))

@@ -3,7 +3,8 @@
 family, every metric file's reader, a fixture family through generator,
 hub, reference and ``run.py`` up to the engine) and of
 ``benchmark/tests/test_exaone_moe_family.py``,
-``test_qwen3_next_family.py`` and ``test_phi4flash_family.py`` but their rehearsed runs, which take minutes. The files stay where the benchmark keeps them; this module
+``test_qwen3_next_family.py``, ``test_phi4flash_family.py`` and
+``test_axk1_family.py`` but their rehearsed runs, which take minutes. The files stay where the benchmark keeps them; this module
 only gives them a name under ``tests/``.
 
 One case is replaced: the seam's test of an unknown ``model_type`` names
@@ -36,7 +37,7 @@ def _cases_of(name: str) -> dict:
 
 globals().update(_cases_of("test_seam"))
 for _family in ("test_exaone_moe_family", "test_qwen3_next_family",
-                "test_phi4flash_family"):
+                "test_phi4flash_family", "test_axk1_family"):
     globals().update({k: v for k, v in _cases_of(_family).items()
                       if "rehears" not in k})
 

@@ -13,7 +13,12 @@ program pins its digests again, and says so. PR 41 did for the six
 9, 5 and 3 positions step through 32 slots where they stepped through 4.
 ``decode-past-16-blocks`` is from PR 41's parent, where only a longest row
 past 16 blocks had a table of 32 slots: the program every shorter row now
-runs is that one, text for text.
+runs is that one, text for text. ``decode-past-two-tiles`` is from PR 42's
+parent: what that PR added to ``common._over_tiles`` (values narrower than
+the keys, tiles with no values of their own) left the loop over the filled
+tiles of every family that pages K and V as it was, down to the order of
+its operations (a gather moved ahead of a reshape had made it another
+program, which the machine's compile cache would not have known).
 """
 
 from __future__ import annotations
@@ -37,21 +42,27 @@ FAMILIES = {"llama": (llama, llama.LlamaConfig),
 PINNED = {
     ("llama", "float32", "decode"): "ff3c2a1e16526eb9",
     ("llama", "float32", "decode-past-16-blocks"): "ff3c2a1e16526eb9",
+    ("llama", "float32", "decode-past-two-tiles"): "de10f278fba446b1",
     ("llama", "float32", "prefill"): "2384c9807e24cde3",
     ("llama", "bfloat16", "decode"): "fecca8e7814f2856",
     ("llama", "bfloat16", "decode-past-16-blocks"): "fecca8e7814f2856",
+    ("llama", "bfloat16", "decode-past-two-tiles"): "185d9f2da608cc06",
     ("llama", "bfloat16", "prefill"): "b9db3f7fcec8e591",
     ("exaone_moe", "float32", "decode"): "67b0d27ade84e175",
     ("exaone_moe", "float32", "decode-past-16-blocks"): "67b0d27ade84e175",
+    ("exaone_moe", "float32", "decode-past-two-tiles"): "295798baeed1830c",
     ("exaone_moe", "float32", "prefill"): "f60f53665d09d6e1",
     ("exaone_moe", "bfloat16", "decode"): "27c74dc4fd4b723b",
     ("exaone_moe", "bfloat16", "decode-past-16-blocks"): "27c74dc4fd4b723b",
+    ("exaone_moe", "bfloat16", "decode-past-two-tiles"): "5784d46755a90206",
     ("exaone_moe", "bfloat16", "prefill"): "45542946a61fe728",
     ("qwen3_next", "float32", "decode"): "d424ee57b47bb163",
     ("qwen3_next", "float32", "decode-past-16-blocks"): "d424ee57b47bb163",
+    ("qwen3_next", "float32", "decode-past-two-tiles"): "c42f67b2817b66bb",
     ("qwen3_next", "float32", "prefill"): "d3bd2b0489d00d06",
     ("qwen3_next", "bfloat16", "decode"): "12ce9dce817a9064",
     ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "12ce9dce817a9064",
+    ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "831fdf7c7bf216a2",
     ("qwen3_next", "bfloat16", "prefill"): "9be76f1f541ba68d",
 }
 
@@ -59,13 +70,15 @@ PINNED = {
 def programs(module, cfg) -> dict[str, str]:
     """The lowered text of the engine's decode step (three rows of 9, 5 and
     3 cached positions in a bucket of four; ``decode-past-16-blocks``: of
-    70, 5 and 3, so that the longest row holds 18 blocks of 4) and of a
-    20-token prefill."""
+    70, 5 and 3, so that the longest row holds 18 blocks of 4;
+    ``decode-past-two-tiles``: of 140, 5 and 3, 35 blocks, so that the table
+    is 256 slots wide and the attention runs over the filled tiles) and of
+    a 20-token prefill."""
     params = module.init_params(jax.random.key(1), cfg)
     engine = GenEngine(params, cfg, max_batch=4, queue_limit=8,
                        max_new_tokens=8, kv_mb=1, block_tokens=4)
     pool = engine.pool
-    lease = pool.alloc(20)
+    lease = pool.alloc(40)
 
     def decode(*lengths):
         _w, rows = engine._decode_inputs(
@@ -79,6 +92,7 @@ def programs(module, cfg) -> dict[str, str]:
         return {
             "decode": decode(9, 5, 3),
             "decode-past-16-blocks": decode(70, 5, 3),
+            "decode-past-two-tiles": decode(140, 5, 3),
             "prefill": engine._jprefill.lower(
                 engine.params, np.zeros((1, 20), np.int32), blocks,
                 *pool.arrays).as_text()}

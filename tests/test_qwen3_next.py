@@ -511,5 +511,8 @@ def test_served_over_http_like_the_others(small, tmp_path):
         engine.stop()
         serve.install(None)
     assert got == want
+    # PR 42 added ``values`` (a page of one array), 0 for every family
+    # that pages K and V
     assert kvcache.CacheSpec._fields == ("layers", "kv_heads", "head_dim",
-                                         "state")
+                                         "state", "values")
+    assert qwen3_next.cache_spec(cfg).values == 0

@@ -1,0 +1,77 @@
+"""What the TPU's compiler makes of the latent page, compiled here for a
+chip that is described and not attached (no chip time, ~2 s): the reason
+``models/axk1.py`` states a page of 640 columns for a latent of 576.
+
+The chip holds an array whose innermost dimension is no multiple of its 128
+lanes with another dimension innermost (for ``[layers, blocks, 1, 16, 576]``
+the blocks), and a program that reads a block's positions by 576 columns
+then starts by copying the whole pool into that order: 2.4 GB a step at the
+benchmark's size, seen in the compiled decode step (PERF.md, Findings, PR
+42). At 640 columns the pool lies as it is read.
+"""
+
+from __future__ import annotations
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from demodel_tpu.models import axk1
+from demodel_tpu.serve import kvcache
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # what is compiled for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+#: the benchmark's pool: 2 520 MiB of blocks of 16 positions, and the scratch
+BLOCKS = 18432 + 1
+
+
+def _born(shape, sharding) -> list[int]:
+    """The order (innermost first) in which the chip holds a pool array of
+    ``shape`` born as a program's output, as ``KVBlockPool`` makes it."""
+    text = jax.jit(lambda: jnp.zeros(shape, jnp.bfloat16),
+                   out_shardings=sharding).lower().compile().as_text()
+    layout = re.search(r"entry_computation_layout=\{\(\)->[^{]*\{([\d,]+)",
+                       text)
+    return [int(d) for d in layout.group(1).split(",")]
+
+
+def test_the_latent_page_lies_as_it_is_read(one_chip):
+    cfg = axk1.AxK1Config(num_hidden_layers=7, dtype="bfloat16")
+    spec = axk1.cache_spec(cfg)
+    assert (spec.head_dim, spec.values, cfg.latent_dim) == (640, 512, 576)
+    assert spec.head_dim % axk1.LANES == 0
+    page = (spec.layers, BLOCKS, spec.kv_heads, 16, spec.head_dim)
+    # columns innermost, then a block's positions
+    assert _born(page, one_chip)[:2] == [4, 3]
+
+
+def test_a_page_of_576_columns_would_lie_blocks_innermost(one_chip):
+    """The finding itself (a pool of a thousand blocks is not laid out so,
+    one of four thousand and more is): should a later compiler lay 576
+    columns out as they are read, the page can shrink to the latent's own
+    width."""
+    assert _born((7, BLOCKS, 1, 16, 576), one_chip)[0] == 1
+    # a page of K and V at a head of 128 never had the question
+    assert _born((8, 4097, 8, 16, 128), one_chip)[:2] == [4, 3]
+    assert kvcache.CacheSpec(8, 8, 128).values == 0
