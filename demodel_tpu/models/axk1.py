@@ -477,7 +477,8 @@ def cache_spec(cfg: AxK1Config):
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, 1, cfg.page_dim,
-                     values=cfg.kv_lora_rank)
+                     values=cfg.kv_lora_rank, readers=cfg.num_hidden_layers,
+                     query_heads=cfg.num_attention_heads)
 
 
 def step_prefill(params, tokens, cfg: AxK1Config, mesh: Mesh | None = None):

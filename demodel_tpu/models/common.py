@@ -75,7 +75,9 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
     A paged past wider than two tiles a row comes as the tiles its rows
     have filled (``kvcache.Tiles``, from ``Paged.filled``) and the same
     softmax runs over those alone, a chunk of tiles a trip: the rectangle
-    is the case in which nothing can be skipped."""
+    is the case in which nothing can be skipped. Between the trips the
+    running softmax is kept a tile where that is little beside the tile's
+    own bytes, and a row where it is not (:func:`_over_tiles`)."""
     B, T, H, hd = q.shape
     Hkv, vd = k.shape[2], v.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
@@ -119,24 +121,36 @@ def _over_tiles(q, s_new, v, tiles, scale):
     cached prefixes, so every query sees every position its row holds): q
     [B, T, Hkv, g, hd], ``s_new`` the masked float32 scores of the new
     keys [B, Hkv, g, T, T], ``v`` their values. A trip of the loop takes a
-    chunk of tiles and leaves, for each, the running softmax's three in
-    float32, side by side in one array (the values its probabilities
-    weigh, cast to q's dtype before that product; its largest score; the
-    sum of the exponentials below it); a row's tiles and its new keys are
-    combined at the end. A row with no tile filled sees its new keys
-    only. The values are ``vd`` wide, as ``v`` is; tiles with no ``v`` of
-    their own give the first ``vd`` columns of their keys."""
+    chunk of tiles and has, for each, the running softmax's three in
+    float32 (the values its probabilities weigh, cast to q's dtype before
+    that product; its largest score; the sum of the exponentials below
+    it). What the loop carries of them is the index's to say, from bytes
+    (``kvcache.Tiles.by_row``). Where a tile's three are little beside the
+    keys and values they were taken from (grouped-query heads of 128 or
+    256 values) they are left **a tile**, where the tile lies in the list,
+    and a row's tiles and its new keys are combined at the end. Where they
+    are a large part of it (a latent page's one cached vector under 64
+    query heads with values of 512) the trip combines its tiles into their
+    rows and the loop carries **one running softmax a row**: the filled
+    tiles lie in row order, so the sum over a row's tiles of a chunk is a
+    product with the chunk's membership ``[rows, tiles]``, and the carry
+    meets the new keys as it is. A row with no tile filled sees its new
+    keys only. The values are ``vd`` wide, as ``v`` is; tiles with no ``v``
+    of their own give the first ``vd`` columns of their keys."""
     B, T, Hkv, g, _hd = q.shape
     vd = v.shape[-1]
     C, n = tiles.row.shape[0], tiles.chunk_tiles
     f32 = jnp.float32
 
-    def trip(i, acc):
+    def partials(i, queries):
+        """Chunk ``i``'s tiles under their rows' ``queries()`` [n, T, Hkv,
+        g, hd] (taken once the keys are gathered): ``(values [n, Hkv, g,
+        T, vd], largest score, sum [n, Hkv, g, T, 1])``, each tile's
+        own."""
         ids, live = tiles.chunk(i)
         pk = tiles.blocks(tiles.k, ids)
         m, c = pk.shape[1], pk.shape[3]
-        s = jnp.einsum("nqkgd,nmkcd->nkgqmc",
-                       lax.dynamic_slice_in_dim(q_tiles, i * n, n),
+        s = jnp.einsum("nqkgd,nmkcd->nkgqmc", queries(),
                        pk).reshape(n, Hkv, g, T, m * c)
         keep = live[:, None, None, None, :]
         s = jnp.where(keep, (s * scale).astype(f32), -1e30)
@@ -152,11 +166,46 @@ def _over_tiles(q, s_new, v, tiles, scale):
                        pk[..., :vd] if tiles.v is None
                        else tiles.blocks(tiles.v, ids),
                        preferred_element_type=f32)
-        new = jnp.concatenate([o, top, p.sum(axis=-1, keepdims=True)],
-                              axis=-1)
+        return o, top, p.sum(axis=-1, keepdims=True)
+
+    def trip(i, acc):
+        new = jnp.concatenate(partials(
+            i, lambda: lax.dynamic_slice_in_dim(q_tiles, i * n, n)),
+            axis=-1)
         return lax.dynamic_update_slice_in_dim(acc, new, i * n, axis=0)
 
+    def trip_rows(i, carry):
+        values, tops, sums = carry              # [B, Hkv, g, T, vd | 1 | 1]
+        rows = tiles.rows(i)
+        o, top, total = partials(
+            i, lambda: q.at[rows].get(mode="promise_in_bounds"))
+        # whose a tile is, [B, n, 1, 1, 1, 1]; a tile past the filled ones
+        # is its row's too, with the least score and no sum: no weight
+        own = (rows[None, :] == jnp.arange(B)[:, None])[
+            ..., None, None, None, None]
+        new = jnp.maximum(tops, jnp.where(own, top[None], -1e30).max(axis=1))
+        w = jnp.where(own, jnp.exp(top[None] - new[:, None]), 0.0)
+        old = jnp.exp(tops - new)
+        return (old * values + jnp.einsum(
+                    "bnkgq,nkgqd->bkgqd", w[..., 0], o,
+                    precision=lax.Precision.HIGHEST),
+                new, old * sums + (w * total[None]).sum(axis=1))
+
     with jax.named_scope("attn.tiles"):
+        if tiles.by_row(Hkv * g * T * (vd + 2) * jnp.dtype(f32).itemsize):
+            values, tops, sums = lax.fori_loop(
+                jnp.uint32(0), tiles.trips, trip_rows,
+                (jnp.zeros((B, Hkv, g, T, vd), f32),
+                 jnp.full((B, Hkv, g, T, 1), -1e30, f32),
+                 jnp.zeros((B, Hkv, g, T, 1), f32)))
+            top = jnp.maximum(tops, s_new.max(axis=-1, keepdims=True))
+            w = jnp.exp(tops - top)
+            p_new = jnp.exp(s_new - top)
+            total = w * sums + p_new.sum(axis=-1, keepdims=True)
+            out = w * values + jnp.einsum(
+                "bkgqs,bskd->bkgqd", p_new.astype(q.dtype), v,
+                preferred_element_type=f32)
+            return (out / total).astype(q.dtype).transpose(0, 3, 1, 2, 4)
         q_tiles = q.at[tiles.row].get(mode="promise_in_bounds")
         # a tile none has filled: no value, the least score, no weight (a
         # broadcast of one tile's: set in the whole array, the compiler

@@ -302,11 +302,13 @@ def _head(params, x, cfg):
 
 def cache_spec(cfg: ExaoneMoeConfig):
     """What the serving engine keeps for a sequence: every layer pages K
-    and V, window and full alike, nothing of fixed size."""
+    and V, window and full alike, nothing of fixed size; the full layers
+    read all of them."""
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim)
+                     cfg.head_dim, readers=cfg.sliding_windows.count(0),
+                     query_heads=cfg.num_attention_heads)
 
 
 def step_prefill(params, tokens, cfg: ExaoneMoeConfig,

@@ -307,11 +307,12 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
 
 def cache_spec(cfg: LlamaConfig):
     """What the serving engine keeps for a sequence: every layer pages K
-    and V, nothing of fixed size."""
+    and V, nothing of fixed size; every layer reads all of them."""
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim)
+                     cfg.head_dim, readers=cfg.num_hidden_layers,
+                     query_heads=cfg.num_attention_heads)
 
 
 def step_prefill(params, tokens, cfg: LlamaConfig, mesh: Mesh | None = None):

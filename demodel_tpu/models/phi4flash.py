@@ -202,7 +202,10 @@ def cache_spec(cfg: Phi4FlashConfig):
                ("ssm_state", (mamba, cfg.d_inner, cfg.mamba_d_state),
                 STATE_DTYPE),
                ("ssm_conv", (mamba, cfg.mamba_d_conv - 1, cfg.d_inner),
-                cfg.dtype)))
+                cfg.dtype)),
+        # the full layer and the cross-attention layers that read its pages
+        readers=kinds.count("full") + kinds.count("cross"),
+        query_heads=cfg.num_attention_heads)
 
 
 # ------------------------------------------------------------------ params

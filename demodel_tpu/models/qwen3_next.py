@@ -181,7 +181,8 @@ def cache_spec(cfg: Qwen3NextConfig):
                               cfg.linear_key_head_dim,
                               cfg.linear_value_head_dim), STATE_DTYPE),
                ("gdn_conv", (gdn, cfg.linear_conv_kernel_dim - 1,
-                             cfg.conv_channels), cfg.dtype)))
+                             cfg.conv_channels), cfg.dtype)),
+        readers=sum(cfg.full), query_heads=cfg.num_attention_heads)
 
 
 # ------------------------------------------------------------------ params

@@ -118,6 +118,7 @@ HUB.inc("gen_state_slots_alloc_total", 0)
 HUB.inc("gen_state_slots_freed_total", 0)
 HUB.inc("gen_kv_positions_width_total", 0)
 HUB.inc("gen_kv_positions_read_total", 0)
+HUB.inc("gen_attn_partial_bytes_total", 0)
 
 
 class CacheSpec(NamedTuple):
@@ -134,13 +135,20 @@ class CacheSpec(NamedTuple):
     of values, two arrays of ``head_dim`` columns; otherwise the page is
     one array whose ``head_dim`` columns are a position's keys and whose
     first ``values`` columns are also its values (a latent layer's ``[c_kv
-    | k_rope]``, one "head" all query heads share)."""
+    | k_rope]``, one "head" all query heads share). ``readers`` of the
+    model's layers read the whole of their rows' pages in a decode step
+    (:meth:`Paged.past`), each with ``query_heads`` query heads: what a
+    wide step's attention keeps in float32 between its trips and its sum
+    is counted from them (:meth:`KVBlockPool.partial_bytes`; 0 where a
+    pool is made by hand and nothing is counted)."""
 
     layers: int
     kv_heads: int
     head_dim: int
     state: tuple[tuple[str, tuple[int, ...], str], ...] = ()
     values: int = 0
+    readers: int = 0
+    query_heads: int = 0
 
 
 class PoolExhausted(Exception):
@@ -270,6 +278,24 @@ class KVBlockPool:
     def in_use_slots(self) -> int:
         with self._lock:
             return self.num_slots - len(self._free_slots)
+
+    def partial_bytes(self, rows: int, slots: int) -> int:
+        """On the host, from shapes: the float32 partials (weighted values,
+        largest score, sum) the attention of a decode step of ``rows`` rows
+        at ``slots`` table slots a row keeps between the trips of its loop
+        over the filled tiles and its sum, over the layers that read whole
+        pages: one set a tile of the table's capacity, or one a row where
+        the rule of :meth:`Tiles.by_row` says so; 0 for a narrow table,
+        which has no loop."""
+        spec = self.spec
+        if not _wide(slots):
+            return 0
+        partial = spec.query_heads * ((spec.values or spec.head_dim) + 2) * 4
+        # a tile of one layer: block_bytes counts every layer's
+        by_row = _by_row(partial,
+                         TILE_BLOCKS * self.block_bytes // spec.layers)
+        return spec.readers * partial * (
+            rows if by_row else rows * (slots // TILE_BLOCKS))
 
     # ------------------------------------------------------- alloc/free
     def alloc(self, n: int) -> BlockLease:
@@ -483,6 +509,15 @@ TILE_CHUNK = 128
 #: what a chunk of keys and one of values may take together of a core's
 #: fast memory (128 MiB on a v5e) and still both be held there
 FAST_BYTES = 96 << 20
+#: a tile's float32 partials (weighted values, largest score and sum for
+#: every query head) may be an eighth of the bytes of the tile they were
+#: taken from and still be kept a tile; past that the loop over the filled
+#: tiles carries them a row. Every page of K and V the benchmark has reads
+#: 0.016-0.032 (4 or 8 query heads a KV head of 128 or 256: 2 080-8 256 B
+#: beside a head's 131 072-262 144 B of a tile), A.X-K1's absorbed step 0.40
+#: (64 heads x 514 float32 = 131 584 B beside 256 positions of 640
+#: bfloat16 = 327 680 B)
+ROW_CARRY_SHARE = 8
 
 
 class Tiles(NamedTuple):
@@ -494,11 +529,17 @@ class Tiles(NamedTuple):
     blocks as one axis; ``v`` None where the page is one array and the
     values are columns of the keys), gathered a chunk a trip from where
     they lie. A
-    tile past the filled ones repeats the last of them, so no block wholly
-    past a row's length is ever read. A trip of a loop over the chunks
-    costs a handful of device operations whatever it moves, so the chunks
-    are few and large and what a trip needs of the index is ready to be
-    sliced."""
+    tile past the filled ones repeats the last of them (its ``row`` too,
+    with no position ``live``), so no block wholly past a row's length is
+    ever read. A trip of a loop over the chunks costs a handful of device
+    operations whatever it moves, so the chunks are few and large and what
+    a trip needs of the index is ready to be sliced: a chunk's ids and
+    ``live`` (:meth:`chunk`) and, for a loop that combines a chunk's tiles
+    into their rows, whose they are (:meth:`rows`). Whether a loop does,
+    or leaves its partial results a tile of the capacity, follows from
+    their bytes beside a tile's (:meth:`by_row`), as whether a trip
+    gathers keys and values apart follows from a chunk's
+    (:attr:`apart`)."""
 
     ids: jax.Array          # [C, TILE_BLOCKS] uint32
     row: jax.Array          # [C] the row a tile belongs to
@@ -526,12 +567,31 @@ class Tiles(NamedTuple):
             * math.prod(self.k.shape[1:]) * self.k.dtype.itemsize \
             > FAST_BYTES
 
+    def by_row(self, partial: int) -> bool:
+        """What a loop over the chunks carries, from bytes: ``partial``
+        are the bytes of the running softmax's three that a tile leaves
+        (every query head's weighted values, largest score and sum, in
+        float32). Beside a tile's own keys and values they are little in
+        grouped-query attention, and are kept a tile of the capacity
+        (False); where they exceed one part in :data:`ROW_CARRY_SHARE` of
+        it the loop would move more partials than pages, and carries one
+        running softmax a row (True)."""
+        tile = TILE_BLOCKS * math.prod(self.k.shape[1:]) \
+            * self.k.dtype.itemsize * (1 if self.v is None else 2)
+        return _by_row(partial, tile)
+
     def chunk(self, i):
         """Chunk ``i``: its tiles' block ids [n * TILE_BLOCKS] and their
         ``live`` [n, positions a tile]."""
         n = self.chunk_tiles
         return (lax.dynamic_slice_in_dim(self.ids, i * n, n).reshape(-1),
                 lax.dynamic_slice_in_dim(self.live, i * n, n))
+
+    def rows(self, i):
+        """The rows [n] chunk ``i``'s tiles belong to: in row order, so a
+        row's tiles of a chunk are adjacent."""
+        n = self.chunk_tiles
+        return lax.dynamic_slice_in_dim(self.row, i * n, n)
 
     def blocks(self, a, ids):
         """A chunk's blocks of ``a`` (``k`` or ``v``), gathered from where
@@ -542,6 +602,12 @@ class Tiles(NamedTuple):
 
 def _chunk_tiles(capacity: int) -> int:
     return math.gcd(TILE_CHUNK, capacity)
+
+
+def _by_row(partial: int, tile: int) -> bool:
+    """A tile's ``partial`` bytes of running softmax outweigh their share
+    of the ``tile`` bytes it reads: carried a row, not a tile."""
+    return partial * ROW_CARRY_SHARE > tile
 
 
 def _wide(slots: int) -> bool:

@@ -919,13 +919,19 @@ class GenEngine:
                     ship.set_attr("bytes", sum(t.rows.nbytes for t in todo))
                 B = len(flight.batch)
                 table, read = flight.kv_positions
+                # the bucket's rows at the table's slots a row
+                partials = self.pool.partial_bytes(
+                    table // flight.width,
+                    flight.width // self.pool.block_tokens)
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead),
                                    ("kv_positions_width", table),
-                                   ("kv_positions_read", read)):
+                                   ("kv_positions_read", read),
+                                   ("attn_partial_bytes", partials)):
                     cycle.set_attr(key, value)
                 HUB.inc("gen_kv_positions_width_total", table)
                 HUB.inc("gen_kv_positions_read_total", read)
+                HUB.inc("gen_attn_partial_bytes_total", partials)
                 if self._slotted:
                     # each row's slot, read and written, unless the module
                     # names what its step moved of it (_observe, below)

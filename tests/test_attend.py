@@ -1,7 +1,11 @@
 """``models/common.attend``: the one attention over new keys and a paged
 cache, against a dense float32 reference written here. A narrow past is a
 rectangle ``(k, v, kpos, live)``; a wide one the tiles its rows have filled
-(``kvcache.Paged.filled`` / ``past``), gathered a chunk at a time."""
+(``kvcache.Paged.filled`` / ``past``), gathered a chunk at a time, whose
+running softmax the loop carries a tile or, where a tile's float32 partials
+outweigh their share of the tile's bytes (``kvcache.Tiles.by_row``), a
+row: a latent page of one array under many query heads, and at this file's
+sizes any group of 8."""
 
 from __future__ import annotations
 
@@ -15,6 +19,7 @@ from demodel_tpu.models.common import attend
 from demodel_tpu.serve import kvcache
 
 B, T, HKV, HD = 3, 5, 2, 8
+VD = 6                  # a latent page's values: the keys' first columns
 BS, M = 4, 3            # block_tokens, blocks of past a row
 
 # the wide past: blocks of 2 positions, so a tile holds 32 and a row's table
@@ -26,12 +31,13 @@ WIDE_LENGTHS = np.asarray([0, 1, TILE - 1, TILE, TILE + 1,
 
 
 def _dense(q, keys, values, qpos, kpos, seen, window, scale=None):
-    """Row by row, head by head, in float32: ``keys`` / ``values`` [B, S,
-    Hkv, hd] at ``kpos`` [B, S], of which a query sees those ``seen`` [B,
-    S] that lie 0..window-1 behind it."""
+    """Row by row, head by head, in float32: ``keys`` [B, S, Hkv, hd] and
+    ``values`` [B, S, Hkv, vd] at ``kpos`` [B, S], of which a query sees
+    those ``seen`` [B, S] that lie 0..window-1 behind it."""
     Bq, Tq, H, hd = q.shape
+    vd = values.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
-    out = np.zeros((Bq, Tq, H, hd), np.float32)
+    out = np.zeros((Bq, Tq, H, vd), np.float32)
     for b in range(Bq):
         for t in range(Tq):
             behind = qpos[b, t] - kpos[b]
@@ -43,7 +49,7 @@ def _dense(q, keys, values, qpos, kpos, seen, window, scale=None):
                 s = keys[b, keep, kv] @ q[b, t, h] * scale
                 w = np.exp(s - s.max())
                 out[b, t, h] = (w / w.sum()) @ values[b, keep, kv]
-    return out.reshape(Bq, Tq, H * hd)
+    return out.reshape(Bq, Tq, H * vd)
 
 
 def _flat(a):
@@ -52,14 +58,18 @@ def _flat(a):
     return a.transpose(0, 1, 3, 2, 4).reshape(Bq, m * bs, Hkv, hd)
 
 
-def _wide_cache(rng, rows: int, dtype=np.float32, layers: int = 2):
+def _wide_cache(rng, rows: int, dtype=np.float32, layers: int = 2,
+                latent: bool = False):
     """A pool of ``layers`` whose blocks are dealt to ``rows`` rows in no
-    order, as ``kvcache.Paged``."""
+    order, as ``kvcache.Paged``: pages of K and V at ``HKV`` heads, or a
+    ``latent`` page of one array, one head all query heads share."""
     blocks = rows * WIDE_SLOTS
-    k, v = (rng.normal(size=(layers, blocks + 1, HKV, WIDE_BS, HD))
-            .astype(np.float32) for _ in range(2))
+    k, v = (rng.normal(size=(layers, blocks + 1, 1 if latent else HKV,
+                             WIDE_BS, HD)).astype(np.float32)
+            for _ in range(2))
     table = rng.permutation(blocks).reshape(rows, WIDE_SLOTS)
-    return kvcache.Paged(jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+    return kvcache.Paged(jnp.asarray(k, dtype),
+                         None if latent else jnp.asarray(v, dtype),
                          jnp.asarray(table, jnp.int32))
 
 
@@ -68,6 +78,13 @@ def _rectangle(cache, layer, lengths):
     kpos = jnp.broadcast_to(jnp.arange(S), (len(lengths), S))
     return (*cache.read(layer, cache.table), kpos,
             kpos < jnp.asarray(lengths)[:, None])
+
+
+def _by_row(past, q, vd: int) -> bool:
+    """What ``common._over_tiles`` asks of the index for queries ``q`` [B,
+    T, H, hd] and values ``vd`` wide."""
+    _rows, Tq, H, _hd = q.shape
+    return past.by_row(H * Tq * (vd + 2) * 4)
 
 
 CASES = [pytest.param(paged, window, group, None, id=f"{paged}-{wid}-{gid}")
@@ -82,6 +99,14 @@ CASES += [pytest.param(f"{how}-{chunk}", 0, group, scale,
           for how, chunk, group, (scale, sid) in itertools.product(
               ("step", "queries"), (32, 4), (1, 8),
               ((None, "scale"), (0.25, "own")))]
+# the shape that takes the row carry for what it is: a latent page of one
+# array (values: the first VD columns of the keys) under 8 query heads.
+# With four tiles a trip the rows of 2 and of 4 tiles straddle two trips.
+CASES += [pytest.param(f"latent-{how}-{chunk}", 0, 8, scale,
+                       id=f"latent-{how}-chunk{chunk}-g8-{sid}")
+          for how, chunk, (scale, sid) in itertools.product(
+              ("step", "queries"), (32, 4),
+              ((None, "scale"), (0.25, "own")))]
 
 
 @pytest.mark.parametrize("paged,window,group,scale", CASES)
@@ -93,15 +118,22 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
     paged case has length 0: it sees only its own new keys, whatever its
     slots hold. A wide past comes as its filled tiles, one query a row or
     five (rows of 0, 1, a tile less one, a tile, a tile and one and the
-    whole width in one batch) and is held to the rectangle over the same
-    pages besides."""
+    whole width in one batch: rows that have filled no tile, as a pad row
+    of the bucket has none, and one that ends a tile exactly) and is held
+    to the rectangle over the same pages besides. Its loop carries a
+    running softmax a tile at one query head a KV head and a row at eight,
+    and a row over a latent page."""
     rng = np.random.default_rng(11)
-    H = HKV * group
-    how, _, chunk = paged.partition("-")
+    how, _, chunk = paged.rpartition("-") if "-" in paged else (paged, "", "")
+    latent = how.startswith("latent")
+    kvh, vd = (1, VD) if latent else (HKV, HD)
+    H = kvh * group
     rows = len(WIDE_LENGTHS) if chunk else B
-    Tq = 1 if how == "step" else T
+    Tq = 1 if how.endswith("step") else T
     q, k, v = (rng.normal(size=(rows, Tq, h, HD)).astype(np.float32)
-               for h in (H, HKV, HKV))
+               for h in (H, kvh, kvh))
+    if latent:
+        v = k[..., :VD]
     lengths = np.asarray([0, 5, 11]) if how == "paged" else \
         WIDE_LENGTHS if chunk else np.zeros(B, int)
     positions = lengths[:, None] + np.arange(Tq)[None, :]
@@ -117,12 +149,16 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
                 jnp.asarray(live))
     elif chunk:
         monkeypatch.setattr(kvcache, "TILE_CHUNK", int(chunk))
-        cache = _wide_cache(rng, rows)
+        cache = _wide_cache(rng, rows, latent=latent)
         assert cache.wide
         past = cache.past(1, cache.filled(jnp.asarray(lengths)))
         assert past.chunk_tiles == int(chunk)
+        assert _by_row(past, q, vd) == (group == 8)
         rectangle = _rectangle(cache, 1, lengths)
-        pk, pv, slots, live = (np.asarray(a) for a in rectangle)
+        pk, pv, slots, live = (a if a is None else np.asarray(a)
+                               for a in rectangle)
+        if latent:
+            pv = pk[..., :VD]
     if past is not None:
         keys = np.concatenate([_flat(pk), k], axis=1)
         values = np.concatenate([_flat(pv), v], axis=1)
@@ -131,7 +167,7 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
     new = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
            jnp.asarray(positions))
     got = attend(*new, window=window, past=past, scale=scale)
-    assert got.shape == (rows, Tq, H * HD) and got.dtype == jnp.float32
+    assert got.shape == (rows, Tq, H * vd) and got.dtype == jnp.float32
     np.testing.assert_allclose(
         np.asarray(got), _dense(q, keys, values, positions, kpos, seen,
                                 window, scale), rtol=2e-5, atol=2e-5)
@@ -142,16 +178,20 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
             rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("group", [2, 8], ids=["a-tile", "a-row"])
 @pytest.mark.parametrize("fast", [kvcache.FAST_BYTES, 0],
                          ids=["together", "apart"])
 @pytest.mark.parametrize("chunk", [64, 4])
-def test_tiles_past_a_rows_length_are_not_read(chunk, fast, monkeypatch):
+def test_tiles_past_a_rows_length_are_not_read(chunk, fast, group,
+                                               monkeypatch):
     """Every block of a tile wholly past its row's length, and every block
     no row's table names, holds NaN: the tiles give the finite result the
     clean pool gives, to the bit; the rectangle, which multiplies what it
     masked by zero, does not. The same where a chunk of keys and one of
     values would not fit fast memory together and a trip gathers the
-    values when it is done with the keys."""
+    values when it is done with the keys, and whether the loop carries its
+    running softmax a tile or a row (a tile past the filled ones repeats
+    the last filled one and weighs nothing in its row)."""
     monkeypatch.setattr(kvcache, "TILE_CHUNK", chunk)
     monkeypatch.setattr(kvcache, "FAST_BYTES", fast)
     rng = np.random.default_rng(3)
@@ -168,12 +208,13 @@ def test_tiles_past_a_rows_length_are_not_read(chunk, fast, monkeypatch):
 
     dirty = clean._replace(k=poisoned(clean.k), v=poisoned(clean.v))
     q, k, v = (jnp.asarray(rng.normal(size=(rows, 1, h, HD)), jnp.float32)
-               for h in (2 * HKV, HKV, HKV))
+               for h in (group * HKV, HKV, HKV))
     pos = jnp.asarray(lengths)[:, None]
 
     def over(cache):
         past = cache.past(0, cache.filled(jnp.asarray(lengths)))
         assert past.apart == (fast == 0)
+        assert _by_row(past, q, HD) == (group == 8)
         return np.asarray(attend(q, k, v, pos, past=past))
 
     got = over(dirty)
@@ -186,16 +227,17 @@ def test_tiles_past_a_rows_length_are_not_read(chunk, fast, monkeypatch):
         q, k, v, pos, past=_rectangle(dirty, 0, lengths)))).any()
 
 
-@pytest.mark.parametrize("past", ["alone", "rectangle", "tiles"])
+@pytest.mark.parametrize("past", ["alone", "rectangle", "tiles", "rows"])
 def test_probabilities_are_in_the_models_dtype(past):
     """float32 softmax, then the model's dtype for the value products: a
     bfloat16 call returns bfloat16 and stays near the float32 one, over
     new keys alone and over both kinds of past, the rectangle and the
-    tiles of the same wide pages."""
+    tiles of the same wide pages, their running softmax carried a tile
+    (two query heads a KV head) and a row (eight)."""
     rng = np.random.default_rng(5)
     rows = 2 if past == "alone" else len(WIDE_LENGTHS)
     q, k, v = (jnp.asarray(rng.normal(size=(rows, 1, h, HD)), jnp.bfloat16)
-               for h in (4, 2, 2))
+               for h in (16 if past == "rows" else 4, 2, 2))
     pos = jnp.asarray([[4], [2]]) if past == "alone" \
         else jnp.asarray(WIDE_LENGTHS)[:, None]
 
@@ -205,7 +247,9 @@ def test_probabilities_are_in_the_models_dtype(past):
         cache = _wide_cache(np.random.default_rng(7), rows, dtype)
         if past == "rectangle":
             return _rectangle(cache, 0, WIDE_LENGTHS)
-        return cache.past(0, cache.filled(jnp.asarray(WIDE_LENGTHS)))
+        tiles = cache.past(0, cache.filled(jnp.asarray(WIDE_LENGTHS)))
+        assert _by_row(tiles, q, HD) == (past == "rows")
+        return tiles
 
     got = attend(q, k, v, pos, past=pages(jnp.bfloat16))
     want = attend(*(a.astype(jnp.float32) for a in (q, k, v)), pos,

@@ -98,18 +98,34 @@ def _served(ckpt, params, cfg, lengths=(40, 17, 9), steps=12,
                          for r, w in zip(ref, wanted)], (ckpt, seqs)
 
 
-@pytest.mark.parametrize("lengths,block_tokens", [
-    ((40, 17, 9), 4),       # a table of two tiles: the rectangle
-    ((70, 33, 5), 2),       # past 64 positions: the tiles the rows filled
-], ids=["inside-two-tiles", "past-two-tiles"])
-def test_float32_program_is_the_reference(small, lengths, block_tokens):
+@pytest.mark.parametrize("lengths,block_tokens,heads", [
+    ((40, 17, 9), 4, 4),    # a table of two tiles: the rectangle
+    ((70, 33, 5), 2, 4),    # past 64 positions: the tiles the rows filled
+    # under 32 heads a tile's float32 partials (32 x 34 x 4 B) are a
+    # quarter of its 32 positions of 128 columns: carried a row
+    ((70, 33, 5), 2, 32),
+], ids=["inside-two-tiles", "past-two-tiles", "past-two-tiles-a-row"])
+def test_float32_program_is_the_reference(small, lengths, block_tokens,
+                                          heads):
     """The same weights computed in float32 by the program: the prompt's
     attention expanded, every decode step absorbed over the latent page
     (which the prefill wrote), against the reference's expanded form at
     every position. No rounding to hide behind: 1e-4 on logits of order 1
     (float32 sums in another order, and the absorbed form multiplies
-    ``w_uk`` into the query before the scores, not into the key)."""
+    ``w_uk`` into the query before the scores, not into the key). Past two
+    tiles with the loop's running softmax carried a tile (4 heads) and a
+    row (32)."""
     ckpt, params, cfg = small
+    if heads != cfg.num_attention_heads:
+        model = dict(SMALL, num_attention_heads=heads,
+                     num_key_value_heads=heads)
+        ckpt = checkpoint.Checkpoint(model, SEED, n_shards=2)
+        params, cfg = _params(ckpt, model)
+    pool = kvcache.KVBlockPool(axk1.cache_spec(cfg), block_tokens=2,
+                               budget_mb=1, dtype="float32")
+    partial = 4 * heads * (32 + 2) * 4      # four layers' set of partials
+    assert pool.partial_bytes(4, 256) == partial * (4 if heads == 32
+                                                    else 4 * 16)
     got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg),
                                    lengths=lengths, block_tokens=block_tokens)
     for (_fed, lg), r in zip(got, ref):
@@ -172,15 +188,17 @@ class TestAgainstTheReference:
 # ------------------------------------------------- the two attention paths
 
 
-@pytest.mark.parametrize("wide", [False, True], ids=["rectangle", "tiles"])
-def test_absorbed_attention_is_the_expanded_one(wide):
+@pytest.mark.parametrize("wide,heads", [(False, 4), (True, 4), (True, 32)],
+                         ids=["rectangle", "tiles", "tiles-a-row"])
+def test_absorbed_attention_is_the_expanded_one(wide, heads):
     """One layer's attention at the last position of each row, computed
     twice from the same weights: expanded over the row's whole prefix, and
     absorbed over a latent page that holds the prefix (one array, its
     values the first 32 columns of its keys), in a table of two tiles and
-    in a wider one read by its filled tiles. float32: 2e-5, the two orders
-    of the same sums."""
-    cfg = axk1.AxK1Config.tiny()
+    in a wider one read by its filled tiles, whose running softmax the
+    loop carries a tile under 4 heads and a row under 32. float32: 2e-5,
+    the two orders of the same sums."""
+    cfg = axk1.AxK1Config.tiny(num_attention_heads=heads)
     layer = axk1.init_params(jax.random.key(11), cfg)["layers"][0]
     bs = 2
     lengths = np.asarray([70, 33, 1, 64] if wide else [40, 17, 1, 64])
@@ -208,6 +226,9 @@ def test_absorbed_attention_is_the_expanded_one(wide):
     step = jnp.take_along_axis(x, at[:, None, None], axis=1)
     past = cache.past(0, cache.filled(at))
     assert past[1] is None if not wide else past.v is None
+    if wide:
+        assert past.by_row(heads * (cfg.kv_lora_rank + 2) * 4) \
+            == (heads == 32)
     got, new = axk1._attn_absorbed(layer, step, cfg, at[:, None], past)
     want = np.take_along_axis(np.asarray(whole),
                               lengths[:, None, None], axis=1)
@@ -321,7 +342,9 @@ def test_the_pool_holds_a_page_of_one_array(small):
     does a pair. A family that pages K and V still gets its two arrays."""
     _ckpt, _params, cfg = small
     spec = axk1.cache_spec(cfg)
-    assert spec == kvcache.CacheSpec(4, 1, 128, values=32)
+    # all four layers read the whole of it, with the model's four heads
+    assert spec == kvcache.CacheSpec(4, 1, 128, values=32, readers=4,
+                                     query_heads=4)
     pool = kvcache.KVBlockPool(spec, block_tokens=4, budget_mb=1,
                                dtype="bfloat16")
     assert pool.block_bytes == 4 * 4 * 128 * 2      # once, not K and V

@@ -18,21 +18,29 @@ parent: what that PR added to ``common._over_tiles`` (values narrower than
 the keys, tiles with no values of their own) left the loop over the filled
 tiles of every family that pages K and V as it was, down to the order of
 its operations (a gather moved ahead of a reshape had made it another
-program, which the machine's compile cache would not have known).
+program, which the machine's compile cache would not have known). PR 43
+gave that loop a second carry (one running softmax a row) for the steps
+whose partials outweigh their share of a tile's bytes, and moved none of
+these digests: which step takes it is a rule on shapes
+(``kvcache.Tiles.by_row``), held below at the benchmark's head counts and
+widths.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+import json
 import re
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import exaone_moe, llama, qwen3_next
-from demodel_tpu.serve import GenEngine
+from demodel_tpu.models import axk1, exaone_moe, llama, phi4flash, qwen3_next
+from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 
 FAMILIES = {"llama": (llama, llama.LlamaConfig),
@@ -125,3 +133,54 @@ def lowered():
 def test_program_is_the_parents(lowered, family, dtype, stage):
     assert digest(lowered(family, dtype)[stage]) \
         == PINNED[family, dtype, stage]
+
+
+#: the benchmark's configurations: module, configuration class, the rows of
+#: its cell's batch bucket, and whether its wide step carries a row
+CONFIGS = {
+    "yi-1.5-6b": (llama, llama.LlamaConfig, 8, False),
+    "k-exaone-236b-l8-ep8": (exaone_moe, exaone_moe.ExaoneMoeConfig, 32,
+                             False),
+    "qwen3-next-80b-l12-ep4": (qwen3_next, qwen3_next.Qwen3NextConfig, 16,
+                               False),
+    "phi-4-mini-flash": (phi4flash, phi4flash.Phi4FlashConfig, 32, False),
+    "ax-k1-519b-l7-ep16": (axk1, axk1.AxK1Config, 64, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_the_carry_follows_from_shapes_alone(name):
+    """What the loop over the filled tiles carries, at each configuration's
+    published head counts and widths in bfloat16 blocks of 16 positions, at
+    both wide widths: a tile's float32 partials (query heads x (values + 2)
+    x 4 B) against the bytes of the tile they were taken from. A.X-K1's
+    absorbed step (64 heads over one cached vector of 640, values of 512)
+    reads 0.40 and carries a row; every family that pages K and V reads
+    0.016-0.032 and keeps a tile. The index inside the program
+    (``Tiles.by_row``) and the host's count of the bytes
+    (``KVBlockPool.partial_bytes``, the step span's ``attn_partial_bytes``)
+    say the same, from the module's own statement of its cache."""
+    module, config, rows, by_row = CONFIGS[name]
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / f"{name}.json").read_text())
+    cfg = config.from_hf({k: v for k, v in doc.items() if k != "benchmark"})
+    spec = module.cache_spec(cfg)
+    assert spec.readers and spec.query_heads == cfg.num_attention_heads
+    # the pages alone, a few blocks of them: the rule reads shapes
+    pool = kvcache.KVBlockPool(spec._replace(state=()), block_tokens=16,
+                               budget_mb=1, dtype="bfloat16")
+    vd = spec.values or spec.head_dim
+    partial = spec.query_heads * (vd + 2) * 4
+    tile = 256 * spec.kv_heads * spec.head_dim * 2 * pool.pages
+    assert (partial / tile > 0.125) == by_row
+    assert (0.39 < partial / tile < 0.41) if by_row \
+        else (0.015 < partial / tile < 0.033)
+    for slots in (256, 2048):
+        cache = kvcache.Paged(pool.k, pool.v,
+                              jnp.zeros((rows, slots), jnp.int32))
+        tiles = cache.past(0, cache.filled(jnp.full((rows,), 700)))
+        assert tiles.by_row(partial) == by_row
+        places = rows if by_row else rows * slots // kvcache.TILE_BLOCKS
+        assert pool.partial_bytes(rows, slots) \
+            == spec.readers * places * partial
+    assert pool.partial_bytes(rows, 2 * kvcache.TILE_BLOCKS) == 0

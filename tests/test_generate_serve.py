@@ -632,6 +632,55 @@ class TestDevicePool:
             assert req.result(5) == np.asarray(llama.generate(
                 params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
 
+    @pytest.mark.parametrize("family,longest,places", [
+        ("llama", 18, 0), ("llama", 70, 4 * 16), ("axk1", 70, 4)],
+        ids=["narrow", "a-tile", "a-row"])
+    def test_a_step_books_the_partials_its_attention_keeps(self, family,
+                                                           longest, places):
+        """``attn_partial_bytes`` on ``serve.decode-step``: the float32
+        partials (every query head's weighted values, largest score and
+        sum) the loop over the filled tiles keeps between its trips and
+        its sum, from shapes. Nothing for a table of two tiles (the
+        rectangle has no loop); one set a tile of the bucket's capacity
+        (four rows of sixteen tiles) for a llama's grouped queries over
+        pages of K and V; one set a row where they outweigh their share of
+        the tile's bytes: A.X-K1's absorbed step, 32 heads x (32 + 2)
+        float32 beside a tile of 32 positions of 128 columns. Counted in
+        ``gen_attn_partial_bytes_total``, which ``/statusz`` has."""
+        from demodel_tpu.models import axk1
+        from demodel_tpu.utils import statusz, trace
+
+        if family == "axk1":
+            cfg = axk1.AxK1Config.tiny(num_attention_heads=32)
+            params = axk1.init_params(jax.random.key(2), cfg)
+            heads, vd = 32, cfg.kv_lora_rank
+        else:
+            _module, params, cfg = _tiny(family)
+            heads, vd = cfg.num_attention_heads, cfg.head_dim
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
+                           max_new_tokens=4, kv_mb=1, block_tokens=2)
+        want = cfg.num_hidden_layers * places * heads * (vd + 2) * 4
+        trace.reset()
+        trace.enable()
+        try:
+            before = HUB.snapshot()
+            _drive(engine, [_prompt(cfg, n, seed=n)
+                            for n in (longest, 9, 5)], 3)
+            for _ in range(2):
+                engine._decode_step()
+            after = HUB.snapshot()
+            steps = [r["attrs"] for r in trace.buffer().snapshot()
+                     if r["name"] == "serve.decode-step"]
+            counters = statusz.snapshot()["counters"]
+        finally:
+            trace.reset()
+            engine.stop()
+        assert [a["attn_partial_bytes"] for a in steps] == [want] * 2
+        assert [a["width"] for a in steps] == [512 if places else 64] * 2
+        name = "gen_attn_partial_bytes_total"
+        assert after[name] - before[name] == 2 * want
+        assert counters[name] >= 2 * want
+
     @pytest.mark.parametrize("longest,slots", [
         (1, 32), (2, 32), (3, 32), (9, 32), (31, 32), (32, 32), (33, 32),
         (64, 32), (65, 256), (300, 256), (512, 256), (513, 2048),

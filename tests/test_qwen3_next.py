@@ -512,7 +512,10 @@ def test_served_over_http_like_the_others(small, tmp_path):
         serve.install(None)
     assert got == want
     # PR 42 added ``values`` (a page of one array), 0 for every family
-    # that pages K and V
+    # that pages K and V; PR 43 who reads whole pages: the attention layers
     assert kvcache.CacheSpec._fields == ("layers", "kv_heads", "head_dim",
-                                         "state", "values")
-    assert qwen3_next.cache_spec(cfg).values == 0
+                                         "state", "values", "readers",
+                                         "query_heads")
+    spec = qwen3_next.cache_spec(cfg)
+    assert (spec.values, spec.readers, spec.query_heads) == (
+        0, sum(cfg.full), cfg.num_attention_heads)

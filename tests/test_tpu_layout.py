@@ -8,11 +8,18 @@ the blocks), and a program that reads a block's positions by 576 columns
 then starts by copying the whole pool into that order: 2.4 GB a step at the
 benchmark's size, seen in the compiled decode step (PERF.md, Findings, PR
 42). At 640 columns the pool lies as it is read.
+
+And of the benchmark's 64-row step over that page (~15 s): the loop over
+the filled tiles carries one running softmax a row (PR 43), so the program
+holds no float32 array of the table's capacity and gathers no queries to
+it.
 """
 
 from __future__ import annotations
 
+import json
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -75,3 +82,51 @@ def test_a_page_of_576_columns_would_lie_blocks_innermost(one_chip):
     # a page of K and V at a head of 128 never had the question
     assert _born((8, 4097, 8, 16, 128), one_chip)[:2] == [4, 3]
     assert kvcache.CacheSpec(8, 8, 128).values == 0
+
+
+def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
+    """``ax-k1-519b-l7-ep16``'s decode step as ``axk1-reason`` runs it (64
+    rows at 256 table slots each: a capacity of 1 024 tiles), compiled for
+    the described chip. At the parent of PR 43 the loop over the filled
+    tiles left ``f32[1024,1,64,1,514]`` (135 MB a layer: filled, written a
+    chunk a trip, gathered by row into ``[64,16,...]``, copied into another
+    order) and gathered its queries to capacity, ``bf16[1024,1,1,64,640]``
+    (84 MB); the step's temporaries were 0.47 GB. Now a tile's partials
+    outweigh their share of the tile (``kvcache.Tiles.by_row``), the carry
+    is a row's, and the temporaries 0.12 GB."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / "ax-k1-519b-l7-ep16.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = axk1.AxK1Config.from_hf(doc)
+    spec = axk1.cache_spec(cfg)
+    rows, slots = engine["max_batch"], 256
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, table, lengths, tokens, k):
+        cache = kvcache.Paged(k, None, table)
+        logits, new, *_stats = axk1.step_decode(params, tokens, cfg, cache,
+                                                lengths)
+        latents, _fresh = kvcache.parts(new)
+        return logits, *kvcache.put_positions(
+            k, None, latents, table[:, 0], lengths % engine["block_tokens"])
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: axk1.init_params(jax.random.key(1), cfg)))
+    compiled = jax.jit(decode, donate_argnums=(4,)).lower(
+        params, shaped((rows, slots), jnp.int32),
+        *(shaped((rows,), jnp.int32),) * 2,
+        shaped((spec.layers, BLOCKS, 1, engine["block_tokens"],
+                spec.head_dim), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    capacity = rows * slots // kvcache.TILE_BLOCKS
+    assert capacity == 1024
+    # no float32 partials and no queries a tile of the capacity
+    assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
+    assert not re.findall(rf"bf16\[{capacity},[\d,]*{spec.head_dim}\]", text)
+    # the carry: a row's weighted values, under every head
+    assert re.search(rf"f32\[{rows},1,{cfg.num_attention_heads},1,"
+                     rf"{spec.values}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
