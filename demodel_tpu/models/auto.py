@@ -18,6 +18,7 @@ from demodel_tpu.models import bert as bert_mod
 from demodel_tpu.models import exaone_moe as exaone_moe_mod
 from demodel_tpu.models import gpt2 as gpt2_mod
 from demodel_tpu.models import llama as llama_mod
+from demodel_tpu.models import longcat_flash as longcat_flash_mod
 from demodel_tpu.models import phi4flash as phi4flash_mod
 from demodel_tpu.models import qwen3_next as qwen3_next_mod
 from demodel_tpu.models.hf_loader import (
@@ -26,6 +27,7 @@ from demodel_tpu.models.hf_loader import (
     load_exaone_moe_params,
     load_gpt2_params,
     load_llama_params,
+    load_longcat_flash_params,
     load_phi4flash_params,
     load_qwen3_next_params,
 )
@@ -87,10 +89,14 @@ def model_from_pull(store, report, mesh=None, placement=None):
         cfg = axk1_mod.AxK1Config.from_hf(config)
         params = load_axk1_params(weights, cfg, mesh=mesh)
         fn = None   # served through its step functions only
+    elif model_type == "longcat_flash":
+        cfg = longcat_flash_mod.LongcatFlashConfig.from_hf(config)
+        params = load_longcat_flash_params(weights, cfg, mesh=mesh)
+        fn = None   # served through its step functions only
     else:
         raise ValueError(f"unsupported model_type {model_type!r} "
                          "(supported: llama, gpt2, bert, exaone_moe, "
-                         "qwen3_next, phi4flash, axk1)")
+                         "qwen3_next, phi4flash, axk1, longcat_flash)")
     log.info("auto: built %s from pulled snapshot (%d tensors)",
              model_type, n_tensors)
     return fn, params, cfg

@@ -20,7 +20,10 @@ learned weight, ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``):
   before are a dense SwiGLU. (``topk_method`` ``none``: no selection bias
   exists, none is created or loaded.)
 
-**Two attention paths for one set of weights.** ``W_kvb`` is held split by
+**Two attention paths for one set of weights**
+(:mod:`demodel_tpu.models.latent`, which LongCat-Flash calls too; what is
+this family's is :attr:`AxK1Config.latent`: YaRN's frequencies, ``mscale``
+squared in the scores' scale). ``W_kvb`` is held split by
 head, ``w_uk`` and ``w_uv`` ``[H, 512, 128]`` each (and ``W_qb`` as its
 unrotated and its rotary columns, ``q_b_nope`` and ``q_b_rope``: held as
 one matrix, the step's compiler transposes all of it every step to get at
@@ -38,7 +41,7 @@ blocks + 1, 1, block_tokens, 640]`` (``kvcache.CacheSpec.values``): the
 pool leases and donates it as it does K and V, a step reads a row's blocks
 once (``Paged.past``) and ``common.attend`` takes the values as the leading
 columns of the keys it gathered. The 576 columns of ``[c_kv | k_r]`` are
-followed by 64 of zeros (:data:`LANES`): the TPU holds an array whose
+followed by 64 of zeros (:data:`latent.LANES`): the TPU holds an array whose
 innermost dimension is no multiple of its 128 lanes with another
 dimension innermost (here the blocks), and every program that took the
 pool would first copy all of it into the order it reads (2.4 GB a step at
@@ -64,15 +67,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from demodel_tpu.models import experts
-from demodel_tpu.models.common import (attend, refuse_unsupported,
-                                       rms_norm)
-from demodel_tpu.utils.metrics import HUB
-
-HUB.inc("gen_latent_kv_bytes_total", 0)
-
-#: the TPU's lanes: the page's width is the latent's rounded up to them
-LANES = 128
+from demodel_tpu.models import experts, latent
+from demodel_tpu.models.common import refuse_unsupported, rms_norm
 
 
 @dataclass(frozen=True)
@@ -125,22 +121,29 @@ class AxK1Config:
         return self.num_hidden_layers - self.first_k_dense_replace
 
     @property
+    def latent(self) -> latent.Geometry:
+        """This family's latent attention: YaRN frequencies and, in the
+        scores' scale, ``(nope + rope) ** -0.5`` times ``mscale(factor,
+        mscale_all_dim) ** 2``, YaRN's correction of their temperature."""
+        inv, factor = yarn_frequencies(self)
+        return latent.Geometry(
+            self.num_attention_heads, self.kv_lora_rank,
+            self.qk_rope_head_dim, tuple(map(float, inv)), factor,
+            (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+            * _mscale(self.rope_factor, self.rope_mscale_all_dim) ** 2,
+            self.rms_norm_eps)
+
+    @property
     def latent_dim(self) -> int:
-        """What a position keeps a layer: ``[c_kv | k_rope]``."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.latent.latent_dim
 
     @property
     def page_dim(self) -> int:
-        """The page's columns a position: :attr:`latent_dim` and zeros up
-        to a multiple of :data:`LANES`."""
-        return -(-self.latent_dim // LANES) * LANES
+        return self.latent.page_dim
 
     @property
     def softmax_scale(self) -> float:
-        """``(nope + rope) ** -0.5`` times ``mscale(factor, mscale_all_dim)
-        ** 2``, YaRN's correction of the scores' temperature."""
-        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 \
-            * _mscale(self.rope_factor, self.rope_mscale_all_dim) ** 2
+        return self.latent.scale
 
     @classmethod
     def tiny(cls, **over) -> "AxK1Config":
@@ -220,9 +223,8 @@ def init_params(key, cfg: AxK1Config) -> dict:
     """Seeded N(0, 1/fan_in) matrices and norms of ones: the tree
     :func:`hf_loader.load_axk1_params` builds."""
     dt = jnp.dtype(cfg.dtype)
-    D, H = cfg.hidden_size, cfg.num_attention_heads
-    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
-        cfg.v_head_dim
+    D = cfg.hidden_size
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     C, Q = cfg.kv_lora_rank, cfg.q_lora_rank
     F, E = cfg.moe_intermediate_size, cfg.n_routed_experts
     keys = iter(jax.random.split(key, 16 * cfg.num_hidden_layers + 2))
@@ -234,14 +236,8 @@ def init_params(key, cfg: AxK1Config) -> dict:
     layers = []
     for i in range(cfg.num_hidden_layers):
         layer = {
-            "q_a_proj": dense(D, Q), "q_a_norm": jnp.ones((Q,), dt),
-            # [out, in], as the checkpoint holds them and the chip's
-            # compiler lays them out for both programs
-            "q_b_nope": dense(H * nope, Q, fan_in=Q),
-            "q_b_rope": dense(H * rope, Q, fan_in=Q),
-            "kv_a_proj": dense(D, C + rope), "kv_a_norm": jnp.ones((C,), dt),
-            "w_uk": dense(H, C, nope), "w_uv": dense(H, C, vd),
-            "o_proj": dense(H * vd, D),
+            **latent.matrices(dense, D, Q, cfg.latent, nope, vd),
+            "q_a_norm": jnp.ones((Q,), dt), "kv_a_norm": jnp.ones((C,), dt),
             "attn_norm": jnp.ones((D,), dt), "mlp_norm": jnp.ones((D,), dt),
         }
         if i >= cfg.first_k_dense_replace:
@@ -310,84 +306,6 @@ def yarn_frequencies(cfg: AxK1Config) -> tuple[np.ndarray, float]:
         cfg.rope_factor, cfg.rope_mscale_all_dim)
 
 
-def _rotate(x, positions, cfg: AxK1Config):
-    """``x`` [B, T, h, rope] at ``positions`` [B, T]: adjacent columns ``(2j,
-    2j + 1)`` are a pair, as the checkpoint holds them; the result has the
-    pairs' first halves before their second (every rotated query meets
-    keys rotated here, so the order drops out of the scores)."""
-    inv, factor = yarn_frequencies(cfg)
-    ang = positions[..., None].astype(jnp.float32) * inv      # [B, T, r/2]
-    cos = (jnp.cos(ang) * factor)[:, :, None, :]
-    sin = (jnp.sin(ang) * factor)[:, :, None, :]
-    a, b = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-# -------------------------------------------------------------- attention
-
-
-def _latent(layer, x, cfg: AxK1Config, positions):
-    """What both paths share: the queries ``(q_nope, q_rope)`` [B, T, H,
-    128 | 64], rotated, and the position's cached vector ``[c_kv | k_r |
-    0]`` [B, T, 1, 640], normalised, rotated and as wide as the page."""
-    B, T, _D = x.shape
-    H = cfg.num_attention_heads
-    c_q = rms_norm(x @ layer["q_a_proj"], layer["q_a_norm"],
-                   cfg.rms_norm_eps)
-    q_nope = jnp.einsum("btq,nq->btn", c_q, layer["q_b_nope"]).reshape(
-        B, T, H, -1)
-    q_rope = jnp.einsum("btq,nq->btn", c_q, layer["q_b_rope"]).reshape(
-        B, T, H, -1)
-    kv = x @ layer["kv_a_proj"]
-    C = cfg.kv_lora_rank
-    c_kv = rms_norm(kv[..., :C], layer["kv_a_norm"], cfg.rms_norm_eps)
-    k_r = _rotate(kv[..., None, C:], positions, cfg)
-    return (q_nope, _rotate(q_rope, positions, cfg),
-            jnp.concatenate([c_kv[:, :, None, :], k_r, jnp.zeros(
-                (B, T, 1, cfg.page_dim - cfg.latent_dim), x.dtype)],
-                axis=-1))
-
-
-def _attn_expanded(layer, x, cfg: AxK1Config, positions):
-    """A prompt's attention, ``x`` [B, T, D] → ``(out, latent [B, T, 1,
-    640])``: keys and values a head from ``c_kv``, ``H`` heads of 192 |
-    128."""
-    B, T, _D = x.shape
-    H, C = cfg.num_attention_heads, cfg.kv_lora_rank
-    q_nope, q_rope, latent = _latent(layer, x, cfg, positions)
-    c_kv = latent[:, :, 0, :C]
-    k_nope = jnp.einsum("btc,hcd->bthd", c_kv, layer["w_uk"])
-    v = jnp.einsum("btc,hcd->bthd", c_kv, layer["w_uv"])
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(
-        latent[..., C:cfg.latent_dim], (B, T, H, cfg.qk_rope_head_dim))],
-        axis=-1)
-    out = attend(jnp.concatenate([q_nope, q_rope], axis=-1), k, v, positions,
-                 scale=cfg.softmax_scale)
-    return out @ layer["o_proj"], latent
-
-
-def _attn_absorbed(layer, x, cfg: AxK1Config, positions, past):
-    """A step's attention over the latent page, ``x`` [B, 1, D] → ``(out,
-    latent [B, 1, 1, 640])``: ``w_uk`` folded into the queries (zeros
-    where the page has them), ``w_uv`` applied to what the probabilities
-    weigh of ``c_kv``; ``past`` has no values of its own
-    (``common.attend``)."""
-    B, T, _D = x.shape
-    H, C = cfg.num_attention_heads, cfg.kv_lora_rank
-    q_nope, q_rope, latent = _latent(layer, x, cfg, positions)
-    with jax.named_scope("attn.latent.absorb"):
-        q = jnp.concatenate(
-            [jnp.einsum("bthd,hcd->bthc", q_nope, layer["w_uk"]), q_rope,
-             jnp.zeros((B, T, H, cfg.page_dim - cfg.latent_dim), x.dtype)],
-            axis=-1)
-        o = attend(q, latent, latent[..., :C], positions, past=past,
-                   scale=cfg.softmax_scale)
-        out = jnp.einsum("bthc,hcd->bthd", o.reshape(B, T, H, C),
-                         layer["w_uv"])
-    return out.reshape(B, T, -1) @ layer["o_proj"], latent
-
-
 # ---------------------------------------------------------- expert layer
 
 
@@ -445,12 +363,10 @@ def _forward(params, tokens, cfg, positions, live, pasts, mesh):
     x = params["embed"][tokens]
     latents, counts = [], []
     for layer, past in zip(params["layers"], pasts):
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("attn.latent"):
-            a, latent = _attn_expanded(layer, h, cfg, positions) \
-                if past is None \
-                else _attn_absorbed(layer, h, cfg, positions, past)
-        latents.append(latent)
+        a, new = latent.attention(
+            layer, rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps),
+            cfg.latent, positions, past)
+        latents.append(new)
         x = x + a
         m, n = _mlp(layer, rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps),
                     live, cfg, mesh)
@@ -525,7 +441,6 @@ def observe(expert_tokens, positions, tokens: int, cfg: AxK1Config) -> dict:
     counted here."""
     attrs = experts.observe(
         expert_tokens, tokens * cfg.num_experts_per_tok * cfg.sparse_layers)
-    attrs["latent_bytes"] = int(positions) * cfg.num_hidden_layers \
-        * cfg.latent_dim * jnp.dtype(cfg.dtype).itemsize
-    HUB.inc("gen_latent_kv_bytes_total", attrs["latent_bytes"])
+    attrs.update(latent.observe(positions, cache_spec(cfg), cfg.latent,
+                                cfg.dtype))
     return attrs

@@ -143,10 +143,13 @@ def swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
-def observe(expert_tokens, assignments: int) -> dict:
+def observe(expert_tokens, assignments: int, free: int = 0) -> dict:
     """A step's ``expert_tokens`` ([expert layers, held experts], on the
     host) and the assignments its tokens made in all → the step span's
-    attributes; the counters are counted here. ``expert_rows`` is what
+    attributes; the counters are counted here. ``free`` of the assignments
+    fell on an expert that is nobody's to hold (an identity expert): they
+    are neither held nor absent, and the family counts them.
+    ``expert_rows`` is what
     :func:`held_part`'s grouped products ran over, layer by layer, as one
     chip runs it (the pad rows of a batch bucket not counted): over
     ``expert_tokens`` it says how much of the work landed."""
@@ -157,7 +160,7 @@ def observe(expert_tokens, assignments: int) -> dict:
         rows = int((-(-expert_tokens.sum(axis=1) // SLAB)).sum()) * SLAB
     HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
     HUB.inc(labeled("gen_moe_assignments_total", held="false"),
-            assignments - landed)
+            assignments - free - landed)
     HUB.inc("gen_moe_experts_hit_total", hit)
     HUB.inc("gen_moe_rows_computed_total", rows)
     return {"expert_tokens": landed, "experts_hit": hit,

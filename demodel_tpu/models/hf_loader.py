@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from demodel_tpu.models import axk1, exaone_moe, phi4flash, qwen3_next
+from demodel_tpu.models import (axk1, exaone_moe, longcat_flash, phi4flash,
+                                qwen3_next)
 from demodel_tpu.models.bert import BertConfig
 from demodel_tpu.models.gpt2 import GPT2Config
 from demodel_tpu.models.llama import LlamaConfig, param_shardings
@@ -220,16 +221,54 @@ def _head_splitter(heads: int, first: int, by_head: bool, sharding):
     return jax.jit(split, out_shardings=(sharding, sharding))
 
 
+@functools.lru_cache(maxsize=None)
+def _folder(scale: float, sharding):
+    """Jitted :func:`longcat_flash.fold` of one latent norm's weight."""
+    return jax.jit(lambda w: longcat_flash.fold(w, scale),
+                   out_shardings=sharding)
+
+
+def _latent_attention(w: "_Weights", a: str, heads: int, nope: int,
+                      sh: dict, scales: tuple = (None, None)) -> dict:
+    """One latent attention's tensors under the prefix ``a`` → the nine
+    leaves :mod:`demodel_tpu.models.latent` reads (``sh`` their shardings
+    by leaf). ``kv_b_proj`` (a head's ``nope`` key rows before its value
+    rows) is split by head into ``w_uk`` and ``w_uv``, ``q_b_proj`` (a
+    head's ``nope`` unrotated rows before its rotary ones) into the two
+    kinds of row. ``scales``: what a family multiplies the normalised
+    ``c_q`` and ``c_kv`` by, folded into the two norms' weights in float32
+    (None: the weight as the checkpoint holds it)."""
+    def lin(name, leaf):
+        return w.get(a + name, transpose=True, sharding=sh.get(leaf))
+
+    def norm(name, leaf, scale):
+        if scale is None:
+            return w.get(a + name, sharding=sh.get(leaf))
+        return _folder(scale, sh.get(leaf))(w.get(a + name))
+
+    w_uk, w_uv = _head_splitter(heads, nope, True, sh.get("w_uk"))(
+        w.get(a + "kv_b_proj.weight"))
+    q_b_nope, q_b_rope = _head_splitter(
+        heads, nope, False, sh.get("q_b_nope"))(w.get(a + "q_b_proj.weight"))
+    return {
+        "q_a_proj": lin("q_a_proj.weight", "q_a_proj"),
+        "q_a_norm": norm("q_a_layernorm.weight", "q_a_norm", scales[0]),
+        "q_b_nope": q_b_nope, "q_b_rope": q_b_rope,
+        "kv_a_proj": lin("kv_a_proj_with_mqa.weight", "kv_a_proj"),
+        "kv_a_norm": norm("kv_a_layernorm.weight", "kv_a_norm", scales[1]),
+        "w_uk": w_uk, "w_uv": w_uv,
+        "o_proj": lin("o_proj.weight", "o_proj"),
+    }
+
+
 def load_axk1_params(weights: dict, cfg: "axk1.AxK1Config",
                      mesh=None) -> dict:
     """The tree of :func:`axk1.init_params` from a checkpoint of the
     DeepSeek-V3 style of names, holding one share of the experts under
-    their global indices. ``kv_b_proj`` (a head's 128 key rows before its
-    128 value rows) is split by head into ``w_uk`` and ``w_uv``, which the
-    expanded prefill and the absorbed decode both read, ``q_b_proj`` (a
-    head's 128 unrotated rows before its 64 rotary ones) into the two
-    kinds of column; the experts are
-    stacked as :func:`load_exaone_moe_params` stacks them. A selection
+    their global indices. The attention is :func:`_latent_attention`'s
+    (``w_uk`` and ``w_uv`` by head, which the expanded prefill and the
+    absorbed decode both read); the experts are stacked as
+    :func:`load_exaone_moe_params` stacks them. A selection
     bias in the checkpoint is refused: the module implements
     ``topk_method`` ``none``, which has none."""
     w = _Weights(weights)
@@ -253,21 +292,10 @@ def load_axk1_params(weights: dict, cfg: "axk1.AxK1Config",
                 f"checkpoint tensor {pre}mlp.gate.e_score_correction_bias: "
                 "a selection bias is not supported by this stack "
                 "(topk_method none)")
-        H, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-        w_uk, w_uv = _head_splitter(H, nope, True, lsh.get("w_uk"))(
-            w.get(pre + "self_attn.kv_b_proj.weight"))
-        q_b_nope, q_b_rope = _head_splitter(
-            H, nope, False, lsh.get("q_b_nope"))(
-            w.get(pre + "self_attn.q_b_proj.weight"))
         layer = {
-            "q_a_proj": lin("self_attn.q_a_proj.weight", "q_a_proj"),
-            "q_a_norm": vec("self_attn.q_a_layernorm.weight", "q_a_norm"),
-            "q_b_nope": q_b_nope, "q_b_rope": q_b_rope,
-            "kv_a_proj": lin("self_attn.kv_a_proj_with_mqa.weight",
-                             "kv_a_proj"),
-            "kv_a_norm": vec("self_attn.kv_a_layernorm.weight", "kv_a_norm"),
-            "w_uk": w_uk, "w_uv": w_uv,
-            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            **_latent_attention(w, pre + "self_attn.",
+                                cfg.num_attention_heads,
+                                cfg.qk_nope_head_dim, lsh),
             "attn_norm": vec("input_layernorm.weight", "attn_norm"),
             "mlp_norm": vec("post_attention_layernorm.weight", "mlp_norm"),
         }
@@ -390,6 +418,68 @@ def load_qwen3_next_params(weights: dict,
                 "out_proj": lin("linear_attn.out_proj.weight", "out_proj"),
             })
         layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
+
+
+def load_longcat_flash_params(weights: dict,
+                              cfg: "longcat_flash.LongcatFlashConfig",
+                              mesh=None) -> dict:
+    """The tree of :func:`longcat_flash.init_params` from a checkpoint of
+    the LongCat-Flash style of names (a layer's two sublayers under
+    ``self_attn.<i>``, ``input_layernorm.<i>``,
+    ``post_attention_layernorm.<i>`` and ``mlps.<i>``, its expert layer
+    under ``mlp``), holding one share of the routed experts under their
+    global indices. Each attention is :func:`_latent_attention`'s, with the
+    two scales of the latent norms' outputs (``mla_scale_q_lora``,
+    ``mla_scale_kv_lora``) folded into ``q_a_layernorm`` and
+    ``kv_a_layernorm``. The router
+    keeps its whole width, identity experts included; a selection bias is
+    taken where the checkpoint has one and is zero where not."""
+    w = _Weights(weights)
+    sh = longcat_flash.param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for li in range(cfg.num_layers):
+        pre = f"layers.{li}."
+        lsh = sh["layers"][li] if sh else {}
+
+        def sublayer(i: int) -> dict:
+            ssh = lsh["sub"][i] if lsh else {}
+            return {
+                "attn": _latent_attention(
+                    w, f"{pre}self_attn.{i}.", cfg.num_attention_heads,
+                    cfg.qk_nope_head_dim, ssh.get("attn", {}),
+                    cfg.latent_scales),
+                "attn_norm": w.get(f"{pre}input_layernorm.{i}.weight",
+                                   sharding=ssh.get("attn_norm")),
+                "mlp_norm": w.get(
+                    f"{pre}post_attention_layernorm.{i}.weight",
+                    sharding=ssh.get("mlp_norm")),
+                **{f"{x}_proj": w.get(f"{pre}mlps.{i}.{x}_proj.weight",
+                                      transpose=True,
+                                      sharding=ssh.get(f"{x}_proj"))
+                   for x in ("gate", "up", "down")},
+            }
+
+        bias = pre + "mlp.router.e_score_correction_bias"
+        layers.append({
+            "sub": [sublayer(0), sublayer(1)],
+            "router": w.get(pre + "mlp.router.classifier.weight",
+                            transpose=True, sharding=lsh.get("router")),
+            "router_bias": w.get(
+                bias, sharding=lsh.get("router_bias")).astype(jnp.float32)
+            if w.has(bias) else _zeros((cfg.router_width,), "float32",
+                                       lsh.get("router_bias"))(),
+            "experts_gate_up": _stack_experts(
+                w, pre, ("gate", "up"), cfg, lsh.get("experts_gate_up")),
+            "experts_down": _stack_experts(
+                w, pre, ("down",), cfg, lsh.get("experts_down")),
+        })
     return {
         "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
         "layers": layers,

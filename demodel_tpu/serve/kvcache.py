@@ -389,6 +389,9 @@ class KVBlockPool:
             # values too)
             "page": "latent" if self.spec.values else "kv",
             "value_dim": self.spec.values or self.spec.head_dim,
+            # the layers that page: a model with two cached sublayers a
+            # layer has twice its own count here
+            "layers": self.spec.layers,
             "block_tokens": self.block_tokens,
             "block_bytes": self.block_bytes,
             "num_blocks": self.num_blocks,

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1, experts, hf_loader
+from demodel_tpu.models import axk1, experts, hf_loader, latent
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB
@@ -208,16 +208,16 @@ def test_absorbed_attention_is_the_expanded_one(wide, heads):
     x = jax.random.normal(jax.random.key(12), (len(lengths), T,
                                                cfg.hidden_size))
     positions = jnp.broadcast_to(jnp.arange(T), x.shape[:2])
-    whole, latent = axk1._attn_expanded(layer, x, cfg, positions)
+    whole, new = latent.expanded(layer, x, cfg.latent, positions)
     # the page: row b's positions in its own blocks, dealt backwards
     slots = kvcache.table_slots(-(-T // bs))
     nb = len(lengths) * slots
     table = np.arange(nb)[::-1].reshape(len(lengths), slots)
     pad = slots * bs - T
-    assert latent.shape[-1] == cfg.page_dim == 128      # 32 | 8 | zeros
-    assert not np.asarray(latent[..., cfg.latent_dim:]).any()
+    assert new.shape[-1] == cfg.page_dim == 128         # 32 | 8 | zeros
+    assert not np.asarray(new[..., cfg.latent_dim:]).any()
     paged = np.zeros((1, nb + 1, 1, bs, cfg.page_dim), np.float32)
-    rows = np.pad(np.asarray(latent), ((0, 0), (0, pad), (0, 0), (0, 0)))
+    rows = np.pad(np.asarray(new), ((0, 0), (0, pad), (0, 0), (0, 0)))
     paged[0, table] = rows.reshape(len(lengths), slots, bs, 1, -1) \
         .transpose(0, 1, 3, 2, 4)
     cache = kvcache.Paged(jnp.asarray(paged), None, jnp.asarray(table))
@@ -229,13 +229,13 @@ def test_absorbed_attention_is_the_expanded_one(wide, heads):
     if wide:
         assert past.by_row(heads * (cfg.kv_lora_rank + 2) * 4) \
             == (heads == 32)
-    got, new = axk1._attn_absorbed(layer, step, cfg, at[:, None], past)
+    got, last = latent.absorbed(layer, step, cfg.latent, at[:, None], past)
     want = np.take_along_axis(np.asarray(whole),
                               lengths[:, None, None], axis=1)
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
     np.testing.assert_allclose(
-        new, np.take_along_axis(np.asarray(latent),
-                                lengths[:, None, None, None], axis=1),
+        last, np.take_along_axis(np.asarray(new),
+                                 lengths[:, None, None, None], axis=1),
         rtol=0, atol=1e-6)
 
 

@@ -24,6 +24,12 @@ whose partials outweigh their share of a tile's bytes, and moved none of
 these digests: which step takes it is a rule on shapes
 (``kvcache.Tiles.by_row``), held below at the benchmark's head counts and
 widths.
+
+PR 44 lifted A.X-K1's latent attention into ``models/latent.py`` for a
+second family to call: A.X-K1's eight digests are from that PR's parent
+and are the same with the attention where it now is. LongCat-Flash's are
+its own first ones (a narrow step, a wide one over the filled tiles of its
+two sublayers a layer, a prefill).
 """
 
 from __future__ import annotations
@@ -39,13 +45,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1, exaone_moe, llama, phi4flash, qwen3_next
+from demodel_tpu.models import (axk1, exaone_moe, llama, longcat_flash,
+                                phi4flash, qwen3_next)
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 
 FAMILIES = {"llama": (llama, llama.LlamaConfig),
             "exaone_moe": (exaone_moe, exaone_moe.ExaoneMoeConfig),
-            "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig)}
+            "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig),
+            "axk1": (axk1, axk1.AxK1Config),
+            "longcat_flash": (longcat_flash,
+                              longcat_flash.LongcatFlashConfig)}
 
 PINNED = {
     ("llama", "float32", "decode"): "ff3c2a1e16526eb9",
@@ -72,6 +82,22 @@ PINNED = {
     ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "12ce9dce817a9064",
     ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "831fdf7c7bf216a2",
     ("qwen3_next", "bfloat16", "prefill"): "9be76f1f541ba68d",
+    ("axk1", "float32", "decode"): "d7b59be681f275d9",
+    ("axk1", "float32", "decode-past-16-blocks"): "d7b59be681f275d9",
+    ("axk1", "float32", "decode-past-two-tiles"): "38f301f8ec933883",
+    ("axk1", "float32", "prefill"): "4737ddce74173f3b",
+    ("axk1", "bfloat16", "decode"): "7afdc0cdbb58a9ee",
+    ("axk1", "bfloat16", "decode-past-16-blocks"): "7afdc0cdbb58a9ee",
+    ("axk1", "bfloat16", "decode-past-two-tiles"): "79c6cc0cef1e4fc4",
+    ("axk1", "bfloat16", "prefill"): "c0d5ea7abb6d68e6",
+    ("longcat_flash", "float32", "decode"): "7daed492bb1931c2",
+    ("longcat_flash", "float32", "decode-past-two-tiles"):
+        "7be5b060b8f32411",
+    ("longcat_flash", "float32", "prefill"): "dbfc42672cc681df",
+    ("longcat_flash", "bfloat16", "decode"): "824c5d651f993864",
+    ("longcat_flash", "bfloat16", "decode-past-two-tiles"):
+        "4b357ca10b71284d",
+    ("longcat_flash", "bfloat16", "prefill"): "125a3908022e6065",
 }
 
 
@@ -145,6 +171,8 @@ CONFIGS = {
                                False),
     "phi-4-mini-flash": (phi4flash, phi4flash.Phi4FlashConfig, 32, False),
     "ax-k1-519b-l7-ep16": (axk1, axk1.AxK1Config, 64, True),
+    "longcat-flash-omni-560b-l4-ep32": (
+        longcat_flash, longcat_flash.LongcatFlashConfig, 64, True),
 }
 
 
@@ -153,9 +181,10 @@ def test_the_carry_follows_from_shapes_alone(name):
     """What the loop over the filled tiles carries, at each configuration's
     published head counts and widths in bfloat16 blocks of 16 positions, at
     both wide widths: a tile's float32 partials (query heads x (values + 2)
-    x 4 B) against the bytes of the tile they were taken from. A.X-K1's
-    absorbed step (64 heads over one cached vector of 640, values of 512)
-    reads 0.40 and carries a row; every family that pages K and V reads
+    x 4 B) against the bytes of the tile they were taken from. The two
+    absorbed steps (A.X-K1's and LongCat-Flash's: 64 heads over one cached
+    vector of 640, values of 512) read 0.40 and carry a row (LongCat-Flash
+    in each of its 8 sublayers); every family that pages K and V reads
     0.016-0.032 and keeps a tile. The index inside the program
     (``Tiles.by_row``) and the host's count of the bytes
     (``KVBlockPool.partial_bytes``, the step span's ``attn_partial_bytes``)

@@ -14,7 +14,7 @@ import jax
 import pytest
 
 from demodel_tpu import serve
-from demodel_tpu.models import llama
+from demodel_tpu.models import llama, longcat_flash
 from demodel_tpu.serve import GenEngine
 from demodel_tpu.utils import compile_cache, trace
 from demodel_tpu.utils.metrics import HUB, labeled, parse_labels
@@ -164,6 +164,37 @@ class TestProgramReady:
         assert recorded == []
         for stage in ("prefill", "decode"):
             assert _ready(after, stage) - _ready(before, stage) == 1
+
+
+def test_a_family_of_two_sublayers_a_layer_is_counted_alike(exporting):
+    """LongCat-Flash (a pool of twice the model's layers, three statistics
+    a step) through the same two dispatch sites: a prompt of 5 makes ready
+    a prefill and the narrow step (two tiles of blocks of 2: 64
+    positions); one of 63 a second prefill and, where its row passes two
+    tiles, the wide step (256 slots): four programs, a span and a count
+    each, and none for what the third request repeats."""
+    cfg = longcat_flash.LongcatFlashConfig.tiny()
+    params = longcat_flash.init_params(jax.random.key(2), cfg)
+    engine = GenEngine(params, cfg, max_batch=2, queue_limit=8,
+                       max_new_tokens=4, kv_mb=1, block_tokens=2).start()
+    born = _programs()
+    try:
+        for prompt in (5, 63, 63):
+            engine.generate(list(range(1, prompt + 1)), 4, timeout=240)
+        described = engine.describe()["programs"]
+    finally:
+        engine.stop()
+    after = _programs()
+    shapes = [(s["attrs"]["stage"],
+               s["attrs"].get("prompt") or s["attrs"]["width"])
+              for s in _spans(compile_cache.READY_SPAN)]
+    assert shapes == [("prefill", 5), ("decode", 64), ("prefill", 63),
+                      ("decode", 512)]
+    for stage in ("prefill", "decode"):
+        assert _ready(after, stage) - _ready(born, stage) == 2
+    assert _ready(after, "other") == _ready(born, "other")
+    assert (described["last"]["stage"], described["last"]["shape"]) \
+        == ("decode", [1, 512])
 
 
 class TestLoadModelSpans:

@@ -13,6 +13,11 @@ And of the benchmark's 64-row step over that page (~15 s): the loop over
 the filled tiles carries one running softmax a row (PR 43), so the program
 holds no float32 array of the table's capacity and gathers no queries to
 it.
+
+And of LongCat-Flash's 64-row step over a page of 8 sublayers (~20 s): the
+same page and the same carry in each of its two latent attentions a layer,
+and its weights held as both programs read them (no array of 30 MB is
+copied into another order).
 """
 
 from __future__ import annotations
@@ -23,9 +28,10 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1
+from demodel_tpu.models import axk1, latent, longcat_flash
 from demodel_tpu.serve import kvcache
 
 
@@ -67,7 +73,7 @@ def test_the_latent_page_lies_as_it_is_read(one_chip):
     cfg = axk1.AxK1Config(num_hidden_layers=7, dtype="bfloat16")
     spec = axk1.cache_spec(cfg)
     assert (spec.head_dim, spec.values, cfg.latent_dim) == (640, 512, 576)
-    assert spec.head_dim % axk1.LANES == 0
+    assert spec.head_dim % latent.LANES == 0
     page = (spec.layers, BLOCKS, spec.kv_heads, 16, spec.head_dim)
     # columns innermost, then a block's positions
     assert _born(page, one_chip)[:2] == [4, 3]
@@ -130,3 +136,66 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     assert re.search(rf"f32\[{rows},1,{cfg.num_attention_heads},1,"
                      rf"{spec.values}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
+    """``longcat-flash-omni-560b-l4-ep32``'s decode step as
+    ``longcat-reason`` runs it (64 rows at 256 table slots each), compiled
+    for the described chip: the pool of 8 paging layers of 640 columns (two
+    sublayers a layer, 1 920 MiB) lies as it is read; every one of the 8
+    loops over the filled tiles carries a row; and the weights are read in
+    the layouts they are held in (``q_b`` ``[out, in]``, ``w_uk`` / ``w_uv``
+    ``[H, 512, 128]``, the experts stacked ``[E, D, 2F]`` / ``[E, F, D]``):
+    the program copies nothing of 30 MB into another order (an expert stack
+    is 0.8 GB and 0.4 GB, a dense block's matrix 151 MB, ``o_proj`` 101 MB),
+    and its temporaries are 0.11 GB beside 12.36 GB of arguments."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / "longcat-flash-omni-560b-l4-ep32.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = longcat_flash.LongcatFlashConfig.from_hf(doc)
+    spec = longcat_flash.cache_spec(cfg)
+    assert (spec.layers, spec.readers, spec.head_dim, spec.values) \
+        == (8, 8, 640, 512)
+    bt = engine["block_tokens"]
+    blocks = (engine["kv_mb"] << 20) // (spec.layers * bt * spec.head_dim
+                                         * 2) + 1
+    assert blocks == 12288 + 1
+    page = (spec.layers, blocks, spec.kv_heads, bt, spec.head_dim)
+    assert _born(page, one_chip)[:2] == [4, 3]
+    rows, slots = engine["max_batch"], 256
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, table, lengths, tokens, k):
+        cache = kvcache.Paged(k, None, table)
+        logits, new, *stats = longcat_flash.step_decode(
+            params, tokens, cfg, cache, lengths)
+        latents, _fresh = kvcache.parts(new)
+        return logits, stats, *kvcache.put_positions(
+            k, None, latents, table[:, 0], lengths % bt)
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: longcat_flash.init_params(jax.random.key(1),
+                                                         cfg)))
+    compiled = jax.jit(decode, donate_argnums=(4,)).lower(
+        params, shaped((rows, slots), jnp.int32),
+        *(shaped((rows,), jnp.int32),) * 2,
+        shaped(page, jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    copied = [m.group(0) for m in re.finditer(
+        r"= (bf16|f32)\[([\d,]+)\][^ ]* (copy|transpose)\(", text)
+        if np.prod([int(d) for d in m.group(2).split(",")])
+        * (2 if m.group(1) == "bf16" else 4) > 30e6
+        # a chunk of 128 gathered tiles, turned for the scores
+        and m.group(2) != "2048,16,640"]
+    assert not copied, copied
+    capacity = rows * slots // kvcache.TILE_BLOCKS
+    assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
+    # the carry: a row's weighted values, under every head
+    assert re.search(rf"f32\[{rows},1,{cfg.num_attention_heads},1,"
+                     rf"{spec.values}\]", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.2e9
+    assert 12.3e9 < memory.argument_size_in_bytes < 12.4e9
