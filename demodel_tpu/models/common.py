@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from demodel_tpu.ops import latent_tiles
 from demodel_tpu.utils.env import env_bool
 
 
@@ -77,7 +78,10 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
     softmax runs over those alone, a chunk of tiles a trip: the rectangle
     is the case in which nothing can be skipped. Between the trips the
     running softmax is kept a tile where that is little beside the tile's
-    own bytes, and a row where it is not (:func:`_over_tiles`)."""
+    own bytes, and a row where it is not; and where it is a row's and the
+    page is one array, a program lowered for a TPU runs a kernel that
+    reads the tiles from the pool in the loop's place
+    (:func:`_over_tiles`)."""
     B, T, H, hd = q.shape
     Hkv, vd = k.shape[2], v.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
@@ -136,8 +140,19 @@ def _over_tiles(q, s_new, v, tiles, scale):
     product with the chunk's membership ``[rows, tiles]``, and the carry
     meets the new keys as it is. A row with no tile filled sees its new
     keys only. The values are ``vd`` wide, as ``v`` is; tiles with no ``v``
-    of their own give the first ``vd`` columns of their keys."""
-    B, T, Hkv, g, _hd = q.shape
+    of their own give the first ``vd`` columns of their keys.
+
+    A trip gathers its chunk of tiles into one array before its products
+    (XLA fuses no gather into the product that reads it). Where the carry
+    is a row's and the page is one array under one cached head, a Pallas
+    kernel does without: it follows the index itself and copies each
+    tile's blocks from the pool into fast memory
+    (:mod:`demodel_tpu.ops.latent_tiles`; the same arithmetic, the same
+    carry out). Which of the two a program holds is the platform's it is
+    lowered for (``lax.platform_dependent``: the kernel on a TPU, the loop
+    everywhere else, where it is also the kernel's oracle), nothing
+    else's."""
+    B, T, Hkv, g, hd = q.shape
     vd = v.shape[-1]
     C, n = tiles.row.shape[0], tiles.chunk_tiles
     f32 = jnp.float32
@@ -193,11 +208,26 @@ def _over_tiles(q, s_new, v, tiles, scale):
 
     with jax.named_scope("attn.tiles"):
         if tiles.by_row(Hkv * g * T * (vd + 2) * jnp.dtype(f32).itemsize):
-            values, tops, sums = lax.fori_loop(
-                jnp.uint32(0), tiles.trips, trip_rows,
-                (jnp.zeros((B, Hkv, g, T, vd), f32),
-                 jnp.full((B, Hkv, g, T, 1), -1e30, f32),
-                 jnp.zeros((B, Hkv, g, T, 1), f32)))
+            def loop():
+                return lax.fori_loop(
+                    jnp.uint32(0), tiles.trips, trip_rows,
+                    (jnp.zeros((B, Hkv, g, T, vd), f32),
+                     jnp.full((B, Hkv, g, T, 1), -1e30, f32),
+                     jnp.zeros((B, Hkv, g, T, 1), f32)))
+
+            def in_place():
+                carry = latent_tiles.over_filled_tiles(
+                    q.transpose(0, 2, 3, 1, 4).reshape(B, g * T, hd), tiles,
+                    scale, vd)
+                return tuple(a.reshape(B, Hkv, g, T, -1) for a in carry)
+
+            if tiles.v is None and Hkv == 1:
+                # one cached vector under every head: on a TPU the kernel
+                # reads the tiles from the pool, and the loop is its oracle
+                values, tops, sums = lax.platform_dependent(
+                    tpu=in_place, default=loop)
+            else:
+                values, tops, sums = loop()
             top = jnp.maximum(tops, s_new.max(axis=-1, keepdims=True))
             w = jnp.exp(tops - top)
             p_new = jnp.exp(s_new - top)

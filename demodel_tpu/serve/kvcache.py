@@ -118,6 +118,7 @@ HUB.inc("gen_state_slots_alloc_total", 0)
 HUB.inc("gen_state_slots_freed_total", 0)
 HUB.inc("gen_kv_positions_width_total", 0)
 HUB.inc("gen_kv_positions_read_total", 0)
+HUB.inc("gen_kv_positions_in_place_total", 0)
 HUB.inc("gen_attn_partial_bytes_total", 0)
 
 
@@ -233,6 +234,8 @@ class KVBlockPool:
                 mesh, P(None, None, heads, None, None))
             #: where the engine puts a program's small per-call inputs
             self.replicated = NamedSharding(mesh, P())
+        #: what the engine's programs are lowered for: the arrays' devices'
+        self.platform = next(iter(self.sharding.device_set)).platform
         #: names of the slot's arrays, in the order :attr:`arrays` holds
         #: them after the pages
         self.state_names = tuple(name for name, _s, _d in spec.state)
@@ -287,15 +290,36 @@ class KVBlockPool:
         pages: one set a tile of the table's capacity, or one a row where
         the rule of :meth:`Tiles.by_row` says so; 0 for a narrow table,
         which has no loop."""
-        spec = self.spec
         if not _wide(slots):
             return 0
-        partial = spec.query_heads * ((spec.values or spec.head_dim) + 2) * 4
+        return self.spec.readers * self._partial * (
+            rows if self._by_row else rows * (slots // TILE_BLOCKS))
+
+    @property
+    def _partial(self) -> int:
+        """A tile's float32 partials, every query head's."""
+        spec = self.spec
+        return spec.query_heads * ((spec.values or spec.head_dim) + 2) * 4
+
+    @property
+    def _by_row(self) -> bool:
         # a tile of one layer: block_bytes counts every layer's
-        by_row = _by_row(partial,
-                         TILE_BLOCKS * self.block_bytes // spec.layers)
-        return spec.readers * partial * (
-            rows if by_row else rows * (slots // TILE_BLOCKS))
+        return _by_row(self._partial,
+                       TILE_BLOCKS * self.block_bytes // self.spec.layers)
+
+    def positions_in_place(self, slots: int, read: int) -> int:
+        """On the host, from shapes and the pool's own devices: how many of
+        the ``read`` positions a decode step's attention reads at ``slots``
+        table slots a row it reads from the pool itself, with no gathered
+        copy: all of them where the step's programs hold the kernel that
+        follows the filled tiles (:mod:`demodel_tpu.ops.latent_tiles`: a
+        page of one array under one cached head, a wide table, the
+        partials carried a row, programs lowered for a TPU), none
+        otherwise: the rule of :func:`models.common._over_tiles`."""
+        in_place = (self.pages == 1 and self.spec.kv_heads == 1
+                    and _wide(slots) and self._by_row
+                    and self.platform == "tpu")
+        return read if in_place else 0
 
     # ------------------------------------------------------- alloc/free
     def alloc(self, n: int) -> BlockLease:

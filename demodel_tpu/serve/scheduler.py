@@ -920,17 +920,20 @@ class GenEngine:
                 B = len(flight.batch)
                 table, read = flight.kv_positions
                 # the bucket's rows at the table's slots a row
+                slots = flight.width // self.pool.block_tokens
                 partials = self.pool.partial_bytes(
-                    table // flight.width,
-                    flight.width // self.pool.block_tokens)
+                    table // flight.width, slots)
+                in_place = self.pool.positions_in_place(slots, read)
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead),
                                    ("kv_positions_width", table),
                                    ("kv_positions_read", read),
+                                   ("kv_positions_in_place", in_place),
                                    ("attn_partial_bytes", partials)):
                     cycle.set_attr(key, value)
                 HUB.inc("gen_kv_positions_width_total", table)
                 HUB.inc("gen_kv_positions_read_total", read)
+                HUB.inc("gen_kv_positions_in_place_total", in_place)
                 HUB.inc("gen_attn_partial_bytes_total", partials)
                 if self._slotted:
                     # each row's slot, read and written, unless the module

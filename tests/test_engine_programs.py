@@ -30,6 +30,11 @@ second family to call: A.X-K1's eight digests are from that PR's parent
 and are the same with the attention where it now is. LongCat-Flash's are
 its own first ones (a narrow step, a wide one over the filled tiles of its
 two sublayers a layer, a prefill).
+
+PR 45 put a Pallas kernel in the place of the row-carry loop where the
+program is lowered for a TPU and the page is one array: the CPU's text of
+every pinned program is the parent's (the tiny latent configurations carry
+a tile), and which platform gets the kernel is held below.
 """
 
 from __future__ import annotations
@@ -101,24 +106,31 @@ PINNED = {
 }
 
 
-def programs(module, cfg) -> dict[str, str]:
+def programs(module, cfg, platform: str | None = None) -> dict[str, str]:
     """The lowered text of the engine's decode step (three rows of 9, 5 and
     3 cached positions in a bucket of four; ``decode-past-16-blocks``: of
     70, 5 and 3, so that the longest row holds 18 blocks of 4;
     ``decode-past-two-tiles``: of 140, 5 and 3, 35 blocks, so that the table
     is 256 slots wide and the attention runs over the filled tiles) and of
-    a 20-token prefill."""
+    a 20-token prefill; lowered for the devices at hand (the CPU), or for
+    ``platform`` with none attached."""
     params = module.init_params(jax.random.key(1), cfg)
     engine = GenEngine(params, cfg, max_batch=4, queue_limit=8,
                        max_new_tokens=8, kv_mb=1, block_tokens=4)
     pool = engine.pool
     lease = pool.alloc(40)
 
+    def lower(program, *args):
+        if platform is None:
+            return program.lower(*args).as_text()
+        return program.trace(*args).lower(
+            lowering_platforms=(platform,)).as_text()
+
     def decode(*lengths):
         _w, rows = engine._decode_inputs(
             [_Seq(None, lease, n, 1) for n in lengths])
-        return engine._jdecode.lower(engine.params, rows, engine._prev_ids,
-                                     *pool.arrays).as_text()
+        return lower(engine._jdecode, engine.params, rows, engine._prev_ids,
+                     *pool.arrays)
 
     try:
         blocks = np.asarray(
@@ -127,9 +139,9 @@ def programs(module, cfg) -> dict[str, str]:
             "decode": decode(9, 5, 3),
             "decode-past-16-blocks": decode(70, 5, 3),
             "decode-past-two-tiles": decode(140, 5, 3),
-            "prefill": engine._jprefill.lower(
-                engine.params, np.zeros((1, 20), np.int32), blocks,
-                *pool.arrays).as_text()}
+            "prefill": lower(
+                engine._jprefill, engine.params, np.zeros((1, 20), np.int32),
+                blocks, *pool.arrays)}
     finally:
         lease.free()
         engine.stop()
@@ -159,6 +171,43 @@ def lowered():
 def test_program_is_the_parents(lowered, family, dtype, stage):
     assert digest(lowered(family, dtype)[stage]) \
         == PINNED[family, dtype, stage]
+
+
+@pytest.mark.parametrize("family,heads,in_place", [
+    ("axk1", 32, True), ("axk1", 4, False), ("llama", 8, False)],
+    ids=["a-latent-page-carried-a-row", "a-latent-page-carried-a-tile",
+         "keys-and-values-apart"])
+def test_the_platform_chooses_the_kernel_at_lowering(family, heads,
+                                                     in_place):
+    """The same trace lowered for the CPU and, with no chip attached, for a
+    TPU: where the wide step's past is a page of one array carried a row
+    (A.X-K1's absorbed step at 32 heads) the TPU's text holds the Pallas
+    kernel's custom call, called by every latent attention, and no loop,
+    the CPU's the loop and no custom call: ``lax.platform_dependent`` in
+    ``common._over_tiles``, resolved when the program is lowered. No
+    environment variable, configuration key or model name is consulted,
+    and none is set here. A page carried a tile (4 heads) and a page of
+    keys and values apart keep the loop on both platforms, and the narrow
+    step and the prefill hold neither."""
+    module, config = FAMILIES[family]
+    tiny = config.tiny(num_attention_heads=heads) if family == "axk1" \
+        else config.tiny()
+    assert tiny.num_attention_heads == heads
+    cfg = dataclasses.replace(tiny, dtype="bfloat16")
+    layers = module.cache_spec(cfg).readers
+    cpu, tpu = programs(module, cfg), programs(module, cfg, platform="tpu")
+    wide = "decode-past-two-tiles"
+    for stage in cpu:
+        assert "tpu_custom_call" not in cpu[stage]
+        assert cpu[stage].count("stablehlo.while") \
+            == (layers if stage == wide else 0)
+        # one function a program holds the kernel (traced and lowered
+        # once, whatever the layers), and every latent attention calls it
+        kernels = layers if in_place and stage == wide else 0
+        assert tpu[stage].count("@tpu_custom_call") == (kernels > 0)
+        assert tpu[stage].count("call @over_filled_tiles") == kernels
+        assert tpu[stage].count("stablehlo.while") \
+            == cpu[stage].count("stablehlo.while") - kernels
 
 
 #: the benchmark's configurations: module, configuration class, the rows of

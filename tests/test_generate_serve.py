@@ -681,6 +681,61 @@ class TestDevicePool:
         assert after[name] - before[name] == 2 * want
         assert counters[name] >= 2 * want
 
+    @pytest.mark.parametrize("family,platform,longest,in_place", [
+        ("axk1", "tpu", 70, True), ("axk1", "cpu", 70, False),
+        ("axk1", "tpu", 18, False), ("llama", "tpu", 70, False)],
+        ids=["a-latent-page-on-a-tpu", "on-the-cpu", "narrow",
+             "keys-and-values-apart"])
+    def test_a_step_books_the_positions_it_reads_in_place(
+            self, family, platform, longest, in_place):
+        """``kv_positions_in_place`` on ``serve.decode-step``: the
+        positions of ``kv_positions_read`` the step's attention reads from
+        the pool itself, with no gathered copy. All of them where the
+        kernel that follows the filled tiles is the step's path: a page of
+        one array whose partials are carried a row (A.X-K1's absorbed step
+        at 32 heads), a wide table, programs lowered for a TPU; none on
+        the CPU, where the loop gathers a chunk a trip, for a table of two
+        tiles, or for a page of keys and values apart. The platform is the
+        pool's devices'; the test says ``tpu`` in its place (the programs
+        it runs are the CPU's: what is held here is the host's count).
+        Counted in ``gen_kv_positions_in_place_total``, which ``/statusz``
+        has."""
+        from demodel_tpu.models import axk1
+        from demodel_tpu.utils import statusz, trace
+
+        if family == "axk1":
+            cfg = axk1.AxK1Config.tiny(num_attention_heads=32)
+            params = axk1.init_params(jax.random.key(2), cfg)
+        else:
+            _module, params, cfg = _tiny(family)
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
+                           max_new_tokens=4, kv_mb=1, block_tokens=2)
+        assert engine.pool.platform == "cpu"
+        engine.pool.platform = platform
+        trace.reset()
+        trace.enable()
+        try:
+            before = HUB.snapshot()
+            _drive(engine, [_prompt(cfg, n, seed=n)
+                            for n in (longest, 9, 5)], 3)
+            for _ in range(2):
+                engine._decode_step()
+            after = HUB.snapshot()
+            steps = [r["attrs"] for r in trace.buffer().snapshot()
+                     if r["name"] == "serve.decode-step"]
+            counters = statusz.snapshot()["counters"]
+        finally:
+            trace.reset()
+            engine.stop()
+        # 3 + 1 + 1 tiles of 32 positions of a wide table, all of a narrow
+        read = (3 + 1 + 1) * 32 if longest == 70 else 4 * 64
+        want = read if in_place else 0
+        assert [(a["kv_positions_read"], a["kv_positions_in_place"])
+                for a in steps] == [(read, want)] * 2
+        name = "gen_kv_positions_in_place_total"
+        assert after[name] - before[name] == 2 * want
+        assert name in counters and counters[name] >= 2 * want
+
     @pytest.mark.parametrize("longest,slots", [
         (1, 32), (2, 32), (3, 32), (9, 32), (31, 32), (32, 32), (33, 32),
         (64, 32), (65, 256), (300, 256), (512, 256), (513, 2048),

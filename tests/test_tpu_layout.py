@@ -9,15 +9,17 @@ then starts by copying the whole pool into that order: 2.4 GB a step at the
 benchmark's size, seen in the compiled decode step (PERF.md, Findings, PR
 42). At 640 columns the pool lies as it is read.
 
-And of the benchmark's 64-row step over that page (~15 s): the loop over
-the filled tiles carries one running softmax a row (PR 43), so the program
-holds no float32 array of the table's capacity and gathers no queries to
-it.
+And of the benchmark's 64-row step over that page (~15 s): the attention
+over the filled tiles carries one running softmax a row (PR 43), so the
+program holds no float32 array of the table's capacity and gathers no
+queries to it; and on a TPU it is the Pallas kernel of
+``ops/latent_tiles.py`` a latent attention (PR 45), which reads the tiles
+from the pool where they lie: no chunk of gathered tiles, no loop.
 
 And of LongCat-Flash's 64-row step over a page of 8 sublayers (~20 s): the
-same page and the same carry in each of its two latent attentions a layer,
-and its weights held as both programs read them (no array of 30 MB is
-copied into another order).
+same page, the same carry and the same kernel in each of its two latent
+attentions a layer, and its weights held as both programs read them (no
+array of 30 MB is copied into another order).
 """
 
 from __future__ import annotations
@@ -69,6 +71,29 @@ def _born(shape, sharding) -> list[int]:
     return [int(d) for d in layout.group(1).split(",")]
 
 
+def _in_place(text: str, attentions: int, rows: int, heads: int,
+              values: int) -> None:
+    """The compiled step holds the kernel's custom call a latent attention
+    (the pool handed to it as it lies), no chunk of gathered tiles, no loop
+    under ``attn.tiles``, and no copy of anything of 30 MB (the pool, a
+    weight) into another order."""
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "latent_filled_tiles" in line]
+    assert len(calls) == attentions
+    assert all('custom_call_target="tpu_custom_call"' in c
+               and "attn.tiles" in c for c in calls)
+    assert not re.findall(r"\[2048,16,[\d,]*640\]", text)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "attn.tiles" in line]
+    copied = [m.group(0) for m in re.finditer(
+        r"= (bf16|f32)\[([\d,]+)\][^ ]* (copy|transpose)\(", text)
+        if np.prod([int(d) for d in m.group(2).split(",")])
+        * (2 if m.group(1) == "bf16" else 4) > 30e6]
+    assert not copied, copied
+    # the carry: a row's weighted values, under every head
+    assert re.search(rf"f32\[{rows},{heads},{values}\]", text)
+
+
 def test_the_latent_page_lies_as_it_is_read(one_chip):
     cfg = axk1.AxK1Config(num_hidden_layers=7, dtype="bfloat16")
     spec = axk1.cache_spec(cfg)
@@ -97,9 +122,12 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     tiles left ``f32[1024,1,64,1,514]`` (135 MB a layer: filled, written a
     chunk a trip, gathered by row into ``[64,16,...]``, copied into another
     order) and gathered its queries to capacity, ``bf16[1024,1,1,64,640]``
-    (84 MB); the step's temporaries were 0.47 GB. Now a tile's partials
-    outweigh their share of the tile (``kvcache.Tiles.by_row``), the carry
-    is a row's, and the temporaries 0.12 GB."""
+    (84 MB); the step's temporaries were 0.47 GB. Since PR 43 a tile's
+    partials outweigh their share of the tile (``kvcache.Tiles.by_row``)
+    and the carry is a row's (0.12 GB of temporaries, of them the loop's
+    chunk of 128 gathered tiles, ``bf16[2048,16,640]``, 42 MB); since PR 45
+    each of the 7 latent attentions is the kernel that copies a tile's
+    blocks from the pool into fast memory, and the temporaries 0.034 GB."""
     doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
                       / "ax-k1-519b-l7-ep16.json").read_text())
     engine = doc.pop("benchmark")["engine"]
@@ -132,10 +160,8 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     # no float32 partials and no queries a tile of the capacity
     assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
     assert not re.findall(rf"bf16\[{capacity},[\d,]*{spec.head_dim}\]", text)
-    # the carry: a row's weighted values, under every head
-    assert re.search(rf"f32\[{rows},1,{cfg.num_attention_heads},1,"
-                     rf"{spec.values}\]", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    _in_place(text, spec.readers, rows, cfg.num_attention_heads, spec.values)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
 
 
 def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
@@ -143,12 +169,14 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
     ``longcat-reason`` runs it (64 rows at 256 table slots each), compiled
     for the described chip: the pool of 8 paging layers of 640 columns (two
     sublayers a layer, 1 920 MiB) lies as it is read; every one of the 8
-    loops over the filled tiles carries a row; and the weights are read in
+    attentions over the filled tiles carries a row and is the kernel that
+    reads them where they lie; and the weights are read in
     the layouts they are held in (``q_b`` ``[out, in]``, ``w_uk`` / ``w_uv``
     ``[H, 512, 128]``, the experts stacked ``[E, D, 2F]`` / ``[E, F, D]``):
     the program copies nothing of 30 MB into another order (an expert stack
     is 0.8 GB and 0.4 GB, a dense block's matrix 151 MB, ``o_proj`` 101 MB),
-    and its temporaries are 0.11 GB beside 12.36 GB of arguments."""
+    and its temporaries are 0.03 GB (0.11 with the loop's chunks, before
+    PR 45) beside 12.36 GB of arguments."""
     doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
                       / "longcat-flash-omni-560b-l4-ep32.json").read_text())
     engine = doc.pop("benchmark")["engine"]
@@ -184,18 +212,9 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
         *(shaped((rows,), jnp.int32),) * 2,
         shaped(page, jnp.bfloat16)).compile()
     text = compiled.as_text()
-    copied = [m.group(0) for m in re.finditer(
-        r"= (bf16|f32)\[([\d,]+)\][^ ]* (copy|transpose)\(", text)
-        if np.prod([int(d) for d in m.group(2).split(",")])
-        * (2 if m.group(1) == "bf16" else 4) > 30e6
-        # a chunk of 128 gathered tiles, turned for the scores
-        and m.group(2) != "2048,16,640"]
-    assert not copied, copied
     capacity = rows * slots // kvcache.TILE_BLOCKS
     assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
-    # the carry: a row's weighted values, under every head
-    assert re.search(rf"f32\[{rows},1,{cfg.num_attention_heads},1,"
-                     rf"{spec.values}\]", text)
+    _in_place(text, spec.readers, rows, cfg.num_attention_heads, spec.values)
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 0.2e9
+    assert memory.temp_size_in_bytes < 0.05e9
     assert 12.3e9 < memory.argument_size_in_bytes < 12.4e9
