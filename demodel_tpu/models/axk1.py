@@ -432,7 +432,8 @@ def step_decode(params, tokens, cfg: AxK1Config, cache, lengths,
         lengths.sum(dtype=jnp.int32)
 
 
-def observe(expert_tokens, positions, tokens: int, cfg: AxK1Config) -> dict:
+def observe(expert_tokens, positions, tokens: int, cfg: AxK1Config,
+            platform: str = "cpu", rows: int = 0) -> dict:
     """A step's stats (on the host) and the tokens it ran → the span's
     attributes: the experts' as :func:`experts.observe` names them, and
     ``latent_bytes``, the positions of the latent page the step's rows read
@@ -440,7 +441,8 @@ def observe(expert_tokens, positions, tokens: int, cfg: AxK1Config) -> dict:
     one (the page's zeros are not the latent's bytes). The counters are
     counted here."""
     attrs = experts.observe(
-        expert_tokens, tokens * cfg.num_experts_per_tok * cfg.sparse_layers)
+        expert_tokens, tokens * cfg.num_experts_per_tok * cfg.sparse_layers,
+        platform=platform, call_rows=rows * cfg.num_experts_per_tok)
     attrs.update(latent.observe(positions, cache_spec(cfg), cfg.latent,
                                 cfg.dtype))
     return attrs

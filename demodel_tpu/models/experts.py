@@ -20,12 +20,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from demodel_tpu.ops import grouped
 from demodel_tpu.utils.metrics import HUB, labeled
 
 HUB.inc(labeled("gen_moe_assignments_total", held="true"), 0)
 HUB.inc(labeled("gen_moe_assignments_total", held="false"), 0)
 HUB.inc("gen_moe_experts_hit_total", 0)
 HUB.inc("gen_moe_rows_computed_total", 0)
+HUB.inc("gen_moe_expert_reads_total", 0)
 
 #: assignment rows one pass of the grouped products holds: a layer with no
 #: more than this computes them all at once, a longer prompt the landed
@@ -37,6 +39,20 @@ def ep_size(mesh: Mesh | None) -> int:
     return int(mesh.shape.get("ep", 1)) if mesh is not None else 1
 
 
+def _grouped(rows, stacked, sizes, preferred_element_type=None):
+    """``rows`` [M, Kd], sorted by group, each group's through its own
+    matrix of ``stacked`` [E, Kd, Nd]. In a program lowered for a TPU the
+    kernel that streams each hit expert once
+    (:mod:`demodel_tpu.ops.grouped`); everywhere else ``lax.ragged_dot``,
+    the portable form and the kernel's oracle. Which one a program holds is
+    the platform's it is lowered for, nothing else's."""
+    return lax.platform_dependent(
+        rows, stacked, sizes,
+        tpu=lambda *a: grouped.grouped_dot(*a, preferred_element_type),
+        default=lambda *a: lax.ragged_dot(
+            *a, preferred_element_type=preferred_element_type))
+
+
 def _slab(x, held, weights, idx, sizes, gate_up, down, valid=None):
     """Assignments ``idx`` (positions in the flattened ``[N * K]``, sorted
     by expert, ``sizes`` [E] of them to each held expert) through their
@@ -45,10 +61,9 @@ def _slab(x, held, weights, idx, sizes, gate_up, down, valid=None):
     K, F = held.shape[1], down.shape[1]
     rows = x[idx // K]
     with jax.named_scope("moe.experts"):
-        h = lax.ragged_dot(rows, gate_up, sizes)
+        h = _grouped(rows, gate_up, sizes)
         h = jax.nn.silu(h[:, :F]) * h[:, F:]
-        y = lax.ragged_dot(h, down, sizes,
-                           preferred_element_type=jnp.float32)
+        y = _grouped(h, down, sizes, jnp.float32)
     # rows past the groups' end belong to no held expert: whatever the
     # grouped product left there is dropped, not scaled
     w = jnp.where(held, weights, 0.0).reshape(-1)[idx]
@@ -143,7 +158,23 @@ def swiglu(x, gate, up, down):
     return (jax.nn.silu(x @ gate) * (x @ up)) @ down
 
 
-def observe(expert_tokens, assignments: int, free: int = 0) -> dict:
+def expert_reads(expert_tokens, rows: int) -> int:
+    """Whole experts' worth of weights the kernel's tiling fetches for a
+    step's ``expert_tokens`` (on the host), a layer's call ``rows``
+    assignment rows long: :func:`held_part`'s calls of
+    :func:`grouped.grouped_dot`, slab by slab, under the same rule
+    (:func:`grouped.row_tile`). A group that crosses a row tile's end
+    counts its expert twice."""
+    tm = grouped.row_tile(min(rows, SLAB))
+    ends = expert_tokens.cumsum(axis=1)
+    starts = ends - expert_tokens
+    return sum(grouped.reads(ends.clip(lo, lo + SLAB)
+                             - starts.clip(lo, lo + SLAB), tm)
+               for lo in range(0, int(ends[:, -1].max()), SLAB))
+
+
+def observe(expert_tokens, assignments: int, free: int = 0,
+            platform: str = "cpu", call_rows: int = 0) -> dict:
     """A step's ``expert_tokens`` ([expert layers, held experts], on the
     host) and the assignments its tokens made in all → the step span's
     attributes; the counters are counted here. ``free`` of the assignments
@@ -152,16 +183,26 @@ def observe(expert_tokens, assignments: int, free: int = 0) -> dict:
     ``expert_rows`` is what
     :func:`held_part`'s grouped products ran over, layer by layer, as one
     chip runs it (the pad rows of a batch bucket not counted): over
-    ``expert_tokens`` it says how much of the work landed."""
+    ``expert_tokens`` it says how much of the work landed.
+    ``expert_reads`` is how many experts' weights those products fetched
+    where the step's program holds the kernel (one lowered for a TPU,
+    ``platform``; a layer's call ``call_rows`` assignment rows long, pad
+    rows and all, or as many as a layer's share of ``assignments``): over
+    ``experts_hit``, 1.0 is each hit expert once; 0 is the compiler's own
+    path."""
     landed = int(expert_tokens.sum())
     hit = int((expert_tokens > 0).sum())
     rows = assignments                  # up to a slab a layer: all of them
     if assignments > SLAB * len(expert_tokens):
         rows = int((-(-expert_tokens.sum(axis=1) // SLAB)).sum()) * SLAB
+    reads = expert_reads(
+        expert_tokens, call_rows or assignments // len(expert_tokens)) \
+        if platform == "tpu" else 0
     HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
     HUB.inc(labeled("gen_moe_assignments_total", held="false"),
             assignments - free - landed)
     HUB.inc("gen_moe_experts_hit_total", hit)
     HUB.inc("gen_moe_rows_computed_total", rows)
+    HUB.inc("gen_moe_expert_reads_total", reads)
     return {"expert_tokens": landed, "experts_hit": hit,
-            "expert_rows": rows}
+            "expert_rows": rows, "expert_reads": reads}

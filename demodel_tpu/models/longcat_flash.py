@@ -405,7 +405,8 @@ def step_decode(params, tokens, cfg: LongcatFlashConfig, cache, lengths,
 
 
 def observe(expert_tokens, zero_tokens, positions, tokens: int,
-            cfg: LongcatFlashConfig) -> dict:
+            cfg: LongcatFlashConfig, platform: str = "cpu",
+            rows: int = 0) -> dict:
     """A step's stats (on the host) and the tokens it ran → the span's
     attributes: ``assignments`` (every choice its tokens made, over all
     layers), ``zero_tokens`` (those that fell on an identity expert: held
@@ -416,6 +417,7 @@ def observe(expert_tokens, zero_tokens, positions, tokens: int,
     free = int(zero_tokens.sum())
     HUB.inc(labeled("gen_moe_assignments_total", held="zero"), free)
     return {"assignments": assignments, "zero_tokens": free,
-            **experts.observe(expert_tokens, assignments, free=free),
+            **experts.observe(expert_tokens, assignments, free=free,
+                              platform=platform, call_rows=rows * cfg.moe_topk),
             **latent.observe(positions, cache_spec(cfg), cfg.latent,
                              cfg.dtype)}

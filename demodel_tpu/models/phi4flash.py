@@ -678,7 +678,8 @@ def step_decode(params, tokens, cfg: Phi4FlashConfig, cache, lengths,
     return _head(params, x[:, 0], cfg), kvcache.Written([full], state), counts
 
 
-def observe(counts, tokens: int, cfg: Phi4FlashConfig) -> dict:
+def observe(counts, tokens: int, cfg: Phi4FlashConfig,
+            platform: str = "cpu", rows: int = 0) -> dict:
     """A step's ``counts`` (on the host) and the tokens it ran → the span's
     attributes, bytes from shapes: ``shared_kv_bytes`` the filled positions
     of the full layer's pages times the layers that read them (a prefill:
@@ -687,7 +688,8 @@ def observe(counts, tokens: int, cfg: Phi4FlashConfig) -> dict:
     written whole); ``state_bytes`` those and each row's Mamba states and
     tails read and written (a prefill: written); ``tail_layers`` the layers
     a prefill ran on its last position only. The counters are counted
-    here."""
+    here. What the step's program was lowered for (``platform``) and its
+    ``rows`` choose nothing in this family's programs."""
     shared, ring, tail = (int(n) for n in np.asarray(counts))
     itemsize = jnp.dtype(cfg.dtype).itemsize
     kinds = cfg.kinds

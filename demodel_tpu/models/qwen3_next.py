@@ -532,9 +532,12 @@ def step_decode(params, tokens, cfg: Qwen3NextConfig, cache, lengths,
     return _head(params, x[:, 0], cfg), Written(kv, state), counts
 
 
-def observe(expert_tokens, tokens: int, cfg: Qwen3NextConfig) -> dict:
-    """A step's ``expert_tokens`` (on the host) and the tokens it ran →
-    the span's attributes; the counters are counted here."""
+def observe(expert_tokens, tokens: int, cfg: Qwen3NextConfig,
+            platform: str = "cpu", rows: int = 0) -> dict:
+    """A step's ``expert_tokens`` (on the host) and the tokens it ran (of
+    the program's ``rows``) → the span's attributes; the counters are
+    counted here."""
     return experts.observe(
         expert_tokens,
-        tokens * cfg.num_experts_per_tok * cfg.num_hidden_layers)
+        tokens * cfg.num_experts_per_tok * cfg.num_hidden_layers,
+        platform=platform, call_rows=rows * cfg.num_experts_per_tok)

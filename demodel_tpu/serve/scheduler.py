@@ -784,12 +784,12 @@ class GenEngine:
                         # it the span ends at dispatch and the pull of
                         # the id takes the wait
                         jax.block_until_ready((ids, *stats))
-                        self._observe(dev, jax.device_get(stats), T)
+                        self._observe(dev, jax.device_get(stats), T, T)
                         stats = []
                 ids, *stats = jax.device_get([ids, *stats])
                 seq.first = None
                 HUB.inc("gen_d2h_bytes_total", pulled)
-                self._observe(None, stats, T)
+                self._observe(None, stats, T, T)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             if seq is not None:
                 log.error("prefill failed for request %d: %s", req.id, exc)
@@ -808,12 +808,16 @@ class GenEngine:
         if seq.generated >= req.max_new_tokens:
             self._retire(seq)
 
-    def _observe(self, span, stats: list, tokens: int) -> None:
+    def _observe(self, span, stats: list, tokens: int, rows: int) -> None:
         """A step's stats, pulled to the host, to the model module that
-        made them: it counts them and names ``span``'s attributes."""
+        made them: it counts them and names ``span``'s attributes.
+        ``tokens`` of the program's ``rows`` were live (a bucket's pad
+        rows are not); the platform the program was lowered for is the
+        pool's devices'."""
         if not stats:
             return
-        attrs = self._module.observe(*stats, tokens=tokens, cfg=self.cfg)
+        attrs = self._module.observe(*stats, tokens=tokens, cfg=self.cfg,
+                                     platform=self.pool.platform, rows=rows)
         if span is not None:
             for key, value in attrs.items():
                 span.set_attr(key, value)
@@ -921,8 +925,8 @@ class GenEngine:
                 table, read = flight.kv_positions
                 # the bucket's rows at the table's slots a row
                 slots = flight.width // self.pool.block_tokens
-                partials = self.pool.partial_bytes(
-                    table // flight.width, slots)
+                bucket = table // flight.width
+                partials = self.pool.partial_bytes(bucket, slots)
                 in_place = self.pool.positions_in_place(slots, read)
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead),
@@ -960,7 +964,7 @@ class GenEngine:
                     # (1.7 ms a cycle at 32 rows)
                     flight.ids = flight.stats = None
                     HUB.inc("gen_d2h_bytes_total", pulled)
-                self._observe(cycle, stats, B)
+                self._observe(cycle, stats, B, bucket)
                 with trace.span("serve.decode-post", batch=B) as post:
                     retired = emitted = 0
                     for seq, tok in zip(flight.batch, ids.tolist()):

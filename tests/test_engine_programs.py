@@ -35,6 +35,15 @@ PR 45 put a Pallas kernel in the place of the row-carry loop where the
 program is lowered for a TPU and the page is one array: the CPU's text of
 every pinned program is the parent's (the tiny latent configurations carry
 a tile), and which platform gets the kernel is held below.
+
+PR 46 put a second kernel behind the same seam: the held experts' grouped
+products (``models/experts._grouped``) are ``ops/grouped.py``'s kernel in a
+program lowered for a TPU and ``lax.ragged_dot`` everywhere else. The four
+expert families' digests are pinned again for it (the CPU's text now holds
+the platform's choice, a ``case`` of one branch, around each product: all 30
+of ``exaone_moe``, ``qwen3_next``, ``axk1`` and ``longcat_flash``);
+``llama``'s eight are the parent's, as is every program of a family with no
+expert layer.
 """
 
 from __future__ import annotations
@@ -71,38 +80,38 @@ PINNED = {
     ("llama", "bfloat16", "decode-past-16-blocks"): "fecca8e7814f2856",
     ("llama", "bfloat16", "decode-past-two-tiles"): "185d9f2da608cc06",
     ("llama", "bfloat16", "prefill"): "b9db3f7fcec8e591",
-    ("exaone_moe", "float32", "decode"): "67b0d27ade84e175",
-    ("exaone_moe", "float32", "decode-past-16-blocks"): "67b0d27ade84e175",
-    ("exaone_moe", "float32", "decode-past-two-tiles"): "295798baeed1830c",
-    ("exaone_moe", "float32", "prefill"): "f60f53665d09d6e1",
-    ("exaone_moe", "bfloat16", "decode"): "27c74dc4fd4b723b",
-    ("exaone_moe", "bfloat16", "decode-past-16-blocks"): "27c74dc4fd4b723b",
-    ("exaone_moe", "bfloat16", "decode-past-two-tiles"): "5784d46755a90206",
-    ("exaone_moe", "bfloat16", "prefill"): "45542946a61fe728",
-    ("qwen3_next", "float32", "decode"): "d424ee57b47bb163",
-    ("qwen3_next", "float32", "decode-past-16-blocks"): "d424ee57b47bb163",
-    ("qwen3_next", "float32", "decode-past-two-tiles"): "c42f67b2817b66bb",
-    ("qwen3_next", "float32", "prefill"): "d3bd2b0489d00d06",
-    ("qwen3_next", "bfloat16", "decode"): "12ce9dce817a9064",
-    ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "12ce9dce817a9064",
-    ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "831fdf7c7bf216a2",
-    ("qwen3_next", "bfloat16", "prefill"): "9be76f1f541ba68d",
-    ("axk1", "float32", "decode"): "d7b59be681f275d9",
-    ("axk1", "float32", "decode-past-16-blocks"): "d7b59be681f275d9",
-    ("axk1", "float32", "decode-past-two-tiles"): "38f301f8ec933883",
-    ("axk1", "float32", "prefill"): "4737ddce74173f3b",
-    ("axk1", "bfloat16", "decode"): "7afdc0cdbb58a9ee",
-    ("axk1", "bfloat16", "decode-past-16-blocks"): "7afdc0cdbb58a9ee",
-    ("axk1", "bfloat16", "decode-past-two-tiles"): "79c6cc0cef1e4fc4",
-    ("axk1", "bfloat16", "prefill"): "c0d5ea7abb6d68e6",
-    ("longcat_flash", "float32", "decode"): "7daed492bb1931c2",
+    ("exaone_moe", "float32", "decode"): "69d4b9d9de4085a3",
+    ("exaone_moe", "float32", "decode-past-16-blocks"): "69d4b9d9de4085a3",
+    ("exaone_moe", "float32", "decode-past-two-tiles"): "73d57ce2e23b8fe9",
+    ("exaone_moe", "float32", "prefill"): "7dfe2b7bf8e5c253",
+    ("exaone_moe", "bfloat16", "decode"): "4bcc7f25d5f5de65",
+    ("exaone_moe", "bfloat16", "decode-past-16-blocks"): "4bcc7f25d5f5de65",
+    ("exaone_moe", "bfloat16", "decode-past-two-tiles"): "b7e005b08919f48a",
+    ("exaone_moe", "bfloat16", "prefill"): "40d60075749c8570",
+    ("qwen3_next", "float32", "decode"): "533379045d4ba4d7",
+    ("qwen3_next", "float32", "decode-past-16-blocks"): "533379045d4ba4d7",
+    ("qwen3_next", "float32", "decode-past-two-tiles"): "a97559f8dfb1d82d",
+    ("qwen3_next", "float32", "prefill"): "5f15f0fdab1e84c0",
+    ("qwen3_next", "bfloat16", "decode"): "edc6a71c70beef8f",
+    ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "edc6a71c70beef8f",
+    ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "ce7268ea6b01e42c",
+    ("qwen3_next", "bfloat16", "prefill"): "429bff75fe08bade",
+    ("axk1", "float32", "decode"): "fdae1f714571c2b7",
+    ("axk1", "float32", "decode-past-16-blocks"): "fdae1f714571c2b7",
+    ("axk1", "float32", "decode-past-two-tiles"): "6422879d22f324fd",
+    ("axk1", "float32", "prefill"): "816cbba5e0584bdf",
+    ("axk1", "bfloat16", "decode"): "d4742f382b311ef3",
+    ("axk1", "bfloat16", "decode-past-16-blocks"): "d4742f382b311ef3",
+    ("axk1", "bfloat16", "decode-past-two-tiles"): "94d098f3a7cc027d",
+    ("axk1", "bfloat16", "prefill"): "99e8d79732ae7e2b",
+    ("longcat_flash", "float32", "decode"): "ee441f5cd5a73a62",
     ("longcat_flash", "float32", "decode-past-two-tiles"):
-        "7be5b060b8f32411",
-    ("longcat_flash", "float32", "prefill"): "dbfc42672cc681df",
-    ("longcat_flash", "bfloat16", "decode"): "824c5d651f993864",
+        "b2c433294a47d517",
+    ("longcat_flash", "float32", "prefill"): "144cbeab07fcc3c3",
+    ("longcat_flash", "bfloat16", "decode"): "091f6ff8756c3bb3",
     ("longcat_flash", "bfloat16", "decode-past-two-tiles"):
-        "4b357ca10b71284d",
-    ("longcat_flash", "bfloat16", "prefill"): "125a3908022e6065",
+        "cfd8e3f084509d10",
+    ("longcat_flash", "bfloat16", "prefill"): "06bae7ddafc6572a",
 }
 
 
@@ -204,10 +213,37 @@ def test_the_platform_chooses_the_kernel_at_lowering(family, heads,
         # one function a program holds the kernel (traced and lowered
         # once, whatever the layers), and every latent attention calls it
         kernels = layers if in_place and stage == wide else 0
-        assert tpu[stage].count("@tpu_custom_call") == (kernels > 0)
+        assert tpu[stage].count('kernel_name = "latent_filled_tiles"') \
+            == (kernels > 0)
         assert tpu[stage].count("call @over_filled_tiles") == kernels
         assert tpu[stage].count("stablehlo.while") \
             == cpu[stage].count("stablehlo.while") - kernels
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_platform_chooses_the_grouped_product_at_lowering(family):
+    """The same trace lowered for the CPU and, with no chip attached, for a
+    TPU: every grouped product of ``experts._slab`` (two a sparse layer, in
+    the step and in the prefill) is on a TPU a call of the one function
+    that holds ``ops/grouped.py``'s kernel, which the program traced and
+    lowered once a shape (gate beside up, down), and no ``ragged_dot`` is
+    left there; the CPU's text holds no custom call. A family with no
+    expert layer holds neither on either platform."""
+    module, config = FAMILIES[family]
+    cfg = dataclasses.replace(config.tiny(), dtype="bfloat16")
+    counted = {"exaone_moe": "sparse_layers", "axk1": "sparse_layers",
+               "qwen3_next": "num_hidden_layers",
+               "longcat_flash": "num_layers"}.get(family)
+    sparse = getattr(cfg, counted) if counted else 0
+    cpu, tpu = programs(module, cfg), programs(module, cfg, platform="tpu")
+    for stage in cpu:
+        assert "tpu_custom_call" not in cpu[stage]
+        assert "ragged" not in tpu[stage]
+        assert tpu[stage].count('kernel_name = "moe_grouped"') \
+            == 2 * (sparse > 0)
+        assert tpu[stage].count("call @grouped_dot") == 2 * sparse
+        assert tpu[stage].count("stablehlo.while") \
+            <= cpu[stage].count("stablehlo.while")
 
 
 #: the benchmark's configurations: module, configuration class, the rows of

@@ -6,12 +6,15 @@ loop runs at sizes the CPU is quick with), and what ``observe`` counts.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from demodel_tpu.models import experts
+from demodel_tpu.ops import grouped
 from demodel_tpu.utils.metrics import HUB, labeled
 
 SLAB = 64
@@ -143,12 +146,17 @@ def test_split_over_ep_is_one_chip(slab, case):
                          ids=lambda c: c.__name__[1:])
 def test_one_slab_is_straight_line(slab, case):
     """Up to a slab of assignments the program has no loop and no branch
-    (a decode step's and a short prompt's are the programs they were)."""
+    on its data (a decode step's and a short prompt's are straight-line
+    code): what ``case`` it holds is the platform's choice of each of the
+    two grouped products (``lax.platform_dependent``: an index that is a
+    constant in a program lowered for one platform, then the one branch
+    that platform has)."""
     *args, first = case()
     text = jax.jit(experts.held_part, static_argnums=6).lower(
         *args, first).as_text()
-    assert "while" not in text and "stablehlo.case" not in text \
-        and "stablehlo.if" not in text
+    assert "while" not in text and "stablehlo.if" not in text
+    assert text.count("stablehlo.case") == 2 * 2
+    assert len(re.findall(r'"stablehlo.case"\(%c', text)) == 2
     *args, first = _over_k10()
     assert "stablehlo.while" in jax.jit(
         experts.held_part, static_argnums=6).lower(*args, first).as_text()
@@ -161,7 +169,8 @@ def test_observe_counts_the_rows_computed():
     before = HUB.snapshot()
     step = np.array([[3, 0, 2], [0, 0, 0]], np.int32)   # 16 rows x K 10
     assert experts.observe(step, 2 * 160) == {
-        "expert_tokens": 5, "experts_hit": 2, "expert_rows": 320}
+        "expert_tokens": 5, "experts_hit": 2, "expert_rows": 320,
+        "expert_reads": 0}
     prompt = np.array([[4000, 96, 1], [0, 0, 0], [9000, 0, 600]], np.int32)
     attrs = experts.observe(prompt, 3 * 38400)
     assert attrs["expert_rows"] == (2 + 0 + 3) * experts.SLAB
@@ -170,3 +179,37 @@ def test_observe_counts_the_rows_computed():
     assert after["gen_moe_rows_computed_total"] \
         - before.get("gen_moe_rows_computed_total", 0) == 320 + 5 * 4096
     assert after[rows] - before.get(rows, 0) == 5 + 13697
+
+
+def _tiles_read(sizes, rows: int, tm: int) -> int:
+    """Row by row: the distinct experts under each row tile of each slab
+    of a layer's sorted assignment rows."""
+    whose = np.repeat(np.arange(len(sizes)), sizes)
+    total = 0
+    for lo in range(0, len(whose), min(rows, experts.SLAB)):
+        slab = whose[lo:lo + min(rows, experts.SLAB)]
+        total += sum(len(set(slab[t:t + tm])) for t in range(0, len(slab), tm))
+    return total
+
+
+@pytest.mark.parametrize("tokens,rows", [
+    ([[3, 0, 2], [0, 0, 0]], 160),              # a step: a tile holds all
+    ([[200, 0, 130], [1, 1, 1]], 512),          # groups longer than a tile
+    ([[4000, 96, 1], [0, 0, 0], [9000, 0, 600]], 38400),    # slabs
+], ids=["a-step", "long-groups", "a-prompt-in-slabs"])
+def test_observe_counts_the_experts_the_kernel_reads(tokens, rows):
+    """``expert_reads``: on a TPU the visits of the kernel's tiling, a layer
+    and a slab at a time (an expert whose group crosses a row tile's end is
+    read once a tile); 0 where the program holds ``lax.ragged_dot``."""
+    tokens = np.array(tokens, np.int32)
+    name = "gen_moe_expert_reads_total"
+    before = HUB.snapshot()[name]
+    assert experts.observe(tokens, len(tokens) * rows)["expert_reads"] == 0
+    assert HUB.snapshot()[name] == before
+    attrs = experts.observe(tokens, len(tokens) * rows, platform="tpu")
+    tm = grouped.row_tile(min(rows, experts.SLAB))
+    want = sum(_tiles_read(sizes, rows, tm) for sizes in tokens)
+    assert attrs["expert_reads"] == want >= attrs["experts_hit"]
+    if rows == 160:
+        assert want == attrs["experts_hit"] == 2
+    assert HUB.snapshot()[name] - before == want

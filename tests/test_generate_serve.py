@@ -736,6 +736,60 @@ class TestDevicePool:
         assert after[name] - before[name] == 2 * want
         assert name in counters and counters[name] >= 2 * want
 
+    @pytest.mark.parametrize("family,platform", [
+        ("exaone_moe", "tpu"), ("exaone_moe", "cpu"), ("qwen3_next", "tpu"),
+        ("llama", "tpu")],
+        ids=["held-experts-on-a-tpu", "on-the-cpu", "a-second-family",
+             "no-expert-layer"])
+    def test_a_step_books_the_experts_its_products_read(self, family,
+                                                        platform):
+        """``expert_reads`` on ``serve.decode-step`` and
+        ``serve.prefill-device``: the experts' weights the step's grouped
+        products fetch where its program holds the kernel that streams
+        each hit expert once (``ops/grouped.py``: programs lowered for a
+        TPU): the kernel's visits for the step's ``expert_tokens``, from
+        the same tiling rule, so ``expert_reads`` over ``experts_hit`` is
+        1.0 while a row tile holds every landed row (these tiny steps); 0
+        on the CPU, where the products are ``lax.ragged_dot``. The
+        platform is the pool's devices'; the test says ``tpu`` in its
+        place (the programs it runs are the CPU's: what is held here is
+        the host's count). Counted in ``gen_moe_expert_reads_total``,
+        which ``/statusz`` has. A family with no expert layer names
+        neither."""
+        from demodel_tpu.utils import statusz, trace
+
+        _module, params, cfg = _tiny(family)
+        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
+                           max_new_tokens=4, kv_mb=1, block_tokens=2)
+        assert engine.pool.platform == "cpu"
+        engine.pool.platform = platform
+        name = "gen_moe_expert_reads_total"
+        trace.reset()
+        trace.enable()
+        try:
+            before = HUB.snapshot()[name]
+            _drive(engine, [_prompt(cfg, n, seed=n) for n in (18, 9, 5)], 3)
+            for _ in range(2):
+                engine._decode_step()
+            after = HUB.snapshot()[name]
+            spans = [r["attrs"] for r in trace.buffer().snapshot()
+                     if r["name"] in ("serve.decode-step",
+                                      "serve.prefill-device")]
+            counters = statusz.snapshot()["counters"]
+        finally:
+            trace.reset()
+            engine.stop()
+        assert len(spans) == 3 + 2
+        if family == "llama":
+            assert not any("expert_reads" in a for a in spans)
+            assert after == before
+            return
+        assert all(a["experts_hit"] > 0 for a in spans)
+        want = [a["experts_hit"] if platform == "tpu" else 0 for a in spans]
+        assert [a["expert_reads"] for a in spans] == want
+        assert after - before == sum(want)
+        assert name in counters and counters[name] >= sum(want)
+
     @pytest.mark.parametrize("longest,slots", [
         (1, 32), (2, 32), (3, 32), (9, 32), (31, 32), (32, 32), (33, 32),
         (64, 32), (65, 256), (300, 256), (512, 256), (513, 2048),

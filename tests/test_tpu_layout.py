@@ -20,6 +20,12 @@ And of LongCat-Flash's 64-row step over a page of 8 sublayers (~20 s): the
 same page, the same carry and the same kernel in each of its two latent
 attentions a layer, and its weights held as both programs read them (no
 array of 30 MB is copied into another order).
+
+And of the held experts' grouped products (PR 46): in a program compiled
+for the chip every one of them is the Pallas kernel of ``ops/grouped.py``
+(two a sparse layer, traced and lowered once a shape from one ``jit``), in
+the step, in a prompt's landed-slabs loop and in ``qwen3-next``'s; the
+compiler's own ``ragged-dot`` is in none.
 """
 
 from __future__ import annotations
@@ -33,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1, latent, longcat_flash
+from demodel_tpu.models import axk1, latent, longcat_flash, qwen3_next
 from demodel_tpu.serve import kvcache
 
 
@@ -94,6 +100,26 @@ def _in_place(text: str, attentions: int, rows: int, heads: int,
     assert re.search(rf"f32\[{rows},{heads},{values}\]", text)
 
 
+def _grouped(lowered, sparse: int) -> str:
+    """The program holds no ``ragged-dot``: each sparse layer's two grouped
+    products are calls of the kernel, which the program traced and lowered
+    once a shape (gate beside up, down) from one ``jit``. Returns the
+    compiled text."""
+    text = lowered.as_text()
+    assert "ragged_dot" not in text
+    assert len(re.findall(r"func\.func private @grouped_dot", text)) == 2
+    assert text.count('kernel_name = "moe_grouped"') == 2
+    assert len(re.findall(r"call @grouped_dot", text)) == 2 * sparse
+    compiled = lowered.compile().as_text()
+    assert "ragged-dot" not in compiled
+    calls = [line for line in compiled.splitlines()
+             if " custom-call(" in line and "moe_grouped" in line]
+    assert len(calls) == 2 * sparse
+    assert all('custom_call_target="tpu_custom_call"' in c
+               and "moe.experts" in c for c in calls)
+    return compiled
+
+
 def test_the_latent_page_lies_as_it_is_read(one_chip):
     cfg = axk1.AxK1Config(num_hidden_layers=7, dtype="bfloat16")
     spec = axk1.cache_spec(cfg)
@@ -149,12 +175,14 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     params = jax.tree.map(
         lambda a: shaped(a.shape, a.dtype),
         jax.eval_shape(lambda: axk1.init_params(jax.random.key(1), cfg)))
-    compiled = jax.jit(decode, donate_argnums=(4,)).lower(
+    lowered = jax.jit(decode, donate_argnums=(4,)).lower(
         params, shaped((rows, slots), jnp.int32),
         *(shaped((rows,), jnp.int32),) * 2,
         shaped((spec.layers, BLOCKS, 1, engine["block_tokens"],
-                spec.head_dim), jnp.bfloat16)).compile()
+                spec.head_dim), jnp.bfloat16))
+    compiled = lowered.compile()
     text = compiled.as_text()
+    _grouped(lowered, cfg.sparse_layers)
     capacity = rows * slots // kvcache.TILE_BLOCKS
     assert capacity == 1024
     # no float32 partials and no queries a tile of the capacity
@@ -218,3 +246,34 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.05e9
     assert 12.3e9 < memory.argument_size_in_bytes < 12.4e9
+
+
+@pytest.mark.parametrize("family,config,name,tokens,sparse", [
+    (axk1, axk1.AxK1Config, "ax-k1-519b-l7-ep16", 1024, 6),
+    (qwen3_next, qwen3_next.Qwen3NextConfig, "qwen3-next-80b-l12-ep4", 1024,
+     12),
+], ids=["ax-k1", "qwen3-next"])
+def test_a_prompts_grouped_products_are_the_kernel(one_chip, family, config,
+                                                   name, tokens, sparse):
+    """A 1 024-token prefill at the published widths, compiled for the
+    described chip: its 8 192 (10 240) assignments a layer go through the
+    landed-slabs loop, whose body holds the kernel twice; no ``ragged-dot``
+    is left in the program."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / f"{name}.json").read_text())
+    doc.pop("benchmark")
+    cfg = config.from_hf(doc)
+    assert tokens * cfg.num_experts_per_tok > family.experts.SLAB
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: family.init_params(jax.random.key(1), cfg)))
+    lowered = jax.jit(
+        lambda params, tokens: family.step_prefill(params, tokens, cfg)
+    ).lower(params, shaped((1, tokens), jnp.int32))
+    text = _grouped(lowered, sparse)
+    assert [line for line in text.splitlines()
+            if " while(" in line and "moe" in line]
