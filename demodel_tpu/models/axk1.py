@@ -69,6 +69,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models import experts, latent
 from demodel_tpu.models.common import refuse_unsupported, rms_norm
+from demodel_tpu.models.hf_loader import Weights
 
 
 @dataclass(frozen=True)
@@ -113,7 +114,7 @@ class AxK1Config:
     @property
     def num_experts(self) -> int:
         """The experts held, under the name the loader that stacks them
-        (``hf_loader._stack_experts``) shares with the other families."""
+        (``experts.stack_experts``) shares with the other families."""
         return self.n_routed_experts
 
     @property
@@ -221,7 +222,7 @@ class AxK1Config:
 
 def init_params(key, cfg: AxK1Config) -> dict:
     """Seeded N(0, 1/fan_in) matrices and norms of ones: the tree
-    :func:`hf_loader.load_axk1_params` builds."""
+    :func:`load_params` builds."""
     dt = jnp.dtype(cfg.dtype)
     D = cfg.hidden_size
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -271,6 +272,76 @@ def param_shardings(cfg: AxK1Config, mesh: Mesh) -> dict:
     shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
     return experts.held_shardings(jax.tree.map(lambda _leaf: rep, shapes),
                                   cfg.n_routed_experts, mesh)
+
+
+from_hf = AxK1Config.from_hf
+#: served through its step functions only
+forward = None
+
+
+def load_params(weights: dict, cfg: AxK1Config, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint of the
+    DeepSeek-V3 style of names, holding one share of the experts under
+    their global indices. The attention is :func:`latent.load_attention`'s
+    (``w_uk`` and ``w_uv`` by head, which the expanded prefill and the
+    absorbed decode both read); the experts are stacked as
+    :func:`experts.stack_experts` stacks them. A selection
+    bias in the checkpoint is refused: the module implements
+    ``topk_method`` ``none``, which has none."""
+    w = Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def held(projs, leaf):
+            return experts.stack_experts(w, pre, projs, cfg, lsh.get(leaf))
+
+        if w.has(pre + "mlp.gate.e_score_correction_bias"):
+            raise ValueError(
+                f"checkpoint tensor {pre}mlp.gate.e_score_correction_bias: "
+                "a selection bias is not supported by this stack "
+                "(topk_method none)")
+        layer = {
+            **latent.load_attention(w, pre + "self_attn.",
+                                cfg.num_attention_heads,
+                                cfg.qk_nope_head_dim, lsh),
+            "attn_norm": vec("input_layernorm.weight", "attn_norm"),
+            "mlp_norm": vec("post_attention_layernorm.weight", "mlp_norm"),
+        }
+        if i >= cfg.first_k_dense_replace:
+            layer.update({
+                "router": lin("mlp.gate.weight", "router"),
+                "experts_gate_up": held(("gate", "up"), "experts_gate_up"),
+                "experts_down": held(("down",), "experts_down"),
+                "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
+                                        "shared_gate_proj"),
+                "shared_up_proj": lin("mlp.shared_experts.up_proj.weight",
+                                      "shared_up_proj"),
+                "shared_down_proj": lin("mlp.shared_experts.down_proj.weight",
+                                        "shared_down_proj"),
+            })
+        else:
+            layer.update({
+                "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+                "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+                "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
 
 
 # ----------------------------------------------------------------- rotary
@@ -393,8 +464,7 @@ def cache_spec(cfg: AxK1Config):
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, 1, cfg.page_dim,
-                     values=cfg.kv_lora_rank, readers=cfg.num_hidden_layers,
-                     query_heads=cfg.num_attention_heads)
+                     values=cfg.kv_lora_rank)
 
 
 def step_prefill(params, tokens, cfg: AxK1Config, mesh: Mesh | None = None):

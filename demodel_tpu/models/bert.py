@@ -17,6 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models.common import (
     layer_norm, refuse_unsupported, use_flash_attention as _use_flash)
+from demodel_tpu.models.hf_loader import Weights
 
 
 @dataclass(frozen=True)
@@ -123,6 +124,43 @@ def param_shardings(cfg: BertConfig, mesh: Mesh) -> dict:
     }
 
 
+from_hf = BertConfig.from_hf
+
+
+def load_params(weights: dict, cfg: BertConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint, each leaf with
+    the placement it arrived with (``mesh`` lays nothing out)."""
+    w = Weights(weights)
+
+    def lin(name):
+        return {"w": w.get(name + ".weight", transpose=True),
+                "b": w.get(name + ".bias")}
+
+    def ln(name):
+        return {"w": w.get(name + ".weight"), "b": w.get(name + ".bias")}
+
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        pre = f"encoder.layer.{i}."
+        layers.append({
+            "q": lin(pre + "attention.self.query"),
+            "k": lin(pre + "attention.self.key"),
+            "v": lin(pre + "attention.self.value"),
+            "attn_out": lin(pre + "attention.output.dense"),
+            "attn_ln": ln(pre + "attention.output.LayerNorm"),
+            "inter": lin(pre + "intermediate.dense"),
+            "out": lin(pre + "output.dense"),
+            "out_ln": ln(pre + "output.LayerNorm"),
+        })
+    return {
+        "word_emb": w.get("embeddings.word_embeddings.weight"),
+        "pos_emb": w.get("embeddings.position_embeddings.weight"),
+        "type_emb": w.get("embeddings.token_type_embeddings.weight"),
+        "emb_ln": ln("embeddings.LayerNorm"),
+        "layers": layers,
+    }
+
+
 def encode(params, tokens, cfg: BertConfig, attention_mask=None,
            token_type_ids=None, mesh: Mesh | None = None):
     """tokens [B, T] → last hidden state [B, T, D]."""
@@ -164,3 +202,7 @@ def encode(params, tokens, cfg: BertConfig, attention_mask=None,
         h = h @ layer["out"]["w"] + layer["out"]["b"]
         x = layer_norm(x + h, layer["out_ln"]["w"], layer["out_ln"]["b"], eps)
     return x
+
+
+#: what a pulled BERT runs: the encoder
+forward = encode

@@ -75,13 +75,11 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
 
     A paged past wider than two tiles a row comes as the tiles its rows
     have filled (``kvcache.Tiles``, from ``Paged.filled``) and the same
-    softmax runs over those alone, a chunk of tiles a trip: the rectangle
-    is the case in which nothing can be skipped. Between the trips the
-    running softmax is kept a tile where that is little beside the tile's
-    own bytes, and a row where it is not; and where it is a row's and the
-    page is one array, a program lowered for a TPU runs a kernel that
-    reads the tiles from the pool in the loop's place
-    (:func:`_over_tiles`)."""
+    softmax runs over those alone, a chunk of tiles a trip, one running
+    softmax a row carried between the trips: the rectangle is the case in
+    which nothing can be skipped. Where the page is one array under one
+    cached head, a program lowered for a TPU runs a kernel that reads the
+    tiles from the pool in the loop's place (:func:`_over_tiles`)."""
     B, T, H, hd = q.shape
     Hkv, vd = k.shape[2], v.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
@@ -124,48 +122,42 @@ def _over_tiles(q, s_new, v, tiles, scale):
     """:func:`attend` over the filled tiles of a paged past (the rows'
     cached prefixes, so every query sees every position its row holds): q
     [B, T, Hkv, g, hd], ``s_new`` the masked float32 scores of the new
-    keys [B, Hkv, g, T, T], ``v`` their values. A trip of the loop takes a
-    chunk of tiles and has, for each, the running softmax's three in
-    float32 (the values its probabilities weigh, cast to q's dtype before
-    that product; its largest score; the sum of the exponentials below
-    it). What the loop carries of them is the index's to say, from bytes
-    (``kvcache.Tiles.by_row``). Where a tile's three are little beside the
-    keys and values they were taken from (grouped-query heads of 128 or
-    256 values) they are left **a tile**, where the tile lies in the list,
-    and a row's tiles and its new keys are combined at the end. Where they
-    are a large part of it (a latent page's one cached vector under 64
-    query heads with values of 512) the trip combines its tiles into their
-    rows and the loop carries **one running softmax a row**: the filled
-    tiles lie in row order, so the sum over a row's tiles of a chunk is a
-    product with the chunk's membership ``[rows, tiles]``, and the carry
-    meets the new keys as it is. A row with no tile filled sees its new
-    keys only. The values are ``vd`` wide, as ``v`` is; tiles with no ``v``
-    of their own give the first ``vd`` columns of their keys.
+    keys [B, Hkv, g, T, T], ``v`` their values. One loop, which carries
+    **one running softmax a row** in float32 (the values its probabilities
+    weigh, cast to q's dtype before that product; its largest score; the
+    sum of the exponentials below it). A trip takes a chunk of tiles, has
+    those three for each tile and combines them into their rows: the
+    filled tiles lie in row order, so the sum over a row's tiles of a
+    chunk is a product with the chunk's membership ``[rows, tiles]``.
+    After the loop the carry meets the new keys as it is; a row with no
+    tile filled sees its new keys only. Nothing the loop holds is as large
+    as the table's capacity. The values are ``vd`` wide, as ``v`` is;
+    tiles with no ``v`` of their own give the first ``vd`` columns of
+    their keys.
 
     A trip gathers its chunk of tiles into one array before its products
-    (XLA fuses no gather into the product that reads it). Where the carry
-    is a row's and the page is one array under one cached head, a Pallas
-    kernel does without: it follows the index itself and copies each
-    tile's blocks from the pool into fast memory
-    (:mod:`demodel_tpu.ops.latent_tiles`; the same arithmetic, the same
-    carry out). Which of the two a program holds is the platform's it is
-    lowered for (``lax.platform_dependent``: the kernel on a TPU, the loop
-    everywhere else, where it is also the kernel's oracle), nothing
-    else's."""
+    (XLA fuses no gather into the product that reads it). Where the page
+    is one array under one cached head, a Pallas kernel does without: it
+    follows the index itself and copies each tile's blocks from the pool
+    into fast memory (:mod:`demodel_tpu.ops.latent_tiles`; the same
+    arithmetic, the same carry out). Which of the two a program holds is
+    the platform's it is lowered for (``lax.platform_dependent``: the
+    kernel on a TPU, the loop everywhere else, where it is also the
+    kernel's oracle), nothing else's."""
     B, T, Hkv, g, hd = q.shape
     vd = v.shape[-1]
-    C, n = tiles.row.shape[0], tiles.chunk_tiles
+    n = tiles.chunk_tiles
     f32 = jnp.float32
 
-    def partials(i, queries):
-        """Chunk ``i``'s tiles under their rows' ``queries()`` [n, T, Hkv,
-        g, hd] (taken once the keys are gathered): ``(values [n, Hkv, g,
-        T, vd], largest score, sum [n, Hkv, g, T, 1])``, each tile's
-        own."""
+    def partials(i, rows):
+        """Chunk ``i``'s tiles under the queries of their ``rows`` [n]
+        (taken once the keys are gathered): ``(values [n, Hkv, g, T, vd],
+        largest score, sum [n, Hkv, g, T, 1])``, each tile's own."""
         ids, live = tiles.chunk(i)
         pk = tiles.blocks(tiles.k, ids)
         m, c = pk.shape[1], pk.shape[3]
-        s = jnp.einsum("nqkgd,nmkcd->nkgqmc", queries(),
+        s = jnp.einsum("nqkgd,nmkcd->nkgqmc",
+                       q.at[rows].get(mode="promise_in_bounds"),
                        pk).reshape(n, Hkv, g, T, m * c)
         keep = live[:, None, None, None, :]
         s = jnp.where(keep, (s * scale).astype(f32), -1e30)
@@ -183,17 +175,10 @@ def _over_tiles(q, s_new, v, tiles, scale):
                        preferred_element_type=f32)
         return o, top, p.sum(axis=-1, keepdims=True)
 
-    def trip(i, acc):
-        new = jnp.concatenate(partials(
-            i, lambda: lax.dynamic_slice_in_dim(q_tiles, i * n, n)),
-            axis=-1)
-        return lax.dynamic_update_slice_in_dim(acc, new, i * n, axis=0)
-
-    def trip_rows(i, carry):
+    def trip(i, carry):
         values, tops, sums = carry              # [B, Hkv, g, T, vd | 1 | 1]
         rows = tiles.rows(i)
-        o, top, total = partials(
-            i, lambda: q.at[rows].get(mode="promise_in_bounds"))
+        o, top, total = partials(i, rows)
         # whose a tile is, [B, n, 1, 1, 1, 1]; a tile past the filled ones
         # is its row's too, with the least score and no sum: no weight
         own = (rows[None, :] == jnp.arange(B)[:, None])[
@@ -206,57 +191,36 @@ def _over_tiles(q, s_new, v, tiles, scale):
                     precision=lax.Precision.HIGHEST),
                 new, old * sums + (w * total[None]).sum(axis=1))
 
+    def loop():
+        return lax.fori_loop(
+            jnp.uint32(0), tiles.trips, trip,
+            (jnp.zeros((B, Hkv, g, T, vd), f32),
+             jnp.full((B, Hkv, g, T, 1), -1e30, f32),
+             jnp.zeros((B, Hkv, g, T, 1), f32)))
+
+    def in_place():
+        carry = latent_tiles.over_filled_tiles(
+            q.transpose(0, 2, 3, 1, 4).reshape(B, g * T, hd), tiles,
+            scale, vd)
+        return tuple(a.reshape(B, Hkv, g, T, -1) for a in carry)
+
     with jax.named_scope("attn.tiles"):
-        if tiles.by_row(Hkv * g * T * (vd + 2) * jnp.dtype(f32).itemsize):
-            def loop():
-                return lax.fori_loop(
-                    jnp.uint32(0), tiles.trips, trip_rows,
-                    (jnp.zeros((B, Hkv, g, T, vd), f32),
-                     jnp.full((B, Hkv, g, T, 1), -1e30, f32),
-                     jnp.zeros((B, Hkv, g, T, 1), f32)))
-
-            def in_place():
-                carry = latent_tiles.over_filled_tiles(
-                    q.transpose(0, 2, 3, 1, 4).reshape(B, g * T, hd), tiles,
-                    scale, vd)
-                return tuple(a.reshape(B, Hkv, g, T, -1) for a in carry)
-
-            if tiles.v is None and Hkv == 1:
-                # one cached vector under every head: on a TPU the kernel
-                # reads the tiles from the pool, and the loop is its oracle
-                values, tops, sums = lax.platform_dependent(
-                    tpu=in_place, default=loop)
-            else:
-                values, tops, sums = loop()
-            top = jnp.maximum(tops, s_new.max(axis=-1, keepdims=True))
-            w = jnp.exp(tops - top)
-            p_new = jnp.exp(s_new - top)
-            total = w * sums + p_new.sum(axis=-1, keepdims=True)
-            out = w * values + jnp.einsum(
-                "bkgqs,bskd->bkgqd", p_new.astype(q.dtype), v,
-                preferred_element_type=f32)
-            return (out / total).astype(q.dtype).transpose(0, 3, 1, 2, 4)
-        q_tiles = q.at[tiles.row].get(mode="promise_in_bounds")
-        # a tile none has filled: no value, the least score, no weight (a
-        # broadcast of one tile's: set in the whole array, the compiler
-        # folds it into a constant of its size)
-        acc = lax.fori_loop(
-            jnp.uint32(0), tiles.trips, trip, jnp.broadcast_to(
-                jnp.zeros((vd + 2,), f32).at[vd].set(-1e30),
-                (C, Hkv, g, T, vd + 2)))
-        # a row's tiles, side by side: [B, tiles a row]
-        own = (tiles.own >= 0)[..., None, None, None]
-        acc = acc.at[jnp.maximum(tiles.own, 0)].get(mode="promise_in_bounds")
-        tops = jnp.where(own, acc[..., vd], -1e30)      # [B, n, Hkv, g, T]
-        top = jnp.maximum(tops.max(axis=1), s_new.max(axis=-1))
-        w = jnp.where(own, jnp.exp(tops - top[:, None]), 0.0)
-        p_new = jnp.exp(s_new - top[..., None])
-        total = (w * acc[..., vd + 1]).sum(axis=1) + p_new.sum(axis=-1)
-        out = (w[..., None] * acc[..., :vd]).sum(axis=1) + jnp.einsum(
+        if tiles.v is None and Hkv == 1:
+            # one cached vector under every head: on a TPU the kernel
+            # reads the tiles from the pool, and the loop is its oracle
+            values, tops, sums = lax.platform_dependent(
+                tpu=in_place, default=loop)
+        else:
+            values, tops, sums = loop()
+        top = jnp.maximum(tops, s_new.max(axis=-1, keepdims=True))
+        w = jnp.exp(tops - top)
+        p_new = jnp.exp(s_new - top)
+        total = w * sums + p_new.sum(axis=-1, keepdims=True)
+        out = w * values + jnp.einsum(
             "bkgqs,bskd->bkgqd", p_new.astype(q.dtype), v,
             preferred_element_type=f32)
-        out = (out / total[..., None]).astype(q.dtype)
-    return out.transpose(0, 3, 1, 2, 4)                  # [B, T, Hkv, g, vd]
+        # [B, T, Hkv, g, vd]
+        return (out / total).astype(q.dtype).transpose(0, 3, 1, 2, 4)
 
 
 def use_flash_attention() -> bool:

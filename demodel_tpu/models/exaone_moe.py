@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models import experts
 from demodel_tpu.models.common import attend, rms_norm
+from demodel_tpu.models.hf_loader import Weights
 from demodel_tpu.models.llama import _rope
 
 
@@ -156,7 +157,7 @@ class ExaoneMoeConfig:
 
 def init_params(key, cfg: ExaoneMoeConfig) -> dict:
     """Seeded N(0, 1/fan_in) matrices, norms of ones, a zero selection
-    bias: the tree :func:`hf_loader.load_exaone_moe_params` builds."""
+    bias: the tree :func:`load_params` builds."""
     dt = jnp.dtype(cfg.dtype)
     D, hd = cfg.hidden_size, cfg.head_dim
     H, Hkv = cfg.num_attention_heads, cfg.num_key_value_heads
@@ -208,6 +209,75 @@ def param_shardings(cfg: ExaoneMoeConfig, mesh: Mesh) -> dict:
     shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
     return experts.held_shardings(jax.tree.map(lambda _leaf: rep, shapes),
                                   cfg.num_experts, mesh)
+
+
+from_hf = ExaoneMoeConfig.from_hf
+#: served through its step functions only
+forward = None
+
+
+def load_params(weights: dict, cfg: ExaoneMoeConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint that
+    holds one share of the experts under their global indices
+    (``mlp.experts.<ep_rank * num_experts + j>``). Per-expert matrices are
+    stacked, gate beside up, so that a projection is one grouped product;
+    the router keeps its whole width. Tensors of the multi-token-prediction
+    layer stay in ``weights``."""
+    w = Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for i, sparse in enumerate(cfg.sparse):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def held(projs, leaf):
+            return experts.stack_experts(w, pre, projs, cfg, lsh.get(leaf))
+
+        layer = {
+            "q_proj": lin("self_attn.q_proj.weight", "q_proj"),
+            "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+            "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            "q_norm": vec("self_attn.q_norm.weight", "q_norm"),
+            "k_norm": vec("self_attn.k_norm.weight", "k_norm"),
+            "attn_norm": vec("post_attn_layernorm.weight", "attn_norm"),
+            "mlp_norm": vec("post_feedforward_layernorm.weight", "mlp_norm"),
+        }
+        if sparse:
+            layer.update({
+                "router": lin("mlp.gate.weight", "router"),
+                "router_bias": vec("mlp.gate.e_score_correction_bias",
+                                   "router_bias").astype(jnp.float32),
+                "experts_gate_up": held(("gate", "up"),
+                                           "experts_gate_up"),
+                "experts_down": held(("down",), "experts_down"),
+                "shared_gate_proj": lin("mlp.shared_experts.gate_proj.weight",
+                                        "shared_gate_proj"),
+                "shared_up_proj": lin("mlp.shared_experts.up_proj.weight",
+                                      "shared_up_proj"),
+                "shared_down_proj": lin("mlp.shared_experts.down_proj.weight",
+                                        "shared_down_proj"),
+            })
+        else:
+            layer.update({
+                "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+                "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+                "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
 
 
 # -------------------------------------------------------------- attention
@@ -302,13 +372,11 @@ def _head(params, x, cfg):
 
 def cache_spec(cfg: ExaoneMoeConfig):
     """What the serving engine keeps for a sequence: every layer pages K
-    and V, window and full alike, nothing of fixed size; the full layers
-    read all of them."""
+    and V, window and full alike, nothing of fixed size."""
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim, readers=cfg.sliding_windows.count(0),
-                     query_heads=cfg.num_attention_heads)
+                     cfg.head_dim)
 
 
 def step_prefill(params, tokens, cfg: ExaoneMoeConfig,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from demodel_tpu.models.hf_loader import Weights, setter, zeros
 from demodel_tpu.ops import grouped
 from demodel_tpu.utils.metrics import HUB, labeled
 
@@ -206,3 +207,22 @@ def observe(expert_tokens, assignments: int, free: int = 0,
     HUB.inc("gen_moe_expert_reads_total", reads)
     return {"expert_tokens": landed, "experts_hit": hit,
             "expert_rows": rows, "expert_reads": reads}
+
+
+def stack_experts(w: Weights, pre: str, projs, cfg, sharding):
+    """The held experts' ``<pre>mlp.experts.<e>.<p>_proj.weight`` (``[out,
+    in]`` each, under their index in the whole layer) for the projections
+    ``projs`` → one ``[E, in, len(projs) * out]``, a projection's runs side
+    by side. Each matrix is popped, set into the stack in place and freed,
+    so boot holds the stack and one matrix, not the experts twice."""
+    D, F = cfg.hidden_size, cfg.moe_intermediate_size
+    shape = (cfg.num_experts, *((F, D) if projs == ("down",)
+                                else (D, len(projs) * F)))
+    stack = zeros(shape, cfg.dtype, sharding)()
+    put = setter(sharding)
+    first = cfg.ep_rank * cfg.num_experts
+    for j in range(cfg.num_experts):
+        for i, p in enumerate(projs):
+            stack = put(stack, w.get(
+                f"{pre}mlp.experts.{first + j}.{p}_proj.weight"), j, i * F)
+    return stack

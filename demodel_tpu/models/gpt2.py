@@ -16,6 +16,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models.common import (
     layer_norm, refuse_unsupported, use_flash_attention as _use_flash)
+from demodel_tpu.models.hf_loader import Weights
 
 
 @dataclass(frozen=True)
@@ -106,6 +107,38 @@ def param_shardings(cfg: GPT2Config, mesh: Mesh) -> dict:
         "wpe": sh(None, None),
         "layers": [dict(layer) for _ in range(cfg.n_layer)],
         "ln_f": ln(),
+    }
+
+
+from_hf = GPT2Config.from_hf
+
+
+def load_params(weights: dict, cfg: GPT2Config, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint, each leaf with
+    the placement it arrived with (``mesh`` lays nothing out)."""
+    w = Weights(weights)
+    layers = []
+    for i in range(cfg.n_layer):
+        pre = f"h.{i}."
+        layers.append({
+            "ln_1": {"w": w.get(pre + "ln_1.weight"),
+                     "b": w.get(pre + "ln_1.bias")},
+            "c_attn": {"w": w.get(pre + "attn.c_attn.weight"),
+                       "b": w.get(pre + "attn.c_attn.bias")},
+            "c_proj": {"w": w.get(pre + "attn.c_proj.weight"),
+                       "b": w.get(pre + "attn.c_proj.bias")},
+            "ln_2": {"w": w.get(pre + "ln_2.weight"),
+                     "b": w.get(pre + "ln_2.bias")},
+            "mlp_fc": {"w": w.get(pre + "mlp.c_fc.weight"),
+                       "b": w.get(pre + "mlp.c_fc.bias")},
+            "mlp_proj": {"w": w.get(pre + "mlp.c_proj.weight"),
+                         "b": w.get(pre + "mlp.c_proj.bias")},
+        })
+    return {
+        "wte": w.get("wte.weight"),
+        "wpe": w.get("wpe.weight"),
+        "layers": layers,
+        "ln_f": {"w": w.get("ln_f.weight"), "b": w.get("ln_f.bias")},
     }
 
 

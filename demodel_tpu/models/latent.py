@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from demodel_tpu.models.common import attend, rms_norm
+from demodel_tpu.models.hf_loader import Weights, folder, head_splitter
 from demodel_tpu.utils.metrics import HUB
 
 HUB.inc("gen_latent_kv_bytes_total", 0)
@@ -186,3 +187,36 @@ def observe(positions, spec, geo: Geometry, dtype) -> dict:
         * jnp.dtype(dtype).itemsize
     HUB.inc("gen_latent_kv_bytes_total", moved)
     return {"latent_bytes": moved}
+
+
+def load_attention(w: Weights, a: str, heads: int, nope: int, sh: dict,
+                   scales: tuple = (None, None)) -> dict:
+    """One latent attention's tensors under the prefix ``a`` → the nine
+    leaves this module reads (``sh`` their shardings by leaf).
+    ``kv_b_proj`` (a head's ``nope`` key rows before its value rows) is
+    split by head into ``w_uk`` and ``w_uv``, ``q_b_proj`` (a head's
+    ``nope`` unrotated rows before its rotary ones) into the two kinds of
+    row. ``scales``: what a family multiplies the normalised
+    ``c_q`` and ``c_kv`` by, folded into the two norms' weights in float32
+    (None: the weight as the checkpoint holds it)."""
+    def lin(name, leaf):
+        return w.get(a + name, transpose=True, sharding=sh.get(leaf))
+
+    def norm(name, leaf, scale):
+        if scale is None:
+            return w.get(a + name, sharding=sh.get(leaf))
+        return folder(scale, sh.get(leaf))(w.get(a + name))
+
+    w_uk, w_uv = head_splitter(heads, nope, True, sh.get("w_uk"))(
+        w.get(a + "kv_b_proj.weight"))
+    q_b_nope, q_b_rope = head_splitter(
+        heads, nope, False, sh.get("q_b_nope"))(w.get(a + "q_b_proj.weight"))
+    return {
+        "q_a_proj": lin("q_a_proj.weight", "q_a_proj"),
+        "q_a_norm": norm("q_a_layernorm.weight", "q_a_norm", scales[0]),
+        "q_b_nope": q_b_nope, "q_b_rope": q_b_rope,
+        "kv_a_proj": lin("kv_a_proj_with_mqa.weight", "kv_a_proj"),
+        "kv_a_norm": norm("kv_a_layernorm.weight", "kv_a_norm", scales[1]),
+        "w_uk": w_uk, "w_uv": w_uv,
+        "o_proj": lin("o_proj.weight", "o_proj"),
+    }

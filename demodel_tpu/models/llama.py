@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from demodel_tpu.models.common import (attend, refuse_unsupported,
                                        rms_norm,
                                        use_flash_attention as _use_flash)
+from demodel_tpu.models.hf_loader import Weights, lay
 from demodel_tpu.ops.ring_attention import (
     dense_attention,
     ring_attention_sharded,
@@ -139,6 +140,50 @@ def param_shardings(cfg: LlamaConfig, mesh: Mesh) -> dict:
         "layers": [dict(layer) for _ in range(cfg.num_hidden_layers)],
         "final_norm": rep1,
         "lm_head": sh(None, "tp") if cfg.vocab_size % tp == 0 else sh(None, None),
+    }
+
+
+from_hf = LlamaConfig.from_hf
+
+
+def load_params(weights: dict, cfg: LlamaConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint. ``mesh`` lays every leaf out as :func:`param_shardings`
+    wants it (column/row-parallel over ``tp``): the delivery plan's
+    leading-axis shards are re-laid on the mesh's devices."""
+    w = Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        layers.append({
+            "attn_norm": w.get(pre + "input_layernorm.weight",
+                               sharding=lsh.get("attn_norm")),
+            "q_proj": lin("self_attn.q_proj.weight", "q_proj"),
+            "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+            "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+            "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+            "mlp_norm": w.get(pre + "post_attention_layernorm.weight",
+                              sharding=lsh.get("mlp_norm")),
+            "gate_proj": lin("mlp.gate_proj.weight", "gate_proj"),
+            "up_proj": lin("mlp.up_proj.weight", "up_proj"),
+            "down_proj": lin("mlp.down_proj.weight", "down_proj"),
+        })
+    embed = w.get("embed_tokens.weight", sharding=sh.get("embed"))
+    if w.has("lm_head.weight"):
+        head = w.get("lm_head.weight", transpose=True,
+                     sharding=sh.get("lm_head"))
+    else:  # tied embeddings
+        head = lay(embed, True, sh.get("lm_head"))
+    return {
+        "embed": embed,
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": head,
     }
 
 
@@ -307,12 +352,11 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
 
 def cache_spec(cfg: LlamaConfig):
     """What the serving engine keeps for a sequence: every layer pages K
-    and V, nothing of fixed size; every layer reads all of them."""
+    and V, nothing of fixed size."""
     from demodel_tpu.serve.kvcache import CacheSpec
 
     return CacheSpec(cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim, readers=cfg.num_hidden_layers,
-                     query_heads=cfg.num_attention_heads)
+                     cfg.head_dim)
 
 
 def step_prefill(params, tokens, cfg: LlamaConfig, mesh: Mesh | None = None):

@@ -65,6 +65,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models import experts, latent
 from demodel_tpu.models.common import refuse_unsupported, rms_norm
+from demodel_tpu.models.hf_loader import Weights, fold, zeros
 from demodel_tpu.utils.metrics import HUB, labeled
 
 HUB.inc(labeled("gen_moe_assignments_total", held="zero"), 0)
@@ -108,7 +109,7 @@ class LongcatFlashConfig:
     @property
     def num_experts(self) -> int:
         """The experts held, under the name the loader that stacks them
-        (``hf_loader._stack_experts``) shares with the other families."""
+        (``experts.stack_experts``) shares with the other families."""
         return self.n_routed_experts
 
     @property
@@ -196,17 +197,10 @@ class LongcatFlashConfig:
 # ------------------------------------------------------------------ params
 
 
-def fold(weight, scale: float):
-    """A latent norm's weight with its scale folded in, in float32: such a
-    scale (``12 ** 0.5``) is no bfloat16 number, and a weight of ones would
-    carry its rounding into every column of the latent."""
-    return weight.astype(jnp.float32) * scale
-
-
 def init_params(key, cfg: LongcatFlashConfig) -> dict:
     """Seeded N(0, 1/fan_in) matrices, norms of ones (the two latent norms
     with their scales folded in), a zero selection bias: the tree
-    :func:`hf_loader.load_longcat_flash_params` builds."""
+    :func:`load_params` builds."""
     dt = jnp.dtype(cfg.dtype)
     D, I = cfg.hidden_size, cfg.ffn_hidden_size
     C, Q = cfg.kv_lora_rank, cfg.q_lora_rank
@@ -257,6 +251,71 @@ def param_shardings(cfg: LongcatFlashConfig, mesh: Mesh) -> dict:
     shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
     return experts.held_shardings(jax.tree.map(lambda _leaf: rep, shapes),
                                   cfg.n_routed_experts, mesh)
+
+
+from_hf = LongcatFlashConfig.from_hf
+#: served through its step functions only
+forward = None
+
+
+def load_params(weights: dict, cfg: LongcatFlashConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint of
+    the LongCat-Flash style of names (a layer's two sublayers under
+    ``self_attn.<i>``, ``input_layernorm.<i>``,
+    ``post_attention_layernorm.<i>`` and ``mlps.<i>``, its expert layer
+    under ``mlp``), holding one share of the routed experts under their
+    global indices. Each attention is :func:`latent.load_attention`'s, with the
+    two scales of the latent norms' outputs (``mla_scale_q_lora``,
+    ``mla_scale_kv_lora``) folded into ``q_a_layernorm`` and
+    ``kv_a_layernorm``. The router
+    keeps its whole width, identity experts included; a selection bias is
+    taken where the checkpoint has one and is zero where not."""
+    w = Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
+    layers = []
+    for li in range(cfg.num_layers):
+        pre = f"layers.{li}."
+        lsh = sh["layers"][li] if sh else {}
+
+        def sublayer(i: int) -> dict:
+            ssh = lsh["sub"][i] if lsh else {}
+            return {
+                "attn": latent.load_attention(
+                    w, f"{pre}self_attn.{i}.", cfg.num_attention_heads,
+                    cfg.qk_nope_head_dim, ssh.get("attn", {}),
+                    cfg.latent_scales),
+                "attn_norm": w.get(f"{pre}input_layernorm.{i}.weight",
+                                   sharding=ssh.get("attn_norm")),
+                "mlp_norm": w.get(
+                    f"{pre}post_attention_layernorm.{i}.weight",
+                    sharding=ssh.get("mlp_norm")),
+                **{f"{x}_proj": w.get(f"{pre}mlps.{i}.{x}_proj.weight",
+                                      transpose=True,
+                                      sharding=ssh.get(f"{x}_proj"))
+                   for x in ("gate", "up", "down")},
+            }
+
+        bias = pre + "mlp.router.e_score_correction_bias"
+        layers.append({
+            "sub": [sublayer(0), sublayer(1)],
+            "router": w.get(pre + "mlp.router.classifier.weight",
+                            transpose=True, sharding=lsh.get("router")),
+            "router_bias": w.get(
+                bias, sharding=lsh.get("router_bias")).astype(jnp.float32)
+            if w.has(bias) else zeros((cfg.router_width,), "float32",
+                                       lsh.get("router_bias"))(),
+            "experts_gate_up": experts.stack_experts(
+                w, pre, ("gate", "up"), cfg, lsh.get("experts_gate_up")),
+            "experts_down": experts.stack_experts(
+                w, pre, ("down",), cfg, lsh.get("experts_down")),
+        })
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
 
 
 # ---------------------------------------------------------- expert layer
@@ -363,9 +422,7 @@ def cache_spec(cfg: LongcatFlashConfig):
     from demodel_tpu.serve.kvcache import CacheSpec
 
     geo = cfg.latent
-    return CacheSpec(2 * cfg.num_layers, 1, geo.page_dim, values=geo.rank,
-                     readers=2 * cfg.num_layers,
-                     query_heads=cfg.num_attention_heads)
+    return CacheSpec(2 * cfg.num_layers, 1, geo.page_dim, values=geo.rank)
 
 
 def step_prefill(params, tokens, cfg: LongcatFlashConfig,

@@ -60,9 +60,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from demodel_tpu.models.common import attend, layer_norm
+from demodel_tpu.models.hf_loader import Weights, lay
 from demodel_tpu.utils.metrics import HUB
 
 #: positions a chunk of the prefill's scan holds
@@ -202,10 +203,7 @@ def cache_spec(cfg: Phi4FlashConfig):
                ("ssm_state", (mamba, cfg.d_inner, cfg.mamba_d_state),
                 STATE_DTYPE),
                ("ssm_conv", (mamba, cfg.mamba_d_conv - 1, cfg.d_inner),
-                cfg.dtype)),
-        # the full layer and the cross-attention layers that read its pages
-        readers=kinds.count("full") + kinds.count("cross"),
-        query_heads=cfg.num_attention_heads)
+                cfg.dtype)))
 
 
 # ------------------------------------------------------------------ params
@@ -284,7 +282,7 @@ def init_layers(key, cfg: Phi4FlashConfig) -> tuple[jax.Array, list[dict]]:
 
 
 def init_params(key, cfg: Phi4FlashConfig) -> dict:
-    """Seeded weights as the tree :func:`hf_loader.load_phi4flash_params`
+    """Seeded weights as the tree :func:`load_params`
     builds: the embedding (which is the head), the final norm, and the
     layers as :func:`stack_layers` groups them."""
     dt = jnp.dtype(cfg.dtype)
@@ -292,6 +290,83 @@ def init_params(key, cfg: Phi4FlashConfig) -> dict:
     return {"embed": embed, "final_ln_w": jnp.ones((cfg.hidden_size,), dt),
             "final_ln_b": jnp.zeros((cfg.hidden_size,), dt),
             **stack_layers(layers, cfg)}
+
+
+from_hf = Phi4FlashConfig.from_hf
+#: served through its step functions only
+forward = None
+
+
+def load_params(weights: dict, cfg: Phi4FlashConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` (the layers grouped and stacked by
+    :func:`stack_layers`). Every layer's mixer is
+    ``attn`` in the checkpoint, whatever its kind. An attention layer's
+    ``Wqkv`` (queries, then keys, then values) enters as the query columns
+    and the key and value columns apart, so that a prefill can make keys
+    for a whole prompt and a query for its last position; the head is the
+    embedding, which the tree holds once."""
+    w = Weights(weights)
+    # tp shards nothing of this family: every leaf is laid replicated
+    rep = NamedSharding(mesh, PartitionSpec()) if mesh is not None else None
+    nq = cfg.num_attention_heads * cfg.head_dim
+    layers = []
+    for i, kind in enumerate(cfg.kinds):
+        pre = f"layers.{i}."
+
+        def lin(name):
+            return w.get(pre + name, transpose=True, sharding=rep)
+
+        def vec(name):
+            return w.get(pre + name, sharding=rep)
+
+        layer = {
+            "ln1_w": vec("input_layernorm.weight"),
+            "ln1_b": vec("input_layernorm.bias"),
+            "ln2_w": vec("post_attention_layernorm.weight"),
+            "ln2_b": vec("post_attention_layernorm.bias"),
+            "fc1": lin("mlp.fc1.weight"),
+            "fc2": lin("mlp.fc2.weight"),
+        }
+        if kind == "mamba":
+            conv = w.get(pre + "attn.conv1d.weight")        # [Dn, 1, K]
+            layer.update({
+                "in_proj": lin("attn.in_proj.weight"),
+                "conv_w": lay(conv.reshape(conv.shape[0], conv.shape[-1]),
+                               True, rep),
+                "conv_b": vec("attn.conv1d.bias"),
+                "x_proj": lin("attn.x_proj.weight"),
+                "dt_proj": lin("attn.dt_proj.weight"),
+                "dt_bias": vec("attn.dt_proj.bias"),
+                "A_log": vec("attn.A_log"),
+                "D": vec("attn.D"),
+                "out_proj": lin("attn.out_proj.weight"),
+            })
+        elif kind == "gmu":
+            layer.update({
+                "in_proj": lin("attn.in_proj.weight"),
+                "out_proj": lin("attn.out_proj.weight"),
+            })
+        else:
+            wqkv = w.get(pre + "attn.Wqkv.weight")
+            bqkv = w.get(pre + "attn.Wqkv.bias")
+            layer.update({"wq": lay(wqkv[:nq], True, rep),
+                          "bq": lay(bqkv[:nq], sharding=rep)})
+            if kind != "cross":
+                layer.update({"wkv": lay(wqkv[nq:], True, rep),
+                              "bkv": lay(bqkv[nq:], sharding=rep)})
+            layer.update({
+                "out_proj": lin("attn.out_proj.weight"),
+                "out_bias": vec("attn.out_proj.bias"),
+                "subln": vec("attn.inner_cross_attn.subln.weight"),
+                **{f"lambda_{x}": vec(f"attn.inner_cross_attn.lambda_{x}")
+                   for x in ("q1", "k1", "q2", "k2")}})
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=rep),
+        "final_ln_w": w.get("final_layernorm.weight", sharding=rep),
+        "final_ln_b": w.get("final_layernorm.bias", sharding=rep),
+        **stack_layers(layers, cfg),
+    }
 
 
 # ------------------------------------------------------ the selective scan

@@ -53,6 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from demodel_tpu.models import experts
 from demodel_tpu.models.common import attend
+from demodel_tpu.models.hf_loader import Weights, lay, regrouper
 from demodel_tpu.models.llama import _rope
 
 #: positions a chunk of the prefill's recurrence holds
@@ -181,8 +182,7 @@ def cache_spec(cfg: Qwen3NextConfig):
                               cfg.linear_key_head_dim,
                               cfg.linear_value_head_dim), STATE_DTYPE),
                ("gdn_conv", (gdn, cfg.linear_conv_kernel_dim - 1,
-                             cfg.conv_channels), cfg.dtype)),
-        readers=sum(cfg.full), query_heads=cfg.num_attention_heads)
+                             cfg.conv_channels), cfg.dtype)))
 
 
 # ------------------------------------------------------------------ params
@@ -191,7 +191,7 @@ def cache_spec(cfg: Qwen3NextConfig):
 def init_params(key, cfg: Qwen3NextConfig) -> dict:
     """Seeded N(0, 1/fan_in) matrices; the zero-centred norms and ``A_log``
     zeros, ``dt_bias`` and the gated norm ones: the tree
-    :func:`hf_loader.load_qwen3_next_params` builds. A GDN layer's
+    :func:`load_params` builds. A GDN layer's
     ``in_proj_qkvz`` holds ``q | k | v | z`` side by side (heads in order
     inside each) and ``in_proj_ba`` ``b | a``; an attention layer's
     ``q_proj`` the heads' queries, then their gates."""
@@ -250,6 +250,93 @@ def param_shardings(cfg: Qwen3NextConfig, mesh: Mesh) -> dict:
     shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
     return experts.held_shardings(jax.tree.map(lambda _leaf: rep, shapes),
                                   cfg.num_experts, mesh)
+
+
+from_hf = Qwen3NextConfig.from_hf
+#: served through its step functions only
+forward = None
+
+
+def load_params(weights: dict, cfg: Qwen3NextConfig, mesh=None) -> dict:
+    """The tree of :func:`init_params` from a checkpoint that
+    holds one share of the experts under their global indices. Hugging
+    Face lays ``in_proj_qkvz`` and ``in_proj_ba`` out a key head at a time
+    (``q | k | v | z`` of one head, then the next) and ``q_proj`` an
+    attention head at a time (its query, then its gate): they are regrouped
+    so that each part is one run of columns. Tensors of the
+    multi-token-prediction layer stay in ``weights``."""
+    w = Weights(weights)
+    sh = param_shardings(cfg, mesh) if mesh is not None else {}
+    Hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    r = cfg.linear_num_value_heads // Hk
+    rdv = r * cfg.linear_value_head_dim
+    layers = []
+    for i, full in enumerate(cfg.full):
+        pre = f"layers.{i}."
+        lsh = sh["layers"][i] if sh else {}
+
+        def lin(name, leaf):
+            return w.get(pre + name, transpose=True, sharding=lsh.get(leaf))
+
+        def vec(name, leaf):
+            return w.get(pre + name, sharding=lsh.get(leaf))
+
+        def regrouped(name, leaf, groups, widths):
+            return regrouper(groups, widths, lsh.get(leaf))(
+                w.get(pre + name))
+
+        def held(projs, leaf):
+            return experts.stack_experts(w, pre, projs, cfg, lsh.get(leaf))
+
+        layer = {
+            "in_norm": vec("input_layernorm.weight", "in_norm"),
+            "post_norm": vec("post_attention_layernorm.weight", "post_norm"),
+            "router": lin("mlp.gate.weight", "router"),
+            "experts_gate_up": held(("gate", "up"), "experts_gate_up"),
+            "experts_down": held(("down",), "experts_down"),
+            "shared_gate_proj": lin("mlp.shared_expert.gate_proj.weight",
+                                    "shared_gate_proj"),
+            "shared_up_proj": lin("mlp.shared_expert.up_proj.weight",
+                                  "shared_up_proj"),
+            "shared_down_proj": lin("mlp.shared_expert.down_proj.weight",
+                                    "shared_down_proj"),
+            "shared_gate": lin("mlp.shared_expert_gate.weight",
+                               "shared_gate"),
+        }
+        if full:
+            layer.update({
+                "q_proj": regrouped("self_attn.q_proj.weight", "q_proj",
+                                    cfg.num_attention_heads,
+                                    (cfg.head_dim, cfg.head_dim)),
+                "k_proj": lin("self_attn.k_proj.weight", "k_proj"),
+                "v_proj": lin("self_attn.v_proj.weight", "v_proj"),
+                "o_proj": lin("self_attn.o_proj.weight", "o_proj"),
+                "q_norm": vec("self_attn.q_norm.weight", "q_norm"),
+                "k_norm": vec("self_attn.k_norm.weight", "k_norm"),
+            })
+        else:
+            conv = w.get(pre + "linear_attn.conv1d.weight")     # [C, 1, K]
+            layer.update({
+                "in_proj_qkvz": regrouped(
+                    "linear_attn.in_proj_qkvz.weight", "in_proj_qkvz", Hk,
+                    (dk, dk, rdv, rdv)),
+                "in_proj_ba": regrouped("linear_attn.in_proj_ba.weight",
+                                        "in_proj_ba", Hk, (r, r)),
+                "conv": lay(conv.reshape(conv.shape[0], conv.shape[-1]),
+                             True, lsh.get("conv")),
+                "A_log": vec("linear_attn.A_log", "A_log"),
+                "dt_bias": vec("linear_attn.dt_bias", "dt_bias"),
+                "gdn_norm": vec("linear_attn.norm.weight", "gdn_norm"),
+                "out_proj": lin("linear_attn.out_proj.weight", "out_proj"),
+            })
+        layers.append(layer)
+    return {
+        "embed": w.get("embed_tokens.weight", sharding=sh.get("embed")),
+        "layers": layers,
+        "final_norm": w.get("norm.weight", sharding=sh.get("final_norm")),
+        "lm_head": w.get("lm_head.weight", transpose=True,
+                         sharding=sh.get("lm_head")),
+    }
 
 
 # ------------------------------------------------------------------ norms

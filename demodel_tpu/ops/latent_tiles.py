@@ -1,7 +1,6 @@
 """The filled tiles of a latent page, read where they lie: the Pallas TPU
 kernel a latent attention's decode step runs in the place of the chunk's
-gather and its loop (:func:`demodel_tpu.models.common._over_tiles`, the
-row carry).
+gather and its loop (:func:`demodel_tpu.models.common._over_tiles`).
 
 A latent page is ONE array, ``[layers x blocks, 1, block_tokens, 640]``: a
 position's one cached vector is every head's key, and its first ``vd``
@@ -28,7 +27,7 @@ started before this tile's products.
 - scalar prefetch: the tiles' block ids, where each row's tiles start in
   the list and how many it has filled, how many positions of each tile are
   its row's (a prefix), the count of filled tiles;
-- the arithmetic is ``_over_tiles``' own, ``partials`` then ``trip_rows``:
+- the arithmetic is ``_over_tiles``' own, ``partials`` then ``trip``:
   scores in the queries' dtype, scaled there, masked in float32; a tile's
   exponentials below ITS largest score, cast to the queries' dtype before
   the product with the tile's first ``vd`` columns, accumulated in

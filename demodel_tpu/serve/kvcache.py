@@ -48,12 +48,11 @@ The pool is two halves that never touch each other:
   counters, ``describe()``. Admission and eviction are list operations.
 - the **arrays** on the device: ``k`` and ``v``, ``[L, num_blocks + 1,
   Hkv, block_tokens, hd]`` in the model's dtype, ``L`` the layers that page
-  (positions and head
-  width innermost, the order the TPU's attention reads; the extra block
-  is scratch no lease can hold), so the byte budget
-  (``DEMODEL_GEN_KV_MB``) is an HBM budget. Under a ``tp`` mesh they are
-  sharded on the KV-head axis by ``llama._head_align``'s rule (replicated
-  when the heads do not divide); then the spec's state arrays, replicated.
+  (positions and head width innermost, the order the TPU's attention
+  reads; the extra block is scratch no lease can hold), so the byte budget
+  (``budget_mb``) is an HBM budget. Under a ``tp`` mesh they are sharded on
+  the KV-head axis by ``llama._head_align``'s rule (replicated when the
+  heads do not divide); then the spec's state arrays, replicated.
   The budget pays for the slots first and the blocks with the rest. Every
   program that writes them takes them all donated and returns them all
   (:meth:`KVBlockPool.apply`), so the bytes never move: a decode step
@@ -102,7 +101,6 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from demodel_tpu.tier import TierBudget
-from demodel_tpu.utils.env import gen_block_tokens, gen_kv_mb
 from demodel_tpu.utils.logging import get_logger
 from demodel_tpu.utils.metrics import HUB
 
@@ -119,7 +117,6 @@ HUB.inc("gen_state_slots_freed_total", 0)
 HUB.inc("gen_kv_positions_width_total", 0)
 HUB.inc("gen_kv_positions_read_total", 0)
 HUB.inc("gen_kv_positions_in_place_total", 0)
-HUB.inc("gen_attn_partial_bytes_total", 0)
 
 
 class CacheSpec(NamedTuple):
@@ -136,20 +133,13 @@ class CacheSpec(NamedTuple):
     of values, two arrays of ``head_dim`` columns; otherwise the page is
     one array whose ``head_dim`` columns are a position's keys and whose
     first ``values`` columns are also its values (a latent layer's ``[c_kv
-    | k_rope]``, one "head" all query heads share). ``readers`` of the
-    model's layers read the whole of their rows' pages in a decode step
-    (:meth:`Paged.past`), each with ``query_heads`` query heads: what a
-    wide step's attention keeps in float32 between its trips and its sum
-    is counted from them (:meth:`KVBlockPool.partial_bytes`; 0 where a
-    pool is made by hand and nothing is counted)."""
+    | k_rope]``, one "head" all query heads share)."""
 
     layers: int
     kv_heads: int
     head_dim: int
     state: tuple[tuple[str, tuple[int, ...], str], ...] = ()
     values: int = 0
-    readers: int = 0
-    query_heads: int = 0
 
 
 class PoolExhausted(Exception):
@@ -186,24 +176,22 @@ class KVBlockPool:
     ``spec`` (the model module's :class:`CacheSpec`) fixes the block
     geometry and the slot's arrays; ``slots`` how many sequences can hold
     a slot at once (the engine's ``max_batch``; ignored when the spec has
-    no state); the byte budget (``DEMODEL_GEN_KV_MB`` unless overridden)
-    pays for the slots and fixes the block count with what is left;
-    ``mesh`` (the engine's) fixes where the arrays live. All ledger state
-    sits behind one lock and never touches the arrays; the arrays belong
-    to the engine thread alone, which hands them to its programs through
-    :meth:`apply`.
+    no state); the byte budget (``budget_mb``) pays for the slots and
+    fixes the block count with what is left; ``mesh`` (the engine's) fixes
+    where the arrays live. All ledger state sits behind one lock and never
+    touches the arrays; the arrays belong to the engine thread alone,
+    which hands them to its programs through :meth:`apply`.
     """
 
     def __init__(self, spec: CacheSpec, *,
                  slots: int = 0,
-                 block_tokens: int | None = None,
-                 budget_mb: int | None = None,
+                 block_tokens: int = 16,
+                 budget_mb: int = 256,
                  dtype: str = "float32", mesh=None):
         self.spec = spec
         layers, kv_heads, head_dim = spec.layers, spec.kv_heads, spec.head_dim
-        self.block_tokens = int(block_tokens or gen_block_tokens())
-        budget_bytes = int(budget_mb if budget_mb is not None
-                           else gen_kv_mb()) << 20
+        self.block_tokens = int(block_tokens)
+        budget_bytes = int(budget_mb) << 20
         dt = jnp.dtype(dtype)
         #: arrays a page is made of: K and V, or a latent layer's one
         self.pages = 1 if spec.values else 2
@@ -282,43 +270,17 @@ class KVBlockPool:
         with self._lock:
             return self.num_slots - len(self._free_slots)
 
-    def partial_bytes(self, rows: int, slots: int) -> int:
-        """On the host, from shapes: the float32 partials (weighted values,
-        largest score, sum) the attention of a decode step of ``rows`` rows
-        at ``slots`` table slots a row keeps between the trips of its loop
-        over the filled tiles and its sum, over the layers that read whole
-        pages: one set a tile of the table's capacity, or one a row where
-        the rule of :meth:`Tiles.by_row` says so; 0 for a narrow table,
-        which has no loop."""
-        if not _wide(slots):
-            return 0
-        return self.spec.readers * self._partial * (
-            rows if self._by_row else rows * (slots // TILE_BLOCKS))
-
-    @property
-    def _partial(self) -> int:
-        """A tile's float32 partials, every query head's."""
-        spec = self.spec
-        return spec.query_heads * ((spec.values or spec.head_dim) + 2) * 4
-
-    @property
-    def _by_row(self) -> bool:
-        # a tile of one layer: block_bytes counts every layer's
-        return _by_row(self._partial,
-                       TILE_BLOCKS * self.block_bytes // self.spec.layers)
-
     def positions_in_place(self, slots: int, read: int) -> int:
         """On the host, from shapes and the pool's own devices: how many of
         the ``read`` positions a decode step's attention reads at ``slots``
         table slots a row it reads from the pool itself, with no gathered
         copy: all of them where the step's programs hold the kernel that
         follows the filled tiles (:mod:`demodel_tpu.ops.latent_tiles`: a
-        page of one array under one cached head, a wide table, the
-        partials carried a row, programs lowered for a TPU), none
-        otherwise: the rule of :func:`models.common._over_tiles`."""
+        page of one array under one cached head, a wide table, programs
+        lowered for a TPU), none otherwise: the rule of
+        :func:`models.common._over_tiles`."""
         in_place = (self.pages == 1 and self.spec.kv_heads == 1
-                    and _wide(slots) and self._by_row
-                    and self.platform == "tpu")
+                    and _wide(slots) and self.platform == "tpu")
         return read if in_place else 0
 
     # ------------------------------------------------------- alloc/free
@@ -536,15 +498,6 @@ TILE_CHUNK = 128
 #: what a chunk of keys and one of values may take together of a core's
 #: fast memory (128 MiB on a v5e) and still both be held there
 FAST_BYTES = 96 << 20
-#: a tile's float32 partials (weighted values, largest score and sum for
-#: every query head) may be an eighth of the bytes of the tile they were
-#: taken from and still be kept a tile; past that the loop over the filled
-#: tiles carries them a row. Every page of K and V the benchmark has reads
-#: 0.016-0.032 (4 or 8 query heads a KV head of 128 or 256: 2 080-8 256 B
-#: beside a head's 131 072-262 144 B of a tile), A.X-K1's absorbed step 0.40
-#: (64 heads x 514 float32 = 131 584 B beside 256 positions of 640
-#: bfloat16 = 327 680 B)
-ROW_CARRY_SHARE = 8
 
 
 class Tiles(NamedTuple):
@@ -558,15 +511,13 @@ class Tiles(NamedTuple):
     they lie. A
     tile past the filled ones repeats the last of them (its ``row`` too,
     with no position ``live``), so no block wholly past a row's length is
-    ever read. A trip of a loop over the chunks costs a handful of device
+    ever read. A trip of the loop over the chunks costs a handful of device
     operations whatever it moves, so the chunks are few and large and what
     a trip needs of the index is ready to be sliced: a chunk's ids and
-    ``live`` (:meth:`chunk`) and, for a loop that combines a chunk's tiles
-    into their rows, whose they are (:meth:`rows`). Whether a loop does,
-    or leaves its partial results a tile of the capacity, follows from
-    their bytes beside a tile's (:meth:`by_row`), as whether a trip
-    gathers keys and values apart follows from a chunk's
-    (:attr:`apart`)."""
+    ``live`` (:meth:`chunk`) and whose its tiles are (:meth:`rows`), by
+    which the trip combines them into the one running softmax it carries a
+    row. Whether a trip gathers keys and values apart follows from a
+    chunk's bytes (:attr:`apart`)."""
 
     ids: jax.Array          # [C, TILE_BLOCKS] uint32
     row: jax.Array          # [C] the row a tile belongs to
@@ -574,7 +525,8 @@ class Tiles(NamedTuple):
     #                         a position of its row: none of a tile past
     #                         the filled ones
     own: jax.Array          # [B, tiles a row] where a row's tiles lie in
-    #                         the list, -1 where it has filled none
+    #                         the list, -1 where it has filled none (the
+    #                         kernel's: a row's first tile and its count)
     trips: jax.Array        # [] uint32: the chunks that hold a filled tile
     #                         (unsigned: a loop's index then slices without
     #                         a test for a negative start)
@@ -593,19 +545,6 @@ class Tiles(NamedTuple):
         return self.v is not None and 2 * self.chunk_tiles * TILE_BLOCKS \
             * math.prod(self.k.shape[1:]) * self.k.dtype.itemsize \
             > FAST_BYTES
-
-    def by_row(self, partial: int) -> bool:
-        """What a loop over the chunks carries, from bytes: ``partial``
-        are the bytes of the running softmax's three that a tile leaves
-        (every query head's weighted values, largest score and sum, in
-        float32). Beside a tile's own keys and values they are little in
-        grouped-query attention, and are kept a tile of the capacity
-        (False); where they exceed one part in :data:`ROW_CARRY_SHARE` of
-        it the loop would move more partials than pages, and carries one
-        running softmax a row (True)."""
-        tile = TILE_BLOCKS * math.prod(self.k.shape[1:]) \
-            * self.k.dtype.itemsize * (1 if self.v is None else 2)
-        return _by_row(partial, tile)
 
     def chunk(self, i):
         """Chunk ``i``: its tiles' block ids [n * TILE_BLOCKS] and their
@@ -629,12 +568,6 @@ class Tiles(NamedTuple):
 
 def _chunk_tiles(capacity: int) -> int:
     return math.gcd(TILE_CHUNK, capacity)
-
-
-def _by_row(partial: int, tile: int) -> bool:
-    """A tile's ``partial`` bytes of running softmax outweigh their share
-    of the ``tile`` bytes it reads: carried a row, not a tile."""
-    return partial * ROW_CARRY_SHARE > tile
 
 
 def _wide(slots: int) -> bool:

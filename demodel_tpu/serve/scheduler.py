@@ -12,7 +12,7 @@ no-overcommit discipline the KV budget exists to enforce.
 
 Backpressure rides the proxy plane's admission contract: a full waiting
 queue answers :class:`QueueOverflow`, which the HTTP surface maps to
-503 + ``Retry-After`` (``DEMODEL_GEN_RETRY_AFTER``) — loudly rejected,
+503 + ``Retry-After`` (:data:`RETRY_AFTER_S`) — loudly rejected,
 never silently dropped; every admitted request carries an
 :class:`AdmissionTicket` that must settle exactly once.
 
@@ -48,12 +48,13 @@ from typing import Any, Iterator
 from demodel_tpu.serve import kvcache
 from demodel_tpu.serve.kvcache import KVBlockPool, PoolExhausted
 from demodel_tpu.utils import compile_cache, trace
-from demodel_tpu.utils.env import (gen_max_batch, gen_max_new_tokens,
-                                   gen_queue_limit, gen_retry_after_s)
 from demodel_tpu.utils.logging import get_logger
 from demodel_tpu.utils.metrics import HUB, labeled
 
 log = get_logger("serve.scheduler")
+
+#: the Retry-After hint (seconds) a queue-overflow 503 carries
+RETRY_AFTER_S = 1
 
 #: pre-register the generation families at import (house idiom)
 HUB.inc(labeled("gen_tokens_total", stage="prefill"), 0)
@@ -264,11 +265,11 @@ class GenEngine:
 
     def __init__(self, params, cfg, mesh=None, *,
                  pool: KVBlockPool | None = None,
-                 max_batch: int | None = None,
-                 queue_limit: int | None = None,
-                 max_new_tokens: int | None = None,
-                 block_tokens: int | None = None,
-                 kv_mb: int | None = None,
+                 max_batch: int = 8,
+                 queue_limit: int = 64,
+                 max_new_tokens: int = 256,
+                 block_tokens: int = 16,
+                 kv_mb: int = 256,
                  model: str = "inline"):
         import jax
         import jax.numpy as jnp
@@ -309,7 +310,7 @@ class GenEngine:
         self.cfg = cfg
         self.mesh = mesh
         self.model = model
-        self.max_batch = int(max_batch or gen_max_batch())
+        self.max_batch = int(max_batch)
         # a slot a running sequence: one freed by a row still in flight is
         # written by that row before the prefill that takes it over (the
         # device runs them in the order they were queued), as its blocks are
@@ -317,10 +318,8 @@ class GenEngine:
             module.cache_spec(cfg), slots=self.max_batch,
             block_tokens=block_tokens, budget_mb=kv_mb, dtype=cfg.dtype,
             mesh=mesh)
-        self.max_new_cap = int(max_new_tokens or gen_max_new_tokens())
-        self.admission = AdmissionQueue(
-            queue_limit if queue_limit is not None else gen_queue_limit(),
-            gen_retry_after_s())
+        self.max_new_cap = int(max_new_tokens)
+        self.admission = AdmissionQueue(queue_limit, RETRY_AFTER_S)
 
         #: every decode step returns this many ids, whatever its bucket,
         #: so the ids one step hands the next have one shape for life
@@ -923,22 +922,17 @@ class GenEngine:
                     ship.set_attr("bytes", sum(t.rows.nbytes for t in todo))
                 B = len(flight.batch)
                 table, read = flight.kv_positions
-                # the bucket's rows at the table's slots a row
-                slots = flight.width // self.pool.block_tokens
-                bucket = table // flight.width
-                partials = self.pool.partial_bytes(bucket, slots)
-                in_place = self.pool.positions_in_place(slots, read)
+                in_place = self.pool.positions_in_place(
+                    flight.width // self.pool.block_tokens, read)
                 for key, value in (("batch", B), ("width", flight.width),
                                    ("ahead", flight.ahead),
                                    ("kv_positions_width", table),
                                    ("kv_positions_read", read),
-                                   ("kv_positions_in_place", in_place),
-                                   ("attn_partial_bytes", partials)):
+                                   ("kv_positions_in_place", in_place)):
                     cycle.set_attr(key, value)
                 HUB.inc("gen_kv_positions_width_total", table)
                 HUB.inc("gen_kv_positions_read_total", read)
                 HUB.inc("gen_kv_positions_in_place_total", in_place)
-                HUB.inc("gen_attn_partial_bytes_total", partials)
                 if self._slotted:
                     # each row's slot, read and written, unless the module
                     # names what its step moved of it (_observe, below)
@@ -964,7 +958,8 @@ class GenEngine:
                     # (1.7 ms a cycle at 32 rows)
                     flight.ids = flight.stats = None
                     HUB.inc("gen_d2h_bytes_total", pulled)
-                self._observe(cycle, stats, B, bucket)
+                # the bucket's rows: the table's positions over its width
+                self._observe(cycle, stats, B, table // flight.width)
                 with trace.span("serve.decode-post", batch=B) as post:
                     retired = emitted = 0
                     for seq, tok in zip(flight.batch, ids.tolist()):

@@ -635,7 +635,7 @@ class TieredStore:
                             if e2.errno != errno.ENOSPC:
                                 raise
                             self._enter_degraded(e2)
-                            prefix = _partial_bytes(self.store, key,
+                            prefix = _landed_prefix(self.store, key,
                                                     w.offset)
                             w.checkpoint()
                             w.abort(keep_partial=True)
@@ -837,7 +837,7 @@ class TieredStore:
         self.hot.close()
 
 
-def _partial_bytes(store: Store, key: str, size: int) -> bytes:
+def _landed_prefix(store: Store, key: str, size: int) -> bytes:
     """The durably landed prefix of ``partial/<key>`` — the relay seed for
     a mid-stream degraded switch (waiters already streamed these bytes, so
     a short read here must fail the flight, not desync it)."""

@@ -199,49 +199,6 @@ def proxy_ktls() -> bool:
     return env_bool("DEMODEL_PROXY_KTLS", True)
 
 
-def gen_block_tokens() -> int:
-    """``DEMODEL_GEN_BLOCK``: tokens per KV-cache block in the paged
-    generation pool (:mod:`demodel_tpu.serve.kvcache`). Smaller blocks
-    waste less tail capacity per sequence; larger blocks cut block-table
-    overhead. 16 matches the vLLM default."""
-    return env_int("DEMODEL_GEN_BLOCK", 16, minimum=1)
-
-
-def gen_kv_mb() -> int:
-    """``DEMODEL_GEN_KV_MB``: byte budget (MB) for the paged KV pool —
-    the serving twin of ``DEMODEL_TIER_RAM_MB``, accounted through the
-    same :class:`~demodel_tpu.tier.TierBudget` shape so KV memory shows
-    up next to the RAM tier on statusz."""
-    return env_int("DEMODEL_GEN_KV_MB", 256, minimum=1)
-
-
-def gen_max_batch() -> int:
-    """``DEMODEL_GEN_MAX_BATCH``: running-sequence cap for the
-    continuous-batching scheduler — one decode step advances at most
-    this many sequences together."""
-    return env_int("DEMODEL_GEN_MAX_BATCH", 8, minimum=1)
-
-
-def gen_queue_limit() -> int:
-    """``DEMODEL_GEN_QUEUE``: waiting-queue depth past which admission
-    answers 503 + Retry-After (the proxy plane's admission contract,
-    applied to generation)."""
-    return env_int("DEMODEL_GEN_QUEUE", 64, minimum=1)
-
-
-def gen_retry_after_s() -> int:
-    """``DEMODEL_GEN_RETRY_AFTER``: the Retry-After hint (seconds) a
-    queue-overflow 503 carries."""
-    return env_int("DEMODEL_GEN_RETRY_AFTER", 1, minimum=1)
-
-
-def gen_max_new_tokens() -> int:
-    """``DEMODEL_GEN_MAX_NEW``: per-request cap on generated tokens —
-    admission reserves KV blocks for the WORST CASE (prompt + this cap),
-    so the cap is also the no-overcommit bound."""
-    return env_int("DEMODEL_GEN_MAX_NEW", 256, minimum=1)
-
-
 def store_reprobe_secs() -> int:
     """``DEMODEL_STORE_REPROBE_SECS``: how often a node in degraded
     read-through mode re-probes the store with a small real write; a

@@ -195,12 +195,6 @@ def _knob_rows() -> list[tuple[str, Any]]:
         ("DEMODEL_STORE_REPROBE_SECS", env.store_reprobe_secs()),
         ("DEMODEL_SCRUB_INTERVAL_SECS", env.scrub_interval_secs()),
         ("DEMODEL_SCRUB_RATE_MB_S", env.scrub_rate_mb_s()),
-        ("DEMODEL_GEN_BLOCK", env.gen_block_tokens()),
-        ("DEMODEL_GEN_KV_MB", env.gen_kv_mb()),
-        ("DEMODEL_GEN_MAX_BATCH", env.gen_max_batch()),
-        ("DEMODEL_GEN_QUEUE", env.gen_queue_limit()),
-        ("DEMODEL_GEN_RETRY_AFTER", env.gen_retry_after_s()),
-        ("DEMODEL_GEN_MAX_NEW", env.gen_max_new_tokens()),
     ]
 
 

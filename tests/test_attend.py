@@ -1,16 +1,16 @@
 """``models/common.attend``: the one attention over new keys and a paged
 cache, against a dense float32 reference written here. A narrow past is a
 rectangle ``(k, v, kpos, live)``; a wide one the tiles its rows have filled
-(``kvcache.Paged.filled`` / ``past``), gathered a chunk at a time, whose
-running softmax the loop carries a tile or, where a tile's float32 partials
-outweigh their share of the tile's bytes (``kvcache.Tiles.by_row``), a
-row: a latent page of one array under many query heads, and at this file's
-sizes any group of 8."""
+(``kvcache.Paged.filled`` / ``past``), gathered a chunk at a time by one
+loop that carries one running softmax a row, over pages of keys and values
+and over a latent page of one array."""
 
 from __future__ import annotations
 
 import itertools
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -80,11 +80,10 @@ def _rectangle(cache, layer, lengths):
             kpos < jnp.asarray(lengths)[:, None])
 
 
-def _by_row(past, q, vd: int) -> bool:
-    """What ``common._over_tiles`` asks of the index for queries ``q`` [B,
-    T, H, hd] and values ``vd`` wide."""
-    _rows, Tq, H, _hd = q.shape
-    return past.by_row(H * Tq * (vd + 2) * 4)
+def _float32(text: str) -> list[tuple[int, ...]]:
+    """The shapes of the float32 arrays a lowered text names."""
+    return [tuple(int(d) for d in dims.split("x"))
+            for dims in re.findall(r"tensor<([0-9x]+)xf32>", text)]
 
 
 CASES = [pytest.param(paged, window, group, None, id=f"{paged}-{wid}-{gid}")
@@ -99,9 +98,9 @@ CASES += [pytest.param(f"{how}-{chunk}", 0, group, scale,
           for how, chunk, group, (scale, sid) in itertools.product(
               ("step", "queries"), (32, 4), (1, 8),
               ((None, "scale"), (0.25, "own")))]
-# the shape that takes the row carry for what it is: a latent page of one
-# array (values: the first VD columns of the keys) under 8 query heads.
-# With four tiles a trip the rows of 2 and of 4 tiles straddle two trips.
+# a latent page of one array (values: the first VD columns of the keys)
+# under 8 query heads. With four tiles a trip the rows of 2 and of 4 tiles
+# straddle two trips.
 CASES += [pytest.param(f"latent-{how}-{chunk}", 0, 8, scale,
                        id=f"latent-{how}-chunk{chunk}-g8-{sid}")
           for how, chunk, (scale, sid) in itertools.product(
@@ -120,9 +119,7 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
     five (rows of 0, 1, a tile less one, a tile, a tile and one and the
     whole width in one batch: rows that have filled no tile, as a pad row
     of the bucket has none, and one that ends a tile exactly) and is held
-    to the rectangle over the same pages besides. Its loop carries a
-    running softmax a tile at one query head a KV head and a row at eight,
-    and a row over a latent page."""
+    to the rectangle over the same pages besides."""
     rng = np.random.default_rng(11)
     how, _, chunk = paged.rpartition("-") if "-" in paged else (paged, "", "")
     latent = how.startswith("latent")
@@ -153,7 +150,6 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
         assert cache.wide
         past = cache.past(1, cache.filled(jnp.asarray(lengths)))
         assert past.chunk_tiles == int(chunk)
-        assert _by_row(past, q, vd) == (group == 8)
         rectangle = _rectangle(cache, 1, lengths)
         pk, pv, slots, live = (a if a is None else np.asarray(a)
                                for a in rectangle)
@@ -178,7 +174,7 @@ def test_attend_matches_a_dense_reference(paged, window, group, scale,
             rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("group", [2, 8], ids=["a-tile", "a-row"])
+@pytest.mark.parametrize("group", [2, 8], ids=["g2", "g8"])
 @pytest.mark.parametrize("fast", [kvcache.FAST_BYTES, 0],
                          ids=["together", "apart"])
 @pytest.mark.parametrize("chunk", [64, 4])
@@ -189,9 +185,9 @@ def test_tiles_past_a_rows_length_are_not_read(chunk, fast, group,
     clean pool gives, to the bit; the rectangle, which multiplies what it
     masked by zero, does not. The same where a chunk of keys and one of
     values would not fit fast memory together and a trip gathers the
-    values when it is done with the keys, and whether the loop carries its
-    running softmax a tile or a row (a tile past the filled ones repeats
-    the last filled one and weighs nothing in its row)."""
+    values when it is done with the keys, under two and eight query heads
+    a KV head (a tile past the filled ones repeats the last filled one and
+    weighs nothing in its row)."""
     monkeypatch.setattr(kvcache, "TILE_CHUNK", chunk)
     monkeypatch.setattr(kvcache, "FAST_BYTES", fast)
     rng = np.random.default_rng(3)
@@ -214,7 +210,6 @@ def test_tiles_past_a_rows_length_are_not_read(chunk, fast, group,
     def over(cache):
         past = cache.past(0, cache.filled(jnp.asarray(lengths)))
         assert past.apart == (fast == 0)
-        assert _by_row(past, q, HD) == (group == 8)
         return np.asarray(attend(q, k, v, pos, past=past))
 
     got = over(dirty)
@@ -227,17 +222,18 @@ def test_tiles_past_a_rows_length_are_not_read(chunk, fast, group,
         q, k, v, pos, past=_rectangle(dirty, 0, lengths)))).any()
 
 
-@pytest.mark.parametrize("past", ["alone", "rectangle", "tiles", "rows"])
+@pytest.mark.parametrize("past", ["alone", "rectangle", "tiles-g2",
+                                  "tiles-g8"])
 def test_probabilities_are_in_the_models_dtype(past):
     """float32 softmax, then the model's dtype for the value products: a
     bfloat16 call returns bfloat16 and stays near the float32 one, over
     new keys alone and over both kinds of past, the rectangle and the
-    tiles of the same wide pages, their running softmax carried a tile
-    (two query heads a KV head) and a row (eight)."""
+    tiles of the same wide pages (under two query heads a KV head and
+    under eight)."""
     rng = np.random.default_rng(5)
     rows = 2 if past == "alone" else len(WIDE_LENGTHS)
     q, k, v = (jnp.asarray(rng.normal(size=(rows, 1, h, HD)), jnp.bfloat16)
-               for h in (16 if past == "rows" else 4, 2, 2))
+               for h in (16 if past == "tiles-g8" else 4, 2, 2))
     pos = jnp.asarray([[4], [2]]) if past == "alone" \
         else jnp.asarray(WIDE_LENGTHS)[:, None]
 
@@ -247,9 +243,7 @@ def test_probabilities_are_in_the_models_dtype(past):
         cache = _wide_cache(np.random.default_rng(7), rows, dtype)
         if past == "rectangle":
             return _rectangle(cache, 0, WIDE_LENGTHS)
-        tiles = cache.past(0, cache.filled(jnp.asarray(WIDE_LENGTHS)))
-        assert _by_row(tiles, q, HD) == (past == "rows")
-        return tiles
+        return cache.past(0, cache.filled(jnp.asarray(WIDE_LENGTHS)))
 
     got = attend(q, k, v, pos, past=pages(jnp.bfloat16))
     want = attend(*(a.astype(jnp.float32) for a in (q, k, v)), pos,
@@ -257,3 +251,36 @@ def test_probabilities_are_in_the_models_dtype(past):
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), atol=3e-2)
+
+
+@pytest.mark.parametrize("latent,group", [(False, 2), (False, 8), (True, 8)],
+                         ids=["kv-g2", "kv-g8", "latent-g8"])
+def test_nothing_of_the_tables_capacity_is_kept_in_float32(latent, group,
+                                                           monkeypatch):
+    """The lowered text of :func:`attend` over the tiles of five rows, 20
+    tiles of capacity taken four a trip, in bfloat16: the loop carries its
+    running softmax a row, ``[5, Hkv, g, T, vd | 1 | 1]`` in float32, and
+    no float32 array of the program has an axis of 20 (a loop that left
+    its partials a tile of the capacity kept ``[20, Hkv, g, T, vd + 2]``
+    through its trips and gathered it a row at the end)."""
+    monkeypatch.setattr(kvcache, "TILE_CHUNK", 4)
+    rows = 5
+    lengths = jnp.asarray(WIDE_LENGTHS[:rows])
+    capacity = rows * WIDE_SLOTS // kvcache.TILE_BLOCKS
+    cache = _wide_cache(np.random.default_rng(2), rows, jnp.bfloat16,
+                        latent=latent)
+    kvh, vd = (1, VD) if latent else (HKV, HD)
+    q, k, v = (jnp.zeros((rows, 1, h, w), jnp.bfloat16)
+               for h, w in ((kvh * group, HD), (kvh, HD), (kvh, vd)))
+
+    def step(q, k, v, lengths, pk, pv, table):
+        paged = kvcache.Paged(pk, pv, table)
+        tiles = paged.past(1, paged.filled(lengths))
+        assert tiles.row.shape == (capacity,) and tiles.chunk_tiles == 4
+        return attend(q, k, v, lengths[:, None], past=tiles)
+
+    text = jax.jit(step).lower(q, k, v, lengths, *cache[:3]).as_text()
+    (loop,) = re.findall(r"stablehlo\.while\(.*\) : (.*)\n", text)
+    assert _float32(loop) == [(rows, kvh, group, 1, vd)] \
+        + [(rows, kvh, group, 1, 1)] * 2
+    assert not [dims for dims in _float32(text) if capacity in dims]

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1, experts, hf_loader, latent
+from demodel_tpu.models import axk1, experts, latent
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB
@@ -55,7 +55,7 @@ ENGINE = dict(max_batch=4, queue_limit=8, max_new_tokens=24, kv_mb=1)
 def _params(ckpt, model: dict, mesh=None):
     cfg = axk1.AxK1Config.from_hf(model)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    params = hf_loader.load_axk1_params(weights, cfg, mesh=mesh)
+    params = axk1.load_params(weights, cfg, mesh=mesh)
     assert not weights, sorted(weights)     # the loader took every tensor
     return params, cfg
 
@@ -101,10 +101,8 @@ def _served(ckpt, params, cfg, lengths=(40, 17, 9), steps=12,
 @pytest.mark.parametrize("lengths,block_tokens,heads", [
     ((40, 17, 9), 4, 4),    # a table of two tiles: the rectangle
     ((70, 33, 5), 2, 4),    # past 64 positions: the tiles the rows filled
-    # under 32 heads a tile's float32 partials (32 x 34 x 4 B) are a
-    # quarter of its 32 positions of 128 columns: carried a row
-    ((70, 33, 5), 2, 32),
-], ids=["inside-two-tiles", "past-two-tiles", "past-two-tiles-a-row"])
+    ((70, 33, 5), 2, 32),   # the same under 32 heads
+], ids=["inside-two-tiles", "past-two-tiles", "past-two-tiles-32-heads"])
 def test_float32_program_is_the_reference(small, lengths, block_tokens,
                                           heads):
     """The same weights computed in float32 by the program: the prompt's
@@ -113,19 +111,13 @@ def test_float32_program_is_the_reference(small, lengths, block_tokens,
     every position. No rounding to hide behind: 1e-4 on logits of order 1
     (float32 sums in another order, and the absorbed form multiplies
     ``w_uk`` into the query before the scores, not into the key). Past two
-    tiles with the loop's running softmax carried a tile (4 heads) and a
-    row (32)."""
+    tiles under 4 heads and under 32."""
     ckpt, params, cfg = small
     if heads != cfg.num_attention_heads:
         model = dict(SMALL, num_attention_heads=heads,
                      num_key_value_heads=heads)
         ckpt = checkpoint.Checkpoint(model, SEED, n_shards=2)
         params, cfg = _params(ckpt, model)
-    pool = kvcache.KVBlockPool(axk1.cache_spec(cfg), block_tokens=2,
-                               budget_mb=1, dtype="float32")
-    partial = 4 * heads * (32 + 2) * 4      # four layers' set of partials
-    assert pool.partial_bytes(4, 256) == partial * (4 if heads == 32
-                                                    else 4 * 16)
     got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg),
                                    lengths=lengths, block_tokens=block_tokens)
     for (_fed, lg), r in zip(got, ref):
@@ -189,15 +181,14 @@ class TestAgainstTheReference:
 
 
 @pytest.mark.parametrize("wide,heads", [(False, 4), (True, 4), (True, 32)],
-                         ids=["rectangle", "tiles", "tiles-a-row"])
+                         ids=["rectangle", "tiles", "tiles-32-heads"])
 def test_absorbed_attention_is_the_expanded_one(wide, heads):
     """One layer's attention at the last position of each row, computed
     twice from the same weights: expanded over the row's whole prefix, and
     absorbed over a latent page that holds the prefix (one array, its
     values the first 32 columns of its keys), in a table of two tiles and
-    in a wider one read by its filled tiles, whose running softmax the
-    loop carries a tile under 4 heads and a row under 32. float32: 2e-5,
-    the two orders of the same sums."""
+    in a wider one read by its filled tiles, under 4 heads and under 32.
+    float32: 2e-5, the two orders of the same sums."""
     cfg = axk1.AxK1Config.tiny(num_attention_heads=heads)
     layer = axk1.init_params(jax.random.key(11), cfg)["layers"][0]
     bs = 2
@@ -226,9 +217,6 @@ def test_absorbed_attention_is_the_expanded_one(wide, heads):
     step = jnp.take_along_axis(x, at[:, None, None], axis=1)
     past = cache.past(0, cache.filled(at))
     assert past[1] is None if not wide else past.v is None
-    if wide:
-        assert past.by_row(heads * (cfg.kv_lora_rank + 2) * 4) \
-            == (heads == 32)
     got, last = latent.absorbed(layer, step, cfg.latent, at[:, None], past)
     want = np.take_along_axis(np.asarray(whole),
                               lengths[:, None, None], axis=1)
@@ -342,9 +330,7 @@ def test_the_pool_holds_a_page_of_one_array(small):
     does a pair. A family that pages K and V still gets its two arrays."""
     _ckpt, _params, cfg = small
     spec = axk1.cache_spec(cfg)
-    # all four layers read the whole of it, with the model's four heads
-    assert spec == kvcache.CacheSpec(4, 1, 128, values=32, readers=4,
-                                     query_heads=4)
+    assert spec == kvcache.CacheSpec(4, 1, 128, values=32)
     pool = kvcache.KVBlockPool(spec, block_tokens=4, budget_mb=1,
                                dtype="bfloat16")
     assert pool.block_bytes == 4 * 4 * 128 * 2      # once, not K and V
@@ -582,4 +568,4 @@ def test_a_selection_bias_in_the_checkpoint_is_refused(small):
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
     weights["model.layers.1.mlp.gate.e_score_correction_bias"] = jnp.zeros(16)
     with pytest.raises(ValueError, match="e_score_correction_bias"):
-        hf_loader.load_axk1_params(weights, cfg)
+        axk1.load_params(weights, cfg)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import exaone_moe, experts, hf_loader
+from demodel_tpu.models import exaone_moe, experts
 from demodel_tpu.serve import GenEngine
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB, labeled
@@ -50,7 +50,7 @@ SEED = 2147483700
 def _params(ckpt, model: dict, mesh=None):
     cfg = exaone_moe.ExaoneMoeConfig.from_hf(model)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    params = hf_loader.load_exaone_moe_params(weights, cfg, mesh=mesh)
+    params = exaone_moe.load_params(weights, cfg, mesh=mesh)
     assert not weights, sorted(weights)     # the loader took every tensor
     return params, cfg
 

@@ -632,55 +632,6 @@ class TestDevicePool:
             assert req.result(5) == np.asarray(llama.generate(
                 params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
 
-    @pytest.mark.parametrize("family,longest,places", [
-        ("llama", 18, 0), ("llama", 70, 4 * 16), ("axk1", 70, 4)],
-        ids=["narrow", "a-tile", "a-row"])
-    def test_a_step_books_the_partials_its_attention_keeps(self, family,
-                                                           longest, places):
-        """``attn_partial_bytes`` on ``serve.decode-step``: the float32
-        partials (every query head's weighted values, largest score and
-        sum) the loop over the filled tiles keeps between its trips and
-        its sum, from shapes. Nothing for a table of two tiles (the
-        rectangle has no loop); one set a tile of the bucket's capacity
-        (four rows of sixteen tiles) for a llama's grouped queries over
-        pages of K and V; one set a row where they outweigh their share of
-        the tile's bytes: A.X-K1's absorbed step, 32 heads x (32 + 2)
-        float32 beside a tile of 32 positions of 128 columns. Counted in
-        ``gen_attn_partial_bytes_total``, which ``/statusz`` has."""
-        from demodel_tpu.models import axk1
-        from demodel_tpu.utils import statusz, trace
-
-        if family == "axk1":
-            cfg = axk1.AxK1Config.tiny(num_attention_heads=32)
-            params = axk1.init_params(jax.random.key(2), cfg)
-            heads, vd = 32, cfg.kv_lora_rank
-        else:
-            _module, params, cfg = _tiny(family)
-            heads, vd = cfg.num_attention_heads, cfg.head_dim
-        engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
-                           max_new_tokens=4, kv_mb=1, block_tokens=2)
-        want = cfg.num_hidden_layers * places * heads * (vd + 2) * 4
-        trace.reset()
-        trace.enable()
-        try:
-            before = HUB.snapshot()
-            _drive(engine, [_prompt(cfg, n, seed=n)
-                            for n in (longest, 9, 5)], 3)
-            for _ in range(2):
-                engine._decode_step()
-            after = HUB.snapshot()
-            steps = [r["attrs"] for r in trace.buffer().snapshot()
-                     if r["name"] == "serve.decode-step"]
-            counters = statusz.snapshot()["counters"]
-        finally:
-            trace.reset()
-            engine.stop()
-        assert [a["attn_partial_bytes"] for a in steps] == [want] * 2
-        assert [a["width"] for a in steps] == [512 if places else 64] * 2
-        name = "gen_attn_partial_bytes_total"
-        assert after[name] - before[name] == 2 * want
-        assert counters[name] >= 2 * want
-
     @pytest.mark.parametrize("family,platform,longest,in_place", [
         ("axk1", "tpu", 70, True), ("axk1", "cpu", 70, False),
         ("axk1", "tpu", 18, False), ("llama", "tpu", 70, False)],
@@ -692,8 +643,8 @@ class TestDevicePool:
         positions of ``kv_positions_read`` the step's attention reads from
         the pool itself, with no gathered copy. All of them where the
         kernel that follows the filled tiles is the step's path: a page of
-        one array whose partials are carried a row (A.X-K1's absorbed step
-        at 32 heads), a wide table, programs lowered for a TPU; none on
+        one array under one cached head (A.X-K1's absorbed step), a wide
+        table, programs lowered for a TPU; none on
         the CPU, where the loop gathers a chunk a trip, for a table of two
         tiles, or for a page of keys and values apart. The platform is the
         pool's devices'; the test says ``tpu`` in its place (the programs
@@ -1683,6 +1634,45 @@ class TestGenerateHTTP:
             t.join(timeout=120)
         finally:
             engine.stop()
+
+    def test_a_herd_is_answered_200_or_503_and_never_dropped(
+            self, gen_server, tiny_model):
+        """Twelve requests at once against an engine one row wide with
+        room for two to wait: every one is answered, with all its tokens
+        or with 503 and ``Retry-After``, some of each, none reset or left
+        hanging; and the pool's blocks and bytes are back at zero."""
+        params, cfg = tiny_model
+        engine = serve.boot(params, cfg, max_batch=1, queue_limit=2,
+                            max_new_tokens=4, kv_mb=4)
+        answers: list = [None] * 12
+
+        def post(i):
+            try:
+                status, doc = _post(f"{gen_server}/generate", {
+                    "prompt": _prompt(cfg, 4, seed=i), "max_new_tokens": 4})
+                answers[i] = (status, len(doc["tokens"]))
+            except urllib.error.HTTPError as exc:
+                answers[i] = (exc.code, exc.headers.get("Retry-After"))
+            except Exception as exc:  # noqa: BLE001 - a drop must show
+                answers[i] = (-1, repr(exc))
+
+        try:
+            threads = [threading.Thread(target=post, args=(i,))
+                       for i in range(len(answers))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            serve.install(None)
+            engine.stop()
+        served = [a for a in answers if a and a[0] == 200]
+        refused = [a for a in answers if a and a[0] == 503]
+        assert len(served) + len(refused) == len(answers), answers
+        assert served and all(n == 4 for _c, n in served)
+        assert refused and all(int(after) >= 1 for _c, after in refused)
+        assert engine.pool.in_use_blocks == 0
+        assert engine.pool.budget.describe()["in_use_bytes"] == 0
 
     def test_statusz_generation_section(self, gen_server, tiny_model):
         params, cfg = tiny_model

@@ -2,7 +2,7 @@
 
 Tiny random reference models are instantiated with ``transformers``, their
 logits compared against our functional forwards fed by the SAME weights —
-through the hf_loader directly and through the full pull→sink→auto path.
+through each family's ``load_params`` directly and through the full pull→sink→auto path.
 """
 
 import json
@@ -28,11 +28,6 @@ from demodel_tpu.models import bert as bert_mod  # noqa: E402
 from demodel_tpu.models import gpt2 as gpt2_mod  # noqa: E402
 from demodel_tpu.models import llama as llama_mod  # noqa: E402
 from demodel_tpu.models.auto import model_from_pull  # noqa: E402
-from demodel_tpu.models.hf_loader import (  # noqa: E402
-    load_bert_params,
-    load_gpt2_params,
-    load_llama_params,
-)
 
 from .fake_registries import make_hf_handler  # noqa: E402
 from .servers import FakeUpstream  # noqa: E402
@@ -54,7 +49,7 @@ def test_llama_parity_gqa():
         want = ref(torch.tensor(toks)).logits.numpy()
 
     cfg = llama_mod.LlamaConfig.from_hf(hf_cfg.to_dict())
-    params = load_llama_params(_state_np(ref), cfg)
+    params = llama_mod.load_params(_state_np(ref), cfg)
     got = np.asarray(llama_mod.forward(params, jnp.asarray(toks, jnp.int32),
                                        cfg))
     np.testing.assert_allclose(got, want, atol=2e-4)
@@ -69,7 +64,7 @@ def test_gpt2_logits_tied_head():
     with torch.no_grad():
         want = ref(torch.tensor(toks)).logits.numpy()
     cfg = gpt2_mod.GPT2Config.from_hf(hf_cfg.to_dict())
-    params = load_gpt2_params(_state_np(ref), cfg)
+    params = gpt2_mod.load_params(_state_np(ref), cfg)
     got = np.asarray(gpt2_mod.forward(params, jnp.asarray(toks, jnp.int32),
                                       cfg))
     np.testing.assert_allclose(got, want, atol=2e-4)
@@ -83,7 +78,7 @@ def _bert_rig():
     torch.manual_seed(2)
     ref = transformers.BertModel(hf_cfg).eval()
     cfg = bert_mod.BertConfig.from_hf(hf_cfg.to_dict())
-    params = load_bert_params(_state_np(ref), cfg)
+    params = bert_mod.load_params(_state_np(ref), cfg)
     return ref, cfg, params
 
 
@@ -121,7 +116,7 @@ def _files_from_hf(model, config: dict) -> dict:
 
 
 def test_gpt2_parity_via_sink(tmp_path, mesh8):
-    """Full path: fake hub → pull_to_hbm (sharded) → hf_loader → logits
+    """Full path: fake hub → pull_to_hbm (sharded) → ``load_params`` → logits
     parity with torch."""
     hf_cfg = transformers.GPT2Config(
         vocab_size=96, n_positions=32, n_embd=48, n_layer=2, n_head=4)
@@ -137,7 +132,7 @@ def test_gpt2_parity_via_sink(tmp_path, mesh8):
         report, placed = delivery.pull_to_hbm(
             "org/g2", cfg, endpoint=f"http://{up.authority}", mesh=mesh8)
         gcfg = gpt2_mod.GPT2Config.from_hf(cfgd)
-        params = load_gpt2_params(placed.arrays, gcfg)
+        params = gpt2_mod.load_params(placed.arrays, gcfg)
         toks = np.arange(2 * 10).reshape(2, 10) % 96
         with torch.no_grad():
             want = ref(torch.tensor(toks)).logits.numpy()
@@ -213,3 +208,61 @@ def test_auto_rejects_unsupported_config_fields(tmp_path, mesh8):
                 model_from_pull(store, bad, mesh=mesh8)
         finally:
             store.close()
+
+
+FAMILIES = ["llama", "gpt2", "bert", "exaone_moe", "qwen3_next", "phi4flash",
+            "axk1", "longcat_flash"]
+
+
+@pytest.mark.parametrize("model_type", FAMILIES)
+def test_a_family_is_the_module_of_its_name(model_type):
+    """``auto.family`` finds a ``model_type``'s module by its name (``-``
+    written ``_``), and the module states the three things
+    ``model_from_pull`` asks of it: its configuration from a
+    ``config.json``, its loader, and its forward function or None (a
+    family only the engine runs states its step functions instead)."""
+    import importlib
+    import inspect
+
+    from demodel_tpu.models import auto
+
+    module = auto.family(model_type)
+    assert module is importlib.import_module(
+        f"demodel_tpu.models.{model_type}")
+    assert auto.family(model_type.replace("_", "-")) is module
+    assert model_type in auto.families()
+    cfg = module.from_hf.__self__       # the configuration's own from_hf
+    assert inspect.isclass(cfg) and cfg.__module__ == module.__name__
+    assert list(inspect.signature(module.load_params).parameters) \
+        == ["weights", "cfg", "mesh"]
+    if module.forward is None:
+        assert callable(module.step_prefill) and callable(module.step_decode)
+    else:
+        assert {"cfg", "mesh"} <= set(
+            inspect.signature(module.forward).parameters)
+
+
+@pytest.mark.parametrize("name", [
+    "common", "latent", "experts", "moe", "auto", "hf_loader", "os", "a.b",
+    "../llama", "", None, 7])
+def test_what_states_no_family_is_not_offered(name, monkeypatch):
+    """A module of ``models/`` that states none of the three names is no
+    family, and neither is a name that is no module of ``models/``: the
+    same ``ValueError``, which lists the families found by looking, and
+    nothing is imported for it."""
+    import importlib
+
+    from demodel_tpu.models import auto
+
+    assert auto.families() == sorted(FAMILIES)
+    if isinstance(name, str) and name.isidentifier():
+        assert name not in auto.families()
+    imported = []
+    real = importlib.import_module
+    monkeypatch.setattr(auto.importlib, "import_module", lambda n, *a: (
+        imported.append(n), real(n, *a))[1])
+    with pytest.raises(ValueError) as refused:
+        auto.family(name)
+    assert str(refused.value).startswith(f"unsupported model_type {name!r} ")
+    assert ", ".join(sorted(FAMILIES)) in str(refused.value)
+    assert all(n.startswith("demodel_tpu.models") for n in imported)

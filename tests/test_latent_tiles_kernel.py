@@ -1,7 +1,7 @@
 """The Pallas kernel that reads a latent page's filled tiles where they lie
 (``ops/latent_tiles.py``), under Pallas' TPU interpreter on the CPU, at the
 page's real 640 columns, blocks of 16 positions and 64 heads: its ``(values,
-largest score, sum)`` against the row-carry loop of
+largest score, sum)`` against the loop of
 ``models/common._over_tiles``, which stays as the portable form and as the
 kernel's oracle, and the whole attention that comes of it against a plain
 float32 softmax over the rectangle.
@@ -101,7 +101,7 @@ def test_the_kernel_is_the_loop(monkeypatch, case, dtype):
             interpret=pltpu.InterpretParams()))
     n = jnp.asarray(lengths, jnp.int32)
     tiles = cache.past(1, cache.filled(n))
-    assert tiles.v is None and tiles.by_row(HEADS * (VALUES + 2) * 4)
+    assert tiles.v is None
     assert int(tiles.trips) == -(-sum(-(-m // TILE) for m in lengths) // 8)
     out = common.attend(q, new, new[..., :VALUES], n[:, None], past=tiles,
                         scale=SCALE)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import hf_loader, latent
+from demodel_tpu.models import latent
 from demodel_tpu.models import longcat_flash as lf
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
@@ -54,7 +54,7 @@ FAMILY = families.of(SMALL)
 def _params(ckpt, model: dict, mesh=None):
     cfg = lf.LongcatFlashConfig.from_hf(model)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    params = hf_loader.load_longcat_flash_params(weights, cfg, mesh=mesh)
+    params = lf.load_params(weights, cfg, mesh=mesh)
     assert not weights, sorted(weights)     # the loader took every tensor
     return params, cfg
 
@@ -100,10 +100,8 @@ def _served(ckpt, params, cfg, lengths=(40, 17, 9), steps=12,
 @pytest.mark.parametrize("lengths,block_tokens,heads", [
     ((40, 17, 9), 4, 4),    # a table of two tiles: the rectangle
     ((70, 33, 5), 2, 4),    # past 64 positions: the tiles the rows filled
-    # under 32 heads a tile's float32 partials (32 x 34 x 4 B) are a
-    # quarter of its 32 positions of 128 columns: carried a row
-    ((70, 33, 5), 2, 32),
-], ids=["inside-two-tiles", "past-two-tiles", "past-two-tiles-a-row"])
+    ((70, 33, 5), 2, 32),   # the same under 32 heads
+], ids=["inside-two-tiles", "past-two-tiles", "past-two-tiles-32-heads"])
 def test_float32_program_is_the_reference(small, lengths, block_tokens,
                                           heads):
     """The same weights computed in float32 by the program: the prompt's
@@ -114,18 +112,13 @@ def test_float32_program_is_the_reference(small, lengths, block_tokens,
     against the reference's expanded form at every position with the
     scales where the equations have them. No rounding to hide behind: 2e-4
     on logits of order 1 (float32 sums in another order; a latent scaled by
-    ``12 ** 0.5`` before or after a product). Past two tiles with the
-    loop's running softmax carried a tile (4 heads) and a row (32)."""
+    ``12 ** 0.5`` before or after a product). Past two tiles under 4 heads
+    and under 32."""
     ckpt, params, cfg = small
     if heads != cfg.num_attention_heads:
         model = dict(SMALL, num_attention_heads=heads)
         ckpt = checkpoint.Checkpoint(model, SEED, n_shards=2)
         params, cfg = _params(ckpt, model)
-    pool = kvcache.KVBlockPool(lf.cache_spec(cfg), block_tokens=2,
-                               budget_mb=1, dtype="float32")
-    partial = 4 * heads * (32 + 2) * 4      # four sublayers' partials
-    assert pool.partial_bytes(4, 256) == partial * (4 if heads == 32
-                                                    else 4 * 16)
     got, _wanted, ref, _ = _served(ckpt, *_float32(params, cfg),
                                    lengths=lengths, block_tokens=block_tokens)
     for (_fed, lg), r in zip(got, ref):
@@ -294,7 +287,7 @@ def test_scales_are_folded_into_the_latent_norms(small):
         dict(SMALL, mla_scale_q_lora=False, mla_scale_kv_lora=False))
     assert plain.latent_scales == (1.0, 1.0)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    unscaled = hf_loader.load_longcat_flash_params(weights, plain)
+    unscaled = lf.load_params(weights, plain)
     np.testing.assert_array_equal(
         unscaled["layers"][1]["sub"][1]["attn"]["kv_a_norm"], 1.0)
     # one attention, float32, against the reference's
@@ -451,14 +444,12 @@ def test_ep_mesh_holds_the_same_layer():
 
 def test_the_pool_counts_sublayers(small):
     """The module states two paging layers a layer of the model, each read
-    whole by its own attention; the pool's bytes, the partial sums a wide
-    step keeps and what ``describe`` says follow from that count and not
-    from the model's."""
+    whole by its own attention; the pool's bytes and what ``describe``
+    says follow from that count and not from the model's."""
     _ckpt, _params, cfg = small
     spec = lf.cache_spec(cfg)
     assert cfg.num_layers == 2
-    assert spec == kvcache.CacheSpec(4, 1, 128, values=32, readers=4,
-                                     query_heads=4)
+    assert spec == kvcache.CacheSpec(4, 1, 128, values=32)
     pool = kvcache.KVBlockPool(spec, block_tokens=4, budget_mb=1,
                                dtype="bfloat16")
     assert pool.block_bytes == 4 * 4 * 128 * 2      # four sublayers, once
@@ -474,14 +465,11 @@ def test_the_pool_counts_sublayers(small):
     assert (published.latent.latent_dim, published.latent.page_dim) \
         == (576, 640)
     spec = lf.cache_spec(published)
-    assert spec == kvcache.CacheSpec(8, 1, 640, values=512, readers=8,
-                                     query_heads=64)
+    assert spec == kvcache.CacheSpec(8, 1, 640, values=512)
     big = kvcache.KVBlockPool(spec, block_tokens=16, budget_mb=1,
                               dtype="bfloat16")
     assert big.block_bytes == 16 * 10240
     assert big.describe()["layers"] == 8
-    # 64 rows of a wide step: one running softmax a row a sublayer
-    assert big.partial_bytes(64, 256) == 8 * 64 * 64 * (512 + 2) * 4
     assert latent.observe(1, spec, published.latent,
                           "bfloat16")["latent_bytes"] == 9216
     assert FAMILY.position_bytes(dict(SMALL, num_layers=4, kv_lora_rank=512,
@@ -695,12 +683,12 @@ def test_a_selection_bias_in_the_checkpoint_enters_the_choice_only(small):
     bias = np.zeros(cfg.router_width, np.float32)
     bias[20] = 1.0
     weights[name] = jnp.asarray(bias)
-    biased = hf_loader.load_longcat_flash_params(weights, cfg)
+    biased = lf.load_params(weights, cfg)
     assert biased["layers"][0]["router_bias"].dtype == jnp.float32
     np.testing.assert_array_equal(biased["layers"][0]["router_bias"], bias)
     weights = {n: jnp.asarray(ckpt.tensor(n)) for n in ckpt.tensors
                if not n.endswith("e_score_correction_bias")}
-    without = hf_loader.load_longcat_flash_params(weights, cfg)
+    without = lf.load_params(weights, cfg)
     assert not np.asarray(without["layers"][0]["router_bias"]).any()
     x = jax.random.normal(jax.random.key(25), (32, cfg.hidden_size),
                           jnp.bfloat16)

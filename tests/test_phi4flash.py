@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import hf_loader, phi4flash
+from demodel_tpu.models import phi4flash
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB
@@ -47,7 +47,7 @@ LENGTHS = (150, 30, 9)
 def _params(ckpt, model: dict, mesh=None):
     cfg = phi4flash.Phi4FlashConfig.from_hf(model)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    params = hf_loader.load_phi4flash_params(weights, cfg, mesh=mesh)
+    params = phi4flash.load_params(weights, cfg, mesh=mesh)
     assert not weights, sorted(weights)     # the loader took every tensor
     return params, cfg
 

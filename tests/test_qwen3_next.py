@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import experts, hf_loader, qwen3_next
+from demodel_tpu.models import experts, qwen3_next
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
 from demodel_tpu.utils.metrics import HUB, labeled
@@ -52,7 +52,7 @@ LENGTHS = (150, 70, 9)
 def _params(ckpt, model: dict, mesh=None):
     cfg = qwen3_next.Qwen3NextConfig.from_hf(model)
     weights = {name: jnp.asarray(ckpt.tensor(name)) for name in ckpt.tensors}
-    params = hf_loader.load_qwen3_next_params(weights, cfg, mesh=mesh)
+    params = qwen3_next.load_params(weights, cfg, mesh=mesh)
     assert not weights, sorted(weights)     # the loader took every tensor
     return params, cfg
 
@@ -512,10 +512,8 @@ def test_served_over_http_like_the_others(small, tmp_path):
         serve.install(None)
     assert got == want
     # PR 42 added ``values`` (a page of one array), 0 for every family
-    # that pages K and V; PR 43 who reads whole pages: the attention layers
+    # that pages K and V; the attention layers alone page
     assert kvcache.CacheSpec._fields == ("layers", "kv_heads", "head_dim",
-                                         "state", "values", "readers",
-                                         "query_heads")
+                                         "state", "values")
     spec = qwen3_next.cache_spec(cfg)
-    assert (spec.values, spec.readers, spec.query_heads) == (
-        0, sum(cfg.full), cfg.num_attention_heads)
+    assert (spec.values, spec.layers) == (0, sum(cfg.full))

@@ -10,11 +10,13 @@ benchmark's size, seen in the compiled decode step (PERF.md, Findings, PR
 42). At 640 columns the pool lies as it is read.
 
 And of the benchmark's 64-row step over that page (~15 s): the attention
-over the filled tiles carries one running softmax a row (PR 43), so the
-program holds no float32 array of the table's capacity and gathers no
-queries to it; and on a TPU it is the Pallas kernel of
-``ops/latent_tiles.py`` a latent attention (PR 45), which reads the tiles
-from the pool where they lie: no chunk of gathered tiles, no loop.
+over the filled tiles carries one running softmax a row, so the program
+holds no float32 array of the table's capacity and gathers no queries to
+it; and on a TPU it is the Pallas kernel of ``ops/latent_tiles.py`` a
+latent attention (PR 45), which reads the tiles from the pool where they
+lie: no chunk of gathered tiles, no loop. The same carry over pages of keys
+and values, one reading layer of ``phi-4-mini-flash`` and of ``qwen3-next``
+at their cells' rows (~2 s each): the portable loop, and its temporaries.
 
 And of LongCat-Flash's 64-row step over a page of 8 sublayers (~20 s): the
 same page, the same carry and the same kernel in each of its two latent
@@ -39,7 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from demodel_tpu.models import axk1, latent, longcat_flash, qwen3_next
+from demodel_tpu.models import (axk1, latent, longcat_flash, phi4flash,
+                                qwen3_next)
+from demodel_tpu.models.common import attend
 from demodel_tpu.serve import kvcache
 
 
@@ -148,10 +152,9 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     tiles left ``f32[1024,1,64,1,514]`` (135 MB a layer: filled, written a
     chunk a trip, gathered by row into ``[64,16,...]``, copied into another
     order) and gathered its queries to capacity, ``bf16[1024,1,1,64,640]``
-    (84 MB); the step's temporaries were 0.47 GB. Since PR 43 a tile's
-    partials outweigh their share of the tile (``kvcache.Tiles.by_row``)
-    and the carry is a row's (0.12 GB of temporaries, of them the loop's
-    chunk of 128 gathered tiles, ``bf16[2048,16,640]``, 42 MB); since PR 45
+    (84 MB); the step's temporaries were 0.47 GB. Since PR 43 the carry is
+    a row's (0.12 GB of temporaries, of them the loop's chunk of 128
+    gathered tiles, ``bf16[2048,16,640]``, 42 MB); since PR 45
     each of the 7 latent attentions is the kernel that copies a tile's
     blocks from the pool into fast memory, and the temporaries 0.034 GB."""
     doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
@@ -188,8 +191,59 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     # no float32 partials and no queries a tile of the capacity
     assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
     assert not re.findall(rf"bf16\[{capacity},[\d,]*{spec.head_dim}\]", text)
-    _in_place(text, spec.readers, rows, cfg.num_attention_heads, spec.values)
+    _in_place(text, spec.layers, rows, cfg.num_attention_heads, spec.values)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+@pytest.mark.parametrize("family,config,name", [
+    (phi4flash, phi4flash.Phi4FlashConfig, "phi-4-mini-flash"),
+    (qwen3_next, qwen3_next.Qwen3NextConfig, "qwen3-next-80b-l12-ep4"),
+], ids=["phi-4-mini-flash", "qwen3-next"])
+def test_the_portable_loop_carries_a_row_over_keys_and_values(
+        one_chip, family, config, name):
+    """One reading layer's attention over the filled tiles of pages of keys
+    and values, at the published head counts and the cell's rows, compiled
+    for the described chip at both wide widths (256 and 2 048 table slots a
+    row): the loop of ``common._over_tiles``, which carries a row. The
+    program holds no float32 array of the table's capacity, and its
+    temporaries are 1.61 and 1.73 MB for ``phi-4-mini-flash`` (32 rows, 40
+    query heads over 10 pairs of 128) and 1.48 and 2.20 MB for
+    ``qwen3-next`` (16 rows, 16 heads over 2 of 256). The loop that left
+    its partials a tile of the capacity, which these two ran up to PR 46,
+    kept ``f32[512,10,4,1,130]`` and 26.96 MB (420.1 MB at the next width)
+    and ``f32[256,2,8,1,258]`` and 1.10 MB (17.95 MB)."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / f"{name}.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = config.from_hf(doc)
+    spec = family.cache_spec(cfg)
+    rows, bt = engine["max_batch"], engine["block_tokens"]
+    H, Hkv, hd = cfg.num_attention_heads, spec.kv_heads, spec.head_dim
+    # the cell's budget, all of it pages (the slots take a part of it)
+    page = (spec.layers, (engine["kv_mb"] << 20) // (
+        2 * spec.layers * bt * Hkv * hd * 2) + 1, Hkv, bt, hd)
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(q, k, v, lengths, table, pk, pv):
+        cache = kvcache.Paged(pk, pv, table)
+        return attend(q, k, v, lengths[:, None],
+                      past=cache.past(0, cache.filled(lengths)))
+
+    for slots in (256, 2048):
+        compiled = jax.jit(layer).lower(
+            shaped((rows, 1, H, hd)), *(shaped((rows, 1, Hkv, hd)),) * 2,
+            shaped((rows,), jnp.int32), shaped((rows, slots), jnp.int32),
+            *(shaped(page),) * 2).compile()
+        text = compiled.as_text()
+        assert [line for line in text.splitlines()
+                if " while(" in line and "attn.tiles" in line]
+        assert "tpu_custom_call" not in text    # no kernel: pages apart
+        capacity = rows * slots // kvcache.TILE_BLOCKS
+        assert not re.findall(rf"f32\[{capacity},[\d,]*\]", text)
+        assert re.search(rf"f32\[{rows},{Hkv},{H // Hkv},1,{hd}\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 3e6
 
 
 def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
@@ -197,8 +251,8 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
     ``longcat-reason`` runs it (64 rows at 256 table slots each), compiled
     for the described chip: the pool of 8 paging layers of 640 columns (two
     sublayers a layer, 1 920 MiB) lies as it is read; every one of the 8
-    attentions over the filled tiles carries a row and is the kernel that
-    reads them where they lie; and the weights are read in
+    attentions over the filled tiles is the kernel that reads them where
+    they lie; and the weights are read in
     the layouts they are held in (``q_b`` ``[out, in]``, ``w_uk`` / ``w_uv``
     ``[H, 512, 128]``, the experts stacked ``[E, D, 2F]`` / ``[E, F, D]``):
     the program copies nothing of 30 MB into another order (an expert stack
@@ -210,8 +264,7 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
     engine = doc.pop("benchmark")["engine"]
     cfg = longcat_flash.LongcatFlashConfig.from_hf(doc)
     spec = longcat_flash.cache_spec(cfg)
-    assert (spec.layers, spec.readers, spec.head_dim, spec.values) \
-        == (8, 8, 640, 512)
+    assert (spec.layers, spec.head_dim, spec.values) == (8, 640, 512)
     bt = engine["block_tokens"]
     blocks = (engine["kv_mb"] << 20) // (spec.layers * bt * spec.head_dim
                                          * 2) + 1
@@ -242,7 +295,7 @@ def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
     text = compiled.as_text()
     capacity = rows * slots // kvcache.TILE_BLOCKS
     assert not re.findall(rf"f32\[{capacity},[\d,]*51[24]\]", text)
-    _in_place(text, spec.readers, rows, cfg.num_attention_heads, spec.values)
+    _in_place(text, spec.layers, rows, cfg.num_attention_heads, spec.values)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.05e9
     assert 12.3e9 < memory.argument_size_in_bytes < 12.4e9
