@@ -175,7 +175,8 @@ def expert_reads(expert_tokens, rows: int) -> int:
 
 
 def observe(expert_tokens, assignments: int, free: int = 0,
-            platform: str = "cpu", call_rows: int = 0) -> dict:
+            platform: str = "cpu", call_rows: int = 0,
+            grouped: bool = True) -> dict:
     """A step's ``expert_tokens`` ([expert layers, held experts], on the
     host) and the assignments its tokens made in all → the step span's
     attributes; the counters are counted here. ``free`` of the assignments
@@ -190,7 +191,8 @@ def observe(expert_tokens, assignments: int, free: int = 0,
     ``platform``; a layer's call ``call_rows`` assignment rows long, pad
     rows and all, or as many as a layer's share of ``assignments``): over
     ``experts_hit``, 1.0 is each hit expert once; 0 is the compiler's own
-    path."""
+    path, or a program that holds no grouped product at all (``grouped``
+    false: its family computes its few rows through every expert)."""
     landed = int(expert_tokens.sum())
     hit = int((expert_tokens > 0).sum())
     rows = assignments                  # up to a slab a layer: all of them
@@ -198,7 +200,7 @@ def observe(expert_tokens, assignments: int, free: int = 0,
         rows = int((-(-expert_tokens.sum(axis=1) // SLAB)).sum()) * SLAB
     reads = expert_reads(
         expert_tokens, call_rows or assignments // len(expert_tokens)) \
-        if platform == "tpu" else 0
+        if platform == "tpu" and grouped else 0
     HUB.inc(labeled("gen_moe_assignments_total", held="true"), landed)
     HUB.inc(labeled("gen_moe_assignments_total", held="false"),
             assignments - free - landed)

@@ -72,8 +72,9 @@ is two tiles of :data:`TILE_BLOCKS` slots (the narrowest table there is,
 and not rows x width. :func:`put_blocks` / :func:`put_positions`
 place a prefill's or a step's new K/V, :func:`put_slots` what it leaves in
 the slots: the whole of a layer's part of the slot (a list, one array a
-layer), or one part of it for every layer at once (:class:`Placed`: a
-position of a ring, :func:`ring_put`) — placement is entirely this module's
+layer), one part of it for every layer at once (:class:`Placed`: a
+position of a ring, :func:`ring_put`), or the array as the module made it
+(:class:`Whole`) — placement is entirely this module's
 business, the ring's order (:func:`ring_fill`, :func:`ring_positions`)
 with it. A prefill writes the whole of its slot (a ring in ring order,
 zeros where the prompt is shorter), so a slot taken again carries nothing
@@ -640,12 +641,24 @@ class Placed(NamedTuple):
     at: Any = None
 
 
+class Whole(NamedTuple):
+    """A slot array itself, ``[layers, slots + 1, ...]``, already holding
+    what the step's rows leave in their slots: for a module that reads all
+    its layers' rows' slots of a small array in one gather and can put them
+    back in one select by slot, where a slice update a row is the bucket's
+    rows in device operations (64 a step for 5.6 MB of convolution tails:
+    PERF.md, Findings, PR 49)."""
+
+    array: jax.Array
+
+
 class Written(NamedTuple):
     """What a step of a model with fixed state hands back for the cache: the
     new keys and values of its paging layers, as every model's step does,
     and ``state``, name → what each row's slot holds from now on: one
-    array a layer that keeps it ([B, ...] each, in the layers' order), or
-    a :class:`Placed` where a step writes a part of the slot only."""
+    array a layer that keeps it ([B, ...] each, in the layers' order), a
+    :class:`Placed` where a step writes a part of the slot only, or the
+    array :class:`Whole`."""
 
     kv: list
     state: dict
@@ -717,11 +730,14 @@ def put_slots(arrays, names, state, slots):
     matrix state of megabytes a row). Or it is a :class:`Placed`: a part
     of the slot (one position of a ring, a convolution's few columns) for
     all the layers at once, one in-place slice update a row, as
-    :func:`put_positions` writes the pages."""
+    :func:`put_positions` writes the pages. Or a :class:`Whole`: the array
+    as the module has already made it."""
     out = []
     for a, name in zip(arrays, names):
         new = state[name]
-        if isinstance(new, Placed):
+        if isinstance(new, Whole):
+            a = new.array.astype(a.dtype)
+        elif isinstance(new, Placed):
             for b in range(slots.shape[0]):
                 where = (0,) * (a.ndim - 2) if new.at is None \
                     else tuple(new.at[b])

@@ -4,8 +4,8 @@ family, every metric file's reader, a fixture family through generator,
 hub, reference and ``run.py`` up to the engine) and of
 ``benchmark/tests/test_exaone_moe_family.py``,
 ``test_qwen3_next_family.py``, ``test_phi4flash_family.py``,
-``test_axk1_family.py`` and ``test_longcat_flash_family.py`` but their
-rehearsed runs, which take minutes. The files stay where the benchmark keeps
+``test_axk1_family.py``, ``test_longcat_flash_family.py`` and
+``test_zaya_family.py`` but their rehearsed runs, which take minutes. The files stay where the benchmark keeps
 them; this module only gives them a name under ``tests/``.
 
 Two cases are replaced, because a file the benchmark already has is not
@@ -46,7 +46,7 @@ def _cases_of(name: str) -> dict:
 globals().update(_cases_of("test_seam"))
 for _family in ("test_exaone_moe_family", "test_qwen3_next_family",
                 "test_phi4flash_family", "test_axk1_family",
-                "test_longcat_flash_family"):
+                "test_longcat_flash_family", "test_zaya_family"):
     globals().update({k: v for k, v in _cases_of(_family).items()
                       if "rehears" not in k})
 

@@ -1,4 +1,4 @@
-"""The engine's programs of five families, at their tiny configurations,
+"""The engine's programs of six families, at their tiny configurations,
 lower to the text pinned below (locations stripped), in float32 and in
 bfloat16: what one family adds to ``serve/kvcache.py``,
 ``models/common.attend``, ``models/latent.py``, ``models/experts.py`` or the
@@ -36,7 +36,7 @@ import numpy as np
 import pytest
 
 from demodel_tpu.models import (axk1, exaone_moe, llama, longcat_flash,
-                                phi4flash, qwen3_next)
+                                phi4flash, qwen3_next, zaya)
 from demodel_tpu.models.common import attend
 from demodel_tpu.serve import GenEngine, kvcache
 from demodel_tpu.serve.scheduler import _Seq
@@ -47,7 +47,8 @@ FAMILIES = {"llama": (llama, llama.LlamaConfig),
             "qwen3_next": (qwen3_next, qwen3_next.Qwen3NextConfig),
             "axk1": (axk1, axk1.AxK1Config),
             "longcat_flash": (longcat_flash,
-                              longcat_flash.LongcatFlashConfig)}
+                              longcat_flash.LongcatFlashConfig),
+            "zaya": (zaya, zaya.ZayaConfig)}
 
 PINNED = {
     ("llama", "float32", "decode"): "ff3c2a1e16526eb9",
@@ -90,6 +91,14 @@ PINNED = {
     ("longcat_flash", "bfloat16", "decode-past-two-tiles"):
         "2710cde34e6272cc",
     ("longcat_flash", "bfloat16", "prefill"): "06bae7ddafc6572a",
+    ("zaya", "float32", "decode"): "57f88d0e36fdc3c8",
+    ("zaya", "float32", "decode-past-16-blocks"): "57f88d0e36fdc3c8",
+    ("zaya", "float32", "decode-past-two-tiles"): "7c0f3e2d107ae842",
+    ("zaya", "float32", "prefill"): "7f0fec9713f13f45",
+    ("zaya", "bfloat16", "decode"): "abebf84341f4e880",
+    ("zaya", "bfloat16", "decode-past-16-blocks"): "abebf84341f4e880",
+    ("zaya", "bfloat16", "decode-past-two-tiles"): "32cf73d3ebd0db0f",
+    ("zaya", "bfloat16", "prefill"): "cb0516a6c16b9224",
 }
 
 
@@ -198,6 +207,25 @@ def test_the_platform_chooses_the_kernel_at_lowering(family, heads,
             == cpu[stage].count("stablehlo.while") - kernels
 
 
+def test_a_scanned_stack_holds_each_kernel_once():
+    """ZAYA1's layers are one ``lax.scan``: the wide step lowered for a TPU
+    holds the kernel over the filled tiles of its one-array page (8 query
+    rows under one cached head of ``[v | k^]``) once, called once, in the
+    body of the loop that is the layers, and no loop over tiles; the CPU's
+    text holds that loop inside the layers' and no custom call. The narrow
+    step and the prefill hold the layers' loop alone on both."""
+    cfg = dataclasses.replace(zaya.ZayaConfig.tiny(), dtype="bfloat16")
+    cpu, tpu = programs(zaya, cfg), programs(zaya, cfg, platform="tpu")
+    wide = "decode-past-two-tiles"
+    for stage in cpu:
+        assert "tpu_custom_call" not in cpu[stage]
+        assert cpu[stage].count("stablehlo.while") == 1 + (stage == wide)
+        assert tpu[stage].count("stablehlo.while") == 1
+        for held in ('kernel_name = "latent_filled_tiles"',
+                     "call @over_filled_tiles"):
+            assert tpu[stage].count(held) == (stage == wide)
+
+
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_the_platform_chooses_the_grouped_product_at_lowering(family):
     """The same trace lowered for the CPU and, with no chip attached, for a
@@ -206,7 +234,10 @@ def test_the_platform_chooses_the_grouped_product_at_lowering(family):
     that holds ``ops/grouped.py``'s kernel, which the program traced and
     lowered once a shape (gate beside up, down), and no ``ragged_dot`` is
     left there; the CPU's text holds no custom call. A family with no
-    expert layer holds neither on either platform."""
+    expert layer holds neither on either platform, nor does ZAYA1 at its
+    tiny size, whose programs of few rows send every row through every
+    expert (``zaya.DENSE``; its routed prefill at the published size is
+    held by ``tests/test_tpu_layout.py``)."""
     module, config = FAMILIES[family]
     cfg = dataclasses.replace(config.tiny(), dtype="bfloat16")
     counted = {"exaone_moe": "sparse_layers", "axk1": "sparse_layers",
@@ -234,6 +265,7 @@ CONFIGS = {
     "ax-k1-519b-l7-ep16": (axk1, axk1.AxK1Config, 64),
     "longcat-flash-omni-560b-l4-ep32": (
         longcat_flash, longcat_flash.LongcatFlashConfig, 64),
+    "zaya1-8b-l16": (zaya, zaya.ZayaConfig, 64),
 }
 
 

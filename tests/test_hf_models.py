@@ -211,7 +211,7 @@ def test_auto_rejects_unsupported_config_fields(tmp_path, mesh8):
 
 
 FAMILIES = ["llama", "gpt2", "bert", "exaone_moe", "qwen3_next", "phi4flash",
-            "axk1", "longcat_flash"]
+            "axk1", "longcat_flash", "zaya"]
 
 
 @pytest.mark.parametrize("model_type", FAMILIES)

@@ -10,6 +10,11 @@ Both branches of ``_over_tiles``' ``lax.platform_dependent`` are run at the
 seam itself: the test puts a function in its place that calls the TPU's
 branch (the kernel, interpreted) and the default one (the loop), keeps what
 each returned and hands on the kernel's.
+
+And at the shape a compressed convolutional attention gives it
+(``models/zaya.py``, PR 49): 8 query rows as wide as a page of 512 columns,
+``[v 256 | k^ 256]``, query head ``i`` zero outside the 128 columns of its
+own key head, the first 256 columns the values.
 """
 
 from __future__ import annotations
@@ -47,10 +52,16 @@ CASES = {
 }
 
 
-def _page(lengths, dtype, seed):
+#: ``(query rows, the page's columns, of them values, of them filled)``
+LATENT_PAGE = (HEADS, PAGE, VALUES, LATENT)
+COMPRESSED_PAGE = (8, 512, 256, 512)
+
+
+def _page(lengths, dtype, seed, shape=LATENT_PAGE):
     """A pool of two layers whose blocks hold seeded latents (zeros in the
     page's last 64 columns), a table of shuffled blocks (a pad row's names
     the scratch block), queries and the new position's latent."""
+    HEADS, PAGE, _VALUES, LATENT = shape
     B = len(lengths)
     rng = np.random.default_rng(seed)
     nb = B * SLOTS
@@ -67,9 +78,10 @@ def _page(lengths, dtype, seed):
                           jnp.asarray(table, jnp.int32)))
 
 
-def _plain(q, new, cache, layer, lengths):
+def _plain(q, new, cache, layer, lengths, shape=LATENT_PAGE):
     """One float32 softmax a row over its cached positions and the new one,
     from the rectangle the table names."""
+    HEADS, PAGE, VALUES, _LATENT = shape
     q, new, k = (np.asarray(a, np.float64) for a in (q, new, cache.k))
     out = np.zeros((len(lengths), HEADS * VALUES))
     for b, n in enumerate(lengths):
@@ -83,11 +95,31 @@ def _plain(q, new, cache, layer, lengths):
     return out
 
 
+def _own_key_head(q, groups: int = 2):
+    """Queries as ``models/zaya.py`` pads them: nothing under the values'
+    columns, and of the keys' only the query head's own key head's."""
+    B, T, H, P = q.shape
+    width = P // 2 // groups
+    head = np.arange(H)[:, None] // (H // groups)
+    column = np.arange(P)[None, :]
+    own = (column >= P // 2 + head * width) \
+        & (column < P // 2 + (head + 1) * width)
+    return jnp.where(jnp.asarray(own), q, 0)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_the_kernel_is_the_loop(monkeypatch, case, dtype):
+@pytest.mark.parametrize("case,shape", [
+    *((case, LATENT_PAGE) for case in sorted(CASES)),
+    ("a-tile-partly-filled", COMPRESSED_PAGE),
+    ("rows-of-unequal-depth", COMPRESSED_PAGE),
+], ids=[*sorted(CASES), "8-rows-of-512-a-tile-partly-filled",
+        "8-rows-of-512-rows-of-unequal-depth"])
+def test_the_kernel_is_the_loop(monkeypatch, case, shape, dtype):
+    HEADS, _PAGE, VALUES, _LATENT = shape
     lengths = CASES[case]
-    q, new, cache = _page(lengths, dtype, seed=len(case))
+    q, new, cache = _page(lengths, dtype, seed=len(case), shape=shape)
+    if shape == COMPRESSED_PAGE:
+        q = _own_key_head(q)
     seen = {}
 
     def both(*args, tpu, default):
@@ -130,5 +162,5 @@ def test_the_kernel_is_the_loop(monkeypatch, case, dtype):
     # the whole attention against a plain float32 softmax
     np.testing.assert_allclose(
         np.asarray(out, np.float32).reshape(len(lengths), -1),
-        _plain(q, new, cache, 1, lengths),
+        _plain(q, new, cache, 1, lengths, shape),
         rtol=1e-4 if tight else 3e-2, atol=1e-4 if tight else 3e-2)

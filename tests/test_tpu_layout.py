@@ -28,6 +28,14 @@ for the chip every one of them is the Pallas kernel of ``ops/grouped.py``
 (two a sparse layer, traced and lowered once a shape from one ``jit``), in
 the step, in a prompt's landed-slabs loop and in ``qwen3-next``'s; the
 compiler's own ``ragged-dot`` is in none.
+
+And of ZAYA1's 64-row step (PR 49, ~10 s): 16 layers under one scan over a
+page of one array of 512 columns and a row of tails a layer; the body of
+the loop holds the kernel of the step's mixing and the kernel over the
+filled tiles at 8 query rows, and reads a layer's experts out of the one
+stack of all layers' where they lie; the device operations a step are
+counted. And of its 1 024-token prefill (~5 s): the grouped kernel over
+that stack.
 """
 
 from __future__ import annotations
@@ -42,7 +50,7 @@ import numpy as np
 import pytest
 
 from demodel_tpu.models import (axk1, latent, longcat_flash, phi4flash,
-                                qwen3_next)
+                                qwen3_next, zaya)
 from demodel_tpu.models.common import attend
 from demodel_tpu.serve import kvcache
 
@@ -81,6 +89,15 @@ def _born(shape, sharding) -> list[int]:
     return [int(d) for d in layout.group(1).split(",")]
 
 
+def _copied(text: str) -> list[str]:
+    """What a compiled program copies or transposes of 30 MB and more (the
+    pool, a weight, an expert)."""
+    return [m.group(0) for m in re.finditer(
+        r"= (bf16|f32)\[([\d,]+)\][^ ]* (copy|transpose)\(", text)
+        if np.prod([int(d) for d in m.group(2).split(",")])
+        * (2 if m.group(1) == "bf16" else 4) > 30e6]
+
+
 def _in_place(text: str, attentions: int, rows: int, heads: int,
               values: int) -> None:
     """The compiled step holds the kernel's custom call a latent attention
@@ -95,11 +112,7 @@ def _in_place(text: str, attentions: int, rows: int, heads: int,
     assert not re.findall(r"\[2048,16,[\d,]*640\]", text)
     assert not [line for line in text.splitlines()
                 if " while(" in line and "attn.tiles" in line]
-    copied = [m.group(0) for m in re.finditer(
-        r"= (bf16|f32)\[([\d,]+)\][^ ]* (copy|transpose)\(", text)
-        if np.prod([int(d) for d in m.group(2).split(",")])
-        * (2 if m.group(1) == "bf16" else 4) > 30e6]
-    assert not copied, copied
+    assert not _copied(text)
     # the carry: a row's weighted values, under every head
     assert re.search(rf"f32\[{rows},{heads},{values}\]", text)
 
@@ -330,3 +343,149 @@ def test_a_prompts_grouped_products_are_the_kernel(one_chip, family, config,
     text = _grouped(lowered, sparse)
     assert [line for line in text.splitlines()
             if " while(" in line and "moe" in line]
+
+
+def _operation(line: str) -> tuple[str, str] | None:
+    """``(result type, operation)`` of one instruction of a compiled
+    program's text; the type of a multi-output fusion, a sort or a kernel
+    is a tuple in brackets."""
+    head = re.match(r"\s+(ROOT )?%?[\w.\-]+ = (.*)", line)
+    if not head:
+        return None
+    rest = head.group(2)
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if not depth:
+                break
+        kind, rest = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        kind, _, rest = rest.partition(" ")
+    op = re.match(r"([\w\-]+)\(", rest)
+    return (kind, op.group(1)) if op else None
+
+
+def _operations(text: str) -> tuple[int, int]:
+    """``(a trip of the program's one loop, the rest)``: the instructions
+    of a compiled program that run on the device as operations of their
+    own, counted as the profiler's trace lists them: no parameter, tuple,
+    constant or bitcast, and nothing that is a scalar."""
+    blocks, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            blocks[name] = 0
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            found = _operation(line)
+            if found and found[1] not in (
+                    "parameter", "get-tuple-element", "tuple", "constant",
+                    "bitcast", "while") and not re.match(
+                    r"(s32|u32|pred)\[1?\]", found[0]):
+                blocks[name] += 1
+    (body,) = re.findall(r"while\(.*?body=%?([\w.\-]+)", text)
+    return blocks[body], blocks["ENTRY"]
+
+
+def test_the_scanned_step_reads_its_page_tails_and_experts_as_held(one_chip):
+    """``zaya1-8b-l16``'s decode step as ``zaya1-reason`` runs it (64 rows at
+    256 table slots each), compiled for the described chip: the pool of 16
+    layers of 512 columns (``[v | k^]``, 4 620 MiB with the slots) lies as
+    it is read; the layers are one loop whose body holds two kernels, each
+    once: the step's mixing (``ops/cca_mix.py``) and the filled tiles under
+    8 zero-padded query rows (``ops/latent_tiles.py``); a step's 64 rows go
+    through every expert of the layer in two plain products that read the
+    layer's 16 experts out of the stack of 256 where they lie: the program
+    copies nothing of 30 MB (a layer's experts are 0.27 and 0.13 GB of the
+    stacks' 4.3 and 2.1), its temporaries are 0.01 GB beside 12.56 GB of
+    arguments. **The device operations a step are held**: 56 a trip of the
+    loop and 170 outside it (64 of them the rows' slice updates of the
+    pages), 1 066 a step, where the first form of this step (the mixing in
+    the compiler's hands, a step's rows routed, the tails written a row a
+    slice update) held 121 and 228, 2 164 a step, of which a traced 48 s
+    window kept the first 28.9 s (PERF.md section 6, PR 49)."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / "zaya1-8b-l16.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = zaya.ZayaConfig.from_hf(doc)
+    spec = zaya.cache_spec(cfg)
+    assert (spec.layers, spec.kv_heads, spec.head_dim, spec.values) \
+        == (16, 1, 512, 256)
+    assert spec.head_dim % latent.LANES == 0
+    bt, rows, slots = engine["block_tokens"], engine["max_batch"], 256
+    # the budget pays for the slots first and the blocks with the rest
+    blocks = ((engine["kv_mb"] << 20) - rows * spec.layers * cfg.tail_dim
+              * 2) // (spec.layers * bt * spec.head_dim * 2) + 1
+    assert blocks == 18459 + 1
+    page = (spec.layers, blocks, 1, bt, spec.head_dim)
+    assert _born(page, one_chip)[:2] == [4, 3]
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(params, table, lengths, tokens, slot, k, tails):
+        cache = kvcache.Paged(k, None, table, {"tail": tails}, slot)
+        logits, new, *stats = zaya.step_decode(params, tokens, cfg, cache,
+                                               lengths)
+        pages, fresh = kvcache.parts(new)
+        return (jnp.argmax(logits, axis=-1), stats,
+                *kvcache.put_positions(k, None, pages, table[:, 0],
+                                       lengths % bt),
+                *kvcache.put_slots((tails,), ("tail",), fresh, slot))
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: zaya.init_params(jax.random.key(1), cfg)))
+    lowered = jax.jit(decode, donate_argnums=(5, 6)).lower(
+        params, shaped((rows, slots), jnp.int32),
+        *(shaped((rows,), jnp.int32),) * 3, shaped(page, jnp.bfloat16),
+        shaped((spec.layers, rows + 1, cfg.tail_dim), jnp.bfloat16))
+    text = lowered.as_text()
+    assert "ragged_dot" not in text and "grouped_dot" not in text
+    assert text.count("call @over_filled_tiles") == 1
+    assert text.count("call @step_rows") == 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(name for c in calls
+                  for name in ("latent_filled_tiles", "cca_mix_step")
+                  if name in c) == ["cca_mix_step", "latent_filled_tiles"]
+    assert all("attn.tiles" in c or "attn.cca.mix" in c for c in calls)
+    # nothing of an expert's size is copied out of the stacks
+    assert not _copied(text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.05e9
+    assert 12.5e9 < memory.argument_size_in_bytes < 12.6e9
+    trip, rest = _operations(text)
+    assert trip <= 60 and rest <= 180, (trip, rest)
+    assert spec.layers * trip + rest <= 1150
+
+
+def test_a_prompt_routes_its_rows_through_the_one_stack(one_chip):
+    """A 1 024-token prefill of ``zaya1-8b-l16``, compiled for the described
+    chip: its rows are routed (``experts.routed`` at ``first = 0``, ``K =
+    1``, 1 024 assignments a layer: one pass, no slab loop), and the two
+    grouped products of the loop's body are the kernel of ``ops/grouped.py``
+    fed the stack of all 256 experts, from which nothing is copied."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / "zaya1-8b-l16.json").read_text())
+    doc.pop("benchmark")
+    cfg = zaya.ZayaConfig.from_hf(doc)
+    assert 1024 * cfg.num_experts > zaya.DENSE
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, a.dtype),
+        jax.eval_shape(lambda: zaya.init_params(jax.random.key(1), cfg)))
+    lowered = jax.jit(
+        lambda params, tokens: zaya.step_prefill(params, tokens, cfg)
+    ).lower(params, shaped((1, 1024), jnp.int32))
+    compiled = _grouped(lowered, 1)
+    assert "cca_mix_step" not in compiled
+    assert not _copied(compiled)
