@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from demodel_tpu.ops import latent_tiles
+from demodel_tpu.ops import latent_tiles, paged_tiles
 from demodel_tpu.utils.env import env_bool
 
 
@@ -77,9 +77,10 @@ def attend(q, k, v, positions, *, window: int = 0, past=None,
     have filled (``kvcache.Tiles``, from ``Paged.filled``) and the same
     softmax runs over those alone, a chunk of tiles a trip, one running
     softmax a row carried between the trips: the rectangle is the case in
-    which nothing can be skipped. Where the page is one array under one
-    cached head, a program lowered for a TPU runs a kernel that reads the
-    tiles from the pool in the loop's place (:func:`_over_tiles`)."""
+    which nothing can be skipped. A program lowered for a TPU runs a
+    kernel that reads the tiles from the pool in the loop's place, one for
+    a page of one array under one cached head and one for pages of keys
+    and values apart (:func:`_over_tiles`)."""
     B, T, H, hd = q.shape
     Hkv, vd = k.shape[2], v.shape[-1]
     scale = hd ** -0.5 if scale is None else scale
@@ -136,14 +137,21 @@ def _over_tiles(q, s_new, v, tiles, scale):
     their keys.
 
     A trip gathers its chunk of tiles into one array before its products
-    (XLA fuses no gather into the product that reads it). Where the page
-    is one array under one cached head, a Pallas kernel does without: it
-    follows the index itself and copies each tile's blocks from the pool
-    into fast memory (:mod:`demodel_tpu.ops.latent_tiles`; the same
-    arithmetic, the same carry out). Which of the two a program holds is
-    the platform's it is lowered for (``lax.platform_dependent``: the
-    kernel on a TPU, the loop everywhere else, where it is also the
-    kernel's oracle), nothing else's."""
+    (XLA fuses no gather into the product that reads it; keys and values
+    apart are two gathers, the values' after the keys are done with where
+    both do not fit fast memory, ``tiles.apart``: the loop's concern
+    alone). A Pallas kernel does without: it follows the index itself and
+    copies each tile's blocks from the pool into fast memory, the same
+    arithmetic, the same carry out. Both kinds of page have theirs: one
+    array under one cached head :mod:`demodel_tpu.ops.latent_tiles`, keys
+    and values apart under any number of heads
+    :mod:`demodel_tpu.ops.paged_tiles`. Where the page has one
+    (``tiles.in_place``, the one statement of it: not a pool that lies
+    on several chips, which only the loop is partitioned for, nor a tile
+    too large for the kernel's buffers), which of kernel and loop a
+    program holds is the platform's it is lowered for
+    (``lax.platform_dependent``: the kernel on a TPU, the loop everywhere
+    else, where it is also the kernel's oracle), nothing else's."""
     B, T, Hkv, g, hd = q.shape
     vd = v.shape[-1]
     n = tiles.chunk_tiles
@@ -199,15 +207,19 @@ def _over_tiles(q, s_new, v, tiles, scale):
              jnp.zeros((B, Hkv, g, T, 1), f32)))
 
     def in_place():
-        carry = latent_tiles.over_filled_tiles(
-            q.transpose(0, 2, 3, 1, 4).reshape(B, g * T, hd), tiles,
-            scale, vd)
+        rows = q.transpose(0, 2, 3, 1, 4)
+        if tiles.v is None:
+            carry = latent_tiles.over_filled_tiles(
+                rows.reshape(B, g * T, hd), tiles, scale, vd)
+        else:
+            carry = paged_tiles.over_filled_tiles(
+                rows.reshape(B, Hkv, g * T, hd), tiles, scale)
         return tuple(a.reshape(B, Hkv, g, T, -1) for a in carry)
 
     with jax.named_scope("attn.tiles"):
-        if tiles.v is None and Hkv == 1:
-            # one cached vector under every head: on a TPU the kernel
-            # reads the tiles from the pool, and the loop is its oracle
+        if tiles.in_place:
+            # on a TPU a kernel reads the tiles from the pool, and the
+            # loop is its oracle
             values, tops, sums = lax.platform_dependent(
                 tpu=in_place, default=loop)
         else:

@@ -223,6 +223,13 @@ class KVBlockPool:
                 mesh, P(None, None, heads, None, None))
             #: where the engine puts a program's small per-call inputs
             self.replicated = NamedSharding(mesh, P())
+        #: True where the arrays lie on more than one chip (their heads
+        #: across them, or a copy on each), None on one: what a program's
+        #: :class:`Paged` carries to :func:`_in_place`
+        self.meshed = True if len(self.sharding.device_set) > 1 else None
+        #: one tile of one of a page's arrays, as a kernel's buffer holds it
+        self.tile_bytes = (TILE_BLOCKS * kv_heads * self.block_tokens
+                           * head_dim * dt.itemsize)
         #: what the engine's programs are lowered for: the arrays' devices'
         self.platform = next(iter(self.sharding.device_set)).platform
         #: names of the slot's arrays, in the order :attr:`arrays` holds
@@ -275,12 +282,12 @@ class KVBlockPool:
         """On the host, from shapes and the pool's own devices: how many of
         the ``read`` positions a decode step's attention reads at ``slots``
         table slots a row it reads from the pool itself, with no gathered
-        copy: all of them where the step's programs hold the kernel that
-        follows the filled tiles (:mod:`demodel_tpu.ops.latent_tiles`: a
-        page of one array under one cached head, a wide table, programs
-        lowered for a TPU), none otherwise: the rule of
+        copy: all of them where the step's programs hold a kernel that
+        follows the filled tiles (:func:`_in_place` pages, a wide table,
+        programs lowered for a TPU), none otherwise: the rule of
         :func:`models.common._over_tiles`."""
-        in_place = (self.pages == 1 and self.spec.kv_heads == 1
+        in_place = (_in_place(self.pages == 2, self.spec.kv_heads,
+                              self.tile_bytes, self.meshed)
                     and _wide(slots) and self.platform == "tpu")
         return read if in_place else 0
 
@@ -421,6 +428,9 @@ class Paged(NamedTuple):
     table: jax.Array        # [B, n] block ids
     state: dict = {}        # name -> [layers, slots + 1, ...]
     slots: Any = None       # [B] slot ids
+    meshed: Any = None      # True: the pool lies on more than one chip
+    #                         (``KVBlockPool.meshed``; never False: None
+    #                         is no leaf of a program's arguments)
 
     @property
     def block_tokens(self) -> int:
@@ -478,7 +488,7 @@ class Paged(NamedTuple):
             k=self.k.reshape(L * nb, *self.k.shape[2:]),
             v=None if self.v is None
             else self.v.reshape(L * nb, *self.v.shape[2:]),
-            ids=filled.ids + layer * nb)
+            ids=filled.ids + layer * nb, meshed=self.meshed)
 
     def read_state(self, name: str, layer: int):
         """The rows' slots of one layer of the state array ``name``,
@@ -499,6 +509,12 @@ TILE_CHUNK = 128
 #: what a chunk of keys and one of values may take together of a core's
 #: fast memory (128 MiB on a v5e) and still both be held there
 FAST_BYTES = 96 << 20
+#: what the four buffers of the kernel over keys and values apart may take
+#: of the 16 MiB a kernel is given of fast memory unasked: 2.5 MiB at
+#: Phi-4-mini-flash's 10 pairs of 128, 1 MiB at Qwen3-Next's 2 heads of
+#: 256, 8 MiB at 32 heads of 128 in bfloat16 (compiled for the chip:
+#: tests/test_tpu_layout.py)
+KERNEL_BYTES = 8 << 20
 
 
 class Tiles(NamedTuple):
@@ -518,7 +534,10 @@ class Tiles(NamedTuple):
     ``live`` (:meth:`chunk`) and whose its tiles are (:meth:`rows`), by
     which the trip combines them into the one running softmax it carries a
     row. Whether a trip gathers keys and values apart follows from a
-    chunk's bytes (:attr:`apart`)."""
+    chunk's bytes (:attr:`apart`). The chunks, the gathers and ``apart``
+    are the portable loop's: a program lowered for a TPU reads the filled
+    tiles of either kind of page from the pool itself, a tile at a time
+    (:attr:`in_place`; the kernels take ``ids``, ``live`` and ``own``)."""
 
     ids: jax.Array          # [C, TILE_BLOCKS] uint32
     row: jax.Array          # [C] the row a tile belongs to
@@ -533,16 +552,28 @@ class Tiles(NamedTuple):
     #                         a test for a negative start)
     k: Any = None
     v: Any = None
+    meshed: Any = None      # ``Paged.meshed``
 
     @property
     def chunk_tiles(self) -> int:
         return _chunk_tiles(self.row.shape[0])
 
     @property
+    def in_place(self) -> bool:
+        """A program lowered for a TPU reads these tiles from the pool
+        itself (:func:`_in_place`); where not, every platform's program is
+        the loop."""
+        return _in_place(
+            self.v is not None, self.k.shape[1],
+            TILE_BLOCKS * math.prod(self.k.shape[1:]) * self.k.dtype.itemsize,
+            self.meshed)
+
+    @property
     def apart(self) -> bool:
-        """A chunk of keys and one of values do not fit fast memory
-        together: the compiler would leave one of them in HBM, so a trip
-        gathers the values when it is done with the keys."""
+        """The loop's alone (a kernel holds a tile of each, not a chunk):
+        a chunk of keys and one of values do not fit fast memory together,
+        so the compiler would leave one of them in HBM, and a trip gathers
+        the values when it is done with the keys."""
         return self.v is not None and 2 * self.chunk_tiles * TILE_BLOCKS \
             * math.prod(self.k.shape[1:]) * self.k.dtype.itemsize \
             > FAST_BYTES
@@ -569,6 +600,27 @@ class Tiles(NamedTuple):
 
 def _chunk_tiles(capacity: int) -> int:
     return math.gcd(TILE_CHUNK, capacity)
+
+
+def _in_place(apart: bool, kv_heads: int, tile_bytes: int, meshed) -> bool:
+    """The pages whose filled tiles a kernel reads where they lie, stated
+    once for the programs (:attr:`Tiles.in_place`) and for the host's count
+    of them (:meth:`KVBlockPool.positions_in_place`).
+
+    On one chip: one array under one cached head
+    (:mod:`demodel_tpu.ops.latent_tiles`), and keys and values ``apart``,
+    whatever their heads (:mod:`demodel_tpu.ops.paged_tiles`), where the
+    kernel's four buffers of a tile (``tile_bytes`` each) fit
+    :data:`KERNEL_BYTES`: heads, blocks or a dtype that make a tile larger
+    than the largest compiled for the chip keep the loop. So does a pool
+    ``meshed`` over several chips, of either kind: the compiler partitions
+    the loop by the pool's heads as it did, and has no rule for a Pallas
+    call (JAX refuses to lower one in a program of more than one chip
+    outside a ``shard_map``: before PR 50 a latent family's wide step
+    under a mesh did not lower for a TPU)."""
+    if meshed:
+        return False
+    return 4 * tile_bytes <= KERNEL_BYTES if apart else kv_heads == 1
 
 
 def _wide(slots: int) -> bool:

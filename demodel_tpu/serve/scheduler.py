@@ -364,9 +364,9 @@ class GenEngine:
             if names:
                 slots, table = rows[:, 5], rows[:, 6:]
                 cache = kvcache.Paged(k, v, table, dict(zip(names, state)),
-                                      slots)
+                                      slots, pool.meshed)
             else:
-                cache = kvcache.Paged(k, v, rows[:, 5:])
+                cache = kvcache.Paged(k, v, rows[:, 5:], meshed=pool.meshed)
             logits, new, *stats = module.step_decode(p, toks, cfg, cache,
                                                     lens, mesh=mesh)
             new_kv, fresh = kvcache.parts(new)

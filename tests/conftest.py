@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import os
 import re
+import sys
 
 # append (not clobber) the virtual device count to any existing XLA flags
 _flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
@@ -25,6 +26,19 @@ except RuntimeError:  # backend already up (re-entrant runs) — best effort
     pass
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_profiler_left_running():
+    """A restore server starts the process's sampling profiler
+    (``RestoreServer.start``) and no test that starts one stops it: a later
+    file of the same worker then found the windows it rolled in its own
+    counts (``tests/test_retention.py``'s flush). Stopped where it was
+    started, when the file that started it is done."""
+    yield
+    prof = sys.modules.get("demodel_tpu.utils.profiler")
+    if prof is not None:
+        prof.stop()
 
 
 @pytest.fixture()

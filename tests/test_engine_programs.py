@@ -1,4 +1,4 @@
-"""The engine's programs of six families, at their tiny configurations,
+"""The engine's programs of seven families, at their tiny configurations,
 lower to the text pinned below (locations stripped), in float32 and in
 bfloat16: what one family adds to ``serve/kvcache.py``,
 ``models/common.attend``, ``models/latent.py``, ``models/experts.py`` or the
@@ -15,10 +15,21 @@ partials a tile of the table's capacity went and the one that carries a
 running softmax a row became the only loop (``common._over_tiles``); the
 other 28 did not move. The four expert families' programs hold the
 platform's choice around each grouped product (``experts._grouped``, PR 46).
+**PR 50 pinned again the wide step of every family whose pool holds keys
+and values apart** (``llama``, ``exaone_moe``, ``qwen3_next``: six digests):
+it now holds the platform's choice around its attention over the filled
+tiles, as the latent families' has since PR 45. The rule is the page's kind
+and not a family's name (``kvcache._in_place``), so ``llama``'s and
+``exaone_moe``'s moved with ``qwen3_next``'s though no cell runs their wide
+step; the latent families' and every narrow step and prefill did not move.
+``phi4flash`` joined the table with PR 50: its wide step is that PR's, its
+other six digests are the parent's too (computed from the parent's
+checkout).
 
 Which platform gets which kernel is a matter of lowering and is held below:
 the CPU's text is the loop and ``lax.ragged_dot``, a TPU's the Pallas
-kernels of ``ops/latent_tiles.py`` (a page of one array) and
+kernels of ``ops/latent_tiles.py`` (a page of one array),
+``ops/paged_tiles.py`` (pages of keys and values apart) and
 ``ops/grouped.py``.
 """
 
@@ -48,32 +59,33 @@ FAMILIES = {"llama": (llama, llama.LlamaConfig),
             "axk1": (axk1, axk1.AxK1Config),
             "longcat_flash": (longcat_flash,
                               longcat_flash.LongcatFlashConfig),
-            "zaya": (zaya, zaya.ZayaConfig)}
+            "zaya": (zaya, zaya.ZayaConfig),
+            "phi4flash": (phi4flash, phi4flash.Phi4FlashConfig)}
 
 PINNED = {
     ("llama", "float32", "decode"): "ff3c2a1e16526eb9",
     ("llama", "float32", "decode-past-16-blocks"): "ff3c2a1e16526eb9",
-    ("llama", "float32", "decode-past-two-tiles"): "3fa53270296508e8",
+    ("llama", "float32", "decode-past-two-tiles"): "0005db6c858a8ad7",
     ("llama", "float32", "prefill"): "2384c9807e24cde3",
     ("llama", "bfloat16", "decode"): "fecca8e7814f2856",
     ("llama", "bfloat16", "decode-past-16-blocks"): "fecca8e7814f2856",
-    ("llama", "bfloat16", "decode-past-two-tiles"): "fa81b90949460d4f",
+    ("llama", "bfloat16", "decode-past-two-tiles"): "4b42d427ccf0c088",
     ("llama", "bfloat16", "prefill"): "b9db3f7fcec8e591",
     ("exaone_moe", "float32", "decode"): "69d4b9d9de4085a3",
     ("exaone_moe", "float32", "decode-past-16-blocks"): "69d4b9d9de4085a3",
-    ("exaone_moe", "float32", "decode-past-two-tiles"): "24295e950934b10c",
+    ("exaone_moe", "float32", "decode-past-two-tiles"): "7b67a6baab5a3b14",
     ("exaone_moe", "float32", "prefill"): "7dfe2b7bf8e5c253",
     ("exaone_moe", "bfloat16", "decode"): "4bcc7f25d5f5de65",
     ("exaone_moe", "bfloat16", "decode-past-16-blocks"): "4bcc7f25d5f5de65",
-    ("exaone_moe", "bfloat16", "decode-past-two-tiles"): "275945a0b399c97a",
+    ("exaone_moe", "bfloat16", "decode-past-two-tiles"): "a7bd74db1ea6c9ba",
     ("exaone_moe", "bfloat16", "prefill"): "40d60075749c8570",
     ("qwen3_next", "float32", "decode"): "533379045d4ba4d7",
     ("qwen3_next", "float32", "decode-past-16-blocks"): "533379045d4ba4d7",
-    ("qwen3_next", "float32", "decode-past-two-tiles"): "316605cba9ab9d6f",
+    ("qwen3_next", "float32", "decode-past-two-tiles"): "c29f35b38d268075",
     ("qwen3_next", "float32", "prefill"): "5f15f0fdab1e84c0",
     ("qwen3_next", "bfloat16", "decode"): "edc6a71c70beef8f",
     ("qwen3_next", "bfloat16", "decode-past-16-blocks"): "edc6a71c70beef8f",
-    ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "52abb412eb173ef7",
+    ("qwen3_next", "bfloat16", "decode-past-two-tiles"): "3057d884547ff87e",
     ("qwen3_next", "bfloat16", "prefill"): "429bff75fe08bade",
     ("axk1", "float32", "decode"): "fdae1f714571c2b7",
     ("axk1", "float32", "decode-past-16-blocks"): "fdae1f714571c2b7",
@@ -99,6 +111,14 @@ PINNED = {
     ("zaya", "bfloat16", "decode-past-16-blocks"): "abebf84341f4e880",
     ("zaya", "bfloat16", "decode-past-two-tiles"): "32cf73d3ebd0db0f",
     ("zaya", "bfloat16", "prefill"): "cb0516a6c16b9224",
+    ("phi4flash", "float32", "decode"): "5222e99e72323234",
+    ("phi4flash", "float32", "decode-past-16-blocks"): "5222e99e72323234",
+    ("phi4flash", "float32", "decode-past-two-tiles"): "b3ce41b320490afd",
+    ("phi4flash", "float32", "prefill"): "0ae6cbace60daedc",
+    ("phi4flash", "bfloat16", "decode"): "c308157850672aa1",
+    ("phi4flash", "bfloat16", "decode-past-16-blocks"): "c308157850672aa1",
+    ("phi4flash", "bfloat16", "decode-past-two-tiles"): "43c30c212b57bda6",
+    ("phi4flash", "bfloat16", "prefill"): "73bad509276ced20",
 }
 
 
@@ -169,22 +189,24 @@ def test_program_is_the_parents(lowered, family, dtype, stage):
         == PINNED[family, dtype, stage]
 
 
-@pytest.mark.parametrize("family,heads,in_place", [
-    ("axk1", 32, True), ("axk1", 4, True), ("llama", 8, False)],
+@pytest.mark.parametrize("family,heads,kernel", [
+    ("axk1", 32, "latent_filled_tiles"), ("axk1", 4, "latent_filled_tiles"),
+    ("llama", 8, "paged_filled_tiles"),
+    ("qwen3_next", 4, "paged_filled_tiles")],
     ids=["a-latent-page-32-heads", "a-latent-page-4-heads",
-         "keys-and-values-apart"])
-def test_the_platform_chooses_the_kernel_at_lowering(family, heads,
-                                                     in_place):
+         "keys-and-values-apart", "keys-and-values-apart-beside-slots"])
+def test_the_platform_chooses_the_kernel_at_lowering(family, heads, kernel):
     """The same trace lowered for the CPU and, with no chip attached, for a
-    TPU: where the wide step's past is a page of one array under one
-    cached head (A.X-K1's absorbed step, whatever its query heads) the
-    TPU's text holds the Pallas kernel's custom call, called by every
-    latent attention, and no loop, the CPU's the loop and no custom call:
-    ``lax.platform_dependent`` in ``common._over_tiles``, resolved when the
-    program is lowered. No environment variable, configuration key or
-    model name is consulted, and none is set here. A page of keys and
-    values apart keeps the loop on both platforms, and the narrow step and
-    the prefill hold neither."""
+    TPU: the TPU's text of the wide step holds a Pallas kernel's custom
+    call, called by every attention over the filled tiles, and no loop over
+    them, the CPU's the loop and no custom call: ``lax.platform_dependent``
+    in ``common._over_tiles``, resolved when the program is lowered. Which
+    kernel follows from the page: one array under one cached head (A.X-K1's
+    absorbed step, whatever its query heads) is ``ops/latent_tiles.py``'s,
+    keys and values apart (``llama``; ``qwen3_next``'s three paging layers
+    of twelve) ``ops/paged_tiles.py``'s, and a program holds one of them.
+    No environment variable, configuration key or model name is consulted,
+    and none is set here. The narrow step and the prefill hold neither."""
     module, config = FAMILIES[family]
     tiny = config.tiny(num_attention_heads=heads) if family == "axk1" \
         else config.tiny()
@@ -195,16 +217,48 @@ def test_the_platform_chooses_the_kernel_at_lowering(family, heads,
     wide = "decode-past-two-tiles"
     for stage in cpu:
         assert "tpu_custom_call" not in cpu[stage]
-        assert cpu[stage].count("stablehlo.while") \
-            == (layers if stage == wide else 0)
         # one function a program holds the kernel (traced and lowered
-        # once, whatever the layers), and every latent attention calls it
-        kernels = layers if in_place and stage == wide else 0
-        assert tpu[stage].count('kernel_name = "latent_filled_tiles"') \
-            == (kernels > 0)
+        # once, whatever the layers), and every such attention calls it
+        kernels = layers if stage == wide else 0
+        for name in ("latent_filled_tiles", "paged_filled_tiles"):
+            assert tpu[stage].count(f'kernel_name = "{name}"') \
+                == (kernels > 0 and name == kernel)
         assert tpu[stage].count("call @over_filled_tiles") == kernels
-        assert tpu[stage].count("stablehlo.while") \
-            == cpu[stage].count("stablehlo.while") - kernels
+
+    def loops(texts, stage):
+        """Those a step holds beside the narrow step's own."""
+        return texts[stage].count("stablehlo.while") \
+            - texts["decode"].count("stablehlo.while")
+
+    assert loops(cpu, wide) == layers and loops(tpu, wide) == 0
+    assert loops(cpu, "decode-past-16-blocks") == 0 \
+        == loops(tpu, "decode-past-16-blocks")
+
+
+def test_the_readers_of_one_page_call_one_kernel():
+    """Phi-4-mini-flash's wide step reads the one paging layer's filled
+    tiles eight times (``attn.full`` and the seven ``attn.cross`` readers,
+    which are one ``lax.scan``: two call sites at any depth): lowered for a
+    TPU it holds ``ops/paged_tiles.py``'s kernel once, called twice, and no
+    loop over tiles beside its two scans; the CPU's text holds a loop over
+    tiles at each site and no custom call. The narrow step and the prefill
+    hold neither."""
+    cfg = dataclasses.replace(phi4flash.Phi4FlashConfig.tiny(),
+                              dtype="bfloat16")
+    assert phi4flash.cache_spec(cfg).layers == 1
+    cpu = programs(phi4flash, cfg)
+    tpu = programs(phi4flash, cfg, platform="tpu")
+    wide = "decode-past-two-tiles"
+    for stage in cpu:
+        assert "tpu_custom_call" not in cpu[stage]
+        assert cpu[stage].count("stablehlo.while") \
+            == tpu[stage].count("stablehlo.while") + 2 * (stage == wide)
+        assert tpu[stage].count('kernel_name = "paged_filled_tiles"') \
+            == (stage == wide)
+        assert tpu[stage].count("call @over_filled_tiles") \
+            == 2 * (stage == wide)
+    assert tpu[wide].count("stablehlo.while") \
+        == tpu["decode"].count("stablehlo.while") == 2
 
 
 def test_a_scanned_stack_holds_each_kernel_once():

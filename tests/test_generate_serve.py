@@ -632,13 +632,21 @@ class TestDevicePool:
             assert req.result(5) == np.asarray(llama.generate(
                 params, cfg, jnp.asarray([prompt]), 3))[0].tolist()
 
-    @pytest.mark.parametrize("family,platform,longest,in_place", [
-        ("axk1", "tpu", 70, True), ("axk1", "cpu", 70, False),
-        ("axk1", "tpu", 18, False), ("llama", "tpu", 70, False)],
+    @pytest.mark.parametrize("family,platform,longest,pool,in_place", [
+        ("axk1", "tpu", 70, {}, True), ("axk1", "cpu", 70, {}, False),
+        ("axk1", "tpu", 18, {}, False), ("llama", "tpu", 70, {}, True),
+        ("llama", "cpu", 70, {}, False), ("llama", "tpu", 18, {}, False),
+        ("llama", "tpu", 70, {"meshed": True}, False),
+        ("llama", "tpu", 70,
+         {"tile_bytes": kvcache.KERNEL_BYTES // 4 + 1}, False)],
         ids=["a-latent-page-on-a-tpu", "on-the-cpu", "narrow",
-             "keys-and-values-apart"])
+             "keys-and-values-apart-on-a-tpu",
+             "keys-and-values-apart-on-the-cpu",
+             "keys-and-values-apart-narrow",
+             "keys-and-values-apart-on-several-chips",
+             "keys-and-values-apart-a-tile-past-the-buffers"])
     def test_a_step_books_the_positions_it_reads_in_place(
-            self, family, platform, longest, in_place):
+            self, family, platform, longest, pool, in_place):
         """``kv_positions_in_place`` on ``serve.decode-step``: the
         positions of ``kv_positions_read`` the step's attention reads from
         the pool itself, with no gathered copy. All of them where the
@@ -661,8 +669,10 @@ class TestDevicePool:
             _module, params, cfg = _tiny(family)
         engine = GenEngine(params, cfg, max_batch=3, queue_limit=8,
                            max_new_tokens=4, kv_mb=1, block_tokens=2)
-        assert engine.pool.platform == "cpu"
+        assert engine.pool.platform == "cpu" and engine.pool.meshed is None
         engine.pool.platform = platform
+        for name, value in pool.items():
+            setattr(engine.pool, name, value)
         trace.reset()
         trace.enable()
         try:
@@ -864,7 +874,8 @@ class TestDevicePool:
                                                            monkeypatch):
         """A family made here of two step functions: its ``step_decode``
         gets a ``kvcache.Paged`` (the pool's arrays, the batch's block
-        table) and nothing else, with no word from the module about how it
+        table, and whether the pool lies on several chips, which a module
+        never reads: ``kvcache._in_place``) and nothing else, with no word from the module about how it
         wants its cache. The "model" keeps a token's id as its key and
         chooses the sum of the row's live cached keys and the fed token:
         right only if the table names the row's own blocks, in order, and
@@ -927,7 +938,8 @@ class TestDevicePool:
             assert out == want
         pool = engine.pool
         assert pool.in_use_blocks == 0
-        assert kvcache.Paged._fields == ("k", "v", "table", "state", "slots")
+        assert kvcache.Paged._fields == ("k", "v", "table", "state", "slots",
+                                         "meshed")
         assert handed and all(
             kind is kvcache.Paged and shape == pool.k.shape
             and table[0] in (1, 2, 4)
@@ -958,6 +970,9 @@ class TestDevicePool:
                     heads = {s.data.shape[2]
                              for s in pool.k.addressable_shards}
                     assert heads == {cfg.num_key_value_heads // 2}
+                # on several chips a wide step keeps its loop
+                # (kvcache._in_place)
+                assert pool.meshed is (None if m is None else True)
                 assert pool.k.sharding == pool.sharding
             finally:
                 engine.stop()
@@ -966,7 +981,9 @@ class TestDevicePool:
         # KV heads that do not divide tp: replicated, as _head_align says
         odd = KVBlockPool(CacheSpec(2, 3, 8), block_tokens=4, budget_mb=1,
                           mesh=mesh)
-        assert odd.k.sharding.is_fully_replicated
+        # a copy a chip is a program of two chips still
+        assert odd.k.sharding.is_fully_replicated and odd.meshed is True
+        assert odd.tile_bytes == kvcache.TILE_BLOCKS * 3 * 4 * 8 * 4
 
     @pytest.mark.parametrize("stage", ["prefill", "decode", "decode-ahead",
                                        "decode-pull", "prefill-ahead",

@@ -16,7 +16,11 @@ it; and on a TPU it is the Pallas kernel of ``ops/latent_tiles.py`` a
 latent attention (PR 45), which reads the tiles from the pool where they
 lie: no chunk of gathered tiles, no loop. The same carry over pages of keys
 and values, one reading layer of ``phi-4-mini-flash`` and of ``qwen3-next``
-at their cells' rows (~2 s each): the portable loop, and its temporaries.
+at their cells' rows (~2 s each): since PR 50 the kernel of
+``ops/paged_tiles.py``; where a tile is past the kernel's buffers, and
+where the pool lies on the four chips of the described host (``tp`` = 4 over
+``yi-1.5-34b``'s heads, a latent page a copy a chip), the loop as the
+parent's program had it.
 
 And of LongCat-Flash's 64-row step over a page of 8 sublayers (~20 s): the
 same page, the same carry and the same kernel in each of its two latent
@@ -56,10 +60,10 @@ from demodel_tpu.serve import kvcache
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def chips():
+    """The four chips of a described v5e host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -70,9 +74,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(chips[0])
 
 
 #: the benchmark's pool: 2 520 MiB of blocks of 16 positions, and the scratch
@@ -115,6 +126,24 @@ def _in_place(text: str, attentions: int, rows: int, heads: int,
     assert not _copied(text)
     # the carry: a row's weighted values, under every head
     assert re.search(rf"f32\[{rows},{heads},{values}\]", text)
+
+
+def _apart_in_place(text: str, calls: int, Hkv: int, block_tokens: int,
+                    hd: int) -> None:
+    """The compiled program holds the kernel over pages of keys and values
+    apart ``calls`` times (both pools handed to it as they lie), under
+    ``attn.tiles``; no loop there, and no chunk of gathered tiles."""
+    held = [line for line in text.splitlines()
+            if " custom-call(" in line and "paged_filled_tiles" in line]
+    assert len(held) == calls
+    assert all('custom_call_target="tpu_custom_call"' in c
+               and "attn.tiles" in c for c in held)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "attn.tiles" in line]
+    chunk = kvcache.TILE_CHUNK * kvcache.TILE_BLOCKS
+    assert not re.findall(rf"\[{chunk},{Hkv},{block_tokens},{hd}\]", text)
+    assert not re.findall(rf"\[{kvcache.TILE_CHUNK},{kvcache.TILE_BLOCKS},"
+                          rf"{Hkv},{block_tokens},{hd}\]", text)
 
 
 def _grouped(lowered, sparse: int) -> str:
@@ -212,19 +241,22 @@ def test_the_wide_step_keeps_no_partials_of_the_tables_capacity(one_chip):
     (phi4flash, phi4flash.Phi4FlashConfig, "phi-4-mini-flash"),
     (qwen3_next, qwen3_next.Qwen3NextConfig, "qwen3-next-80b-l12-ep4"),
 ], ids=["phi-4-mini-flash", "qwen3-next"])
-def test_the_portable_loop_carries_a_row_over_keys_and_values(
+def test_keys_and_values_apart_are_read_where_they_lie(
         one_chip, family, config, name):
     """One reading layer's attention over the filled tiles of pages of keys
     and values, at the published head counts and the cell's rows, compiled
     for the described chip at both wide widths (256 and 2 048 table slots a
-    row): the loop of ``common._over_tiles``, which carries a row. The
-    program holds no float32 array of the table's capacity, and its
-    temporaries are 1.61 and 1.73 MB for ``phi-4-mini-flash`` (32 rows, 40
-    query heads over 10 pairs of 128) and 1.48 and 2.20 MB for
-    ``qwen3-next`` (16 rows, 16 heads over 2 of 256). The loop that left
-    its partials a tile of the capacity, which these two ran up to PR 46,
-    kept ``f32[512,10,4,1,130]`` and 26.96 MB (420.1 MB at the next width)
-    and ``f32[256,2,8,1,258]`` and 1.10 MB (17.95 MB)."""
+    row): since PR 50 the kernel of ``ops/paged_tiles.py``, which copies a
+    tile's K blocks and V blocks from the pools into fast memory, and
+    carries a row. The program holds no loop, no chunk of gathered tiles
+    (``bf16[2048,10,16,128]`` and ``bf16[2048,2,16,256]``: 42 and 34 MB,
+    once for the keys and once for the values, a trip) and no float32
+    array of the table's capacity; its temporaries are 2.03 and 2.61 MB for
+    ``phi-4-mini-flash`` (32 rows, 40 query heads over 10 pairs of 128) and
+    1.35 and 2.24 MB for ``qwen3-next`` (16 rows, 16 heads over 2 of 256):
+    the index of the tiles, which the kernel takes as scalars. The loop,
+    which stays as every other platform's program, held 1.61 and 1.73 MB
+    and 1.48 and 2.20 MB beside its chunks."""
     doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
                       / f"{name}.json").read_text())
     engine = doc.pop("benchmark")["engine"]
@@ -250,13 +282,169 @@ def test_the_portable_loop_carries_a_row_over_keys_and_values(
             shaped((rows,), jnp.int32), shaped((rows, slots), jnp.int32),
             *(shaped(page),) * 2).compile()
         text = compiled.as_text()
-        assert [line for line in text.splitlines()
-                if " while(" in line and "attn.tiles" in line]
-        assert "tpu_custom_call" not in text    # no kernel: pages apart
+        _apart_in_place(text, 1, Hkv, bt, hd)
         capacity = rows * slots // kvcache.TILE_BLOCKS
         assert not re.findall(rf"f32\[{capacity},[\d,]*\]", text)
-        assert re.search(rf"f32\[{rows},{Hkv},{H // Hkv},1,{hd}\]", text)
+        assert re.search(rf"f32\[{rows},{Hkv},{H // Hkv},{hd}\]", text)
         assert compiled.memory_analysis().temp_size_in_bytes < 3e6
+
+
+@pytest.mark.parametrize("Hkv,hd,bt,dtype,kernel", [
+    (32, 128, 16, jnp.bfloat16, True), (8, 128, 32, jnp.bfloat16, True),
+    (32, 128, 16, jnp.float32, False), (10, 128, 64, jnp.bfloat16, False)],
+    ids=["32-heads-of-128", "blocks-of-32", "32-heads-in-float32",
+         "blocks-of-64"])
+def test_a_tile_past_the_kernels_buffers_keeps_the_loop(one_chip, Hkv, hd,
+                                                        bt, dtype, kernel):
+    """``block_tokens`` is the engine's argument and ``llama`` takes any
+    checkpoint's heads, so a tile of keys (16 blocks, all heads) has no
+    bound of its own; the kernel holds four. Where they fit
+    ``kvcache.KERNEL_BYTES`` (8 MiB: 32 heads of 128 in bfloat16 at 16
+    positions a block are exactly that, the largest tile the rule admits)
+    the program compiled for the described chip holds the kernel, so what
+    the rule admits does fit the fast memory a kernel is given; past it
+    (the same heads in float32: 16 MiB; Phi-4-mini-flash's pairs at 64
+    positions a block: 10 MiB) ``Tiles.in_place`` is false and the program
+    is the loop, as the parent's was, and compiles too."""
+    rows, g, slots = 8, 2, 256
+    tile = kvcache.TILE_BLOCKS * Hkv * bt * hd * jnp.dtype(dtype).itemsize
+    assert (4 * tile <= kvcache.KERNEL_BYTES) == kernel
+
+    def shaped(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def layer(q, k, v, lengths, table, pk, pv):
+        cache = kvcache.Paged(pk, pv, table)
+        past = cache.past(0, cache.filled(lengths))
+        assert past.in_place == kernel
+        return attend(q, k, v, lengths[:, None], past=past)
+
+    text = jax.jit(layer).lower(
+        shaped((rows, 1, g * Hkv, hd)), *(shaped((rows, 1, Hkv, hd)),) * 2,
+        shaped((rows,), jnp.int32), shaped((rows, slots), jnp.int32),
+        *(shaped((1, 4096, Hkv, bt, hd)),) * 2).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "paged_filled_tiles" in line]
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "attn.tiles" in line]
+    assert (len(calls), len(loops)) == ((1, 0) if kernel else (0, 1))
+
+
+def _gathered(text: str) -> list[int]:
+    """The bytes of every array a compiled program gathers from the other
+    chips (a result may be a tuple of them)."""
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    return [int(np.prod([int(d) for d in dims.split(",")])) * sizes[kind]
+            for line in text.splitlines()
+            for op in (re.search(r"= (.*?) all-gather(-start)?\(", line),)
+            if op
+            for kind, dims in re.findall(r"(\w+)\[([\d,]+)\]", op.group(1))]
+
+
+def test_a_pool_across_four_chips_keeps_the_loop(chips):
+    """``yi-1.5-34b-l40-tp4``'s wide decode step (two of its layers, 8 rows
+    at 256 table slots) compiled for the four described chips as the engine
+    builds it under ``tp`` = 4, the pool split over its 8 KV heads
+    (``KVBlockPool.meshed``): the program is the parent's, a loop over the
+    filled tiles a layer which the compiler partitions by head (a chip
+    gathers a chunk of ITS two heads, ``bf16[2048,2,16,128]``), no Pallas
+    call, and nothing gathered from the other chips but a step's ids: no
+    all-gather of a pool, which a custom call the compiler has no rule for
+    would need. Without what the pool hands its programs the step does not
+    lower for the four chips at all: JAX refuses a Pallas call in a
+    program of several chips outside a ``shard_map``."""
+    import dataclasses
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from demodel_tpu.models import llama
+
+    mesh = Mesh(np.array(chips).reshape(1, 4), ("dp", "tp"))
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / "yi-1.5-34b-l40-tp4.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = dataclasses.replace(llama.LlamaConfig.from_hf(doc),
+                              num_hidden_layers=2)
+    rows, bt, slots = engine["max_batch"], engine["block_tokens"], 256
+    made = []
+
+    def make():
+        made.append(kvcache.KVBlockPool(
+            llama.cache_spec(cfg), block_tokens=bt,
+            budget_mb=engine["kv_mb"], dtype=cfg.dtype, mesh=mesh))
+        return made[0].arrays
+
+    arrays = jax.eval_shape(make)
+    pool, = made
+    assert pool.meshed is True and pool.platform == "tpu"
+    assert pool.sharding.spec == P(None, None, "tp", None, None)
+    assert pool.positions_in_place(slots, 4096) == 0
+    rep = NamedSharding(mesh, P())
+
+    def lowered(meshed):
+        def decode(params, table, lengths, tokens, k, v):
+            cache = kvcache.Paged(k, v, table, meshed=meshed)
+            logits, new = llama.step_decode(params, tokens, cfg, cache,
+                                            lengths, mesh=mesh)
+            pages, _ = kvcache.parts(new)
+            return (jnp.argmax(logits, axis=-1), *kvcache.put_positions(
+                k, v, pages, table[:, 0], lengths % bt))
+
+        params = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            jax.eval_shape(lambda: llama.init_params(jax.random.key(1),
+                                                     cfg)),
+            llama.param_shardings(cfg, mesh))
+        vector = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=rep)
+        return jax.jit(decode, donate_argnums=(4, 5),
+                       out_shardings=(rep, *pool.shardings)).lower(
+            params, jax.ShapeDtypeStruct((rows, slots), jnp.int32,
+                                         sharding=rep), vector, vector,
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=pool.sharding)
+              for a in arrays))
+
+    text = lowered(pool.meshed).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert len([line for line in text.splitlines()
+                if " while(" in line and "attn.tiles" in line]) == 2
+    heads = cfg.num_key_value_heads // 4
+    chunk = kvcache.TILE_CHUNK * kvcache.TILE_BLOCKS
+    assert re.findall(rf"bf16\[{chunk},{heads},{bt},{cfg.head_dim}\]", text)
+    gathered = _gathered(text)
+    assert gathered and max(gathered) < 1 << 10
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lowered(None)
+
+
+def test_a_latent_page_on_four_chips_keeps_the_loop(chips):
+    """A latent attention over the filled tiles of a page of one array (16
+    heads over one cached vector of 640 columns, a copy of the pool on each
+    of four chips, as under ``ep``) lowered for the four described chips:
+    with what the pool hands its programs it is the loop; without, as
+    before PR 50, it does not lower (``ops/latent_tiles.py``'s kernel in a
+    program of several chips)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    rep = NamedSharding(Mesh(np.array(chips), ("ep",)), P())
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    def lowered(meshed):
+        def layer(q, k, lengths, table, pk):
+            cache = kvcache.Paged(pk, None, table, meshed=meshed)
+            return attend(q, k, k[..., :512], lengths[:, None], scale=0.1,
+                          past=cache.past(0, cache.filled(lengths)))
+
+        return jax.jit(layer).lower(
+            shaped((8, 1, 16, 640)), shaped((8, 1, 1, 640)),
+            shaped((8,), jnp.int32), shaped((8, 256), jnp.int32),
+            shaped((2, 1024, 1, 16, 640)))
+
+    text = lowered(True).as_text()
+    assert "tpu_custom_call" not in text and "stablehlo.while" in text
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lowered(None)
 
 
 def test_the_double_layers_step_reads_its_page_and_weights_as_held(one_chip):
@@ -366,28 +554,136 @@ def _operation(line: str) -> tuple[str, str] | None:
     return (kind, op.group(1)) if op else None
 
 
-def _operations(text: str) -> tuple[int, int]:
-    """``(a trip of the program's one loop, the rest)``: the instructions
-    of a compiled program that run on the device as operations of their
-    own, counted as the profiler's trace lists them: no parameter, tuple,
-    constant or bitcast, and nothing that is a scalar."""
+def _runs(line: str) -> bool:
+    """An instruction that runs on the device as an operation of its own,
+    as the profiler's trace lists them: no parameter, tuple, constant,
+    bitcast or loop (its body's run), and nothing that is a scalar."""
+    found = _operation(line)
+    return bool(found) and found[1] not in (
+        "parameter", "get-tuple-element", "tuple", "constant", "bitcast",
+        "while") and not re.match(r"(s32|u32|pred)\[1?\]", found[0])
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """A compiled program's computations by name (the entry's ``ENTRY``),
+    each the lines of its instructions."""
     blocks, name = {}, None
     for line in text.splitlines():
         head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
         if head:
             name = "ENTRY" if head.group(1) else head.group(2)
-            blocks[name] = 0
+            blocks[name] = []
         elif line.startswith("}"):
             name = None
         elif name:
-            found = _operation(line)
-            if found and found[1] not in (
-                    "parameter", "get-tuple-element", "tuple", "constant",
-                    "bitcast", "while") and not re.match(
-                    r"(s32|u32|pred)\[1?\]", found[0]):
-                blocks[name] += 1
+            blocks[name].append(line)
+    return blocks
+
+
+def _operations(text: str) -> tuple[int, int]:
+    """``(a trip of the program's one loop, the rest)``: the device
+    operations (:func:`_runs`) of a compiled program."""
+    blocks = _computations(text)
     (body,) = re.findall(r"while\(.*?body=%?([\w.\-]+)", text)
-    return blocks[body], blocks["ENTRY"]
+    return sum(map(_runs, blocks[body])), sum(map(_runs, blocks["ENTRY"]))
+
+
+def _step_operations(text: str, loops: dict, scans: dict) -> int:
+    """The device operations (:func:`_runs`) of one run of a compiled
+    program whose loops nest: the entry's, and each loop's body's times
+    its trips. The compiled text names no trip count, so the caller does:
+    ``loops`` by a scope in the loop's own name (the loop over the chunks
+    of filled tiles, a gather the compiler made a loop over its rows),
+    ``scans`` for a loop under no scope (a ``lax.scan`` of layers) by a
+    scope found in its body."""
+    blocks = _computations(text)
+
+    def run(name):
+        total = sum(map(_runs, blocks[name]))
+        for line in blocks[name]:
+            if (_operation(line) or ("", ""))[1] != "while":
+                continue
+            body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+            scope = re.search(r'op_name="([^"]*)"', line).group(1)
+            if scope.count("/") == 1:       # jit(decode)/while: a scan
+                (trips,) = {n for key, n in scans.items()
+                            if any(key in inner for inner in blocks[body])}
+            else:
+                (trips,) = {n for key, n in loops.items() if key in scope}
+            total += trips * run(body)
+        return total
+
+    return run("ENTRY")
+
+
+def _wide_step(one_chip, family, config, name: str):
+    """``(compiled text, cache spec, block_tokens)`` of a configuration's
+    decode step at its cell's rows and 256 table slots a row, compiled for
+    the described chip as the engine builds it (the pools and the slots
+    donated; keys and values apart, slots beside them)."""
+    doc = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                      / f"{name}.json").read_text())
+    engine = doc.pop("benchmark")["engine"]
+    cfg = config.from_hf(doc)
+    spec = family.cache_spec(cfg)
+    rows, bt, slots = engine["max_batch"], engine["block_tokens"], 256
+    pool = jax.eval_shape(lambda: kvcache.KVBlockPool(
+        spec, slots=rows, block_tokens=bt, budget_mb=engine["kv_mb"],
+        dtype=cfg.dtype).arrays)
+    names = [n for n, *_ in spec.state]
+
+    def shaped(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def decode(params, table, lengths, tokens, slot, k, v, *state):
+        cache = kvcache.Paged(k, v, table, dict(zip(names, state)), slot)
+        logits, new, *stats = family.step_decode(params, tokens, cfg, cache,
+                                                 lengths)
+        pages, fresh = kvcache.parts(new)
+        return (jnp.argmax(logits, axis=-1), stats,
+                *kvcache.put_positions(k, v, pages, table[:, 0],
+                                       lengths % bt),
+                *kvcache.put_slots(state, names, fresh, slot))
+
+    params = jax.tree.map(shaped, jax.eval_shape(
+        lambda: family.init_params(jax.random.key(1), cfg)))
+    vector = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        decode, donate_argnums=tuple(range(5, 5 + len(pool)))).lower(
+        params, jax.ShapeDtypeStruct((rows, slots), jnp.int32,
+                                     sharding=one_chip),
+        vector, vector, vector, *map(shaped, pool)).compile()
+    return compiled.as_text(), spec, bt
+
+
+@pytest.mark.parametrize("family,config,name,calls,rows,parents", [
+    (phi4flash, phi4flash.Phi4FlashConfig, "phi-4-mini-flash", 2, 32, 3983),
+    (qwen3_next, qwen3_next.Qwen3NextConfig, "qwen3-next-80b-l12-ep4", 3, 16,
+     3249),
+], ids=["phi-4-mini-flash", "qwen3-next"])
+def test_a_wide_step_reads_keys_and_values_where_they_lie(
+        one_chip, family, config, name, calls, rows, parents):
+    """``phi-4-mini-flash``'s and ``qwen3-next-80b-l12-ep4``'s decode steps
+    as ``phi4flash-reason`` and ``qwen3next-doc`` run them (32 and 16 rows
+    at 256 table slots each), compiled for the described chip: every
+    attention over the filled tiles is the kernel of ``ops/paged_tiles.py``
+    under ``attn.tiles`` (Phi-4-mini-flash's eight readers are two call
+    sites, ``attn.full`` and the body of the scan that is the seven
+    ``attn.cross`` layers; Qwen3-Next's three paging layers three), there
+    is no loop under ``attn.tiles`` and nothing of a gathered chunk (42 and
+    34 MB, for the keys and again for the values, a trip). **The device
+    operations a step are fewer than the parent's**: 3 444 where 3 983 and
+    3 104 where 3 249, both counted by :func:`_step_operations` with the
+    parent's loops over the tiles at the two trips the cells' fills take
+    (242 and ~160 filled tiles in chunks of 128), a gather the compiler
+    made a loop over its rows at the rows, Phi-4-mini-flash's two scans at
+    their 8 and 7 layers (PERF.md section 6, PR 50; a profile of the
+    parent's step counted 4 230)."""
+    text, spec, bt = _wide_step(one_chip, family, config, name)
+    _apart_in_place(text, calls, spec.kv_heads, bt, spec.head_dim)
+    count = _step_operations(text, {"attn.tiles": 2, "_take": rows},
+                             {"attn.cross": 7, "attn.window": 8})
+    assert count < parents - 100, count
 
 
 def test_the_scanned_step_reads_its_page_tails_and_experts_as_held(one_chip):
